@@ -34,9 +34,7 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import numpy as np  # noqa: E402
+import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -140,6 +138,10 @@ def run_phase(host, port, queries):
 
 
 def main():
+    import jax
+
+    from pilosa_tpu.utils.jaxenv import enable_compile_cache
+    enable_compile_cache()
     import tempfile
 
     from pilosa_tpu.server import API, serve
@@ -149,7 +151,7 @@ def main():
     out = {"metric": "coalescer_serving_speedup", "unit": "x",
            "threads": N_THREADS, "queries_per_thread": N_QUERIES,
            "distinct_rows": N_ROWS, "shards": N_SHARDS,
-           "platform": "cpu"}
+           "platform": jax.devices()[0].platform}
     with tempfile.TemporaryDirectory() as tmp:
         log("bench: building holder")
         h = build(tmp)

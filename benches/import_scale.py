@@ -1,4 +1,4 @@
-"""Native import-path thread scaling (VERDICT r3 next #5).
+"""Native import-path thread scaling.
 
 Measures pn_import_build throughput (the fragment bulk-import hot path,
 reference fragment.go:1494-1604 + errgroup-parallel forwarding

@@ -186,6 +186,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     ap.add_argument("--iters", type=int, default=200)
     ap.add_argument("--seed", type=int, default=7)
     args = ap.parse_args(argv)
+    from pilosa_tpu.utils.jaxenv import enable_compile_cache
+    enable_compile_cache()
     rec = run(shards=args.shards, rows=args.rows, alpha=args.alpha,
               base=args.base, iters=args.iters, seed=args.seed)
     import jax

@@ -21,8 +21,8 @@ Q = 64  # padded sparse-filter slots
 
 
 def main():
-    from pilosa_tpu.utils.benchenv import apply_bench_platform
-    apply_bench_platform()
+    from pilosa_tpu.utils.jaxenv import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
 

@@ -1,17 +1,15 @@
-"""Membership-kernel probe (VERDICT r5 #2): measure ns/position on the
-real device for each membership form over a fixed-layout positions bank
-shape (R x L u16, ~48-bit sparse filter):
+"""Membership-kernel probe: measure ns/position on the device for each
+membership form over a fixed-layout positions bank shape (R x L u16,
+~48-bit sparse filter):
 
-- compare: the [P] x [QCAP] equality fan-out (r4 default, ~1 ns/pos)
+- compare: the [P] x [QCAP] equality fan-out (the device default)
 - search:  binary search in the sorted query positions (log2 QCAP)
-- gather:  the filter-bit-table dynamic gather (r4's dense fallback)
-- pallas:  fused compare+rowsum, VMEM-resident query positions
-           (ops/pallas_kernels.pbank_membership_counts)
+- gather:  the filter-bit-table dynamic gather (the dense fallback)
 
-Timing: salted chains (identical-repeat timing is invalid on this
-backend — docs/perf.md §4b); each iteration XORs a salt derived from
-the previous result into the query positions so no sweep can be CSE'd.
-Prints one JSON line per variant."""
+Timing: salted chains (timing an identical repeat lets XLA reuse the
+previous result); each iteration XORs a salt derived from the previous
+result into the query positions so no sweep can be CSE'd. Prints one
+JSON line per variant, each naming the platform it ran on."""
 
 import json
 import os
@@ -29,12 +27,12 @@ ITERS = [4, 12]  # chain lengths for the slope
 
 
 def main():
-    from pilosa_tpu.utils.benchenv import apply_bench_platform
-    apply_bench_platform()
-    from pilosa_tpu.utils.benchenv import hold_for_tpu
-    hold_for_tpu("membership_probe")
+    from pilosa_tpu.utils.jaxenv import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
+
+    platform = jax.devices()[0].platform
 
     rng = np.random.default_rng(3)
     pos = np.sort(rng.integers(0, 4096, (R, L), dtype=np.uint16), axis=1)
@@ -44,11 +42,6 @@ def main():
 
     pos_dev = jnp.asarray(pos)
     qtop_dev = jnp.asarray(q32)
-    grouped = jnp.asarray(pos.view(np.uint32).reshape(R // 16,
-                                                      16 * (L // 2)))
-    qpad = np.full((8, 128), -1, np.int32)
-    qpad.reshape(-1)[:QK] = q32
-    qpad_dev = jnp.asarray(qpad)
     # Filter bit table for the gather form: 4096 bits = 128 u32 words.
     fw = np.zeros(128, np.uint32)
     for p in q:
@@ -79,7 +72,7 @@ def main():
         def chain(qt, k):
             def body(_, carry):
                 qt_c, acc = carry
-                c = fn(pos_dev if name != "pallas" else grouped, qt_c)
+                c = fn(pos_dev, qt_c)
                 s = (c[0] & 1).astype(qt_c.dtype)
                 return (qt_c ^ s, acc + c[-1])
             (_, acc) = jax.lax.fori_loop(
@@ -100,6 +93,7 @@ def main():
             / (ITERS[1] - ITERS[0])
         print(json.dumps({
             "metric": "pbank_membership_ns_per_position",
+            "platform": platform,
             "variant": name,
             "value": per_iter / positions * 1e9,
             "unit": "ns/position",
@@ -113,22 +107,9 @@ def main():
     results["search"] = run_variant("search", counts_search, qtop_dev)
     results["gather"] = run_variant("gather", counts_gather, qtop_dev)
 
-    from pilosa_tpu.ops import pallas_kernels as pk
-    if pk.available():
-        def counts_pallas(g, qt_pad):
-            return pk.pbank_membership_counts(g, qt_pad, qk=QK)
-        try:
-            results["pallas"] = run_variant("pallas", counts_pallas,
-                                            qpad_dev)
-        except Exception as e:
-            print(json.dumps({"variant": "pallas",
-                              "error": repr(e)[:400]}), flush=True)
-    else:
-        print(json.dumps({"variant": "pallas",
-                          "skipped": "no TPU backend"}), flush=True)
-
     best = min(results, key=results.get)
     print(json.dumps({"metric": "pbank_membership_best",
+                      "platform": platform,
                       "best": best,
                       "value": results[best] / positions * 1e9,
                       "unit": "ns/position",

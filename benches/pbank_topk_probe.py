@@ -18,8 +18,8 @@ BLOCK = int(os.environ.get("PILOSA_PROBE_BLOCK", 8192))
 
 
 def main():
-    from pilosa_tpu.utils.benchenv import apply_bench_platform
-    apply_bench_platform()
+    from pilosa_tpu.utils.jaxenv import enable_compile_cache
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
 

@@ -37,9 +37,7 @@ import sys
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import numpy as np  # noqa: E402
+import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -160,6 +158,10 @@ def run_phase(host, port, schedule):
 
 
 def main():
+    import jax
+
+    from pilosa_tpu.utils.jaxenv import enable_compile_cache
+    enable_compile_cache()
     import tempfile
 
     from pilosa_tpu.server import API, serve
@@ -173,7 +175,7 @@ def main():
            "distinct_rows": N_ROWS, "shards": N_SHARDS,
            "zipf_s": ZIPF_S,
            "repeat_fraction": round(repeat_fraction, 4),
-           "platform": "cpu"}
+           "platform": jax.devices()[0].platform}
     with tempfile.TemporaryDirectory() as tmp:
         log("bench: building holder")
         h = build(tmp)

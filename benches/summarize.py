@@ -1,8 +1,8 @@
-"""Summarize benches/*_r0N_{tpu,cpu}.jsonl records into one markdown
-table (for docs/perf.md and the round notes).
+"""Summarize benches/*_r0N_<backend>.jsonl records into one markdown
+table.
 
 Usage: python benches/summarize.py [round] [backend]
-       (defaults: round 4, backend tpu)
+       (defaults: round 4, backend cpu)
 
 Skips partial records (a leg killed mid-run leaves {"partial": true});
 flags invalid device-time rows (above-roofline measurements are stored
@@ -42,7 +42,7 @@ def fmt(v):
 
 def main():
     rnd = sys.argv[1] if len(sys.argv) > 1 else "4"
-    backend = sys.argv[2] if len(sys.argv) > 2 else "tpu"
+    backend = sys.argv[2] if len(sys.argv) > 2 else "cpu"
     base = os.path.dirname(os.path.abspath(__file__))
     paths = sorted(glob.glob(
         os.path.join(base, f"*_r0{rnd}_{backend}.jsonl")))

@@ -34,8 +34,8 @@ ITERS = 5
 
 
 def main():
-    from pilosa_tpu.utils.benchenv import apply_bench_platform
-    apply_bench_platform()
+    from pilosa_tpu.utils.jaxenv import enable_compile_cache
+    enable_compile_cache()
     from pilosa_tpu.core.holder import Holder
     from pilosa_tpu.executor import Executor
 
@@ -87,8 +87,10 @@ def main():
         cpu_t = time.perf_counter() - t0
         assert pairs == want.pairs, (pairs[:3], want.pairs[:3])
 
+        import jax
         print(json.dumps({
             "metric": "tanimoto_molecule_topn_p50_latency",
+            "platform": jax.devices()[0].platform,
             "value": tpu_t,
             "unit": "seconds",
             "vs_baseline": cpu_t / tpu_t,

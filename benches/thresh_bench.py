@@ -13,8 +13,8 @@ expansion leg runs with the optimizer ON too, so the comparison is
 "best possible expansion" vs the opcode — CSE already dedupes the
 shared subsets, and the gap that remains is the point of the opcode.
 
-One JSON line per (n, k) shape on stdout, appended to
-``thresh_r01_cpu.jsonl``. Env knobs: THRESH_BENCH_BITS (400000),
+One JSON line per (n, k) shape on stdout, naming the backend it ran
+on. Env knobs: THRESH_BENCH_BITS (400000),
 THRESH_BENCH_ROWS (16), THRESH_BENCH_QUERIES (8 per leg),
 THRESH_BENCH_REPEATS (3).
 """
@@ -27,9 +27,7 @@ import sys
 import tempfile
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-import numpy as np  # noqa: E402
+import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -39,8 +37,6 @@ N_ROWS = int(os.environ.get("THRESH_BENCH_ROWS", 16))
 N_QUERIES = int(os.environ.get("THRESH_BENCH_QUERIES", 8))
 REPEATS = int(os.environ.get("THRESH_BENCH_REPEATS", 3))
 SHAPES = ((4, 2), (6, 3), (8, 4))  # (n operands, k threshold)
-ARTIFACT = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                        "thresh_r01_cpu.jsonl")
 
 
 def log(msg):
@@ -48,10 +44,7 @@ def log(msg):
 
 
 def emit(rec):
-    line = json.dumps(rec)
-    print(line, flush=True)
-    with open(ARTIFACT, "a") as fh:
-        fh.write(line + "\n")
+    print(json.dumps(rec), flush=True)
 
 
 def operand_rows(q, n):
@@ -93,14 +86,16 @@ def run_leg(ex, reqs):
 
 
 def main():
+    import jax
+
+    from pilosa_tpu.utils.jaxenv import enable_compile_cache
+    enable_compile_cache()
     from pilosa_tpu.core.holder import Holder
     from pilosa_tpu.executor import Executor
     from pilosa_tpu.executor import megakernel as megamod
     from pilosa_tpu.ops.bitset import SHARD_WIDTH
 
     log(f"thresh-bench: building holder ({N_BITS} bits, {N_ROWS} rows)")
-    if os.path.exists(ARTIFACT):
-        os.remove(ARTIFACT)
     with tempfile.TemporaryDirectory() as tmp:
         h = Holder(tmp)
         h.open()
@@ -141,7 +136,7 @@ def main():
                         e_stats["plan_entries"]
                         / max(1, t_stats["plan_entries"]), 2),
                     "bit_identical": True,
-                    "backend": "cpu",
+                    "backend": jax.devices()[0].platform,
                 })
         finally:
             megamod.MEGAKERNEL_ENABLED = prev

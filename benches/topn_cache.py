@@ -4,8 +4,8 @@ cache.go:136 + fragment.top, fragment.go:1067)."""
 import json, os, sys, tempfile, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import numpy as np
-from pilosa_tpu.utils.benchenv import apply_bench_platform
-apply_bench_platform()
+from pilosa_tpu.utils.jaxenv import enable_compile_cache
+enable_compile_cache()
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.executor import Executor
 
@@ -21,8 +21,8 @@ with tempfile.TemporaryDirectory() as tmp:
     load_s = time.perf_counter() - t0
     ex = Executor(h)
     (want,) = ex.execute("c2", "TopN(f, n=10)")  # warm
-    from pilosa_tpu.utils.benchenv import measurement_context
-    ctx = measurement_context()
+    import jax
+    ctx = {"platform": jax.devices()[0].platform}
     times = []
     for _ in range(200):
         t0 = time.perf_counter()

@@ -15,10 +15,16 @@ TPU-first:
   durability uses the reference's roaring file format (cookie 12348).
 """
 
-from pilosa_tpu.ops.bitset import (  # noqa: F401
-    SHARD_WIDTH,
-    WORDS_PER_SHARD,
-    WORD_BITS,
-)
-
 __version__ = "0.1.0"
+
+_BITSET_EXPORTS = ("SHARD_WIDTH", "WORDS_PER_SHARD", "WORD_BITS")
+
+
+def __getattr__(name):
+    # Resolved on first use, not at import: ops.bitset imports jax, and
+    # the storage codec (pilosa_tpu.storage) must stay importable by a
+    # process that is not allowed to load it (chip_smoke.py's parent).
+    if name in _BITSET_EXPORTS:
+        from pilosa_tpu.ops import bitset
+        return getattr(bitset, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

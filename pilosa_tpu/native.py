@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import contextlib
 import ctypes
+import logging
 import os
 import subprocess
 from pilosa_tpu.utils.locks import make_lock
@@ -48,6 +49,7 @@ _lock = make_lock("native._lock")
 # a failed sanitizer load must not poison a later plain request). The
 # key space is closed: '' plus _SAN_VARIANTS.
 _libs: Dict[str, Optional[ctypes.CDLL]] = {}
+_load_errors: Dict[str, str] = {}
 _libc: Optional[ctypes.CDLL] = None
 _force_python = 0
 
@@ -66,17 +68,23 @@ def _so_path(san: str) -> str:
     return os.path.join(_NATIVE_DIR, name)
 
 
-def _build(san: str) -> bool:
+def _build(san: str) -> Optional[str]:
+    """Run make; None on success, else why it failed (make's stderr)."""
     if not os.path.isdir(_NATIVE_DIR):
-        return False
+        return f"{_NATIVE_DIR} is not a directory"
     cmd = ["make", "-C", _NATIVE_DIR]
     if san:
         cmd.append(f"SAN={san}")
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        return os.path.exists(_so_path(san))
-    except (OSError, subprocess.SubprocessError):
-        return False
+    except subprocess.CalledProcessError as e:
+        return (e.stderr or b"").decode("utf-8", "replace").strip() \
+            or f"make exited {e.returncode}"
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return repr(e)
+    if not os.path.exists(_so_path(san)):
+        return f"make succeeded but {_so_path(san)} is missing"
+    return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:
@@ -183,18 +191,41 @@ def load() -> Optional[ctypes.CDLL]:
         # lock EXISTS to make every caller wait for the single
         # first-touch make; there is nothing useful to do before the
         # library is bound, so blocking under it is the point.
-        if _build(san) or os.path.exists(so_path):
+        error = _build(san)
+        if error is None or os.path.exists(so_path):
             try:
                 lib = _bind(ctypes.CDLL(so_path))
-            except (OSError, AttributeError):
+                error = None
+            except (OSError, AttributeError) as e:
                 # AttributeError = missing symbol in a stale library
                 # that make could not refresh; OSError also covers a
                 # sanitizer runtime that is not preloaded into this
                 # process. Fall back to the Python paths either way.
-                lib = None
+                error = f"{error or 'make ok'}; bind failed: {e!r}"
+        if lib is None:
+            # Once per variant (the result is cached below): storage
+            # takes the numpy paths from here on, which is several
+            # times slower on ingest — the operator must see why.
+            # graftlint: disable=GL008 — same closed key space as _libs
+            _load_errors[san] = error or "unknown"
+            logging.getLogger(__name__).warning(
+                "native library %s unavailable, storage falls back to "
+                "numpy paths: %s", os.path.basename(so_path),
+                _load_errors[san])
         # graftlint: disable=GL008 — closed key space ('' + 3 variants)
         _libs[san] = lib
         return lib
+
+
+def status() -> Tuple[bool, str]:
+    """(loaded, reason-when-not) for the start line, GET /info and
+    chip_smoke.py — a server on the numpy fallback says so."""
+    if os.environ.get("PILOSA_TPU_NO_NATIVE"):
+        return False, "disabled by PILOSA_TPU_NO_NATIVE"
+    if load() is not None:
+        return True, ""
+    return False, _load_errors.get(active_san(),
+                                   "unknown sanitizer variant")
 
 
 def available() -> bool:

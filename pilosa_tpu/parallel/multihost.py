@@ -75,6 +75,12 @@ def child(process_id: int, coordinator: str) -> None:
 
     assert len(jax.devices()) == SHARDS, jax.devices()
     assert len(jax.local_devices()) == DEVICES_PER_PROCESS
+    # What cmd_server and GET /info call: it must answer for this
+    # process's devices only — memory_stats() raises on the other
+    # host's.
+    from pilosa_tpu.utils.jaxenv import describe_devices
+    assert [d["id"] for d in describe_devices()] == \
+        [d.id for d in jax.local_devices()]
     mesh = MeshContext()  # all global devices, shard axis
     sharding = NamedSharding(mesh.mesh, P(None, MeshContext.SHARD_AXIS,
                                           None))

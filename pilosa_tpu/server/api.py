@@ -957,9 +957,7 @@ class API:
         per-opcode instruction table and per-kind byte splits priced
         by ops/megakernel.plan_cost, per-cohort achieved bandwidth
         EWMAs from the profiler's sampled fences, and the
-        predicted-vs-measured cost-model residuals ranked by drift —
-        the live replacement for docs/perf.md's hand-run roofline
-        micro legs."""
+        predicted-vs-measured cost-model residuals ranked by drift."""
         from pilosa_tpu.utils.roofline import ROOFLINE
         node_id, _ = self._node_ident()
         self.refresh_memory_gauges()
@@ -1167,6 +1165,7 @@ class API:
         ledger totals, coalescer queue depth, jit-cache/retrace/fusion
         counters, slow-query count, watchdog state. The coordinator's
         cluster_health() merges one of these per node."""
+        from pilosa_tpu.executor import megakernel as _megamod
         from pilosa_tpu.utils.hotspots import WORKLOAD
         from pilosa_tpu.utils.memledger import LEDGER
         from pilosa_tpu.utils.sentinel import SENTINEL as _SENTINEL
@@ -1209,6 +1208,7 @@ class API:
                 # Heterogeneous megakernel (executor/megakernel.py):
                 # mixed-signature flushes collapsed to single
                 # plan-buffer launches, and what those plans cost.
+                "megakernelEnabled": _megamod.MEGAKERNEL_ENABLED,
                 "megaLaunches": self.executor.mega_launches,
                 "megaQueries": self.executor.mega_queries,
                 "megaPlanEntries": self.executor.mega_plan_entries,
@@ -2194,11 +2194,30 @@ class API:
 
     def info(self) -> Dict[str, Any]:
         import os
+
+        import jax
+
+        from pilosa_tpu import native
+        from pilosa_tpu.utils.jaxenv import describe_devices
+        native_loaded, native_error = native.status()
+        mesh = self.executor.mesh
         # tailDroppedBytes > 0 means torn op-log tails were sidecarred at
         # open — data the operator should know was dropped (ADVICE r2).
         return {"shardWidth": SHARD_WIDTH, "cpuPhysicalCores": os.cpu_count(),
                 "version": __version__,
-                "tailDroppedBytes": self.holder.tail_dropped_bytes()}
+                "tailDroppedBytes": self.holder.tail_dropped_bytes(),
+                # Where this process actually runs: a server that came
+                # up on the CPU says so here, per device it addresses,
+                # with the allocator's own byte counters. deviceCount
+                # is global (other hosts' included under
+                # jax.distributed).
+                "devices": describe_devices(),
+                "deviceCount": jax.device_count(),
+                "meshDevices": (int(mesh.mesh.devices.size)
+                                if mesh is not None else 1),
+                "compileCacheDir": jax.config.jax_compilation_cache_dir,
+                "native": {"loaded": native_loaded,
+                           "error": native_error}}
 
     def version(self) -> Dict[str, str]:
         return {"version": __version__}

@@ -70,8 +70,8 @@ _PENDING, _CLAIMED, _EJECTED = 0, 1, 2
 
 # RTT-hiding pipelined dispatch (kill switch): while batch K's results
 # drain on the finalizer thread, the dispatcher plans + launches batch
-# K+1 — the plan-build and H2D that docs/perf.md §5 shows sitting
-# serially inside every flush otherwise. Depth is exactly one in-flight
+# K+1 — the plan-build and H2D that otherwise sit serially inside
+# every flush. Depth is exactly one in-flight
 # batch (double buffering); write-containing or single-item flushes
 # barrier and run the exact serial path, so results are always
 # identical to PILOSA_TPU_PIPELINE=0.
@@ -586,8 +586,7 @@ class QueryCoalescer:
         to the finalizer and return to collecting the next window.
         While the previous batch drains device->host, this one's plan
         build and H2D run concurrently: the overlap that buys back the
-        per-flush RTT (docs/perf.md §5, scored by
-        pilosa_device_idle_ratio)."""
+        per-flush host time (scored by pilosa_device_idle_ratio)."""
         self.stats.count(f"coalescer.flush.{reason}", 1)
         self.stats.histogram("coalescer.batch_size", len(batch))
         self._note_workload(batch)

@@ -66,9 +66,10 @@ class Config:
     # always wins — config can disable the collective path, never
     # re-enable it past the blunt switch.
     mesh_collectives: bool = True
-    # JAX platform override ("" = default). "cpu" keeps the server
-    # serving host-path queries when the accelerator transport is down —
-    # without it, the first jax.devices() blocks on a hung backend.
+    # JAX platform to require ("" = whatever JAX picks, which is the
+    # CPU with a warning when no accelerator is found). "tpu" makes a
+    # chipless start fatal; "cpu" is for tests. The start line and
+    # GET /info say which one the process ended up on.
     platform: str = ""
     # Multi-host SPMD (jax.distributed): when coordinator is set, the
     # server calls jax.distributed.initialize before building the mesh,
@@ -196,8 +197,9 @@ class Config:
     # SAMPLED device fences into achieved-GB/s / roofline-fraction
     # estimators (served at GET /debug/roofline, gauges on /metrics).
     # `gbps = 0` auto-resolves the roofline from the attached device
-    # kind (utils/benchenv table); a non-TPU backend is labeled
-    # estimate-only. No fences of its own: with profile_sample_every =
+    # kind (utils/roofline.PEAK_HBM_GBPS); a kind that is not in the
+    # table has no roofline and publishes no fraction. No fences of
+    # its own: with profile_sample_every =
     # 0 and no ?profile=true traffic the plane only accumulates byte
     # counters. TOML accepts a [roofline] table (enabled / gbps /
     # ewma_alpha / max_cohorts) or the flat roofline_* spelling; env

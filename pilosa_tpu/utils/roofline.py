@@ -1,9 +1,9 @@
 """Live roofline attribution: measured bytes/bandwidth per megakernel
 launch, calibrated against the optimizer's predicted cost.
 
-The serving path's success metric is roofline fraction (docs/perf.md
-§"Device-time roofline table"), but until this plane it was only
-computable by hand-running micro benches. ops/megakernel.plan_cost()
+Every hot kernel here is HBM-bandwidth-bound, so the share of the HBM
+roofline a launch achieves is its figure of merit; before this plane it
+was only computable by hand-running micro benches. ops/megakernel.plan_cost()
 prices every launch's HBM traffic from the verified [P, 4] IR (host
 numpy, microseconds); the executor joins that cost vector with the
 *sampled* device fences already flowing through the profiler
@@ -22,8 +22,9 @@ fence-free) and feeds this recorder. What comes out:
   heuristics need (PAPERS.md 1402.4466, 1709.07821).
 
 The roofline itself comes from the ``[roofline]`` config section
-(``gbps = 0`` auto-resolves from the device kind via utils/benchenv's
-table; on CPU the number is clearly labeled estimate-only). Sampling
+(``gbps = 0`` auto-resolves from the device kind via PEAK_HBM_GBPS
+below; a kind that is not in the table has no roofline, and no
+fraction is published for it). Sampling
 bias: ``pilosa_executor_device_seconds`` is fed only by 1-in-N fences,
 so the recorder carries the profiler's sample rate and reports the
 scaled ``deviceSecondsEstimate`` next to the raw sampled sum —
@@ -52,6 +53,32 @@ COHORT_NBYTES = 192
 # noise on CPU easily swings 10-15%, so a drift flag needs a real
 # inversion, not jitter.
 DRIFT_MARGIN = 1.25
+
+
+# Peak HBM bandwidth per chip, GB/s, keyed by a substring of the
+# lower-cased ``device_kind``. One row per part this code has run on:
+# TPU v5e, 819 GB/s (Google Cloud documentation, "TPU v5e" system
+# architecture table). A kind that is not here is an error, never a
+# default — a fraction of the wrong peak is worse than none.
+PEAK_HBM_GBPS = (
+    ("v5e", 819.0),
+    ("v5 lite", 819.0),
+)
+
+
+class UnknownDeviceKind(LookupError):
+    """The device's kind has no row in PEAK_HBM_GBPS."""
+
+
+def resolve_roofline(device: Any) -> Tuple[float, str]:
+    """(peak GB/s, lower-cased kind) for a jax device; raises
+    UnknownDeviceKind when the kind has no measured-against peak."""
+    kind = (getattr(device, "device_kind", "") or "").lower()
+    for probe, gbps in PEAK_HBM_GBPS:
+        if probe in kind:
+            return gbps, kind
+    raise UnknownDeviceKind(
+        f"no HBM peak on record for device kind {kind!r}")
 
 
 def _ewma(old: Optional[float], x: float, alpha: float) -> float:
@@ -124,6 +151,8 @@ class RooflineRecorder:
 
     def set_resolved(self, gbps: float, kind: str,
                      estimated: bool) -> None:
+        """gbps = 0 records "this kind has no roofline": resolution
+        stops being retried and no fraction is ever published."""
         with self._lock:
             self._resolved = (float(gbps), str(kind), bool(estimated))
 
@@ -132,8 +161,8 @@ class RooflineRecorder:
             self.sample_every = max(0, int(n))
 
     def roofline_gbps(self) -> Tuple[float, str, bool]:
-        """(GB/s, source label, estimate-only?) — config wins; an
-        auto-resolved non-TPU backend is always estimate-only."""
+        """(GB/s, source label, estimate-only?) — config wins; 0 GB/s
+        means there is no roofline to take a fraction of."""
         if self.gbps_configured > 0:
             return self.gbps_configured, "config", False
         if self._resolved is not None:
@@ -188,7 +217,8 @@ class RooflineRecorder:
                     device_s: float) -> Optional[Dict[str, float]]:
         """A launch that hit a sampled fence: join bytes with measured
         seconds. Returns {bytesPerS, gbps, frac} for the caller's
-        timeline counter track, or None when unusable."""
+        timeline counter track (frac None without a roofline), or
+        None when unusable."""
         if not self.enabled or device_s <= 0:
             return None
         with self._lock:
@@ -198,8 +228,9 @@ class RooflineRecorder:
             bytes_per_s = total_bytes / device_s
             gbps = bytes_per_s / 1e9
             roof, _src, _est = self.roofline_gbps()
-            frac = (gbps / roof) if roof > 0 else 0.0
+            frac = None
             if roof > 0:
+                frac = gbps / roof
                 self._frac_ewma = _ewma(self._frac_ewma, frac,
                                         self.ewma_alpha)
             rec = self._cohort(cohort_key)
@@ -335,11 +366,14 @@ class RooflineRecorder:
             roof, _src, _est = self.roofline_gbps()
             agg = (self.fenced_bytes / self.fenced_device_s / 1e9
                    if self.fenced_device_s > 0 else 0.0)
-            stats.gauge("roofline_gbps", roof)
             stats.gauge("roofline_achieved_gbps", agg)
-            stats.gauge("roofline_fraction",
-                        self._frac_ewma
-                        if self._frac_ewma is not None else 0.0)
+            if roof > 0:
+                # Only against a known peak: an unknown device kind
+                # publishes achieved GB/s and no fraction.
+                stats.gauge("roofline_gbps", roof)
+                stats.gauge("roofline_fraction",
+                            self._frac_ewma
+                            if self._frac_ewma is not None else 0.0)
             stats.gauge("roofline_cohorts", len(self._cohorts))
             stats.gauge("roofline_drift_flagged",
                         sum(1 for r in self._cohorts.values()

@@ -267,19 +267,23 @@ class TimelineRecorder:
             self.dispatches_total += 1
 
     def note_bandwidth(self, bytes_per_s: float,
-                       roofline_frac: float) -> None:
+                       roofline_frac: Optional[float]) -> None:
         """One achieved-bandwidth sample (a megakernel launch that hit
         a sampled device fence): feeds the ph:"C" counter lanes in the
         export. Independent of request sampling, like note_dispatch —
-        the fence already happened, recording it costs one append."""
+        the fence already happened, recording it costs one append.
+        roofline_frac is None on a device with no roofline on record;
+        that sample then has no fraction lane."""
         if not self.enabled:
             return
         with self._gap_lock:
-            self._counters.append((time.time(), float(bytes_per_s),
-                                   float(roofline_frac)))
+            self._counters.append((
+                time.time(), float(bytes_per_s),
+                None if roofline_frac is None else float(roofline_frac)))
             self.counters_total += 1
 
-    def counter_samples(self) -> List[Tuple[float, float, float]]:
+    def counter_samples(
+            self) -> List[Tuple[float, float, Optional[float]]]:
         with self._gap_lock:
             return list(self._counters)
 
@@ -296,10 +300,11 @@ class TimelineRecorder:
                            "cat": "pilosa", "ts": ts, "dur": 0,
                            "pid": pid, "tid": 0,
                            "args": {"bytes_per_s": bps}})
-            events.append({"name": "roofline_fraction", "ph": "C",
-                           "cat": "pilosa", "ts": ts, "dur": 0,
-                           "pid": pid, "tid": 0,
-                           "args": {"fraction": frac}})
+            if frac is not None:
+                events.append({"name": "roofline_fraction", "ph": "C",
+                               "cat": "pilosa", "ts": ts, "dur": 0,
+                               "pid": pid, "tid": 0,
+                               "args": {"fraction": frac}})
         return events
 
     def gap_summary(self, now_pc: Optional[float] = None
@@ -425,7 +430,9 @@ class TimelineRecorder:
                 "requestsSkipped": self.requests_skipped,
                 "ringCapacity": self._ring.maxlen,
                 "sampleEvery": self.sample_every,
-                "counterSamples": len(counters) // 2,
+                "counterSamples": sum(
+                    1 for e in counters
+                    if e["name"] == "launch_bytes_per_s"),
                 "deviceIdleRatio": gap["idleRatio"],
                 "dispatchGap": gap,
                 "stageMedianS": self._stage_medians(reqs),

@@ -573,3 +573,26 @@ def test_idle_ratio_strictly_decreases_with_pipeline(ex, monkeypatch):
     assert pipe_ratio < serial_ratio, (
         f"pipelined idle ratio {pipe_ratio:.3f} must drop below the "
         f"serial {serial_ratio:.3f}")
+
+
+def test_a_device_that_cannot_initialise_is_an_error(monkeypatch):
+    """`auto` asks the backend which platform it is; a backend that
+    cannot start must raise, not read as "megakernel off" / "Pallas
+    off" and let the server carry on somewhere else."""
+    import jax
+
+    from pilosa_tpu.ops import pallas_kernels
+
+    def no_backend():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", no_backend)
+    monkeypatch.delenv("PILOSA_TPU_MEGAKERNEL", raising=False)
+    monkeypatch.delenv("PILOSA_TPU_NO_PALLAS", raising=False)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        megamod._default_enabled()
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        pallas_kernels.available()
+    # An explicit setting never asks the backend.
+    monkeypatch.setenv("PILOSA_TPU_MEGAKERNEL", "0")
+    assert megamod._default_enabled() is False

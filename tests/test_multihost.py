@@ -3,9 +3,14 @@ missing #5): the cross-host code path — one global mesh over two
 processes' devices, shard-axis reductions lowered to cross-process
 collectives — must compile and reduce correctly."""
 
+import json
 import os
+import signal
 import subprocess
 import sys
+import time
+import urllib.error
+import urllib.request
 
 import pytest
 
@@ -51,3 +56,64 @@ def test_two_process_jax_distributed_dryrun():
     assert proc.returncode == 0, out
     assert "multihost dryrun: OK" in out, out
     assert out.count("OK counts=") == 2, out  # both processes verified
+
+
+@pytest.mark.timeout(180)
+def test_two_servers_start_under_jax_distributed(tmp_path):
+    """The documented multi-host deployment (jax_coordinator /
+    jax_num_processes): both servers start, log the start line, and
+    GET /info describes each one's OWN devices beside the global count
+    (memory_stats() raises on another host's device). Start-up and
+    /info dispatch no cross-process computation, so this needs no CPU
+    collectives plugin."""
+    from tests.test_cluster_procs import _free_ports
+    coord, *binds = _free_ports(3)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ)
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               PILOSA_TPU_JAX_COORDINATOR=f"127.0.0.1:{coord}",
+               PILOSA_TPU_JAX_NUM_PROCESSES="2")
+    procs, logs = [], []
+    for i, port in enumerate(binds):
+        logs.append(tmp_path / f"server{i}.log")
+        with open(logs[i], "wb") as log:
+            procs.append(subprocess.Popen(
+                [sys.executable, "-m", "pilosa_tpu.cli", "server",
+                 "-d", str(tmp_path / f"d{i}"), "-b", f"127.0.0.1:{port}"],
+                stdout=log, stderr=log,
+                env=dict(env, PILOSA_TPU_JAX_PROCESS_ID=str(i))))
+    try:
+        infos = []
+        deadline = time.time() + 120
+        for i, port in enumerate(binds):
+            while True:
+                try:
+                    with urllib.request.urlopen(
+                            f"http://127.0.0.1:{port}/info", timeout=5) as r:
+                        infos.append(json.loads(r.read()))
+                    break
+                except (urllib.error.URLError, OSError):
+                    assert procs[i].poll() is None and \
+                        time.time() < deadline, logs[i].read_text()[-3000:]
+                    time.sleep(0.3)
+        ids = [[d["id"] for d in info["devices"]] for info in infos]
+        assert [len(x) for x in ids] == [4, 4] and not set(ids[0]) & set(ids[1])
+        assert [info["deviceCount"] for info in infos] == [8, 8]
+        # The default mesh spans every host's devices.
+        assert [info["meshDevices"] for info in infos] == [8, 8]
+        for log in logs:
+            assert " count=8 local=4 " in log.read_text()
+    finally:
+        for p in procs:
+            if p.poll() is None:
+                p.send_signal(signal.SIGTERM)
+        rcs = []
+        for p in procs:
+            try:
+                rcs.append(p.wait(timeout=30))
+            except subprocess.TimeoutExpired:
+                p.kill()
+                rcs.append(p.wait(timeout=10))
+    assert rcs == [0, 0], [log.read_text()[-2000:] for log in logs]

@@ -383,6 +383,35 @@ def test_unknown_san_variant_yields_none(monkeypatch):
     assert not native.available()
 
 
+def test_build_failure_is_logged_once_and_reported(monkeypatch, caplog,
+                                                   tmp_path):
+    """make fails and no library exists: load() returns None as
+    before, but says why — one WARNING carrying make's stderr, and
+    status() (the start line, GET /info, chip_smoke.py) reports it."""
+    import logging
+    monkeypatch.setattr(native, "_build",
+                        lambda san: "make: g++: No such file")
+    monkeypatch.setattr(native, "_so_path",
+                        lambda san: str(tmp_path / "absent.so"))
+    monkeypatch.setattr(native, "_libs", {})
+    monkeypatch.setattr(native, "_load_errors", {})
+    monkeypatch.delenv("PILOSA_TPU_NATIVE_SAN", raising=False)
+    with caplog.at_level(logging.WARNING, logger="pilosa_tpu.native"):
+        assert native.load() is None
+        assert native.load() is None      # cached: not built again
+        assert native.status() == (False, "make: g++: No such file")
+    warnings = [r for r in caplog.records if r.levelno == logging.WARNING]
+    assert len(warnings) == 1
+    assert "make: g++: No such file" in warnings[0].getMessage()
+
+
+def test_status_when_loaded_and_when_disabled(monkeypatch):
+    assert native.status() == (True, "")
+    monkeypatch.setenv("PILOSA_TPU_NO_NATIVE", "1")
+    assert native.status() == (False,
+                               "disabled by PILOSA_TPU_NO_NATIVE")
+
+
 def test_load_cache_is_keyed_on_san_variant(monkeypatch):
     """A variant requested AFTER another was first loaded must not be
     served that cached library (regression: a single _tried/_lib pair

@@ -91,30 +91,6 @@ def test_executor_pallas_path_topn(tmp_path, monkeypatch):
     holder.close()
 
 
-def test_pbank_membership_counts_matches_numpy():
-    """Fused membership+rowsum (probe-stage, VERDICT r5 #2): grouped
-    u16-pair layout vs a numpy reference, pads excluded."""
-    rng = np.random.default_rng(5)
-    R, L, qk = 2048, 48, 48
-    pos = np.sort(rng.integers(0, 4096, (R, L), dtype=np.uint16), axis=1)
-    # Pad some rows (0xFFFF matches nothing).
-    lens = rng.integers(10, L + 1, R)
-    mask = np.arange(L)[None, :] >= lens[:, None]
-    pos[mask] = 0xFFFF
-    q = np.unique(rng.integers(0, 4096, qk * 2, dtype=np.uint16))[:qk]
-    qtop_pad = np.full((8, 128), -1, np.int32)
-    qtop_pad.reshape(-1)[:len(q)] = q.astype(np.int32)
-    grouped = (pos.view(np.uint32)
-               .reshape(R // 16, 16 * (L // 2)))
-    got = np.asarray(pk.pbank_membership_counts(
-        jnp.asarray(grouped), jnp.asarray(qtop_pad), qk=len(q),
-        interpret=True))
-    qset = set(int(x) for x in q)
-    want = np.array([sum(1 for p in row if int(p) in qset and p != 0xFFFF)
-                     for row in pos], np.int32)
-    np.testing.assert_array_equal(got, want)
-
-
 def test_pbank_search_membership_matches_compare(tmp_path, monkeypatch):
     """The searchsorted membership form answers identically to the
     compare form through the full executor tanimoto path."""
@@ -159,8 +135,7 @@ def test_pbank_search_membership_matches_compare(tmp_path, monkeypatch):
 def test_pbank_membership_auto_resolves_per_backend(tmp_path,
                                                     monkeypatch):
     """'auto' (the default) must resolve to 'search' on the XLA CPU
-    backend (measured 1.33x warm / 7.7x faster cold at 1M molecules,
-    docs/round5-notes.md §3) and be cached under the RESOLVED name, so
+    backend and be cached under the RESOLVED name, so
     an explicit-'search' run shares the same compiled kernel."""
     from pilosa_tpu.core.holder import Holder
     from pilosa_tpu.core.field import FieldOptions
@@ -191,56 +166,3 @@ def test_pbank_membership_auto_resolves_per_backend(tmp_path,
     forms = {key[3] for key in executor_mod.Executor._PBANK_KERNELS}
     assert "search" in forms
     assert "auto" not in forms
-
-
-# ------------------------------------------------------- megakernel loop
-
-
-def _mega_reference(slab, instrs):
-    """Host reference for the plan-buffer interpreter."""
-    from pilosa_tpu.ops import megakernel as mk
-    ref = slab.copy()
-    for op, d, a, b in instrs:
-        va, vb = ref[a], ref[b]
-        ref[d] = {mk.OP_AND: va & vb, mk.OP_OR: va | vb,
-                  mk.OP_XOR: va ^ vb, mk.OP_ANDNOT: va & ~vb,
-                  mk.OP_ZERO: np.zeros_like(va), mk.OP_COPY: va}[op]
-    return ref
-
-
-def test_mega_interpret_matches_reference_with_raw_chains():
-    """The Pallas plan-buffer loop must honor read-after-write chains
-    BETWEEN plan entries (entry k reading the register entry k-1
-    wrote) — the property a grid-per-entry formulation breaks."""
-    from pilosa_tpu.ops import megakernel as mk
-    rng = np.random.default_rng(5)
-    slab = rng.integers(0, 2**32, (16, 2, 8), dtype=np.uint32)
-    instrs = np.array([
-        [mk.OP_AND, 12, 0, 1],
-        [mk.OP_OR, 12, 12, 2],      # reads its own prior write
-        [mk.OP_ANDNOT, 13, 3, 12],  # reads entry 1's write
-        [mk.OP_XOR, 13, 13, 4],
-        [mk.OP_COPY, 14, 13, 0],
-        [mk.OP_ZERO, 15, 15, 15],
-        [mk.OP_OR, 14, 14, 15],
-    ], np.int32)
-    out = np.asarray(pk.mega_interpret(jnp.asarray(slab),
-                                       jnp.asarray(instrs),
-                                       interpret=True))
-    assert np.array_equal(out, _mega_reference(slab, instrs))
-
-
-def test_mega_interpret_random_programs():
-    from pilosa_tpu.ops import megakernel as mk
-    rng = np.random.default_rng(17)
-    slab = rng.integers(0, 2**32, (8, 1, 4), dtype=np.uint32)
-    for _ in range(5):
-        p = int(rng.integers(1, 12))
-        instrs = np.stack([
-            rng.integers(0, 6, p), rng.integers(0, 8, p),
-            rng.integers(0, 8, p), rng.integers(0, 8, p),
-        ], axis=1).astype(np.int32)
-        out = np.asarray(pk.mega_interpret(jnp.asarray(slab),
-                                           jnp.asarray(instrs),
-                                           interpret=True))
-        assert np.array_equal(out, _mega_reference(slab, instrs))

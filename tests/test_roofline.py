@@ -206,6 +206,9 @@ def test_launch_cost_metrics_families(ex):
     from pilosa_tpu.utils.profile import QueryProfile
 
     ex.stats = MemStatsClient()
+    # The CPU backend has no peak on record, so the roofline is given
+    # by config here (the gauges below exist only against a known one).
+    ROOFLINE.configure(gbps=819.0)
     profs = [QueryProfile(i, q, sample_device=True)
              for i, q, _s in MIXED]
     ex.execute_batch_shaped(MIXED, profiles=profs)
@@ -342,6 +345,29 @@ def test_disabled_recorder_records_nothing():
     snap = rec.snapshot()
     assert snap["launches"] == 0 and snap["fencedLaunches"] == 0
     assert snap["unattributedFences"] == 0
+
+
+def test_unknown_device_kind_publishes_no_fraction(ex):
+    """A launch on a device with no peak on record (the CPU backend):
+    the kind resolves to "no roofline" once, achieved GB/s still
+    accumulates, and no fraction reaches /metrics or the timeline."""
+    from pilosa_tpu.utils.profile import QueryProfile
+
+    ex.stats = MemStatsClient()
+    profs = [QueryProfile(i, q, sample_device=True)
+             for i, q, _s in MIXED]
+    ex.execute_batch_shaped(MIXED, profiles=profs)
+    assert ROOFLINE.roofline_gbps() == (0.0, "cpu", True)
+    assert not ROOFLINE.needs_resolve()
+    bw = ROOFLINE.note_device("A", 10**9, 0.01)
+    assert bw["gbps"] == pytest.approx(100.0) and bw["frac"] is None
+    ROOFLINE.publish(ex.stats)
+    prom = prometheus_text(ex.stats)
+    assert "pilosa_roofline_achieved_gbps" in prom
+    assert "pilosa_roofline_fraction" not in prom
+    assert "pilosa_roofline_gbps" not in prom
+    snap = ROOFLINE.snapshot()
+    assert snap["rooflineGbps"] == 0.0 and snap["estimateOnly"] is True
 
 
 def test_roofline_gbps_source_precedence():

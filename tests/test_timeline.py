@@ -168,6 +168,17 @@ def test_snapshot_chrome_trace_event_shape():
     assert 0.0 <= summary["deviceIdleRatio"] <= 1.0
 
 
+def test_bandwidth_sample_without_a_roofline_has_no_fraction_lane():
+    """On a device with no peak on record the recorder passes None:
+    the bytes/s lane is kept, no fraction is invented."""
+    rec = TimelineRecorder()
+    rec.note_bandwidth(2.5e9, None)
+    doc = rec.snapshot()
+    cs = [e for e in doc["traceEvents"] if e["ph"] == "C"]
+    assert [e["name"] for e in cs] == ["launch_bytes_per_s"]
+    assert doc["summary"]["counterSamples"] == 1
+
+
 def test_bandwidth_counter_track_shape():
     """Roofline plane counter tracks: note_bandwidth exports two
     Perfetto ph:"C" samples (launch_bytes_per_s + roofline_fraction)
