@@ -204,10 +204,8 @@ def slo_stanza(out, times):
 def stage_timeline_breakdown(ex, q, out, iters: int = 3):
     """Where the per-call time goes, not just its total: a few profiled
     (device-fenced) runs AFTER the timed loop record plan/dispatch/
-    device/fetch medians on the host clock, and the timeline plane's
-    dispatch-gap analyzer contributes its gap ratio."""
+    device/fetch medians on the host clock."""
     from pilosa_tpu.utils.profile import QueryProfile
-    from pilosa_tpu.utils.timeline import TIMELINE
 
     stages = {"planS": [], "dispatchS": [], "deviceS": [], "fetchS": []}
     for _ in range(max(1, iters)):
@@ -219,12 +217,6 @@ def stage_timeline_breakdown(ex, q, out, iters: int = 3):
         stages["fetchS"].append(prof.totals["materialize"])
     out["stage_breakdown"] = {
         k: float(np.median(v)) for k, v in stages.items()}
-    # Gap ratio over the whole bench run's dispatches (the timed loop
-    # included): raise the gap window to cover it.
-    TIMELINE.configure(gap_window_s=3600.0)
-    gap = TIMELINE.gap_summary()
-    out["dispatch_gap_ratio"] = gap["idleRatio"]
-    out["timeline_dispatches"] = gap["dispatchesTotal"]
 
 
 def bench_device_time(holder):

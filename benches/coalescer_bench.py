@@ -173,7 +173,7 @@ def main():
             direct_qps, direct_obs = run_phase(host, port, queries)
             coal = QueryCoalescer(api.executor, window_s=0.002,
                                   max_batch=N_THREADS, max_queue=1024,
-                                  stats=api.stats, tracer=api.tracer)
+                                  stats=api.stats)
             coal.start()
             api.coalescer = coal
             log(f"bench: {workload}/coalesced")

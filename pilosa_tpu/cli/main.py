@@ -44,8 +44,7 @@ def drain_telemetry(api, watchdog=None, logger=None) -> None:
     from pilosa_tpu.utils.hotspots import WORKLOAD
     if WORKLOAD.enabled:
         WORKLOAD.dump(logger)
-    # Timeline plane: the last request timelines + the idle ratio the
-    # process died with (utils/timeline.py).
+    # Timeline plane: the last request records (utils/timeline.py).
     from pilosa_tpu.utils.timeline import TIMELINE
     if TIMELINE.enabled:
         TIMELINE.dump(logger)
@@ -63,10 +62,8 @@ def drain_telemetry(api, watchdog=None, logger=None) -> None:
         SENTINEL.dump(logger)
     tracer = getattr(api, "tracer", None)
     if tracer is not None:
-        # The finished-span ring leaves evidence even when no exporter
-        # is configured (RecordingTracer.dump); exporters then flush.
-        if hasattr(tracer, "dump"):
-            tracer.dump(logger)
+        # The timeline dump above left the last records in the log;
+        # an exporter now ships what it still holds.
         if hasattr(tracer, "stop"):
             tracer.stop()  # final flush of pending spans
         elif hasattr(tracer, "flush"):
@@ -79,7 +76,7 @@ def cmd_server(args) -> int:
     from pilosa_tpu.utils.config import load_config
     from pilosa_tpu.utils.logger import Logger
     from pilosa_tpu.utils.stats import MemStatsClient, NopStatsClient
-    from pilosa_tpu.utils.tracing import RecordingTracer
+    from pilosa_tpu.utils.tracing import ContextTracer
 
     cfg = load_config(args.config, {
         "data_dir": args.data_dir, "bind": args.bind,
@@ -189,7 +186,7 @@ def cmd_server(args) -> int:
                                  sampler_param=cfg.tracing_sampler_param)
         tracer.start()
     else:
-        tracer = RecordingTracer()
+        tracer = ContextTracer()
     api = API(holder, mesh=mesh, cluster=cluster, stats=stats,
               tracer=tracer, client_ssl_context=cfg.client_ssl_context())
     api.logger = logger
@@ -242,14 +239,23 @@ def cmd_server(args) -> int:
                        max_fragments=cfg.workload_max_fragments,
                        max_rows=cfg.workload_max_rows,
                        max_signatures=cfg.workload_max_signatures)
-    # Request-lifecycle timeline plane (utils/timeline.py): per-request
-    # stage timelines at GET /debug/timeline + the dispatch-gap idle
-    # ratio on /metrics. [timeline] enabled=false is the kill switch.
+    # Request records (utils/timeline.py): one span tree per request
+    # at GET /debug/timeline, its stage seconds in /debug/vars.
+    # [timeline] enabled=false is the kill switch. Under a
+    # jax.profiler session each span is also a `pilosa:<stage>` event
+    # on its thread's line of the trace's host plane; the exporter,
+    # when one is configured, ships every finished record.
+    import jax.profiler
+    from pilosa_tpu.utils.jaxenv import COMPILES
     from pilosa_tpu.utils.timeline import TIMELINE
     TIMELINE.configure(enabled=cfg.timeline_enabled,
                        ring=cfg.timeline_ring,
-                       sample_every=cfg.timeline_sample_every,
-                       gap_window_s=cfg.timeline_gap_window_s)
+                       sample_every=cfg.timeline_sample_every)
+    TIMELINE.annotation = jax.profiler.TraceAnnotation
+    TIMELINE.exporter = tracer if cfg.tracing_endpoint else None
+    # Every XLA compile from here on is counted, with its cause
+    # (xla.* counters, the table in GET /debug/queries).
+    COMPILES.install(stats)
     # Roofline attribution plane ([roofline] section, utils/roofline):
     # per-launch bytes joined with the profiler's sampled fences into
     # achieved GB/s at GET /debug/roofline. gbps = 0 auto-resolves
@@ -313,7 +319,7 @@ def cmd_server(args) -> int:
             max_batch=cfg.coalescer_max_batch,
             max_queue=cfg.coalescer_max_queue,
             deadline_s=cfg.coalescer_deadline_ms / 1e3,
-            stats=stats, tracer=tracer, logger=logger,
+            stats=stats, logger=logger,
             pipeline=cfg.coalescer_pipeline)
         coalescer.start()
         api.coalescer = coalescer

@@ -55,10 +55,9 @@ from pilosa_tpu.pql.ast import BETWEEN, EQ, GT, GTE, LT, LTE, NEQ
 from pilosa_tpu.utils.fingerprint import request_key
 from pilosa_tpu.utils.hotspots import WORKLOAD
 from pilosa_tpu.utils.memledger import LEDGER
-from pilosa_tpu.utils.timeline import (
-    LANE_CACHE, LANE_DEVICE, LANE_DISPATCH, LANE_FETCH, LANE_PLAN,
-    TIMELINE,
-)
+from pilosa_tpu.utils.jaxenv import COMPILES
+from pilosa_tpu.utils.profile import transfer
+from pilosa_tpu.utils.timeline import TIMELINE
 
 _LOG = logging.getLogger("pilosa_tpu.executor")
 
@@ -170,6 +169,56 @@ FUSION_ENABLED = os.environ.get("PILOSA_TPU_FUSION", "1") != "0"
 # disables. The first warm hit after startup is always checked.
 TOPN_SELFCHECK_EVERY = int(os.environ.get("PILOSA_TPU_TOPN_SELFCHECK",
                                           256))
+
+
+def named(fn: Callable, name: str) -> Callable:
+    """`fn` under the function name `name` and a `jax.named_scope` of
+    the same name, so that once jitted its XLA module reads
+    `jit_<name>` in a profiler trace (and in JAX's compile events)
+    instead of `jit_run` or `jit__lambda_`, and its ops carry the
+    scope in their metadata. Instruction names — what XLA calls a
+    fusion — are not touched by a scope."""
+    import jax
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    scoped.__name__ = scoped.__qualname__ = name
+    return scoped
+
+
+def upload(host: np.ndarray):
+    """One host->device put of a small operand vector, as an `h2d`
+    stage of the request record (bank uploads are core/view.py's and
+    are not charged to a request)."""
+    import jax.numpy as jnp
+    with transfer("h2d", int(host.nbytes)):
+        return jnp.asarray(host)
+
+
+def fetch_host(arrays) -> float:
+    """The `d2h` stage: block until the host copy of every array one
+    call's finalize will read is there (its async copy was started by
+    prefetch_pendings); returns the seconds waited. A jax.Array caches
+    the copy it fetched, a fusion handle caches its group's, so the
+    finalize that follows reads host memory. A failed transfer is left
+    for that finalize to raise."""
+    if not arrays:
+        return 0.0
+    with transfer("d2h", transfer_nbytes(arrays), len(arrays)) as sp:
+        for a in arrays:
+            try:
+                host = getattr(a, "host", None)   # fusion.FusedEval
+                if host is not None:
+                    host()
+                else:
+                    # graftlint: materialize — this IS the device->host
+                    # boundary of the call: its one blocking fetch.
+                    np.asarray(a)
+            except Exception:
+                pass
+    return sp.duration()
 
 
 class _Pending:
@@ -493,7 +542,12 @@ class _StagedEval:
                 from pilosa_tpu.ops.bitset import popcount
                 return popcount(out, axis=-1)  # [S]
             return out
-        return run
+        return named(run, self.program)
+
+    @property
+    def program(self) -> str:
+        """The program's name in traces: `tree_count` / `tree_row`."""
+        return f"tree_{self.mode}"
 
 
 class Executor:
@@ -703,11 +757,27 @@ class Executor:
 
     # ------------------------------------------------------- profiling hooks
 
-    def _note_jit_compile(self) -> None:
-        """Count one fresh XLA trace+compile (jit-cache miss). '+= 1'
-        is not atomic and every request thread can land here."""
+    def _note_jit_compile(self, program: str = "", key: Any = "") -> None:
+        """Count one fresh XLA trace+compile (jit-cache miss) of
+        `program` under jit-cache key `key`. '+= 1' is not atomic and
+        every request thread can land here. The key goes to the
+        compile log's table and onto this thread's next `dispatch`
+        span (the call that pays for the compile)."""
         with self._jit_stats_lock:
             self.jit_compiles += 1
+        COMPILES.note_key(program, key)
+        self._tls.jit_miss = str(key)[:200]
+
+    def _dispatch_span(self, program: str):
+        """The `dispatch` stage around one enqueue of `program`:
+        `jit=miss` plus the readable key when this thread just missed
+        the jit cache (the call then traces and compiles), else
+        `jit=hit`."""
+        key = self._tls.__dict__.pop("jit_miss", None)
+        if key is None:
+            return TIMELINE.stage("dispatch", program=program, jit="hit")
+        return TIMELINE.stage("dispatch", program=program, jit="miss",
+                              key=key)
 
     def _profile(self):
         """The QueryProfile attached to the current thread's in-flight
@@ -915,24 +985,19 @@ class Executor:
                 return False
         return True
 
-    def _request_cache_get(self, key: tuple, profile=None
+    def _request_cache_get(self, key: tuple, profile=None, span=None
                            ) -> Optional[Dict[str, Any]]:
         """Request-tier lookup + hit attribution (cacheHit profile op,
-        timeline `cache` lane slice)."""
-        t0 = time.perf_counter()
+        timed by the caller's open `cache.lookup` span)."""
         val = self.result_cache.lookup_request(
             key, self._request_deps_current)
         if val is None:
             return None
         if profile is not None:
-            dur = time.perf_counter() - t0
             op = profile.begin_op("cache")
             op.attrs["cacheHit"] = True
-            profile.end_op(op, dur)
-            tl = getattr(profile, "timeline", None)
-            if tl is not None:
-                TIMELINE.event(tl, "cache", LANE_CACHE, t0, dur,
-                               hit=True)
+            profile.end_op(op, span.duration() if span is not None
+                           else 0.0)
         return val
 
     def _request_cache_fill(self, key: tuple, deps: dict,
@@ -982,16 +1047,17 @@ class Executor:
         program; returns (idx, staged, opts) with results still pending.
         `batch_tail_writes`: a later query in the same batch writes, so
         deferred reads must snapshot (see _tls.later_writes)."""
-        if isinstance(query, str):
-            query = parse_string_cached(query)
-        if isinstance(query, Call):
-            query = Query([query])
-        if self.max_writes_per_request > 0 and \
-                write_call_count(query) > self.max_writes_per_request:
-            raise ExecutionError("too many write commands")
-        idx = self.holder.index(index_name)
-        if idx is None:
-            raise ExecutionError(f"index not found: {index_name}")
+        with TIMELINE.phase("plan"):
+            if isinstance(query, str):
+                query = parse_string_cached(query)
+            if isinstance(query, Call):
+                query = Query([query])
+            if self.max_writes_per_request > 0 and \
+                    write_call_count(query) > self.max_writes_per_request:
+                raise ExecutionError("too many write commands")
+            idx = self.holder.index(index_name)
+            if idx is None:
+                raise ExecutionError(f"index not found: {index_name}")
         opts = ExecOptions()
         staged = []
         calls = list(query.calls)
@@ -1004,42 +1070,47 @@ class Executor:
         try:
             for i, call in enumerate(calls):
                 op = prof.begin_op(call.name) if prof is not None else None
-                t0 = time.perf_counter() if prof is not None else 0.0
+                # One `plan` span per call; `h2d` and `dispatch` split
+                # it. The op node's dispatchS is the span's reading:
+                # its own segments plus what interrupted them.
+                sp = TIMELINE.phase("plan", op=call.name)
                 try:
-                    self._translate_call(idx, call)
-                    # Deferred reads (TopN chunking) consult this to know
-                    # whether lazily re-reading fragment state in finalize
-                    # is still safe.
-                    self._tls.later_writes = batch_tail_writes or any(
-                        _peel_options(c).name in _WRITE_CALLS
-                        for c in calls[i + 1:])
-                    staged.append((call, self._execute_call(idx, call,
-                                                            shards, opts)))
+                    with sp:
+                        self._translate_call(idx, call)
+                        # Deferred reads (TopN chunking) consult this to
+                        # know whether lazily re-reading fragment state
+                        # in finalize is still safe.
+                        self._tls.later_writes = batch_tail_writes or any(
+                            _peel_options(c).name in _WRITE_CALLS
+                            for c in calls[i + 1:])
+                        staged.append((call, self._execute_call(
+                            idx, call, shards, opts)))
                 finally:
                     if op is not None:
-                        prof.end_op(op, time.perf_counter() - t0)
+                        prof.end_op(op, sp.elapsed())
         finally:
             self._tls.later_writes = False
         return idx, staged, opts
 
     def _finalize_staged(self, idx: Index, staged) -> List[Any]:
+        """Per call, in order: `d2h` — block on the call's result
+        arrays — then `finish` — build the host-side result from them
+        (k-selection, GroupBy merge, key translation). Call i's host
+        work runs while the device is still on call i+1. materializeS
+        of the op node is the two spans' readings."""
         prof = self._profile()
-        tl = prof.timeline if prof is not None else None
         results = []
         for i, (call, result) in enumerate(staged):
-            t0 = time.perf_counter() if prof is not None else 0.0
-            d2h = 0
-            if isinstance(result, _Pending):
-                if prof is not None:
-                    d2h = transfer_nbytes(result.arrays)
-                result = result.finalize()
-            self._translate_result(idx, call, result)
+            pending = isinstance(result, _Pending)
+            arrays = result.arrays if pending else ()
+            fetch_s = fetch_host(arrays)
+            with TIMELINE.phase("finish", op=call.name) as sp:
+                if pending:
+                    result = result.finalize()
+                self._translate_result(idx, call, result)
             if prof is not None:
-                mat_s = time.perf_counter() - t0
-                prof.finish_op(i, mat_s, d2h)
-                if tl is not None:
-                    TIMELINE.event(tl, "materialize", LANE_FETCH, t0,
-                                   mat_s, op=call.name, d2hBytes=d2h)
+                prof.finish_op(i, fetch_s + sp.elapsed(),
+                               transfer_nbytes(arrays))
             results.append(result)
         return results
 
@@ -1085,6 +1156,12 @@ class Executor:
         pipelined serving path (server/coalescer.py) runs this for
         batch K+1 while batch K's execute_batch_finish is still
         draining, overlapping plan build + H2D with device time."""
+        # One `plan` phase for the whole dispatch half (h2d, dispatch
+        # and eval-tier cache lookups interrupt it).
+        with TIMELINE.phase("plan", requests=len(requests)):
+            return self._batch_begin(requests, profiles, deps)
+
+    def _batch_begin(self, requests, profiles, deps) -> "_BatchInFlight":
         from pilosa_tpu.executor.fusion import FusionCollector
         profs = list(profiles) if profiles is not None \
             else [None] * len(requests)
@@ -1149,7 +1226,8 @@ class Executor:
             prefetch_pendings(staged)
         return _BatchInFlight(staged_q, out, profs, deps_l)
 
-    def execute_batch_finish(self, flight: "_BatchInFlight") -> List[Any]:
+    def execute_batch_finish(self, flight: "_BatchInFlight"
+                             ) -> List[Any]:
         """The drain half of execute_batch: block on every pending
         transfer and build host results. Safe to run from a different
         thread than the begin (the pipelined coalescer's finalizer):
@@ -1198,22 +1276,25 @@ class Executor:
         deps_l: List[Optional[dict]] = [None] * n
         run: List[int] = []
         write_seen = False
-        for j, (index_name, q, shards) in enumerate(requests):
-            forced = profs[j] is not None and getattr(
-                profs[j], "forced", False)
-            key = None
-            if not write_seen and not forced:
-                key = self._request_cache_key(index_name, q, shards)
-            if not write_seen and query_is_write(q):
-                write_seen = True
-            if key is not None:
-                hit = self._request_cache_get(key, profs[j])
-                if hit is not None:
-                    out[j] = hit
-                    continue
-                keys[j] = key
-                deps_l[j] = {}
-            run.append(j)
+        with TIMELINE.stage("cache.lookup") as sp:
+            for j, (index_name, q, shards) in enumerate(requests):
+                forced = profs[j] is not None and getattr(
+                    profs[j], "forced", False)
+                key = None
+                if not write_seen and not forced:
+                    key = self._request_cache_key(index_name, q, shards)
+                if not write_seen and query_is_write(q):
+                    write_seen = True
+                if key is not None:
+                    hit = self._request_cache_get(key, profs[j], sp)
+                    if hit is not None:
+                        out[j] = hit
+                        continue
+                    keys[j] = key
+                    deps_l[j] = {}
+                run.append(j)
+            sp.set("hits", n - len(run))
+            sp.set("misses", sum(1 for j in run if keys[j] is not None))
         flight = self.execute_batch_begin(
             [requests[j] for j in run],
             profiles=[profs[j] for j in run],
@@ -1226,21 +1307,24 @@ class Executor:
         out, keys, deps_l, run, requests = (sh.out, sh.keys, sh.deps_l,
                                             sh.run, sh.requests)
         res = self.execute_batch_finish(sh.flight)
-        for j, r in zip(run, res):
-            index_name = requests[j][0]
-            if isinstance(r, Exception):
-                out[j] = r
-                continue
-            results, opts = r
-            try:
-                shaped = self.shape_response(index_name, results, opts)
-            except Exception as e:
-                out[j] = e
-                continue
-            if deps_l[j] is not None:
-                self._request_cache_fill(keys[j], deps_l[j], shaped,
-                                         opts)
-            out[j] = shaped
+        # One `finish` phase for shaping every request's response.
+        with TIMELINE.phase("finish", op="shape", requests=len(run)):
+            for j, r in zip(run, res):
+                index_name = requests[j][0]
+                if isinstance(r, Exception):
+                    out[j] = r
+                    continue
+                results, opts = r
+                try:
+                    shaped = self.shape_response(index_name, results,
+                                                 opts)
+                except Exception as e:
+                    out[j] = e
+                    continue
+                if deps_l[j] is not None:
+                    self._request_cache_fill(keys[j], deps_l[j], shaped,
+                                             opts)
+                out[j] = shaped
         return out
 
     def execute_full(self, index_name: str, query,
@@ -1257,20 +1341,24 @@ class Executor:
         and fill after shaping. Forced (?profile=true) profiles bypass
         the lookup — their tree must describe a real execution — but
         still refresh the fill."""
-        key = self._request_cache_key(index_name, query, shards)
         forced = profile is not None and getattr(profile, "forced",
                                                  False)
-        if key is not None and not forced:
-            hit = self._request_cache_get(key, profile)
-            if hit is not None:
-                return hit
+        with TIMELINE.stage("cache.lookup") as sp:
+            key = self._request_cache_key(index_name, query, shards)
+            hit = None
+            if key is not None and not forced:
+                hit = self._request_cache_get(key, profile, sp)
+            sp.set("hit", hit is not None)
+        if hit is not None:
+            return hit
         deps: Optional[dict] = {} if key is not None else None
         with self._dep_capture(deps):
             results, opts = self._execute_query(index_name, query,
                                                 shards, profile=profile)
-            resp = self.shape_response(index_name, results, opts)
-        if deps is not None:
-            self._request_cache_fill(key, deps, resp, opts)
+            with TIMELINE.phase("finish", op="shape"):
+                resp = self.shape_response(index_name, results, opts)
+                if deps is not None:
+                    self._request_cache_fill(key, deps, resp, opts)
         return resp
 
     def shape_response(self, index_name: str, results, opts: "ExecOptions"
@@ -1591,8 +1679,10 @@ class Executor:
         ONE vmapped XLA program (executor/fusion.py) and the returned
         FusedEval handle resolves to this query's slice."""
         prof = self._profile()
-        t_plan0 = time.perf_counter() if prof is not None else 0.0
-        staged = self._stage_tree(idx, call, shards, mode)
+        # planS of the eval node: tree staging, one reading.
+        with TIMELINE.stage("plan.stage", mode=mode) as ps:
+            staged = self._stage_tree(idx, call, shards, mode)
+        plan_s = ps.duration()
         ckey = None
         rc = self.result_cache
         forced = prof is not None and getattr(prof, "forced", False)
@@ -1613,26 +1703,23 @@ class Executor:
             # invalidation.
             ckey = ("eval", idx.name, staged.fp,
                     tuple(int(s) for s in shards))
-            hit = rc.lookup(ckey, staged.gen)
+            with TIMELINE.stage("cache.lookup", tier="eval") as cs:
+                hit = rc.lookup(ckey, staged.gen)
+                cs.set("hit", hit is not None)
             if hit is not None:
                 if prof is not None:
-                    plan_s = time.perf_counter() - t_plan0
                     node = prof.tree(staged.mode, staged.sig, None,
                                      plan_s, 0, staged.n_shards)
                     node.attrs["cacheHit"] = True
-                    tl = prof.timeline
-                    if tl is not None:
-                        TIMELINE.event(tl, "cache", LANE_CACHE,
-                                       t_plan0, plan_s, hit=True)
                 return hit
         if fusible and FUSION_ENABLED and (
                 self.mesh is None or self._mesh_fusion_enabled()):
             fuser = getattr(self._tls, "fuser", None)
             if fuser is not None:
-                out = fuser.add(staged, prof, t_plan0)
+                out = fuser.add(staged, prof, plan_s)
                 return _CacheFillEval(out, rc, ckey, staged.gen) \
                     if ckey is not None else out
-        out = self._run_staged(staged, prof, t_plan0)
+        out = self._run_staged(staged, prof, plan_s)
         return _CacheFillEval(out, rc, ckey, staged.gen) \
             if ckey is not None else out
 
@@ -1822,16 +1909,17 @@ class Executor:
         fn = self._jit_get(staged.sig)
         hit = fn is not None
         if fn is None:
-            self._note_jit_compile()
+            self._note_jit_compile(staged.program, staged.sig)
             fn = jax.jit(staged.runner())
             self._jit_put(staged.sig, fn)
         return fn, hit
 
     def _cached_args(self, akey: tuple, build: Callable):
         """LRU arg-cache get-or-build: returns (arrays, uploaded).
-        `build()` runs OUTSIDE the lock (device puts can block on the
-        transfer); two threads racing the same new key just put twice,
-        and last-insert wins."""
+        `build()` — the `upload` of each operand vector — runs OUTSIDE
+        the lock (device puts can block on the transfer); two threads
+        racing the same new key just put twice, and last-insert
+        wins."""
         with self._arg_cache_lock:
             cached = self._arg_cache.pop(akey, None)
         uploaded = cached is None
@@ -1854,12 +1942,11 @@ class Executor:
 
         def build():
             # graftlint: disable=GL003 — staged.idxs/params are host
-            # lists; np.asarray here marshals them for upload (the
-            # device transfer is the jnp.asarray), it fetches nothing.
-            idxs = jnp.asarray(np.asarray(staged.idxs, dtype=np.int32))
+            # lists; np.asarray here marshals them for upload, it
+            # fetches nothing.
+            idxs = upload(np.asarray(staged.idxs, dtype=np.int32))
             # graftlint: disable=GL003 — host-list upload, as above.
-            params = jnp.asarray(np.asarray(staged.params,
-                                            dtype=np.uint32))
+            params = upload(np.asarray(staged.params, dtype=np.uint32))
             return idxs, params
 
         akey = (staged.sig, tuple(staged.idxs), tuple(staged.params))
@@ -1869,57 +1956,42 @@ class Executor:
     def _call_program(self, fn, *args):
         """Run phase: the single funnel every compiled tree-program
         invocation goes through — fused and unfused alike. Tests stub
-        this to count real XLA dispatches. The timeline's dispatch-gap
-        analyzer taps the funnel (host wall timestamps of the async
-        enqueue — zero fences), so `pilosa_device_idle_ratio` sees
-        every dispatch however it was reached."""
-        t0 = time.perf_counter()
-        out = fn(*args)
-        TIMELINE.note_dispatch(t0, time.perf_counter() - t0)
-        return out
+        this to count real XLA dispatches. Callers bracket it with
+        `_dispatch_span(program)`: the `dispatch` stage is the host's
+        enqueue (an async call on a jit hit, trace + compile on a
+        miss), never the device's execution."""
+        return fn(*args)
 
-    def _run_staged(self, staged: "_StagedEval", prof, t_plan0: float):
+    def _run_staged(self, staged: "_StagedEval", prof, plan_s: float):
         """Compile + run one staged eval on its own (the unfused
-        path). `prof`/`t_plan0` carry the profiling context captured
-        when planning started."""
+        path). `prof`/`plan_s` carry the profiling context and the
+        staging seconds of the `plan.stage` span."""
         fn, jit_hit = self._tree_fn(staged)
         idxs, params, uploaded = self._staged_args(staged)
+        # planS is the tree staging; dispatchS is the fn() call itself
+        # (async enqueue on a cache hit, trace+compile on a miss);
+        # deviceS is the fenced XLA execution time — sampled queries
+        # only, so the unprofiled path keeps its fully-async dispatch
+        # queue.
+        with self._dispatch_span(staged.program) as ds:
+            out = self._call_program(fn, staged.bank_arrays, idxs,
+                                     params, staged.lits)
         if prof is None:
-            return self._call_program(fn, staged.bank_arrays, idxs,
-                                      params, staged.lits)
-        # Profiled run: planS covers planning + bank/operand staging up
-        # to the program call; dispatchS is the fn() call itself (async
-        # enqueue on a cache hit, trace+compile on a miss); deviceS is
-        # the fenced XLA execution time — sampled queries only, so the
-        # unprofiled path keeps its fully-async dispatch queue.
+            return out
+        dispatch_s = ds.duration()
         h2d = (transfer_nbytes((idxs, params)) if uploaded else 0) \
             + (staged.lits.nbytes if staged.lits is not None else 0)
-        plan_s = time.perf_counter() - t_plan0
         node = prof.tree(staged.mode, staged.sig, jit_hit, plan_s, h2d,
                          staged.n_shards)
-        tl = prof.timeline
-        if tl is not None:
-            TIMELINE.event(tl, "plan", LANE_PLAN, t_plan0, plan_s,
-                           jit="hit" if jit_hit else "miss")
-        t_disp = time.perf_counter()
-        out = self._call_program(fn, staged.bank_arrays, idxs, params,
-                                 staged.lits)
-        dispatch_s = time.perf_counter() - t_disp
         prof.tree_dispatch(node, dispatch_s)
-        if tl is not None:
-            TIMELINE.event(tl, "dispatch", LANE_DISPATCH, t_disp,
-                           dispatch_s, shards=staged.n_shards)
         device_s = 0.0
         if prof.sample_device:
-            # Device slices exist ONLY when the profiler already fenced
-            # this query (?profile=true / sampled 1-in-N) — the
-            # timeline adds zero fences of its own.
-            t_dev = time.perf_counter()
-            device_s = _fence_device(out)
+            # A `device` span exists ONLY when the profiler already
+            # fenced this query (?profile=true / sampled 1-in-N) — the
+            # record adds zero fences of its own.
+            with TIMELINE.stage("device"):
+                device_s = _fence_device(out)
             prof.tree_device(node, device_s)
-            if tl is not None:
-                TIMELINE.event(tl, "device", LANE_DEVICE, t_dev,
-                               device_s)
         if staged.fp is not None:
             # Feed the cache-opportunity estimator: what one eval of
             # this signature actually cost (dispatch enqueue + fenced
@@ -2308,7 +2380,7 @@ class Executor:
         from pilosa_tpu.core.fragment import CONTAINER_BITS
         host = np.zeros((1, n_shards, CONTAINER_BITS // 32), np.uint32)
         arr = self.mesh.put_bank(host) if self.mesh \
-            else jnp.asarray(host)
+            else upload(host)
         built = ViewBank(arr, {}, 0, {})
         with self._bank_cache_lock:
             bank = self._bank_cache.pop(key, None)
@@ -2356,7 +2428,7 @@ class Executor:
         key = f"topn:{with_filter}:{shape}:{use_pallas}"
         fn = self._jit_get(key)
         if fn is None:
-            self._note_jit_compile()
+            self._note_jit_compile(self._counts_program(with_filter), key)
             if with_filter:
                 if use_pallas:
                     def run(chunk, filt):
@@ -2377,9 +2449,14 @@ class Executor:
                     def run(chunk, filt):
                         c = popcount(chunk, axis=(-2, -1))
                         return c
-            fn = jax.jit(run)
+            fn = jax.jit(named(run, self._counts_program(with_filter)))
             self._jit_put(key, fn)
         return fn
+
+    @staticmethod
+    def _counts_program(with_filter: bool) -> str:
+        """The TopN bank sweep's name in traces."""
+        return "topn_sweep" if with_filter else "topn_sweep_unfiltered"
 
     def _dispatch_counts(self, bank_array, filter_words):
         """Queue the counts kernel; returns unfetched device output.
@@ -2390,10 +2467,10 @@ class Executor:
         filter_words = _align_words(filter_words, bank_array.shape[-1])
         fn = self._counts_fn(filter_words is not None, bank_array.shape)
         # Through the _call_program funnel: TopN sweeps are device
-        # dispatches too, and the timeline's dispatch-gap analyzer
-        # must see them or idle ratios under TopN traffic would read
-        # as pure idle.
-        return self._call_program(fn, bank_array, filter_words)
+        # dispatches too.
+        with self._dispatch_span(
+                self._counts_program(filter_words is not None)):
+            return self._call_program(fn, bank_array, filter_words)
 
     def _fetch_counts(self, out, filter_words):
         """Block on a _dispatch_counts output: (counts_np, raw_np)."""
@@ -2408,10 +2485,12 @@ class Executor:
         from pilosa_tpu.ops.bitset import popcount
         fn = self._jit_get("popcount_row")
         if fn is None:
-            self._note_jit_compile()
-            fn = jax.jit(lambda w: popcount(w, axis=(-2, -1)))
+            self._note_jit_compile("popcount_row", "popcount_row")
+            fn = jax.jit(named(lambda w: popcount(w, axis=(-2, -1)),
+                               "popcount_row"))
             self._jit_put("popcount_row", fn)
-        return self._call_program(fn, words)
+        with self._dispatch_span("popcount_row"):
+            return self._call_program(fn, words)
 
     def _execute_topn(self, idx: Index, call: Call, shards) -> PairsResult:
         """Exact TopN (reference executeTopN 2-phase approximation,
@@ -2767,11 +2846,6 @@ class Executor:
             # trailing broadcast axis makes membership layout-agnostic.
             return (pos[..., None].astype(jnp.int32) == qtop).any(-1)
 
-        # graftlint: disable=GL006 — class-level kernel cache (benches
-        # monkeypatch _pbank_kernel as a classmethod, so no instance is
-        # available to note compiles on); keys are (k, filter, layout,
-        # membership) — a bounded, shape-stable set per deployment.
-        @jax.jit
         def kernel(fw, pos, aux, params):
             # aux: starts [R+1] (flat) | lens [R] (fixed)
             raw = aux if fixed else aux[1:] - aux[:-1]
@@ -2813,7 +2887,13 @@ class Executor:
             score = jnp.where(keep, c, -1)
             return jax.lax.top_k(score, k)
 
-        cls._PBANK_KERNELS[key] = kernel
+        # graftlint: disable=GL006 — class-level kernel cache (benches
+        # monkeypatch _pbank_kernel as a classmethod, so no instance is
+        # available to note compiles on); keys are (k, filter, layout,
+        # membership) — a bounded, shape-stable set per deployment.
+        # The compile log (utils/jaxenv.py) counts it all the same.
+        kernel = cls._PBANK_KERNELS[key] = jax.jit(
+            named(kernel, "topn_positions"))
         return kernel
 
     def _topn_positions(self, pb, filter_words, n: int, tanimoto: int,
@@ -2831,7 +2911,7 @@ class Executor:
         # Params are identical for every segment — build/upload ONCE.
         # (Per-segment rebuilds were one host->device put per segment
         # per query.)
-        params = jnp.asarray(
+        params = upload(
             np.asarray([min_threshold, tanimoto, 0], np.uint32))
         if tanimoto and src_dev is not None:
             params = params.at[2].set(
@@ -2962,20 +3042,21 @@ class Executor:
                 # padding idiom.
                 pad = 1 << (len(sel) - 1).bit_length()
                 sel = sel + [sel[0]] * (pad - len(sel))
-                sel_dev = jnp.asarray(np.asarray(sel, np.int32))
+                sel_dev = upload(np.asarray(sel, np.int32))
                 pkey = f"rankpatch:{bank.array.shape}:{pad}"
                 fn = self._jit_get(pkey)
                 if fn is None:
-                    self._note_jit_compile()
+                    self._note_jit_compile("rank_patch", pkey)
 
                     def patch(c, bank_arr, sel_ix):
                         new = popcount(bank_arr[sel_ix], axis=(-2, -1))
                         return c.at[sel_ix].set(
                             new.astype(c.dtype))
-                    fn = jax.jit(patch)
+                    fn = jax.jit(named(patch, "rank_patch"))
                     self._jit_put(pkey, fn)
-                counts = self._call_program(fn, entry.counts,
-                                            bank.array, sel_dev)
+                with self._dispatch_span("rank_patch"):
+                    counts = self._call_program(fn, entry.counts,
+                                                bank.array, sel_dev)
                 self._note_rank("patch")
         if counts is None:
             counts = self._dispatch_counts(bank.array, None)
@@ -3026,7 +3107,7 @@ class Executor:
             tkey = f"ranktopk:{counts.shape}:{k}"
             fn = self._jit_get(tkey)
             if fn is None:
-                self._note_jit_compile()
+                self._note_jit_compile("rank_topk", tkey)
 
                 def topk(c, params):
                     thr = params[0].astype(jnp.int32)
@@ -3036,11 +3117,11 @@ class Executor:
                     score = jnp.where(ci >= jnp.maximum(1, thr),
                                       ci, -1)
                     return jax.lax.top_k(score, k)
-                fn = jax.jit(topk)
+                fn = jax.jit(named(topk, "rank_topk"))
                 self._jit_put(tkey, fn)
-            params = jnp.asarray(
-                np.asarray([min_threshold], np.uint32))
-            out = self._call_program(fn, counts, params)
+            params = upload(np.asarray([min_threshold], np.uint32))
+            with self._dispatch_span("rank_topk"):
+                out = self._call_program(fn, counts, params)
 
             def finalize() -> PairsResult:
                 vals, idxs = (np.asarray(x) for x in out)
@@ -3278,16 +3359,31 @@ class Executor:
         def _jit(key, builder):
             fn = self._jit_get(key)
             if fn is None:
-                self._note_jit_compile()
-                fn = jax.jit(builder)
+                # "gb_cnt0:(3, 16, 48)" -> program "groupby_cnt0".
+                program = "groupby_" + key.split(":", 1)[0][3:]
+                self._note_jit_compile(program, key)
+                fn = jax.jit(named(builder, program))
                 self._jit_put(key, fn)
-            return fn
+
+            def call(*args):
+                with self._dispatch_span("groupby"):
+                    return fn(*args)
+            return call
+
+        def _host(dev):
+            # GroupBy iterates on the host: each depth's counts are
+            # fetched before the next is planned.
+            with transfer("d2h", int(dev.nbytes)):
+                # graftlint: disable=GL003 — GroupBy frontier pruning
+                # is a host decision by design: one count vector per
+                # depth gates which prefixes expand.
+                return np.asarray(dev)
 
         def stacks_at(depth):
             _, ids = child_rows[depth]
             bank = banks[depth]
-            sel = jnp.asarray(np.asarray([bank.slot(r) for r in ids],
-                                         dtype=np.int32))
+            sel = upload(np.asarray([bank.slot(r) for r in ids],
+                                    dtype=np.int32))
             return bank.array[sel][..., :wmin]  # [R, S, Wmin]
 
         n_shards, depth_n = len(shards), len(child_rows)
@@ -3303,7 +3399,7 @@ class Executor:
 
         def frontier_chunk(frontier, c0, c1):
             sub = frontier[c0:c1]
-            return sub if isinstance(sub, jnp.ndarray) else jnp.asarray(sub)
+            return sub if isinstance(sub, jnp.ndarray) else upload(sub)
 
         for depth in range(depth_n - 1):
             stacks = stacks_at(depth)
@@ -3311,12 +3407,9 @@ class Executor:
             if prefixes is None:
                 cnt = _jit(f"gb_cnt0:{stacks.shape}",
                            lambda st: popcount(st, axis=(-2, -1)))
-                # graftlint: disable=GL003 — GroupBy frontier pruning
-                # is a host decision by design: one [R] count vector
-                # per depth gates which prefixes expand.
-                nz = np.asarray(cnt(stacks)) > 0
+                nz = _host(cnt(stacks)) > 0
                 keep_idx = np.where(nz)[0]
-                prefixes = stacks[jnp.asarray(keep_idx.astype(np.int32))]
+                prefixes = stacks[upload(keep_idx.astype(np.int32))]
                 prefix_rows = [(int(child_rows[depth][1][i]),)
                                for i in keep_idx]
             else:
@@ -3334,11 +3427,11 @@ class Executor:
                             jnp.bitwise_and(s[:, None], st[None]).reshape(
                                 -1, st.shape[-2], st.shape[-1])))
                     new, counts = expand(sub, stacks)
-                    nz = np.asarray(counts) > 0
+                    nz = _host(counts) > 0
                     keep_idx = np.where(nz)[0]
                     if len(keep_idx) == 0:
                         continue
-                    kept = new[jnp.asarray(keep_idx.astype(np.int32))]
+                    kept = new[upload(keep_idx.astype(np.int32))]
                     kept_bytes += kept.nbytes
                     if not spilled and kept_bytes > self.GROUPBY_CHUNK_BYTES:
                         # Survivors exceed the device budget: collect
@@ -3371,7 +3464,7 @@ class Executor:
         if prefixes is None:
             cnt = _jit(f"gb_cnt0:{stacks.shape}",
                        lambda st: popcount(st, axis=(-2, -1)))
-            counts = np.asarray(cnt(stacks))[None, :]  # [1, R]
+            counts = _host(cnt(stacks))[None, :]  # [1, R]
         else:
             counts = None
         chunk_p = max(1, self.GROUPBY_CHUNK_BYTES //
@@ -3386,7 +3479,7 @@ class Executor:
                     lambda s, st: popcount(
                         jnp.bitwise_and(s[:, None], st[None]),
                         axis=(-2, -1)))
-                chunk_counts = np.asarray(cntk(sub, stacks))  # [p, R]
+                chunk_counts = _host(cntk(sub, stacks))  # [p, R]
             else:
                 chunk_counts = counts[c0:c0 + chunk_p]
             for pi in range(chunk_counts.shape[0]):
@@ -3437,7 +3530,7 @@ class Executor:
         # upload (~1 ms/call, comparable to the whole device sweep).
         sel = getattr(bank, "_bsi_sel", None)
         if sel is None or int(sel.shape[0]) != depth + 1:
-            sel = jnp.asarray(np.asarray(
+            sel = upload(np.asarray(
                 [bank.slot(r) for r in range(depth + 1)], dtype=np.int32))
             bank._bsi_sel = sel
         filter_words = None
@@ -3448,9 +3541,10 @@ class Executor:
 
         key = f"val:{op}:{bank.array.shape}:d{depth}:" \
               f"{filter_words is not None}"
+        program = f"bsi_{op.lower()}"
         fn = self._jit_get(key)
         if fn is None:
-            self._note_jit_compile()
+            self._note_jit_compile(program, key)
             from pilosa_tpu.ops.bitset import popcount
             if op == "Sum":
                 def run(bank_arr, sel, filt):
@@ -3461,9 +3555,10 @@ class Executor:
                 def run(bank_arr, sel, filt):
                     bits, cand = kernel(bank_arr[sel], filt)
                     return bits, popcount(cand, axis=(-2, -1))
-            fn = jax.jit(run)
+            fn = jax.jit(named(run, program))
             self._jit_put(key, fn)
-        a, b = fn(bank.array, sel, filter_words)
+        with self._dispatch_span(program):
+            a, b = fn(bank.array, sel, filter_words)
 
         def finalize() -> ValCount:
             if op == "Sum":

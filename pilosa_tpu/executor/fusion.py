@@ -34,7 +34,6 @@ write that orders between them (tests/test_fusion.py pins this).
 
 from __future__ import annotations
 
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -42,9 +41,7 @@ import numpy as np
 from pilosa_tpu.utils.hotspots import WORKLOAD
 from pilosa_tpu.utils.memledger import LEDGER
 from pilosa_tpu.utils.roofline import ROOFLINE
-from pilosa_tpu.utils.timeline import (
-    LANE_DEVICE, LANE_DISPATCH, LANE_PLAN, TIMELINE,
-)
+from pilosa_tpu.utils.timeline import TIMELINE
 
 
 class FusedEval:
@@ -134,18 +131,14 @@ class _FuseGroup:
         self.batched = False
         self.error: Optional[Exception] = None
 
-    def add(self, staged: Any, prof: Any, t_plan0: float) -> FusedEval:
+    def add(self, staged: Any, prof: Any, plan_s: float) -> FusedEval:
         node = None
         if prof is not None:
             # jit hit/miss is unknown until the group compiles at
             # flush; tree_jit fills it in then. The stacked operand
             # upload is likewise charged at flush via tree_h2d.
-            plan_s = time.perf_counter() - t_plan0
             node = prof.tree(staged.mode, staged.sig, None, plan_s, 0,
                              staged.n_shards)
-            if prof.timeline is not None:
-                TIMELINE.event(prof.timeline, "plan", LANE_PLAN,
-                               t_plan0, plan_s, fused=True)
         b = len(self.entries)
         self.entries.append(staged)
         self.profs.append(prof)
@@ -179,6 +172,8 @@ class _FuseGroup:
         import jax
         import jax.numpy as jnp
 
+        from pilosa_tpu.executor.executor import named
+
         ex = self.executor
         B = len(self.entries)
         rep = self.entries[0]
@@ -189,11 +184,10 @@ class _FuseGroup:
             idxs, params, uploaded = ex._staged_args(rep)
             h2d = ((idxs.nbytes + params.nbytes) if uploaded else 0) \
                 + (rep.lits.nbytes if rep.lits is not None else 0)
-            t0 = time.perf_counter()
-            self.out = ex._call_program(fn, rep.bank_arrays, idxs,
-                                        params, rep.lits)
-            self._attribute(jit_hit, t0, time.perf_counter() - t0, h2d,
-                            fused=False)
+            with ex._dispatch_span(rep.program) as ds:
+                self.out = ex._call_program(fn, rep.bank_arrays, idxs,
+                                            params, rep.lits)
+            self._attribute(jit_hit, ds.duration(), h2d, fused=False)
             return
         # Pad to the next power of two with the first entry's operands
         # so distinct batch sizes share O(log B) compiled variants.
@@ -223,14 +217,18 @@ class _FuseGroup:
             lits = jnp.stack([e.lits for e in rows])
         fn = ex._jit_get(key)
         jit_hit = fn is not None
+        program = "fused_" + rep.program
         if fn is None:
-            ex._note_jit_compile()
+            ex._note_jit_compile(program, key)
             in_axes = (None, 0, 0, 0 if rep.lits is not None else None)
-            fn = jax.jit(jax.vmap(rep.runner(), in_axes=in_axes))
+            fn = jax.jit(named(jax.vmap(rep.runner(), in_axes=in_axes),
+                               program))
             ex._jit_put(key, fn)
-        t0 = time.perf_counter()
-        out = ex._call_program(fn, rep.bank_arrays, idxs, params, lits)
-        dispatch_s = time.perf_counter() - t0
+        with ex._dispatch_span(program) as ds:
+            ds.set("fusedBatch", B)
+            out = ex._call_program(fn, rep.bank_arrays, idxs, params,
+                                   lits)
+        dispatch_s = ds.duration()
         if bp != B:
             out = out[:B]  # drop pad lanes before anything reads them
         self.out = out
@@ -250,10 +248,10 @@ class _FuseGroup:
         # real members, so the per-query sum equals the real traffic.
         h2d = ((idxs.nbytes + params.nbytes) // B if uploaded else 0) \
             + (rep.lits.nbytes if rep.lits is not None else 0)
-        self._attribute(jit_hit, t0, dispatch_s, h2d, fused=True)
+        self._attribute(jit_hit, dispatch_s, h2d, fused=True)
 
-    def _attribute(self, jit_hit: bool, t_disp: float, dispatch_s: float,
-                   h2d: int, fused: bool) -> None:
+    def _attribute(self, jit_hit: bool, dispatch_s: float, h2d: int,
+                   fused: bool) -> None:
         B = len(self.entries)
         fence_profs = []
         for b, (prof, node) in enumerate(zip(self.profs, self.nodes)):
@@ -269,27 +267,15 @@ class _FuseGroup:
                 node.attrs["fusedBatch"] = B
                 node.attrs["batchIndex"] = b
                 prof.set_fused(B)
-            if prof.timeline is not None:
-                # The shared group dispatch, stamped into every
-                # member's timeline with its batch coordinates (same
-                # convention as the profile tree).
-                TIMELINE.event(prof.timeline, "dispatch", LANE_DISPATCH,
-                               t_disp, dispatch_s,
-                               **({"fusedBatch": B, "batchIndex": b}
-                                  if fused else {}))
             if prof.sample_device:
                 fence_profs.append((prof, node))
         device_s = 0.0
         if fence_profs:
             from pilosa_tpu.executor.executor import _fence_device
-            t_dev = time.perf_counter()
-            device_s = _fence_device(self.out)
+            with TIMELINE.stage("device"):
+                device_s = _fence_device(self.out)
             for prof, node in fence_profs:
                 prof.tree_device(node, device_s)
-                if prof.timeline is not None:
-                    TIMELINE.event(prof.timeline, "device", LANE_DEVICE,
-                                   t_dev, device_s,
-                                   **({"fusedBatch": B} if fused else {}))
             # No plan IR on this path, so no byte attribution: count
             # the fenced time as unattributed so /debug/roofline
             # states how much sampled device time its bytes explain.
@@ -314,7 +300,7 @@ class FusionCollector:
         self.executor = executor
         self.groups: Dict[tuple, _FuseGroup] = {}
 
-    def add(self, staged: Any, prof: Any, t_plan0: float) -> FusedEval:
+    def add(self, staged: Any, prof: Any, plan_s: float) -> FusedEval:
         """Stage one eval; returns its FusedEval handle. Grouping is
         by (sig, bank-array identity): the signature equates tree
         shape, widths and shard count, and identity equates the actual
@@ -324,7 +310,7 @@ class FusionCollector:
         group = self.groups.get(key)
         if group is None:
             group = self.groups[key] = _FuseGroup(self.executor)
-        return group.add(staged, prof, t_plan0)
+        return group.add(staged, prof, plan_s)
 
     def flush(self) -> None:
         groups, self.groups = self.groups, {}

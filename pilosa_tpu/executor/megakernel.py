@@ -36,7 +36,6 @@ an over-budget cohort falls back rather than OOM.
 from __future__ import annotations
 
 import os
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -44,10 +43,9 @@ import numpy as np
 from pilosa_tpu.ops import megakernel as mk
 from pilosa_tpu.utils.hotspots import WORKLOAD
 from pilosa_tpu.utils.memledger import LEDGER
+from pilosa_tpu.utils.profile import transfer
 from pilosa_tpu.utils.roofline import ROOFLINE
-from pilosa_tpu.utils.timeline import (
-    LANE_DEVICE, LANE_DISPATCH, TIMELINE,
-)
+from pilosa_tpu.utils.timeline import TIMELINE
 
 def _default_enabled() -> bool:
     """PILOSA_TPU_MEGAKERNEL: 1 forces on, 0 kills, default `auto` =
@@ -55,7 +53,7 @@ def _default_enabled() -> bool:
     where the per-launch floor is the bottleneck; on CPU an XLA launch
     costs ~20 µs while the interpreter's per-launch slab gather is
     real memcpy, so the per-group vmap path measured faster there
-    (benches/mega_burst_bench.py). `auto` asks the backend: a device
+    (PR 11's CPU bench, since removed). `auto` asks the backend: a device
     that cannot initialise is an error here, not "feature off"."""
     flag = os.environ.get("PILOSA_TPU_MEGAKERNEL", "auto").strip().lower()
     if flag in ("1", "true", "yes", "on"):
@@ -258,24 +256,29 @@ def _build(cohort: List[Any]) -> Tuple[mk.Plan, int, List[List[int]]]:
     capture hook, the telemetry) sees exactly the plan that will
     dispatch."""
     w_mega = max(e.width for g in cohort for e in g.entries)
-    low = mk.Lowering()
-    lanes: List[List[int]] = []
-    for g in cohort:
-        g_lanes = []
-        for e in g.entries:
-            g_lanes.append(low.add_entry(e.ir, e.bank_arrays, e.idxs,
-                                         e.params, e.width, e.mode))
-        lanes.append(g_lanes)
-    plan = low.finish()
+    with TIMELINE.stage("plan.lower") as sp:
+        low = mk.Lowering()
+        lanes: List[List[int]] = []
+        for g in cohort:
+            g_lanes = []
+            for e in g.entries:
+                g_lanes.append(low.add_entry(e.ir, e.bank_arrays, e.idxs,
+                                             e.params, e.width, e.mode))
+            lanes.append(g_lanes)
+        plan = low.finish()
+        sp.set("entries", plan.n_instrs)
     if PLAN_OPT_ENABLED:
-        try:
-            from pilosa_tpu.ops import plan_opt
-            plan, _stats = plan_opt.optimize_plan(
-                plan, cohort[0].entries[0].n_shards, w_mega)
-        except Exception:
-            # Best-effort by contract: a surprised optimizer means the
-            # raw Lowering plan launches, never a failed request.
-            pass
+        with TIMELINE.stage("plan.optimise") as sp:
+            try:
+                from pilosa_tpu.ops import plan_opt
+                plan, _stats = plan_opt.optimize_plan(
+                    plan, cohort[0].entries[0].n_shards, w_mega)
+                sp.set("entries", plan.n_instrs)
+            except Exception:
+                # Best-effort by contract: a surprised optimizer means
+                # the raw Lowering plan launches, never a failed
+                # request.
+                pass
     return plan, w_mega, lanes
 
 
@@ -315,14 +318,15 @@ def _launch(executor: Any, cohort: List[Any], plan: mk.Plan,
         # bits on device.
         if PLAN_VERIFY_MODE == "on" or (PLAN_VERIFY_MODE == "auto"
                                         and not jit_hit):
-            try:
-                mk.verify_plan(plan, n_shards, w_mega, mesh=spec)
-            except mk.PlanVerifyError:
-                ex._note_plan_verify(False)
-                raise
-            ex._note_plan_verify(True)
+            with TIMELINE.stage("plan.verify"):
+                try:
+                    mk.verify_plan(plan, n_shards, w_mega, mesh=spec)
+                except mk.PlanVerifyError:
+                    ex._note_plan_verify(False)
+                    raise
+                ex._note_plan_verify(True)
         if fn is None:
-            ex._note_jit_compile()
+            ex._note_jit_compile("mega_plan", key)
             if mesh is not None:
                 # GSPMD partitions the interpreter over the mesh-
                 # sharded banks; the epilogue's count-lane sum over
@@ -350,18 +354,21 @@ def _launch(executor: Any, cohort: List[Any], plan: mk.Plan,
         else:
             def _put(a: Any) -> Any:
                 return jax.device_put(np.asarray(a), mesh.replicated())
-        slots_dev = tuple(_put(s) for s in plan.slots)
-        widths_dev = _put(plan.widths)
-        instrs_dev = _put(plan.instrs)
-        out_count_dev = _put(plan.out_count)
-        out_row_dev = _put(plan.out_row)
-        xslots_dev = tuple(_put(s) for s in plan.xslots)
         plan_bytes = plan.plan_nbytes
-        t0 = time.perf_counter()
-        out = ex._call_program(fn, plan.banks, slots_dev, widths_dev,
-                               instrs_dev, out_count_dev, out_row_dev,
-                               plan.xbanks, xslots_dev)
-        dispatch_s = time.perf_counter() - t0
+        with transfer("h2d", plan_bytes,
+                      4 + len(plan.slots) + len(plan.xslots)):
+            slots_dev = tuple(_put(s) for s in plan.slots)
+            widths_dev = _put(plan.widths)
+            instrs_dev = _put(plan.instrs)
+            out_count_dev = _put(plan.out_count)
+            out_row_dev = _put(plan.out_row)
+            xslots_dev = tuple(_put(s) for s in plan.xslots)
+        with ex._dispatch_span("mega_plan") as ds:
+            ds.set("entries", n_entries)
+            out = ex._call_program(fn, plan.banks, slots_dev, widths_dev,
+                                   instrs_dev, out_count_dev, out_row_dev,
+                                   plan.xbanks, xslots_dev)
+        dispatch_s = ds.duration()
     except Exception as e:
         for g in cohort:
             g.error = e
@@ -432,7 +439,7 @@ def _launch(executor: Any, cohort: List[Any], plan: mk.Plan,
             ex._note_launch_cost(cost)
         if plan.opt_stats is not None:
             ex._note_opt(plan.opt_stats)
-        _attribute(ex, cohort, launch, jit_hit, t0, dispatch_s, plan,
+        _attribute(ex, cohort, launch, jit_hit, dispatch_s, plan,
                    plan_bytes, n_entries, cost, ckey)
     except Exception as e:
         # Per-member error isolation, the _FuseGroup.run contract: an
@@ -449,11 +456,11 @@ def _launch(executor: Any, cohort: List[Any], plan: mk.Plan,
 
 
 def _attribute(ex: Any, cohort: List[Any], launch: _MegaLaunch,
-               jit_hit: bool, t_disp: float, dispatch_s: float,
-               plan: mk.Plan, plan_bytes: int, n_entries: int,
+               jit_hit: bool, dispatch_s: float, plan: mk.Plan,
+               plan_bytes: int, n_entries: int,
                cost: Optional[Dict[str, Any]] = None,
                ckey: str = "") -> None:
-    """Profiler/timeline attribution, the _FuseGroup._attribute
+    """Profile attribution, the _FuseGroup._attribute
     convention: the program ran once for the whole launch, so every
     member sees the shared dispatch (and sampled device) time labeled
     with its launch coordinates. When a sampled fence fires, the cost
@@ -495,27 +502,15 @@ def _attribute(ex: Any, cohort: List[Any], launch: _MegaLaunch,
                 node.attrs["planEntriesBefore"] = opt.entries_before
                 node.attrs["planEntriesAfter"] = opt.entries_after
             prof.set_fused(n_entries)
-            if prof.timeline is not None:
-                extra = {}
-                if opt is not None:
-                    extra = dict(planEntriesBefore=opt.entries_before,
-                                 planEntriesAfter=opt.entries_after)
-                TIMELINE.event(prof.timeline, "dispatch", LANE_DISPATCH,
-                               t_disp, dispatch_s, megaBatch=n_entries,
-                               megaIndex=b, planEntries=plan.n_instrs,
-                               planBytes=plan_bytes, **extra)
             if prof.sample_device:
                 fence_profs.append((prof, node))
     device_s = 0.0
     if fence_profs:
         from pilosa_tpu.executor.executor import _fence_device
-        t_dev = time.perf_counter()
-        device_s = _fence_device(launch.out)
+        with TIMELINE.stage("device", megaBatch=n_entries):
+            device_s = _fence_device(launch.out)
         for prof, node in fence_profs:
             prof.tree_device(node, device_s)
-            if prof.timeline is not None:
-                TIMELINE.event(prof.timeline, "device", LANE_DEVICE,
-                               t_dev, device_s, megaBatch=n_entries)
         if cost is not None:
             # Bytes ÷ the fence we already paid = achieved bandwidth:
             # per-cohort EWMA + drift detection in the recorder, and a

@@ -14,6 +14,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from pilosa_tpu.ops.bitset import SHARD_WIDTH, unpack_positions
+from pilosa_tpu.utils.profile import transfer
 
 
 class RowResult:
@@ -36,8 +37,15 @@ class RowResult:
             return self._columns
         # `words` may be a fusion handle (executor/fusion.FusedEval):
         # np.asarray resolves it against the fused batch output, one
-        # shared transfer per fusion group.
-        host = np.asarray(self.words)
+        # shared transfer per fusion group. The fetch is a `d2h` stage
+        # of the request record: only a response that reads columns
+        # pays it (Count, excludeColumns never do).
+        words = self.words
+        if isinstance(words, np.ndarray):
+            host = words
+        else:
+            with transfer("d2h", int(getattr(words, "nbytes", 0) or 0)):
+                host = np.asarray(words)
         out = []
         for i, shard in enumerate(self.shards):
             pos = unpack_positions(host[i])

@@ -32,7 +32,7 @@ import time
 from collections import deque
 from pilosa_tpu.utils.locks import make_lock
 from pilosa_tpu.utils.stats import NopStatsClient
-from pilosa_tpu.utils.timeline import LANE_REMOTE, TIMELINE
+from pilosa_tpu.utils.timeline import TIMELINE
 from typing import Any, Dict, List, Optional, Sequence
 
 from pilosa_tpu.executor.results import result_to_json
@@ -453,17 +453,16 @@ class ClusterExecutor:
                 # Scatter threads have no open span: adopt the
                 # request's trace id so the outgoing leg injects the
                 # SAME traceparent the coordinator received.
-                if trace_id and hasattr(tracer, "adopt"):
-                    tracer.adopt(trace_id)
-                # Remote-leg slice on the coordinator's request
-                # timeline: how long this node's scatter-gather round
-                # trip took (the remote's own stage slices record on
-                # ITS timeline under the same trace id and assemble
-                # via /cluster/timeline).
+                # Remote-leg span on the coordinator's request record:
+                # how long this node's scatter-gather round trip took
+                # (the remote's own stages record on ITS ring under
+                # the same trace id and assemble via
+                # /cluster/timeline).
                 tl = getattr(profile, "timeline", None) \
                     if profile is not None else None
-                lane = f"hedge:{node.id}" if hedge \
-                    else f"remote:{node.id}"
+                if trace_id and hasattr(tracer, "adopt"):
+                    tracer.adopt(trace_id)
+                lane = "hedge" if hedge else "remote"
                 t0 = time.perf_counter()
                 try:
                     rem_leg = remaining()
@@ -495,9 +494,9 @@ class ClusterExecutor:
                     # second profile fragment (device time would
                     # double-count) or a success slice for a result
                     # that never merged.
-                    TIMELINE.event(tl, lane, LANE_REMOTE, t0, dur,
-                                   remote=node.id,
-                                   shards=len(leg.shards))
+                    TIMELINE.add(tl, lane, t0, t0 + dur,
+                                 own_lane=True, remote=node.id,
+                                 shards=len(leg.shards))
                     if want_profile and res.get("profile") is not None:
                         profile.add_node_fragment(node.id,
                                                   res["profile"])
@@ -507,9 +506,9 @@ class ClusterExecutor:
                     # malformed response shape) previously killed the
                     # scatter thread with `failed` still False and the
                     # merge silently undercounted the lost partition.
-                    TIMELINE.event(tl, lane, LANE_REMOTE,
-                                   t0, time.perf_counter() - t0,
-                                   remote=node.id, error=str(e)[:200])
+                    TIMELINE.add(tl, lane, t0, time.perf_counter(),
+                                 own_lane=True, remote=node.id,
+                                 error=str(e)[:200])
                     with results_lock:
                         # The node did fail its RPC: excluding it from
                         # later rounds is right either way. But the

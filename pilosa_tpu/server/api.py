@@ -19,9 +19,12 @@ from pilosa_tpu.core.field import FieldOptions
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.core import timeq
 from pilosa_tpu.executor import Executor
+from pilosa_tpu.executor.executor import write_call_count
 from pilosa_tpu.executor.results import result_to_json
 from pilosa_tpu.ops.bitset import SHARD_WIDTH
+from pilosa_tpu.pql import Call, Query, parse_string_cached
 from pilosa_tpu.utils.failpoints import FAILPOINTS
+from pilosa_tpu.utils.timeline import TIMELINE
 from pilosa_tpu import __version__
 
 # Fault-injection sites on the server seams (utils/failpoints.py
@@ -96,6 +99,12 @@ class API:
         # Batch-scoped executor signals (fusion counters/group sizes)
         # have no per-query profile to ride — feed them straight in.
         self.executor.stats = self.stats
+        # The transfer counters the request records' h2d / d2h spans
+        # feed (utils/timeline.py), published from the start so a
+        # window without a transfer reads 0, not "no such counter".
+        self.stats.batch((), [(f"executor.{d}_{k}", 0)
+                              for d in ("h2d", "d2h")
+                              for k in ("bytes", "transfers")])
         # The process-wide workload recorder (utils/hotspots.py)
         # increments its counters (pilosa_fragment_reads_total, ...)
         # straight into the stats client at record time so the
@@ -278,18 +287,23 @@ class API:
         self.stats.gauge("executor.jit_cache_size",
                          self.executor.jit_cache_size())
 
-    def _begin_timeline(self, index: str):
-        """Open a request timeline under the SAME trace id the tracer
-        will stamp on this request's spans (minting one when the
-        request arrived without a traceparent), so /debug/queries,
-        exported spans and /debug/timeline all cross-link by it."""
-        from pilosa_tpu.utils.timeline import TIMELINE
+    def begin_request(self, index: str):
+        """Open a request record under the SAME trace id outgoing legs
+        and the profiler carry (minting one when the request arrived
+        without a traceparent), so /debug/queries, exported spans and
+        /debug/timeline all cross-link by it. The HTTP handler calls
+        this before it reads the body and end_request() after the
+        socket write, so the record tiles the whole exchange; query()
+        opens its own for callers that hand it none."""
         tid = getattr(self.tracer, "ensure_trace_id", lambda: None)()
-        return TIMELINE.begin(tid, index)
+        rec = TIMELINE.begin(tid, index, stats=self.stats)
+        if rec is not None and hasattr(self.tracer, "adopt"):
+            # Outgoing node-to-node legs name the root as their parent.
+            self.tracer.adopt(rec.trace_id, rec.root)
+        return rec
 
-    def _end_timeline(self, tl, err) -> None:
-        from pilosa_tpu.utils.timeline import TIMELINE
-        TIMELINE.finish(tl, error=err)
+    def end_request(self, rec, err=None) -> None:
+        TIMELINE.finish(rec, error=err)
         # The request is over: drop the thread-adopted trace id so an
         # embedded (non-HTTP) caller's next query on this thread mints
         # a fresh id instead of stitching every query into one trace.
@@ -299,25 +313,49 @@ class API:
         if adopt is not None:
             adopt(None)
 
+    def _parse_stage(self, rec, query) -> Optional[bool]:
+        """The `pql.parse` stage: parse the text once on the request's
+        own thread (later stages take the parser's cached tree), name
+        the record after its top-level calls, and say whether it
+        writes. None = unparseable; the dispatch path reports that."""
+        with TIMELINE.span(rec, "pql.parse"):
+            try:
+                q = parse_string_cached(query) \
+                    if isinstance(query, str) else query
+                if isinstance(q, Call):
+                    q = Query([q])
+                is_write = write_call_count(q) > 0
+            except Exception:
+                return None
+        if rec is not None:
+            rec.root.attrs["calls"] = ",".join(
+                (c.children[0].name if c.name == "Options" and c.children
+                 else c.name) for c in q.calls[:4])
+        return is_write
+
     def query(self, index: str, query: str,
               shards: Optional[Sequence[int]] = None,
-              remote: bool = False, profile: bool = False
-              ) -> Dict[str, Any]:
+              remote: bool = False, profile: bool = False,
+              record=None) -> Dict[str, Any]:
         """(reference API.Query, api.go:103). Returns the JSON-shaped
         response {"results": [...]}. `remote=True` marks a node-to-node
         sub-query: execute locally only, no re-fan-out (the reference's
         opt.Remote, executor.go:2236). `profile=True` (the
         ?profile=true surface) embeds the execution profile tree in the
-        response with device-time fencing on."""
+        response with device-time fencing on. `record` is the request
+        record the caller opened (and will finish); without one the
+        query opens and finishes its own."""
         _FP_QUERY.fire(index=index, remote=remote)
-        tl = self._begin_timeline(index)
+        tl = record if record is not None else self.begin_request(index)
         prof = self.profiler.begin(index, query, shards,
                                    force=bool(profile))
         prof.timeline = tl
         t0 = _time.perf_counter()
         err = None
         try:
-            resp = self._query(index, query, shards, remote, prof)
+            self._parse_stage(tl, query)
+            with TIMELINE.attached(tl):
+                resp = self._query(index, query, shards, remote, prof)
             if profile:
                 prof.close(_time.perf_counter() - t0)
                 resp = dict(resp)
@@ -328,16 +366,14 @@ class API:
             raise
         finally:
             dur = _time.perf_counter() - t0
-            # Direct-path latency histogram: the baseline the coalesced
-            # path's coalescer.request timing is compared against.
-            self.stats.timing("query.direct", dur)
-            self._end_timeline(tl, err)
             self._observe_query(index, query, dur, prof, err)
+            if record is None:
+                self.end_request(tl, err)
 
     def query_coalesced(self, index: str, query,
                         shards: Optional[Sequence[int]] = None,
-                        remote: bool = False, profile: bool = False
-                        ) -> Dict[str, Any]:
+                        remote: bool = False, profile: bool = False,
+                        record=None) -> Dict[str, Any]:
         """query() that rides the serving-path coalescer when one is
         attached and the request is eligible: concurrent single-query
         HTTP requests share one stacked executor batch (see
@@ -349,82 +385,78 @@ class API:
         if (coal is None or not coal.running or remote
                 or self.cluster_executor is not None):
             return self.query(index, query, shards=shards, remote=remote,
-                              profile=profile)
+                              profile=profile, record=record)
         _FP_QUERY.fire(index=index, remote=remote)
         from pilosa_tpu.server.coalescer import CoalescerStopped
-        tl = self._begin_timeline(index)
+        tl = record if record is not None else self.begin_request(index)
         prof = self.profiler.begin(index, query, shards,
                                    force=bool(profile))
         prof.timeline = tl
         t0 = _time.perf_counter()
         err = None
         try:
-            with self.tracer.span("API.QueryCoalesced",
-                                  index=index) as sp:
-                self.stats.count("query", 1)
-                try:
-                    resp = coal.submit(index, query, shards=shards,
-                                       profile=prof)
-                except CoalescerStopped:
-                    # Lost the race with coalescer.stop(): serve the
-                    # request directly rather than failing it. (Only
-                    # this sentinel retries — a genuine executor
-                    # RuntimeError must surface, not re-run.) Inline
-                    # direct path, not self._query: "query" was already
-                    # counted above and must not double-count.
-                    t1 = _time.perf_counter()
-                    try:
-                        resp = self.executor.execute_full(
-                            index, query, shards=shards, profile=prof)
-                    finally:
-                        self.stats.timing(
-                            "query.direct",
-                            _time.perf_counter() - t1)
-                prof.annotate_span(sp)
-                if profile:
-                    # Forced profiles are excluded from coalescer dedup,
-                    # so resp is this request's own dict — still copy
-                    # before mutating (defense against future sharing).
-                    prof.close(_time.perf_counter() - t0)
-                    resp = dict(resp)
-                    resp["profile"] = prof.to_json()
-                return resp
+            is_write = self._parse_stage(tl, query)
+            self.stats.count("query", 1)
+            try:
+                resp = coal.submit(index, query, shards=shards,
+                                   profile=prof, is_write=is_write)
+            except CoalescerStopped:
+                # Lost the race with coalescer.stop(): serve the
+                # request directly rather than failing it. (Only
+                # this sentinel retries — a genuine executor
+                # RuntimeError must surface, not re-run.) Inline
+                # direct path, not self._query: "query" was already
+                # counted above and must not double-count.
+                with TIMELINE.attached(tl):
+                    resp = self.executor.execute_full(
+                        index, query, shards=shards, profile=prof)
+            if tl is not None:
+                prof.annotate_span(tl.root)
+            if profile:
+                # Forced profiles are excluded from coalescer dedup,
+                # so resp is this request's own dict — still copy
+                # before mutating (defense against future sharing).
+                prof.close(_time.perf_counter() - t0)
+                resp = dict(resp)
+                resp["profile"] = prof.to_json()
+            return resp
         except Exception as e:
             err = e
             raise
         finally:
             dur = _time.perf_counter() - t0
-            self._end_timeline(tl, err)
             self._observe_query(index, query, dur, prof, err)
+            if record is None:
+                self.end_request(tl, err)
 
     def _query(self, index: str, query: str,
                shards: Optional[Sequence[int]] = None,
                remote: bool = False, prof=None) -> Dict[str, Any]:
-        with self.tracer.span("API.Query", index=index) as sp:
-            self.stats.count("query", 1)
-            try:
-                if remote:
-                    # Node-to-node leg: results only; the coordinator owns
-                    # response shaping (columnAttrs etc).
-                    results = self.executor.execute(index, query,
-                                                    shards=shards,
-                                                    profile=prof)
-                    return {"results": [result_to_json(r)
-                                        for r in results]}
-                if self.cluster_executor is not None:
-                    from pilosa_tpu.pql import parse_string
-                    q = parse_string(query) if isinstance(query, str) \
-                        else query
-                    resp = {"results": self.cluster_executor.execute(
-                        index, q, shards=shards, profile=prof)}
-                    self._attach_column_attrs(index, q, resp)
-                    return resp
-                return self.executor.execute_full(index, query,
-                                                  shards=shards,
-                                                  profile=prof)
-            finally:
-                if prof is not None:
-                    prof.annotate_span(sp)
+        self.stats.count("query", 1)
+        try:
+            if remote:
+                # Node-to-node leg: results only; the coordinator owns
+                # response shaping (columnAttrs etc).
+                results = self.executor.execute(index, query,
+                                                shards=shards,
+                                                profile=prof)
+                return {"results": [result_to_json(r)
+                                    for r in results]}
+            if self.cluster_executor is not None:
+                from pilosa_tpu.pql import parse_string
+                q = parse_string(query) if isinstance(query, str) \
+                    else query
+                resp = {"results": self.cluster_executor.execute(
+                    index, q, shards=shards, profile=prof)}
+                self._attach_column_attrs(index, q, resp)
+                return resp
+            return self.executor.execute_full(index, query,
+                                              shards=shards,
+                                              profile=prof)
+        finally:
+            tl = getattr(prof, "timeline", None)
+            if tl is not None:
+                prof.annotate_span(tl.root)
 
     def query_batch(self, items: Sequence[Dict[str, Any]]
                     ) -> List[Dict[str, Any]]:
@@ -443,44 +475,43 @@ class API:
         the cluster path items execute sequentially (fan-out legs
         already pipeline per node) — the HTTP round trip is still
         amortized."""
-        with self.tracer.span("API.QueryBatch", n=len(items)):
-            if self.cluster_executor is not None:
-                # self.query() counts the "query" stat per item.
-                out = []
-                for it in items:
-                    try:
-                        out.append(self.query(it["index"], it["query"],
-                                              shards=it.get("shards")))
-                    except Exception as e:
-                        out.append({"error": str(e)})
-                return out
-            self.stats.count("query", len(items))
-            t0 = _time.perf_counter()
-            # Malformed items degrade per-item, same as execution errors.
-            reqs = []
-            shaped_err = {}
-            for pos, it in enumerate(items):
-                try:
-                    reqs.append((it["index"], it["query"],
-                                 it.get("shards")))
-                except (KeyError, TypeError) as e:
-                    shaped_err[pos] = {"error": f"bad batch item: {e!r}"}
-                    reqs.append(None)
-            shaped = self.executor.execute_batch_shaped(
-                [r for r in reqs if r is not None])
+        if self.cluster_executor is not None:
+            # self.query() counts the "query" stat per item.
             out = []
-            bi = iter(shaped)
-            for pos, r in enumerate(reqs):
-                if r is None:
-                    out.append(shaped_err[pos])
-                    continue
-                res = next(bi)
-                out.append({"error": str(res)}
-                           if isinstance(res, Exception) else res)
-            dur = _time.perf_counter() - t0
-            self._observe_query("*", f"{len(items)} queries", dur,
-                                kind="batch")
+            for it in items:
+                try:
+                    out.append(self.query(it["index"], it["query"],
+                                          shards=it.get("shards")))
+                except Exception as e:
+                    out.append({"error": str(e)})
             return out
+        self.stats.count("query", len(items))
+        t0 = _time.perf_counter()
+        # Malformed items degrade per-item, same as execution errors.
+        reqs = []
+        shaped_err = {}
+        for pos, it in enumerate(items):
+            try:
+                reqs.append((it["index"], it["query"],
+                             it.get("shards")))
+            except (KeyError, TypeError) as e:
+                shaped_err[pos] = {"error": f"bad batch item: {e!r}"}
+                reqs.append(None)
+        shaped = self.executor.execute_batch_shaped(
+            [r for r in reqs if r is not None])
+        out = []
+        bi = iter(shaped)
+        for pos, r in enumerate(reqs):
+            if r is None:
+                out.append(shaped_err[pos])
+                continue
+            res = next(bi)
+            out.append({"error": str(res)}
+                       if isinstance(res, Exception) else res)
+        dur = _time.perf_counter() - t0
+        self._observe_query("*", f"{len(items)} queries", dur,
+                            kind="batch")
+        return out
 
     def _attach_column_attrs(self, index: str, q, resp: Dict[str, Any]
                              ) -> None:
@@ -843,11 +874,8 @@ class API:
         TIMELINE.register_memory(LEDGER)
         ROOFLINE.register_memory(LEDGER)
         SENTINEL.register_memory(LEDGER)
-        if hasattr(self.tracer, "register_memory"):
-            self.tracer.register_memory(LEDGER)
         LEDGER.publish(self.stats)
         WORKLOAD.publish(self.stats)
-        TIMELINE.publish(self.stats)
         # Roofline gauges (pilosa_roofline_*): resolved/achieved GB/s,
         # the fraction EWMA, cohort count, and the drift counter.
         ROOFLINE.publish(self.stats)
@@ -944,8 +972,7 @@ class API:
         """The GET /debug/timeline document (utils/timeline.py):
         Chrome trace-event JSON for the last N recorded requests (or
         one trace id), loadable directly in Perfetto/chrome://tracing,
-        plus the dispatch-gap summary (`deviceIdleRatio` — the baseline
-        ROADMAP 5's RTT-hiding pipeline must improve)."""
+        plus per-stage medians and per-call-name stage means."""
         from pilosa_tpu.utils.timeline import TIMELINE
         node_id, _ = self._node_ident()
         self.refresh_memory_gauges()
@@ -991,7 +1018,6 @@ class API:
         from pilosa_tpu.utils.memledger import HOST_CATEGORIES, LEDGER
         from pilosa_tpu.utils.roofline import ROOFLINE
         from pilosa_tpu.utils.sentinel import SENTINEL
-        from pilosa_tpu.utils.timeline import TIMELINE
         if not SENTINEL.enabled:
             return
         rsnap = ROOFLINE.snapshot()
@@ -1005,7 +1031,6 @@ class API:
         rebuilds = self.executor.rank_cache_rebuilds
         coal = self.coalescer
         gauges = {
-            "device_idle_ratio": TIMELINE.idle_ratio(),
             "roofline_achieved_gbps": rsnap["achievedGbps"],
             "roofline_fraction": rsnap["rooflineFraction"],
             "result_cache_hit_ratio": rc["hitRatio"],
@@ -1078,10 +1103,10 @@ class API:
         Perfetto's process track already shows it, but the JSON must be
         self-describing too)."""
         from pilosa_tpu.utils.timeline import TimelineRecorder
-        evs = TimelineRecorder.metadata_events(pid, node_id)
+        evs = TimelineRecorder.process_metadata(pid, node_id)
         for ev in doc.get("traceEvents", []):
-            if ev.get("ph") != "X":
-                continue  # re-emit our own metadata per pid instead
+            if ev.get("ph") != "X" and ev.get("name") != "thread_name":
+                continue  # the process is re-named per pid above
             ev = dict(ev)
             ev["pid"] = pid
             args = dict(ev.get("args") or {})
@@ -1167,6 +1192,7 @@ class API:
         cluster_health() merges one of these per node."""
         from pilosa_tpu.executor import megakernel as _megamod
         from pilosa_tpu.utils.hotspots import WORKLOAD
+        from pilosa_tpu.utils.jaxenv import COMPILES as _COMPILES
         from pilosa_tpu.utils.memledger import LEDGER
         from pilosa_tpu.utils.sentinel import SENTINEL as _SENTINEL
         from pilosa_tpu.utils.timeline import TIMELINE as _TIMELINE
@@ -1200,6 +1226,12 @@ class API:
                 "queueDepth": coal.queue_depth() if coal is not None
                 else 0,
             },
+            # Every XLA compile of the process (utils/jaxenv.py
+            # CompileLog): totals here, the table by function name at
+            # GET /debug/queries. `executor.retraces` below only sees
+            # the executor's own jit cache.
+            "xla": {k: v for k, v in _COMPILES.snapshot().items()
+                    if k != "byName"},
             "executor": {
                 "jitCacheSize": self.executor.jit_cache_size(),
                 "retraces": self.executor.jit_compiles,
@@ -1260,12 +1292,10 @@ class API:
             # read/write counters + live repeat ratios, so capacity
             # AND access skew read from one health document.
             "workload": workload,
-            # Timeline plane (utils/timeline.py): recorded-request
-            # count + the rolling device idle ratio, so dispatch-floor
-            # pressure reads from the same health document.
+            # Timeline plane (utils/timeline.py): request records
+            # taken so far.
             "timeline": {
                 "requestsRecorded": _TIMELINE.requests_recorded,
-                "deviceIdleRatio": _TIMELINE.idle_ratio(),
             },
             "watchdog": {
                 "running": bool(wd is not None and wd.running),
@@ -1443,7 +1473,7 @@ class API:
         for pid, nd in enumerate(health["nodes"]):
             evs = nd.get("clusterEvents") or []
             if evs:
-                trace_events.extend(TimelineRecorder.metadata_events(
+                trace_events.extend(TimelineRecorder.process_metadata(
                     pid, str(nd.get("id", pid))))
             for ev in evs:
                 merged.append({**ev, "observer": nd.get("id")})

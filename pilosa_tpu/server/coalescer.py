@@ -38,9 +38,11 @@ Robustness pieces a production front door needs:
 - Per-request deadlines: an expired request is ejected from the window
   (its dispatch skipped) and fails with 408 instead of occupying a
   batch slot.
-- Observability: queue depth, batch occupancy, flush-reason counters,
-  and latency histograms via utils/stats.py; flushes are span-annotated
-  via utils/tracing.py.
+- Observability: queue depth, batch occupancy and flush-reason
+  counters via utils/stats.py; every flush is a record of its own in
+  utils/timeline.py (root `coalescer.flush`, its stages beneath it),
+  and every member request holds a `coalescer.wait` span and a
+  `coalescer.flush` span that links to the flush it rode.
 
 Coalescing is semantically invisible: single-item flushes run the exact
 direct path (`Executor.execute_full`), write-containing queries flush
@@ -61,7 +63,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 from pilosa_tpu.server.api import ApiError
 from pilosa_tpu.utils.fingerprint import request_key
 from pilosa_tpu.utils.hotspots import WORKLOAD
-from pilosa_tpu.utils.timeline import LANE_COALESCE, LANE_QUEUE, TIMELINE
+from pilosa_tpu.utils.timeline import TIMELINE
 
 # Item lifecycle: PENDING (queued, still ejectable) -> CLAIMED (taken by
 # the dispatcher; result imminent) or EJECTED (deadline passed while
@@ -134,17 +136,15 @@ class QueryCoalescer:
 
     def __init__(self, executor, window_s: float = 0.0015,
                  max_batch: int = 64, max_queue: int = 256,
-                 deadline_s: float = 0.0, stats=None, tracer=None,
-                 logger=None, pipeline: Optional[bool] = None):
+                 deadline_s: float = 0.0, stats=None, logger=None,
+                 pipeline: Optional[bool] = None):
         from pilosa_tpu.utils.stats import NopStatsClient
-        from pilosa_tpu.utils.tracing import NopTracer
         self.executor = executor
         self.window_s = max(0.0, float(window_s))
         self.max_batch = max(1, int(max_batch))
         self.max_queue = max(1, int(max_queue))
         self.deadline_s = max(0.0, float(deadline_s))
         self.stats = stats or NopStatsClient()
-        self.tracer = tracer or NopTracer()
         self.logger = logger
         # Pipelined dispatch: config default (None -> on) gated by the
         # PILOSA_TPU_PIPELINE env kill switch, and by the executor
@@ -251,14 +251,16 @@ class QueryCoalescer:
 
     def submit(self, index: str, query: Any,
                shards: Optional[Sequence[int]] = None,
-               profile: Any = None) -> Dict[str, Any]:
+               profile: Any = None,
+               is_write: Optional[bool] = None) -> Dict[str, Any]:
         """Queue one query and block until its batch resolves. Returns
         the shaped response dict; raises the per-request exception
         (executor errors, CoalescerOverload, DeadlineExceeded).
         `profile` (a utils/profile QueryProfile) rides along and is
         filled in by the executor when this item's request runs; forced
         profiles are excluded from read-dedup so their tree describes
-        exactly this request's execution.
+        exactly this request's execution. `is_write`, when the caller
+        already parsed the query, saves parsing it again here.
 
         The caller (API.query_coalesced) checks `running` first and
         falls back to the direct path, but the check races with stop():
@@ -267,7 +269,8 @@ class QueryCoalescer:
         from pilosa_tpu.executor.executor import query_is_write
         deadline = (time.monotonic() + self.deadline_s
                     if self.deadline_s > 0 else None)
-        is_write = query_is_write(query)
+        if is_write is None:
+            is_write = query_is_write(query)
         item = _Item(index, query, shards, is_write, deadline,
                      profile=profile)
         with self._cond:
@@ -317,8 +320,6 @@ class QueryCoalescer:
                 item.event.wait()
         else:
             item.event.wait()
-        self.stats.timing("coalescer.request",
-                          time.perf_counter() - item.enqueued_at)
         if isinstance(item.result, Exception):
             raise item.result
         return item.result
@@ -448,12 +449,10 @@ class QueryCoalescer:
         self.stats.histogram("coalescer.batch_size", len(batch))
         self._note_workload(batch)
         try:
-            with self.tracer.span("Coalescer.flush", n=len(batch),
-                                  reason=reason) as span:
-                if len(batch) == 1:
-                    self._execute_direct(batch[0], reason)
-                else:
-                    self._execute_batched(batch, span, reason)
+            if len(batch) == 1:
+                self._execute_direct(batch[0], reason)
+            else:
+                self._execute_batched(batch, reason)
         except Exception as e:  # dispatcher must never die
             if self.logger is not None:
                 self.logger.printf("coalescer flush failed: %r", e)
@@ -465,18 +464,21 @@ class QueryCoalescer:
     def _execute_direct(self, item: _Item, reason: str = "idle") -> None:
         """Batch of one: run the EXACT direct path (execute_full), so a
         lone request degrades to uncoalesced behavior."""
+        rec = getattr(item.profile, "timeline", None)
         if item.profile is not None:
-            wait = time.perf_counter() - item.enqueued_at
-            item.profile.set_coalesced(1, wait)
-            TIMELINE.event(getattr(item.profile, "timeline", None),
-                           "queue", LANE_QUEUE, item.enqueued_at, wait,
-                           batch=1, reason=reason)
-        try:
-            item.result = self.executor.execute_full(
-                item.index, item.query, shards=item.shards,
-                profile=item.profile)
-        except Exception as e:
-            item.result = e
+            now = time.perf_counter()
+            item.profile.set_coalesced(1, now - item.enqueued_at)
+            TIMELINE.add(rec, "coalescer.wait", item.enqueued_at, now,
+                         batch=1, reason=reason)
+        # The stages run on this thread but belong to the request: a
+        # single-item flush has no flush record of its own.
+        with TIMELINE.attached(rec):
+            try:
+                item.result = self.executor.execute_full(
+                    item.index, item.query, shards=item.shards,
+                    profile=item.profile)
+            except Exception as e:
+                item.result = e
         item.event.set()
 
     def _dedup(self, batch: List[_Item]) -> Tuple[
@@ -510,53 +512,68 @@ class QueryCoalescer:
             self.stats.count("coalescer.deduped", len(batch) - len(reqs))
         return reqs, profiles, owner
 
-    def _stamp_queue_wait(self, batch: List[_Item], exec_start: float,
-                          reason: str) -> None:
-        """Queue wait ends when execution STARTS — stamped before the
-        batch runs, so the histogram separates window/queue time from
-        device time (coalescer.request covers the end-to-end sum)."""
+    def _open_flush(self, batch: List[_Item], reason: str,
+                    pipelined: bool):
+        """The flush's own record (root `coalescer.flush`). Each
+        member's queue wait ends where it starts — stamped before the
+        batch runs, so window/queue time and execution time separate."""
+        rec = TIMELINE.begin(None, batch[0].index, stats=self.stats,
+                             name="coalescer.flush", kind="flush",
+                             batch=len(batch), reason=reason,
+                             pipelined=pipelined)
+        start = rec.root.pc_start if rec is not None \
+            else time.perf_counter()
         for item in batch:
-            self.stats.timing("coalescer.queue_wait",
-                              exec_start - item.enqueued_at)
             if item.profile is not None:
-                wait = exec_start - item.enqueued_at
-                item.profile.set_coalesced(len(batch), wait)
-                # Queue-wait slice on the member's own timeline: where
-                # this request sat before its flush started.
-                TIMELINE.event(getattr(item.profile, "timeline", None),
-                               "queue", LANE_QUEUE, item.enqueued_at,
-                               wait, batch=len(batch), reason=reason)
+                item.profile.set_coalesced(len(batch),
+                                           start - item.enqueued_at)
+                TIMELINE.add(getattr(item.profile, "timeline", None),
+                             "coalescer.wait", item.enqueued_at, start,
+                             batch=len(batch), reason=reason)
+        return rec
 
-    def _execute_batched(self, batch: List[_Item], span,
+    def _close_flush(self, rec, batch: List[_Item], profiles,
+                     err: Optional[BaseException] = None) -> None:
+        """Finish the flush's record and hand every member its
+        reference to it: a `coalescer.flush` child over the same
+        interval whose link is the flush's root. Runs BEFORE the
+        members are released, so each sees it when it wakes."""
+        if rec is None:
+            return
+        # Fusion attribution from this flush's OWN profiles (the
+        # process-wide executor counters also move under concurrent
+        # /batch/query traffic, so a before/after delta would claim
+        # work this flush never did).
+        rec.root.attrs["fusedQueries"] = sum(
+            1 for p in profiles
+            if p is not None and getattr(p, "fused_batch", None))
+        TIMELINE.finish(rec, error=err)
+        root = rec.root
+        for item in batch:
+            if item.profile is not None:
+                TIMELINE.add(getattr(item.profile, "timeline", None),
+                             "coalescer.flush", root.pc_start,
+                             root.pc_end, link=root,
+                             batch=len(batch))
+
+    def _execute_batched(self, batch: List[_Item],
                          reason: str = "window") -> None:
         """One executor batch for N requests, identical reads deduped
         (see _dedup)."""
         reqs, profiles, owner = self._dedup(batch)
-        if span is not None:
-            span.set("unique", len(reqs))
-        exec_start = time.perf_counter()
-        self._stamp_queue_wait(batch, exec_start, reason)
-        shaped = self.executor.execute_batch_shaped(reqs,
-                                                    profiles=profiles)
-        flush_s = time.perf_counter() - exec_start
-        for item in batch:
-            if item.profile is not None:
-                # The shared flush (coalesce -> fuse -> dispatch ->
-                # drain) as one slice per member, so a request's
-                # timeline shows the batch it rode and what it cost.
-                TIMELINE.event(getattr(item.profile, "timeline", None),
-                               "coalesce", LANE_COALESCE, exec_start,
-                               flush_s, batch=len(batch),
-                               unique=len(reqs), reason=reason)
-        if span is not None:
-            # Fusion attribution from this flush's OWN profiles (the
-            # process-wide executor counters also move under
-            # concurrent /batch/query traffic, so a before/after delta
-            # would claim work this flush never did).
-            span.set("fusedQueries",
-                     sum(1 for p in profiles
-                         if p is not None
-                         and getattr(p, "fused_batch", None)))
+        rec = self._open_flush(batch, reason, pipelined=False)
+        if rec is not None:
+            rec.root.attrs["unique"] = len(reqs)
+        err = None
+        try:
+            with TIMELINE.attached(rec):
+                shaped = self.executor.execute_batch_shaped(
+                    reqs, profiles=profiles)
+        except Exception as e:
+            err = e
+            raise
+        finally:
+            self._close_flush(rec, batch, profiles, err)
         for res, items in zip(shaped, owner):
             for item in items:
                 item.result = res
@@ -586,24 +603,26 @@ class QueryCoalescer:
         to the finalizer and return to collecting the next window.
         While the previous batch drains device->host, this one's plan
         build and H2D run concurrently: the overlap that buys back the
-        per-flush host time (scored by pilosa_device_idle_ratio)."""
+        per-flush host time. Both halves are stages of ONE flush
+        record; `coalescer.handoff` is the wait for the finalizer."""
         self.stats.count(f"coalescer.flush.{reason}", 1)
         self.stats.histogram("coalescer.batch_size", len(batch))
         self._note_workload(batch)
+        rec = None
+        profiles: List[Any] = []
         try:
-            with self.tracer.span("Coalescer.flush", n=len(batch),
-                                  reason=reason, pipelined=True) as span:
-                reqs, profiles, owner = self._dedup(batch)
-                if span is not None:
-                    span.set("unique", len(reqs))
-                exec_start = time.perf_counter()
-                self._stamp_queue_wait(batch, exec_start, reason)
+            reqs, profiles, owner = self._dedup(batch)
+            rec = self._open_flush(batch, reason, pipelined=True)
+            if rec is not None:
+                rec.root.attrs["unique"] = len(reqs)
+            with TIMELINE.attached(rec):
                 sh = self.executor.execute_batch_shaped_begin(
                     reqs, profiles=profiles)
         except Exception as e:  # dispatch failed: resolve everyone now
             if self.logger is not None:
                 self.logger.printf("coalescer pipelined dispatch "
                                    "failed: %r", e)
+            self._close_flush(rec, batch, profiles, e)
             for item in batch:
                 if not item.event.is_set():
                     item.result = e
@@ -611,6 +630,7 @@ class QueryCoalescer:
             return
         self.pipelined_flushes += 1
         self.stats.count("coalescer.pipelined", 1)
+        handoff = time.perf_counter()
         with self._pl_cond:
             # Depth-1 double buffer: wait for the PREVIOUS batch's
             # drain slot, then occupy it. The wait happens AFTER this
@@ -618,8 +638,8 @@ class QueryCoalescer:
             # the predecessor's drain.
             while self._pl_pending is not None:
                 self._pl_cond.wait()
-            self._pl_pending = (batch, owner, sh, exec_start, reason,
-                                len(reqs))
+            self._pl_pending = (batch, owner, sh, rec, profiles,
+                                handoff)
             self._pl_cond.notify_all()
 
     def _finalize_loop(self) -> None:
@@ -650,17 +670,21 @@ class QueryCoalescer:
                     self._pl_cond.notify_all()
 
     def _finish_pipelined(self, batch: List[_Item],
-                          owner: List[List[_Item]], sh: Any,
-                          exec_start: float, reason: str,
-                          unique: int) -> None:
-        shaped = self.executor.execute_batch_shaped_finish(sh)
-        flush_s = time.perf_counter() - exec_start
-        for item in batch:
-            if item.profile is not None:
-                TIMELINE.event(getattr(item.profile, "timeline", None),
-                               "coalesce", LANE_COALESCE, exec_start,
-                               flush_s, batch=len(batch), unique=unique,
-                               reason=reason, pipelined=True)
+                          owner: List[List[_Item]], sh: Any, rec: Any,
+                          profiles: List[Any], handoff: float) -> None:
+        # The wait for the finalizer's slot (the previous flush still
+        # draining): one more stage of the flush, so that it tiles.
+        TIMELINE.add(rec, "coalescer.handoff", handoff,
+                     time.perf_counter())
+        err = None
+        try:
+            with TIMELINE.attached(rec):
+                shaped = self.executor.execute_batch_shaped_finish(sh)
+        except BaseException as e:
+            err = e
+            raise
+        finally:
+            self._close_flush(rec, batch, profiles, err)
         for res, items in zip(shaped, owner):
             for item in items:
                 item.result = res

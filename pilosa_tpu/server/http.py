@@ -107,6 +107,9 @@ class Handler(BaseHTTPRequestHandler):
     # a keep-alive internal client pays a ~40 ms delayed-ACK stall per
     # response. (The client side sets TCP_NODELAY on its pooled sockets.)
     disable_nagle_algorithm = True
+    # The open request record of the query being handled (None between
+    # requests and on every other route).
+    _rec = None
 
     # -- plumbing -----------------------------------------------------------
 
@@ -118,35 +121,41 @@ class Handler(BaseHTTPRequestHandler):
     def _json(self, obj: Any, status: int = 200,
               force_json: bool = False,
               extra_headers: Optional[dict] = None) -> None:
+        # The last two stages of a query's request record (self._rec;
+        # None on every other route, where these are bare clocks).
+        rec = self._rec
         # Content negotiation (reference http/handler.go:447-489 protobuf
         # vs JSON): internal clients ask for the binary wire codec via
         # Accept; JSON is the public surface and the default.
-        body = None
-        if not force_json and wire.CONTENT_TYPE in (
-                self.headers.get("Accept") or ""):
-            try:
-                body = wire.dumps(obj)
-                ctype = wire.CONTENT_TYPE
-            except TypeError:
-                body = None  # e.g. >64-bit int — JSON handles it
-        if body is None:
-            body = json.dumps(obj).encode("utf-8")
-            ctype = "application/json"
-        self.send_response(status)
-        self.send_header("Content-Type", ctype)
-        self.send_header("Content-Length", str(len(body)))
-        for k, v in (extra_headers or {}).items():
-            self.send_header(k, str(v))
-        self.end_headers()
-        self.wfile.write(body)
+        with TIMELINE.span(rec, "http.serialize"):
+            body = None
+            if not force_json and wire.CONTENT_TYPE in (
+                    self.headers.get("Accept") or ""):
+                try:
+                    body = wire.dumps(obj)
+                    ctype = wire.CONTENT_TYPE
+                except TypeError:
+                    body = None  # e.g. >64-bit int — JSON handles it
+            if body is None:
+                body = json.dumps(obj).encode("utf-8")
+                ctype = "application/json"
+        with TIMELINE.span(rec, "http.write", bytes=len(body)):
+            self.send_response(status)
+            self.send_header("Content-Type", ctype)
+            self.send_header("Content-Length", str(len(body)))
+            for k, v in (extra_headers or {}).items():
+                self.send_header(k, str(v))
+            self.end_headers()
+            self.wfile.write(body)
 
     def _bytes(self, data: bytes, status: int = 200,
                ctype: str = "application/octet-stream") -> None:
-        self.send_response(status)
-        self.send_header("Content-Type", ctype)
-        self.send_header("Content-Length", str(len(data)))
-        self.end_headers()
-        self.wfile.write(data)
+        with TIMELINE.span(self._rec, "http.write", bytes=len(data)):
+            self.send_response(status)
+            self.send_header("Content-Type", ctype)
+            self.send_header("Content-Length", str(len(data)))
+            self.end_headers()
+            self.wfile.write(data)
 
     def _error(self, msg: str, status: int = 400,
                extra_headers: Optional[dict] = None) -> None:
@@ -191,24 +200,31 @@ class Handler(BaseHTTPRequestHandler):
                 ("columnAttrs", "excludeRowAttrs", "excludeColumns")
                 if self._qbool(q, k) or (req or {}).get(k)}
 
-    def _query_proto(self, api, index: str, raw: bytes, q: dict) -> None:
+    def _query_proto(self, api, index: str, q: dict) -> None:
         """Reference-client protobuf query: decode internal.QueryRequest,
         execute, answer internal.QueryResponse
         (http/handler.go:916-995)."""
-        try:
-            req = proto_compat.decode_query_request(raw)
-        except proto_compat.ProtoError as e:
-            raise ApiError(f"invalid protobuf body: {e}")
-        shards = req["shards"] or None
-        if q.get("shards"):
-            shards = [int(s) for s in q["shards"].split(",")]
+        rec = self._rec
+        with TIMELINE.span(rec, "http.read", codec="proto") as rd:
+            raw = self._body()
+            rd.set("bytes", len(raw))
+            try:
+                req = proto_compat.decode_query_request(raw)
+            except proto_compat.ProtoError as e:
+                raise ApiError(f"invalid protobuf body: {e}")
+            shards = req["shards"] or None
+            if q.get("shards"):
+                shards = [int(s) for s in q["shards"].split(",")]
         try:
             pql = self._wrap_options(req["query"],
                                      self._exec_optargs(q, req))
             res = api.query(index, pql, shards=shards,
-                            remote=req["remote"] or self._qbool(q, "remote"))
-            body = proto_compat.encode_query_response(
-                res["results"], column_attr_sets=res.get("columnAttrs"))
+                            remote=req["remote"] or self._qbool(q, "remote"),
+                            record=rec)
+            with TIMELINE.span(rec, "http.serialize", codec="proto"):
+                body = proto_compat.encode_query_response(
+                    res["results"],
+                    column_attr_sets=res.get("columnAttrs"))
         except ValueError as e:
             body = proto_compat.encode_query_response([], err=str(e))
             self._bytes(body, status=400,
@@ -311,18 +327,26 @@ class Handler(BaseHTTPRequestHandler):
         if hasattr(self.api, "tracer"):
             self.api.tracer.extract(self.headers)
         t0 = time.perf_counter()
+        err = None
         try:
             handled = self._handle(method, path, q)
             if not handled:
                 self._error(f"no route for {method} {path}", 404)
         except ApiError as e:
+            err = e
             # e.headers carries response headers (e.g. Retry-After on
             # the coalescer's 429 overload rejection).
             self._error(str(e), e.status,
                         extra_headers=getattr(e, "headers", None))
         except Exception as e:  # mirror the reference's panic recovery
+            err = e
             self._error(f"internal error: {type(e).__name__}: {e}", 500)
         finally:
+            # The query route's request record closes here, after the
+            # reply (or the error reply) is on the socket.
+            rec, self._rec = self._rec, None
+            if rec is not None:
+                self.api.end_request(rec, err)
             try:
                 self._observe_slo(method, path,
                                   time.perf_counter() - t0)
@@ -352,8 +376,14 @@ class Handler(BaseHTTPRequestHandler):
                 # its profile tree when one was recorded — the
                 # structured replacement for grepping SLOW QUERY log
                 # lines (reference LongQueryTime, api.go:1048).
+                from pilosa_tpu.utils.jaxenv import COMPILES
                 self._json({"queries": api.profiler.slow_queries(),
                             "retraces": api.executor.jit_compiles,
+                            # Every XLA compile, by JAX's function
+                            # name: count, seconds, the request stage
+                            # it happened in, and for the executor's
+                            # own programs the jit key that missed.
+                            "xla": COMPILES.snapshot(),
                             "fusedDispatches":
                                 api.executor.fused_dispatches,
                             "fusedQueries": api.executor.fused_queries,
@@ -548,21 +578,29 @@ class Handler(BaseHTTPRequestHandler):
                 self._check_args(q, "shards", "remote", "columnAttrs",
                                  "excludeRowAttrs", "excludeColumns",
                                  "profile")
-                raw = self._body()
+                # The request record (utils/timeline.py) opens before
+                # the body is read and closes in _dispatch after the
+                # reply is written, so its stages tile the exchange.
+                rec = self._rec = api.begin_request(m.group(1))
                 # Reference-client protobuf surface
                 # (http/handler.go:916-995, internal/public.proto).
                 if self.headers.get("Content-Type", "").startswith(
                         proto_compat.CONTENT_TYPE):
-                    self._query_proto(api, m.group(1), raw, q)
+                    self._query_proto(api, m.group(1), q)
                     return True
-                try:
-                    body = json.loads(raw) if raw.lstrip()[:1] == b"{" else None
-                except json.JSONDecodeError:
-                    body = None
-                pql = (body or {}).get("query") if body else raw.decode()
-                shards = None
-                if q.get("shards"):
-                    shards = [int(s) for s in q["shards"].split(",")]
+                with TIMELINE.span(rec, "http.read") as rd:
+                    raw = self._body()
+                    rd.set("bytes", len(raw))
+                    try:
+                        body = json.loads(raw) \
+                            if raw.lstrip()[:1] == b"{" else None
+                    except json.JSONDecodeError:
+                        body = None
+                    pql = (body or {}).get("query") if body \
+                        else raw.decode()
+                    shards = None
+                    if q.get("shards"):
+                        shards = [int(s) for s in q["shards"].split(",")]
                 # URL-arg execution options apply to every call, same as
                 # the reference's request-level ExecOptions
                 # (http/handler.go:186 PostQuery optional args).
@@ -577,15 +615,8 @@ class Handler(BaseHTTPRequestHandler):
                     resp = api.query_coalesced(
                         m.group(1), pql, shards=shards,
                         remote=self._qbool(q, "remote"),
-                        profile=self._qbool(q, "profile"))
-                    # Serialize stage on the request's timeline: the
-                    # handler thread writes the response after the API
-                    # layer closed the timeline, so the slice attaches
-                    # to the thread's last-finished request.
-                    ts0 = time.perf_counter()
+                        profile=self._qbool(q, "profile"), record=rec)
                     self._json(resp)
-                    TIMELINE.note_serialize(ts0,
-                                            time.perf_counter() - ts0)
                 except ApiError:
                     # Already carries its status (429 overload, 408
                     # deadline): must not collapse to a generic 400.

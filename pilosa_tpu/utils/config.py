@@ -178,20 +178,19 @@ class Config:
     # back to dense (and dense banks hotter than it resist demotion
     # below the watermark).
     layout_promote_rate: float = 0.5
-    # Request-lifecycle timeline plane (utils/timeline.py): bounded
-    # per-process ring of per-request stage timelines (queue -> coalesce
-    # -> plan -> dispatch -> device -> materialize -> serialize) served
-    # as Chrome trace-event JSON at GET /debug/timeline, plus the
-    # dispatch-gap analyzer behind pilosa_device_idle_ratio. Host-side
-    # wall timestamps only — device slices appear only on queries the
-    # profiler already fences. `enabled = false` is the kill switch
-    # (recording and the gap analyzer both stop). TOML accepts a
-    # [timeline] table (enabled / ring / sample_every / gap_window_s)
-    # or the flat timeline_* spelling; env uses PILOSA_TPU_TIMELINE_*.
+    # Request records (utils/timeline.py): a bounded per-process ring
+    # of per-request span trees (http.read -> pql.parse ->
+    # coalescer.wait -> plan -> h2d -> dispatch -> d2h -> finish ->
+    # http.serialize -> http.write) served as Chrome trace-event JSON
+    # at GET /debug/timeline, their stage seconds cumulative in
+    # /debug/vars. Host-side clock readings only — a `device` span
+    # appears only on queries the profiler already fences.
+    # `enabled = false` is the kill switch. TOML accepts a [timeline]
+    # table (enabled / ring / sample_every) or the flat timeline_*
+    # spelling; env uses PILOSA_TPU_TIMELINE_*.
     timeline_enabled: bool = True
-    timeline_ring: int = 256        # request timelines kept
+    timeline_ring: int = 256        # request records kept
     timeline_sample_every: int = 1  # record 1 in N requests (1 = all)
-    timeline_gap_window_s: float = 60.0  # idle-ratio rolling window
     # Roofline attribution plane (utils/roofline.py): per-launch HBM
     # bytes from ops/megakernel.plan_cost joined with the profiler's
     # SAMPLED device fences into achieved-GB/s / roofline-fraction
@@ -362,8 +361,6 @@ class Config:
         if self.timeline_ring < 1 or self.timeline_sample_every < 1:
             raise ValueError(
                 "timeline ring/sample_every must be >= 1")
-        if self.timeline_gap_window_s <= 0:
-            raise ValueError("timeline gap_window_s must be > 0")
         if self.roofline_gbps < 0:
             raise ValueError("roofline gbps must be >= 0 (0 = auto)")
         if not 0 < self.roofline_ewma_alpha <= 1:

@@ -39,6 +39,25 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Sequence
 
 from pilosa_tpu.utils.locks import make_lock
+from pilosa_tpu.utils.timeline import TIMELINE
+
+_TRANSFER_COUNTERS = {
+    d: (f"executor.{d}_bytes", f"executor.{d}_transfers")
+    for d in ("h2d", "d2h")}
+
+
+def transfer(direction: str, nbytes: int, transfers: int = 1) -> Any:
+    """The `h2d` / `d2h` stage of the request record attached to this
+    thread, around one upload or one blocking fetch: `bytes` and
+    `transfers` on the span, and the same numbers to the
+    `executor.<direction>_{bytes,transfers}` counters when the record
+    finishes. Like every number of the record they cover recorded
+    requests: all by default, none with `[timeline] enabled=false`,
+    one in N under `sample_every`."""
+    k_bytes, k_transfers = _TRANSFER_COUNTERS[direction]
+    return TIMELINE.stage(direction, bytes=nbytes, transfers=transfers,
+                          counts=((k_bytes, nbytes),
+                                  (k_transfers, transfers)))
 
 
 def pql_text(query: Any, limit: int = 2000) -> str:
@@ -125,10 +144,11 @@ class QueryProfile:
         self.totals = {"plan": 0.0, "dispatch": 0.0, "device": 0.0,
                        "materialize": 0.0}
         self.coalesced: Optional[Dict[str, Any]] = None
-        # Request-timeline handle (utils/timeline._TimelineRequest or
-        # None): the API layer attaches it so executor/coalescer/
-        # cluster seams — which already carry the profile — can record
-        # stage slices without any new plumbing of their own.
+        # The request record (utils/timeline._TimelineRequest or None):
+        # the API layer attaches it so the coalescer and cluster seams
+        # — which already carry the profile — can hand it to the thread
+        # that runs the request's stages. Every stage second in this
+        # profile is the one reading of a span of that record.
         self.timeline: Any = None
         # Largest same-signature fusion group this query's evals ran
         # in (None = nothing fused; see Executor.execute_batch).
@@ -268,8 +288,8 @@ class QueryProfile:
                 self.error = f"{type(error).__name__}: {error}"
 
     def annotate_span(self, span) -> None:
-        """Summarize onto an open tracer span (RecordingTracer Span.set)
-        so exported traces carry the device/host split too."""
+        """Summarize onto the request record's root span so exported
+        traces carry the device/host split too."""
         if span is None:
             return
         span.set("profile.planS", self.totals["plan"])
@@ -407,10 +427,8 @@ class Profiler:
                 # Executor.jit_compiles (served at /debug/queries);
                 # this counter is the /metrics view of the same signal.
                 st.count("executor.retrace", p.jit_misses)
-            if p.h2d_bytes:
-                st.count("executor.h2d_bytes", p.h2d_bytes)
-            if p.d2h_bytes:
-                st.count("executor.d2h_bytes", p.d2h_bytes)
+            # executor.{h2d,d2h}_{bytes,transfers} come from the
+            # request record's h2d / d2h spans (utils/timeline.py).
         if long_query_time > 0 and duration > long_query_time:
             if logger is not None:
                 if kind == "batch":

@@ -11,8 +11,8 @@ an objective. This module adds both:
   series (raw ring + 10:1 decimated tier, so ~2 h of raw detail and
   ~20 h of coarse history at the watchdog cadence fit in a few hundred
   KB, ledger-registered under the host-side ``telemetry`` category).
-  The server samples it from the memory watchdog's cadence with device
-  idle ratio, roofline achieved-GB/s + fraction, cache hit ratios,
+  The server samples it from the memory watchdog's cadence with
+  roofline achieved-GB/s + fraction, cache hit ratios,
   HBM live/padded bytes, mesh collective bytes, and coalescer queue
   depth; per-endpoint q/s and p50/p95/p99 derive from *windowed bucket
   deltas* of the cumulative RED histograms (two ring samples), never

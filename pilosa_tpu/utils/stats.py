@@ -8,6 +8,7 @@ statsd/statsd.go:41, multi-client :164). Implementations here: in-memory
 
 from __future__ import annotations
 
+import bisect
 import threading
 from pilosa_tpu.utils.locks import make_lock
 import time
@@ -34,6 +35,18 @@ class StatsClient:
 
     def timing(self, name: str, value: float, rate: float = 1.0) -> None:
         pass
+
+    def batch(self, histograms: Sequence[tuple] = (),
+              counts: Sequence[tuple] = ()) -> None:
+        """Many observations in one call: `histograms` are (name,
+        extra tags, value, buckets), `counts` are (name, value). A
+        client with a lock takes it once for the lot (a finished
+        request record reports a dozen stages at a time)."""
+        for name, tags, value, buckets in histograms:
+            (self.with_tags(*tags) if tags else self).histogram(
+                name, value, buckets=buckets)
+        for name, value in counts:
+            self.count(name, value)
 
 
 class NopStatsClient(StatsClient):
@@ -119,6 +132,27 @@ class MemStatsClient(StatsClient):
             h["counts"][i] += 1
             h["sum"] += value
 
+    def batch(self, histograms: Sequence[tuple] = (),
+              counts: Sequence[tuple] = ()) -> None:
+        root = self._parent
+        base = self.tags
+        with root._lock:
+            for name, tags, value, buckets in histograms:
+                tags = base + tuple(tags)
+                key = f"{name}{{{','.join(tags)}}}" if tags else name
+                h = root.histos.get(key)
+                if h is None:
+                    b = tuple(buckets) if buckets is not None \
+                        else HISTOGRAM_BUCKETS
+                    h = root.histos[key] = {
+                        "counts": [0] * (len(b) + 1), "sum": 0.0,
+                        "buckets": b}
+                h["counts"][bisect.bisect_left(h["buckets"],
+                                               value)] += 1
+                h["sum"] += value
+            for name, value in counts:
+                root.counters[self._key(name)] += value
+
     def set(self, name: str, value: str, rate: float = 1.0) -> None:
         root = self._parent
         with root._lock:
@@ -200,6 +234,11 @@ class MultiStatsClient(StatsClient):
     def timing(self, name: str, value: float, rate: float = 1.0) -> None:
         for c in self.clients:
             c.timing(name, value, rate)
+
+    def batch(self, histograms: Sequence[tuple] = (),
+              counts: Sequence[tuple] = ()) -> None:
+        for c in self.clients:
+            c.batch(histograms, counts)
 
 
 class Timer:
@@ -356,13 +395,13 @@ METRIC_HELP: Dict[str, str] = {
         "Constant 1 labeled with the server version and jax backend.",
     "pilosa_coalescer_batch_size":
         "Queries per coalesced executor batch.",
-    "pilosa_device_idle_ratio":
-        "Fraction of the rolling window the device spent idle between "
-        "dispatches (utils/timeline.py gap analyzer).",
     "pilosa_executor_fusion_group_size":
         "Queries fused per executor dispatch group.",
     "pilosa_executor_jit_cache_size":
         "Entries in the executor's LRU jit trace cache.",
+    "pilosa_flush_unaccounted_seconds":
+        "Per coalesced flush: its duration minus the union of its "
+        "stage spans.",
     "pilosa_fragment_reads_total":
         "Fragment read accesses recorded by the workload plane.",
     "pilosa_fragment_writes_total":
@@ -386,6 +425,16 @@ METRIC_HELP: Dict[str, str] = {
         "Device bytes held by the TopN rank cache.",
     "pilosa_rank_cache_entries":
         "Live entries in the TopN rank cache.",
+    "pilosa_request_spans_dropped_total":
+        "Spans past a request record's cap, left out of its tree.",
+    "pilosa_request_stage_seconds":
+        "Seconds per request (or per coalesced flush) in each stage "
+        "of the request record, labeled by stage (utils/timeline.py).",
+    "pilosa_request_total_seconds":
+        "Request record root duration: body read to socket write.",
+    "pilosa_request_unaccounted_seconds":
+        "Per request: root duration minus the union of its stage "
+        "spans.",
     "pilosa_roofline_achieved_gbps":
         "Fence-sampled achieved HBM bandwidth, GB/s.",
     "pilosa_roofline_cohorts":
@@ -412,6 +461,15 @@ METRIC_HELP: Dict[str, str] = {
     "pilosa_slo_error_budget_remaining":
         "Fraction of the error budget left over the retained history "
         "span, per endpoint objective.",
+    "pilosa_xla_cache_hits_total":
+        "XLA compiles answered from the persistent compilation cache.",
+    "pilosa_xla_compile_seconds_total":
+        "Seconds spent in XLA backend compiles (jax.monitoring).",
+    "pilosa_xla_compiles_total":
+        "XLA backend compiles of any jitted function, the eager jnp "
+        "helpers included (jax.monitoring).",
+    "pilosa_xla_traces_total":
+        "jaxpr traces of any jitted function (jax.monitoring).",
 }
 
 

@@ -1,40 +1,55 @@
-"""Request-lifecycle timeline plane: where each request's wall-clock goes.
+"""The request record: one tree of spans per request, tiling it.
 
-PRs 3/5/6 made *cost*, *memory*, and *workload shape* observable, but
-none of them can show the one thing ROADMAP item 5 (double-buffered
-dispatch, heterogeneous megakernel) needs to prove itself: the
-*timeline* — how queue wait, coalescing, planning, dispatch, device
-execution, result materialization and HTTP serialization interleave,
-and where the device sits idle between dispatches. This module is the
-in-process analog of reference Pilosa's Jaeger query spans
-(tracing.go:18-56) rendered in the Chrome trace-event format every
-profiler UI speaks (chrome://tracing, Perfetto):
+Every query request (and every coalesced flush) owns ONE record: a
+root ``tracing.Span`` whose children are the stages the request went
+through, named ``<layer>.<stage>`` after the layers of PERF.md §3:
 
-- ``TimelineRecorder``: a bounded per-process ring of per-request
-  timelines. Each request records ``ph:"X"`` slices (queue wait,
-  coalescer flush, plan, dispatch, sampled device time, materialize,
-  serialize, remote fan-out legs) stamped against ONE wall-clock
-  anchor taken at request start — durations are pure
-  ``time.perf_counter()`` deltas, so an NTP step mid-request cannot
-  corrupt them. Served at ``GET /debug/timeline?last=N`` as trace-event
-  JSON loadable directly in Perfetto; ``GET /cluster/timeline/{trace}``
-  assembles the multi-node view by trace id (legs joined by the W3C
-  traceparent the cluster already propagates).
-- the **dispatch-gap analyzer**: every compiled-program invocation
-  (``Executor._call_program`` — fused and unfused alike) notes its
-  enqueue interval into a rolling window; ``idle_ratio()`` is the
-  fraction of that window the device had nothing enqueued. Exported as
-  ``pilosa_device_idle_ratio`` — the baseline number an RTT-hiding
-  pipeline must provably improve.
+    http.read · pql.parse · coalescer.wait · coalescer.flush ·
+    cache.lookup · plan (plan.lower / plan.verify / plan.optimise) ·
+    h2d · dispatch · d2h · finish · http.serialize · http.write
 
-Device slices ride the profiler's *sampled* fences only
-(``QueryProfile.sample_device``): the unsampled hot path records wall
-timestamps of host-side events and pays ZERO new ``block_until_ready``
-fences (pinned by test, same bar as PR 3).
+A stage is opened with ``TIMELINE.span(rec, name, **attrs)`` — one
+``perf_counter`` pair per boundary — and everything else renders from
+the record: the profile tree's stage seconds are the span's one
+reading, ``GET /debug/timeline`` is its Chrome trace-event export,
+the OTLP exporter ships its root (``tracing.spans_to_otlp``), and
+each finished record adds its stage durations to the cumulative
+``request.stage_seconds{stage:<name>}`` histograms of ``/debug/vars``
+(one stats-lock acquisition per record, not per span).
+
+Tiling. A span opened with ``TIMELINE.phase(name)`` (the executor's
+``plan`` and ``finish``) is a *phase*: a span opened inside it whose
+name is not ``<phase>.<x>`` (``h2d``, ``dispatch``, ``d2h``,
+``cache.lookup``) interrupts it — the phase segment is closed, the new
+span becomes its sibling, and the phase resumes in a fresh segment
+afterwards. So a record's top-level children never overlap on one
+thread and their durations add up: the root's ``unaccounted`` time is
+its duration minus the union of its children.
+
+Counters. An opener may hand a span ``counts`` — (counter name, delta)
+pairs, e.g. a transfer's bytes — which the record adds to the stats
+client in the same one batch as its stage durations. This module
+knows no stage or counter name of its own.
+
+On the device trace's clock. Each span also opens the annotation the
+server injected at start (``jax.profiler.TraceAnnotation``, named
+``pilosa:<name>``): under a profiler session it lands in the xplane's
+host plane on its thread's line, beside the ``XLA Ops`` lines, with no
+clock arithmetic (``tools/trace_gaps.py`` joins the two); with no
+session it is a no-op well under a microsecond. This module stays
+jax-free.
+
+A coalesced flush is a record of its own (kind ``flush``, root
+``coalescer.flush``); ``plan`` … ``finish`` happen once per flush and
+are ITS children, and every member request holds a child
+``coalescer.flush`` over the same interval whose ``link`` is the
+flush's root. Linked spans feed the histograms under
+``<name>.member`` so ``stage:coalescer.flush`` counts flushes, not
+riders.
 
 Pure host-side module: NO jax imports, no device interaction —
-recording is list/deque appends under leaf locks (graftlint GL003
-clean by construction).
+recording is list appends on thread-confined objects plus one leaf
+lock at finish (graftlint GL003 clean by construction).
 """
 
 from __future__ import annotations
@@ -43,116 +58,246 @@ import threading
 import time
 import uuid
 from collections import deque
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from pilosa_tpu.utils.locks import make_lock
+from pilosa_tpu.utils.tracing import Span
 
-# Stage lanes (Chrome trace-event tid): one horizontal track per
-# pipeline stage so a request reads top-to-bottom as it flows through
-# the serving path. Names surface via thread_name metadata events.
-LANE_REQUEST = 0
-LANE_QUEUE = 1
-LANE_COALESCE = 2
-LANE_PLAN = 3
-LANE_DISPATCH = 4
-LANE_DEVICE = 5
-LANE_FETCH = 6
-LANE_SERIALIZE = 7
-LANE_REMOTE = 8
-LANE_CACHE = 9
-
-LANE_NAMES = {
-    LANE_REQUEST: "request",
-    LANE_QUEUE: "queue",
-    LANE_COALESCE: "coalesce",
-    LANE_PLAN: "plan",
-    LANE_DISPATCH: "dispatch",
-    LANE_DEVICE: "device",
-    LANE_FETCH: "materialize",
-    LANE_SERIALIZE: "serialize",
-    LANE_REMOTE: "remote",
-    LANE_CACHE: "cache",
-}
-
-# Stage names whose slice durations feed the summary medians (the
-# bench's stage-time breakdown reads these).
-_SUMMARY_STAGES = ("queue", "coalesce", "plan", "dispatch", "device",
-                   "materialize", "serialize")
+# request.stage_seconds bucket bounds: 2^-17 s (7.6 us) .. 16 s.
+STAGE_BUCKETS = tuple(2.0 ** e for e in range(-17, 5))
 
 
 class _TimelineRequest:
-    """One request's recorded slices. ``t0_wall`` is the single
-    wall-clock anchor for export timestamps; every event start is a
-    ``perf_counter`` reading converted at snapshot time as
-    ``t0_wall + (start_pc - t0_pc)`` — monotonic durations, one wall
-    read per request."""
+    """One record: a root span plus what finish() needs. ``stats`` is
+    the client its stage durations go to (the opener's); ``n_spans``
+    bounds the tree."""
 
-    __slots__ = ("trace_id", "index", "seq", "t0_wall", "t0_pc",
-                 "events", "dropped", "error")
+    __slots__ = ("trace_id", "index", "seq", "kind", "root", "stats",
+                 "n_spans", "dropped", "counts", "error", "unaccounted")
 
-    def __init__(self, trace_id: str, index: str, seq: int) -> None:
+    def __init__(self, trace_id: str, index: str, seq: int, kind: str,
+                 name: str, stats: Any, attrs: dict) -> None:
         self.trace_id = trace_id
         self.index = index
         self.seq = seq
-        self.t0_wall = time.time()
-        self.t0_pc = time.perf_counter()
-        # (name, lane, start_pc, dur_s, args-or-None); appended by the
-        # request thread AND (for coalesced/cluster requests) the
-        # dispatcher / scatter threads — list.append is atomic, and the
-        # ring holds the object only after finish(), so snapshot copies
-        # see a consistent prefix.
-        self.events: List[tuple] = []
+        self.kind = kind
+        self.root = Span(name, trace_id, attrs, wall=True)
+        self.stats = stats
+        self.n_spans = 1
         self.dropped = 0
+        self.counts: Dict[str, int] = {}
         self.error: Optional[str] = None
+        self.unaccounted = 0.0
+
+
+class _Clock:
+    """What span() hands back when there is no record to write to: the
+    same surface, two clock reads, nothing kept — callers that feed a
+    span's duration onward (the profile tree) need not care."""
+
+    __slots__ = ("t0", "t1")
+    span = None
+
+    def __init__(self) -> None:
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "_Clock":
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc: object) -> None:
+        self.t1 = time.perf_counter()
+
+    def set(self, key: str, value: Any) -> None:
+        pass
+
+    def duration(self) -> float:
+        return (self.t1 or time.perf_counter()) - self.t0
+
+    elapsed = duration
+
+
+class _Open:
+    """An open span of a record on this thread: context manager and
+    handle. ``duration()`` after exit is the stage's own time — for a
+    phase, the sum of its segments."""
+
+    __slots__ = ("tl", "rec", "name", "attrs", "is_phase", "counts",
+                 "span", "parent", "ann", "resume", "total",
+                 "interrupted")
+
+    def __init__(self, tl: "TimelineRecorder", rec: _TimelineRequest,
+                 name: str, attrs: dict, is_phase: bool = False,
+                 counts: Any = ()) -> None:
+        self.tl = tl
+        self.rec = rec
+        self.name = name
+        self.attrs = attrs
+        self.is_phase = is_phase
+        self.counts = counts
+        self.span: Optional[Span] = None
+        self.parent: Optional[Span] = None
+        self.ann: Any = None
+        self.resume: Optional["_Open"] = None
+        self.total = 0.0
+        self.interrupted = 0.0
+
+    def _start(self, attrs: dict) -> None:
+        rec = self.rec
+        sp = self.span = Span(self.name, rec.trace_id, attrs)
+        sp.tid = self.tl._lane()
+        if rec.n_spans < self.tl.MAX_EVENTS_PER_REQUEST:
+            rec.n_spans += 1
+            # graftlint: disable=GL008 — bounded by n_spans above; the
+            # tree lives for one request and then in the bounded ring.
+            self.parent.children.append(sp)
+        else:
+            rec.dropped += 1
+        factory = self.tl.annotation
+        if factory is not None:
+            ann = self.ann = factory("pilosa:" + self.name)
+            ann.__enter__()
+
+    def _stop(self) -> None:
+        sp = self.span
+        sp.close()
+        self.total += sp.pc_end - sp.pc_start
+        ann, self.ann = self.ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+
+    def __enter__(self) -> "_Open":
+        stack = self.tl._stack()
+        top = stack[-1] if stack else None
+        if top is not None and top.rec is self.rec:
+            if top.is_phase and \
+                    not self.name.startswith(top.name + "."):
+                top._stop()
+                self.resume = top
+                self.parent = top.parent
+            else:
+                self.parent = top.span
+        else:
+            self.parent = self.rec.root
+        self._start(self.attrs)
+        stack.append(self)
+        if self.counts:
+            totals = self.rec.counts
+            for key, n in self.counts:
+                totals[key] = totals.get(key, 0) + n
+        return self
+
+    def __exit__(self, *exc: object) -> None:
+        self._stop()
+        stack = self.tl._stack()
+        if stack and stack[-1] is self:
+            stack.pop()
+        top, self.resume = self.resume, None
+        if top is not None:
+            top.interrupted += self.total + self.interrupted
+            top._start({"resumed": True})
+
+    def set(self, key: str, value: Any) -> None:
+        self.span.attrs[key] = value
+
+    def duration(self) -> float:
+        sp = self.span
+        if sp is None or sp.pc_end is not None:
+            return self.total
+        return self.total + sp.duration()
+
+    def elapsed(self) -> float:
+        """duration() plus what interrupted this phase: the whole time
+        from its first open to now (or to its close)."""
+        return self.duration() + self.interrupted
+
+
+class _Attached:
+    __slots__ = ("tl", "rec", "prev", "ann")
+
+    def __init__(self, tl: "TimelineRecorder", rec: Any) -> None:
+        self.tl = tl
+        self.rec = rec
+        self.prev = None
+        self.ann = None
+
+    def __enter__(self) -> Any:
+        tls = self.tl._tls
+        self.prev = getattr(tls, "rec", None)
+        tls.rec = rec = self.rec
+        factory = self.tl.annotation
+        if rec is not None and factory is not None:
+            # The root on this thread's trace line too, so the time
+            # between two stages reads as the request's (or the
+            # flush's), not as nobody's.
+            self.ann = factory("pilosa:" + rec.root.name)
+            self.ann.__enter__()
+        return rec
+
+    def __exit__(self, *exc: object) -> None:
+        ann, self.ann = self.ann, None
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        self.tl._tls.rec = self.prev
+
+
+def union_seconds(intervals: List[Tuple[float, float]]) -> float:
+    """Total length covered by [start, end) intervals."""
+    covered = 0.0
+    end = float("-inf")
+    for s, e in sorted(intervals):
+        if s > end:
+            covered += e - s
+            end = e
+        elif e > end:
+            covered += e - end
+            end = e
+    return covered
 
 
 class TimelineRecorder:
-    """Process-wide timeline ring + dispatch-gap analyzer (the timeline
-    analog of hotspots.WORKLOAD / memledger.LEDGER).
+    """Process-wide ring of request records (the timeline analog of
+    hotspots.WORKLOAD / memledger.LEDGER).
 
     ``begin`` is on the path of every query: it decides sampling and
-    hands back a request handle (or None — every ``event`` call on a
-    None handle is a no-op, so the unsampled/disabled path costs one
-    attribute read). ``note_dispatch`` is independent of request
-    sampling: the gap analyzer must see EVERY dispatch or idle gaps
-    would be fictional."""
+    hands back a record (or None — every call on a None record is a
+    no-op or a bare clock, so the unsampled/disabled path costs two
+    clock reads a stage)."""
 
-    # Slices kept per request: enough for a realistic multi-call query
-    # (ops × {plan, dispatch, materialize} + queue/flush/serialize)
-    # without letting a 1024-call query bloat the ring.
-    MAX_EVENTS_PER_REQUEST = 192
-    # Rough per-event ledger cost (tuple + strings + args dict).
-    EVENT_NBYTES = 120
+    # Spans kept per record: enough for a realistic multi-call query
+    # or a 64-wide flush of filtered TopNs that all miss the arg cache
+    # (~11 spans a request: plan.stage, two h2d, two dispatch, the plan
+    # segments between, d2h, finish) without letting a 1024-call query
+    # bloat the ring. What is past it is counted, not kept.
+    MAX_EVENTS_PER_REQUEST = 1024
     # Roofline counter-track samples kept (ph:"C" lanes in the export);
     # fed only by sampled device fences, so the ring turns over slowly.
     MAX_COUNTER_SAMPLES = 512
     # Rough per-sample ledger cost (tuple of three floats).
     COUNTER_NBYTES = 48
 
-    def __init__(self, ring: int = 256, sample_every: int = 1,
-                 gap_window_s: float = 60.0,
-                 max_dispatches: int = 4096) -> None:
+    def __init__(self, ring: int = 256, sample_every: int = 1) -> None:
         self.enabled = True
         self.sample_every = max(1, int(sample_every))
-        self.gap_window_s = max(0.001, float(gap_window_s))
         self._lock = make_lock("TimelineRecorder._lock")
         self._ring: deque = deque(maxlen=max(1, int(ring)))
         self._seq = 0
         self.requests_recorded = 0
         self.requests_skipped = 0
         self._tls = threading.local()
-        # Dispatch-gap analyzer: (start_pc, end_pc) per compiled-program
-        # invocation, its own leaf lock — note_dispatch runs on the
-        # dispatch hot path and must never contend with a snapshot
-        # walking the request ring.
-        self._gap_lock = make_lock("TimelineRecorder._gap_lock")
-        self._dispatches: deque = deque(maxlen=max(16, int(max_dispatches)))
-        self.dispatches_total = 0
+        # Injected by the server at start (this module imports no jax):
+        # a context-manager factory taking the event name, i.e.
+        # jax.profiler.TraceAnnotation. None = spans only.
+        self.annotation: Optional[Callable[[str], Any]] = None
+        # A tracer with offer(root) — the OTLP exporter — or None.
+        self.exporter: Any = None
+        # Thread lanes of the Chrome export: ident -> (lane, name).
+        self._lanes: Dict[int, Tuple[int, str]] = {}
         # Roofline counter track: (wall_s, bytes_per_s, fraction)
         # samples from the megakernel's sampled device fences
         # (executor/megakernel._attribute via roofline.note_device) —
-        # exported as ph:"C" Perfetto counter lanes. Guarded by the
-        # gap lock: both are leaf locks fed from the dispatch path.
+        # exported as ph:"C" Perfetto counter lanes.
+        self._counter_lock = make_lock("TimelineRecorder._counter_lock")
         self._counters: deque = deque(maxlen=self.MAX_COUNTER_SAMPLES)
         self.counters_total = 0
 
@@ -160,8 +305,7 @@ class TimelineRecorder:
 
     def configure(self, enabled: Optional[bool] = None,
                   ring: Optional[int] = None,
-                  sample_every: Optional[int] = None,
-                  gap_window_s: Optional[float] = None) -> None:
+                  sample_every: Optional[int] = None) -> None:
         with self._lock:
             if enabled is not None:
                 self.enabled = bool(enabled)
@@ -169,8 +313,6 @@ class TimelineRecorder:
                 self._ring = deque(self._ring, maxlen=max(1, int(ring)))
             if sample_every is not None:
                 self.sample_every = max(1, int(sample_every))
-        if gap_window_s is not None:
-            self.gap_window_s = max(0.001, float(gap_window_s))
 
     def reset(self) -> None:
         """Tests only: drop every recorded timeline and counter."""
@@ -179,104 +321,218 @@ class TimelineRecorder:
             self._seq = 0
             self.requests_recorded = 0
             self.requests_skipped = 0
-        with self._gap_lock:
-            self._dispatches.clear()
-            self.dispatches_total = 0
+        with self._counter_lock:
             self._counters.clear()
             self.counters_total = 0
 
+    # -------------------------------------------------------- thread state
+
+    def _stack(self) -> List[_Open]:
+        try:
+            return self._tls.stack
+        except AttributeError:
+            stack = self._tls.stack = []
+            return stack
+
+    def _lane(self) -> int:
+        try:
+            return self._tls.lane
+        except AttributeError:
+            t = threading.current_thread()
+            with self._lock:
+                lane, name = self._lanes.get(t.ident, (0, ""))
+                if lane and name != t.name:
+                    # The ident of a dead thread, reused.
+                    self._lanes[t.ident] = (lane, t.name)
+                if not lane:
+                    if len(self._lanes) >= 1024:
+                        # Threads come and go (one per connection):
+                        # forget the lanes of the dead before growing.
+                        live = {x.ident for x in threading.enumerate()}
+                        for ident in [i for i in self._lanes
+                                      if i not in live]:
+                            del self._lanes[ident]
+                    lane = 1 + max((v[0] for v in self._lanes.values()),
+                                   default=0)
+                    self._lanes[t.ident] = (lane, t.name)
+            self._tls.lane = lane
+            return lane
+
+    def attached(self, rec: Optional[_TimelineRequest]) -> _Attached:
+        """``with TIMELINE.attached(rec):`` — `rec` is what
+        ``current()`` returns on this thread inside the block: how the
+        executor finds the record (a request's, or the flush's) without
+        a parameter on every call."""
+        return _Attached(self, rec)
+
+    def current(self) -> Optional[_TimelineRequest]:
+        return getattr(self._tls, "rec", None)
+
+    def open_span(self) -> Optional[Span]:
+        """The innermost span open on this thread, of any record (what
+        the compile log tags when XLA compiles inside a stage)."""
+        stack = self._stack()
+        return stack[-1].span if stack else None
+
+    def phase(self, name: str, **attrs: Any) -> Any:
+        """A stage of the attached record that sibling stages may
+        interrupt (module docstring) — unless this thread is already
+        inside phase `name` of that record: then a bare clock, whose
+        elapsed() is the same wall interval. The executor brackets
+        every call of a query with `plan` and `finish`; inside a
+        flush's one `plan` (or `finish`) those are readings, not spans
+        of their own."""
+        rec = getattr(self._tls, "rec", None)
+        if rec is None:
+            return _Clock()
+        stack = self._stack()
+        if stack and stack[-1].name == name and stack[-1].rec is rec:
+            return _Clock()
+        return self.span(rec, name, phase=True, **attrs)
+
     # ------------------------------------------------------------ recording
 
-    def begin(self, trace_id: Optional[str],
-              index: str = "") -> Optional[_TimelineRequest]:
-        """Open a request timeline (None = not sampled / disabled).
-        ``trace_id`` should be the same id the tracer propagates
-        (W3C traceparent) so cross-node legs stitch by it."""
-        # A new request on this thread invalidates the previous one's
-        # post-finish hook: if its serialize slice never fired (error
-        # path, broken pipe), note_serialize must not attach THIS
-        # request's serialize time to an already-published timeline.
-        self._tls.last = None
+    def begin(self, trace_id: Optional[str], index: str = "",
+              stats: Any = None, name: str = "request",
+              kind: str = "request",
+              **attrs: Any) -> Optional[_TimelineRequest]:
+        """Open a record (None = not sampled / disabled). ``trace_id``
+        should be the id the tracer propagates (W3C traceparent) so
+        cross-node legs stitch by it; ``stats`` receives the stage
+        durations at finish()."""
         if not self.enabled:
             return None
         with self._lock:
             self._seq += 1
-            if self.sample_every > 1 and self._seq % self.sample_every:
+            seq = self._seq
+            if self.sample_every > 1 and seq % self.sample_every:
                 self.requests_skipped += 1
                 return None
-        return _TimelineRequest(trace_id or uuid.uuid4().hex, index,
-                                self._seq)
+        if index:
+            attrs["index"] = index
+        rec = _TimelineRequest(trace_id or uuid.uuid4().hex, index, seq,
+                               kind, name, stats, attrs)
+        rec.root.tid = self._lane()
+        return rec
 
-    def event(self, req: Optional[_TimelineRequest], name: str,
-              lane: int, start_pc: float, dur_s: float,
-              **args: Any) -> None:
-        """Record one ``ph:"X"`` slice. ``start_pc`` is a
-        ``time.perf_counter()`` reading; negative durations clamp to 0
-        (clock granularity)."""
-        if req is None:
-            return
-        if len(req.events) >= self.MAX_EVENTS_PER_REQUEST:
-            req.dropped += 1
-            return
-        req.events.append((name, lane, start_pc, max(0.0, dur_s),
-                           args or None))
+    def span(self, rec: Optional[_TimelineRequest], name: str,
+             phase: bool = False, counts: Any = (),
+             **attrs: Any) -> Any:
+        """``with TIMELINE.span(rec, "pql.parse", ...) as s:`` — time
+        one stage of `rec` on this thread. The parent is the innermost
+        span of `rec` open on this thread, else its root; see the
+        module docstring for how phases are interrupted. `s.set(k, v)`
+        adds an attribute, `s.duration()` is the stage's seconds;
+        `phase` makes it one that sibling stages interrupt; `counts`
+        are (counter name, delta) pairs the record hands to its stats
+        client when it finishes."""
+        if rec is None:
+            return _Clock()
+        return _Open(self, rec, name, attrs, phase, counts)
 
-    def finish(self, req: Optional[_TimelineRequest],
+    def stage(self, name: str, **kw: Any) -> Any:
+        """span() on the record attached to this thread."""
+        return self.span(getattr(self._tls, "rec", None), name, **kw)
+
+    def add(self, rec: Optional[_TimelineRequest], name: str,
+            pc_start: float, pc_end: float,
+            link: Optional[Span] = None, own_lane: bool = False,
+            **attrs: Any) -> None:
+        """An interval no single thread brackets — a queue wait ends
+        on the dispatcher's thread while its request's own thread is
+        parked — or one that is only kept once its outcome is known (a
+        fan-out leg that won its hedge race): recorded from two
+        existing clock readings as a child of the root, drawn on the
+        root's lane, or on the calling thread's with `own_lane`
+        (concurrent legs would overlap on one). `link` makes it a
+        reference to a span of another record."""
+        if rec is None:
+            return
+        if rec.n_spans >= self.MAX_EVENTS_PER_REQUEST:
+            rec.dropped += 1
+            return
+        sp = Span(name, rec.trace_id, attrs, pc_start=pc_start)
+        sp.pc_end = max(pc_start, pc_end)
+        sp.link = link
+        sp.tid = self._lane() if own_lane else rec.root.tid
+        rec.n_spans += 1
+        rec.root.children.append(sp)
+
+    def finish(self, rec: Optional[_TimelineRequest],
                error: Optional[BaseException] = None) -> None:
-        """Close a request timeline: append the request-level slice and
-        publish the timeline into the ring. Also remembers the request
-        on the calling thread so a post-response hook (HTTP serialize)
-        can still attach to it."""
-        if req is None:
+        """Close a record: stamp the root, take its unaccounted time,
+        add every span to the cumulative stage histograms (one lock),
+        publish it into the ring and offer it to the exporter."""
+        if rec is None:
             return
+        root = rec.root
+        if root.pc_end is not None:
+            return   # finished already (an error path ran twice)
         if error is not None:
-            req.error = f"{type(error).__name__}: {error}"
-        dur = time.perf_counter() - req.t0_pc
-        args: Dict[str, Any] = {"trace": req.trace_id}
-        if req.index:
-            args["index"] = req.index
-        if req.error:
-            args["error"] = req.error
-        req.events.append(("request", LANE_REQUEST, req.t0_pc,
-                           max(0.0, dur), args))
+            rec.error = f"{type(error).__name__}: {error}"
+            root.attrs["error"] = rec.error
+        root.close()
+        total = root.pc_end - root.pc_start
+        rec.unaccounted = max(0.0, total - union_seconds(
+            [(max(c.pc_start, root.pc_start),
+              min(c.pc_end if c.pc_end is not None else root.pc_end,
+                  root.pc_end))
+             for c in root.children]))
+        if rec.stats is not None:
+            self._feed(rec, total)
         with self._lock:
-            self._ring.append(req)
+            self._ring.append(rec)
             self.requests_recorded += 1
-        self._tls.last = req
+        exporter = self.exporter
+        if exporter is not None:
+            exporter.offer(root)
 
-    def note_serialize(self, start_pc: float, dur_s: float) -> None:
-        """Attach an HTTP-serialize slice to the request this thread
-        most recently finished (the handler thread writes the response
-        after the API layer closed the timeline)."""
-        req = getattr(self._tls, "last", None)
-        if req is None:
-            return
-        self.event(req, "serialize", LANE_SERIALIZE, start_pc, dur_s)
-        self._tls.last = None
+    def _feed(self, rec: _TimelineRequest, total: float) -> None:
+        """One observation per stage name per record (the sum of that
+        name's spans), so a stage's histogram count is the number of
+        requests — or flushes — that went through it, and a record's
+        top-level sums plus its unaccounted time equal its total."""
+        sums: Dict[str, float] = {}
+        for sp in rec.root.walk():
+            if sp is rec.root:
+                continue
+            name = sp.name + ".member" if sp.link is not None else sp.name
+            sums[name] = sums.get(name, 0.0) + (
+                (sp.pc_end if sp.pc_end is not None
+                 else rec.root.pc_end) - sp.pc_start)
+        histos = [("request.stage_seconds", (f"stage:{n}",), v,
+                   STAGE_BUCKETS) for n, v in sums.items()]
+        if rec.kind == "flush":
+            histos.append(("request.stage_seconds",
+                           ("stage:" + rec.root.name,), total,
+                           STAGE_BUCKETS))
+            histos.append(("flush.unaccounted_seconds", (),
+                           rec.unaccounted, STAGE_BUCKETS))
+        else:
+            histos.append(("request.total_seconds", (), total,
+                           STAGE_BUCKETS))
+            histos.append(("request.unaccounted_seconds", (),
+                           rec.unaccounted, STAGE_BUCKETS))
+        counts = list(rec.counts.items())
+        if rec.dropped:
+            # Spans past MAX_EVENTS_PER_REQUEST left the tree: their
+            # time reads as the parent's, or as unaccounted.
+            counts.append(("request.spans_dropped", rec.dropped))
+        rec.stats.batch(histos, counts)
 
-    # ------------------------------------------- dispatch-gap analyzer
-
-    def note_dispatch(self, start_pc: float, dur_s: float) -> None:
-        """One compiled-program invocation (enqueue interval). Always
-        on when the recorder is enabled — independent of request
-        sampling, so the idle ratio reflects every dispatch."""
-        if not self.enabled:
-            return
-        with self._gap_lock:
-            self._dispatches.append((start_pc, start_pc + max(0.0, dur_s)))
-            self.dispatches_total += 1
+    # ---------------------------------------------- roofline counter track
 
     def note_bandwidth(self, bytes_per_s: float,
                        roofline_frac: Optional[float]) -> None:
         """One achieved-bandwidth sample (a megakernel launch that hit
         a sampled device fence): feeds the ph:"C" counter lanes in the
-        export. Independent of request sampling, like note_dispatch —
-        the fence already happened, recording it costs one append.
-        roofline_frac is None on a device with no roofline on record;
-        that sample then has no fraction lane."""
+        export. Independent of request sampling — the fence already
+        happened, recording it costs one append. roofline_frac is None
+        on a device with no roofline on record; that sample then has
+        no fraction lane."""
         if not self.enabled:
             return
-        with self._gap_lock:
+        with self._counter_lock:
             self._counters.append((
                 time.time(), float(bytes_per_s),
                 None if roofline_frac is None else float(roofline_frac)))
@@ -284,7 +540,7 @@ class TimelineRecorder:
 
     def counter_samples(
             self) -> List[Tuple[float, float, Optional[float]]]:
-        with self._gap_lock:
+        with self._counter_lock:
             return list(self._counters)
 
     def _export_counters(self, pid: int) -> List[Dict[str, Any]]:
@@ -307,77 +563,59 @@ class TimelineRecorder:
                                "args": {"fraction": frac}})
         return events
 
-    def gap_summary(self, now_pc: Optional[float] = None
-                    ) -> Dict[str, Any]:
-        """Dispatch-gap stats over the rolling window: ``idleRatio`` is
-        the fraction of the span between the first and last dispatch in
-        the window that no dispatch covered — the time an RTT-hiding
-        pipeline (ROADMAP 5) could fill. In [0, 1] by construction;
-        0.0 with fewer than two dispatches in the window (no gaps are
-        measurable yet)."""
-        now = time.perf_counter() if now_pc is None else now_pc
-        horizon = now - self.gap_window_s
-        with self._gap_lock:
-            ivals = [(s, e) for s, e in self._dispatches if e >= horizon]
-            total = self.dispatches_total
-        out = {"dispatches": len(ivals), "dispatchesTotal": total,
-               "windowS": self.gap_window_s, "idleRatio": 0.0,
-               "busyS": 0.0, "idleS": 0.0, "largestGapS": 0.0}
-        if len(ivals) < 2:
-            return out
-        ivals.sort()
-        span_start, span_end = ivals[0][0], max(e for _, e in ivals)
-        busy = 0.0
-        largest_gap = 0.0
-        cur_s, cur_e = ivals[0]
-        for s, e in ivals[1:]:
-            if s > cur_e:
-                largest_gap = max(largest_gap, s - cur_e)
-                busy += cur_e - cur_s
-                cur_s, cur_e = s, e
-            else:
-                cur_e = max(cur_e, e)
-        busy += cur_e - cur_s
-        span = max(1e-12, span_end - span_start)
-        idle = max(0.0, span - busy)
-        out["busyS"] = busy
-        out["idleS"] = idle
-        out["largestGapS"] = largest_gap
-        out["idleRatio"] = min(1.0, max(0.0, idle / span))
-        return out
-
-    def idle_ratio(self, now_pc: Optional[float] = None) -> float:
-        return self.gap_summary(now_pc)["idleRatio"]
-
     # -------------------------------------------------------------- reading
 
     def _export_events(self, reqs: List[_TimelineRequest], pid: int
                        ) -> List[Dict[str, Any]]:
+        """Chrome ``ph:"X"`` slices, one per span, on the lane of the
+        thread that opened it: spans of one thread nest, so the UI
+        stacks a request's stages under its root."""
         events: List[Dict[str, Any]] = []
         for req in reqs:
-            anchor_us = req.t0_wall * 1e6
-            for name, lane, start_pc, dur_s, args in list(req.events):
-                ev: Dict[str, Any] = {
-                    "name": name, "ph": "X", "cat": "pilosa",
-                    "ts": anchor_us + (start_pc - req.t0_pc) * 1e6,
-                    "dur": dur_s * 1e6,
-                    "pid": pid, "tid": lane,
-                }
-                a = dict(args) if args else {}
-                a.setdefault("trace", req.trace_id)
-                ev["args"] = a
-                events.append(ev)
+            root = req.root
+            anchor_us = root.start * 1e6
+
+            def emit(sp: Span, parent: Optional[Span]) -> None:
+                end = sp.pc_end if sp.pc_end is not None else root.pc_end
+                args: Dict[str, Any] = dict(sp.attrs)
+                args["trace"] = req.trace_id
+                args["spanId"] = sp.span_id
+                if parent is not None:
+                    args["parent"] = parent.name
+                    args["parentSpanId"] = parent.span_id
+                else:
+                    args["kind"] = req.kind
+                    args["unaccountedS"] = req.unaccounted
+                if sp.link is not None:
+                    args["linkSpanId"] = sp.link.span_id
+                events.append({
+                    "name": sp.name, "ph": "X", "cat": "pilosa",
+                    "ts": anchor_us + (sp.pc_start - root.pc_start) * 1e6,
+                    "dur": max(0.0, end - sp.pc_start) * 1e6,
+                    "pid": pid, "tid": sp.tid, "args": args})
+                for c in list(sp.children):
+                    emit(c, sp)
+
+            emit(root, None)
         return events
 
     @staticmethod
-    def metadata_events(pid: int, node_name: str) -> List[Dict[str, Any]]:
+    def process_metadata(pid: int, node_name: str
+                         ) -> List[Dict[str, Any]]:
+        """The Chrome ``ph:"M"`` event naming one process (node)."""
+        return [{"name": "process_name", "ph": "M", "ts": 0, "dur": 0,
+                 "pid": pid, "tid": 0, "args": {"name": node_name}}]
+
+    def metadata_events(self, pid: int, node_name: str
+                        ) -> List[Dict[str, Any]]:
         """Chrome ``ph:"M"`` naming events for one process (node) and
-        its stage lanes. ``ts``/``dur`` ride along as 0 so every event
+        its thread lanes. ``ts``/``dur`` ride along as 0 so every event
         in the document carries the full ph/ts/dur/pid/tid shape (the
         CI smoke validates exactly that)."""
-        meta = [{"name": "process_name", "ph": "M", "ts": 0, "dur": 0,
-                 "pid": pid, "tid": 0, "args": {"name": node_name}}]
-        for lane, lname in LANE_NAMES.items():
+        meta = self.process_metadata(pid, node_name)
+        with self._lock:
+            lanes = sorted(self._lanes.values())
+        for lane, lname in lanes:
             meta.append({"name": "thread_name", "ph": "M", "ts": 0,
                          "dur": 0, "pid": pid, "tid": lane,
                          "args": {"name": lname}})
@@ -385,8 +623,8 @@ class TimelineRecorder:
 
     def requests(self, last: Optional[int] = None,
                  trace_id: Optional[str] = None) -> List[_TimelineRequest]:
-        """Most-recent-last request handles, optionally filtered by
-        trace id and bounded to the last N."""
+        """Most-recent-last records, optionally filtered by trace id
+        and bounded to the last N."""
         with self._lock:
             reqs = list(self._ring)
         if trace_id:
@@ -395,31 +633,58 @@ class TimelineRecorder:
             reqs = reqs[-last:]
         return reqs
 
+    @staticmethod
+    def _stage_sums(req: _TimelineRequest) -> Dict[str, float]:
+        out: Dict[str, float] = {}
+        for sp in req.root.walk():
+            if sp is not req.root and sp.pc_end is not None:
+                out[sp.name] = out.get(sp.name, 0.0) \
+                    + sp.pc_end - sp.pc_start
+        return out
+
     def _stage_medians(self, reqs: List[_TimelineRequest]
                        ) -> Dict[str, float]:
         per: Dict[str, List[float]] = {}
         for req in reqs:
-            for name, _lane, _s, dur_s, _a in list(req.events):
-                if name in _SUMMARY_STAGES:
-                    per.setdefault(name, []).append(dur_s)
-        out = {}
-        for name, vals in per.items():
-            vals.sort()
-            out[name] = vals[len(vals) // 2]
-        return out
+            for name, s in self._stage_sums(req).items():
+                per.setdefault(name, []).append(s)
+        return {name: sorted(vals)[len(vals) // 2]
+                for name, vals in per.items()}
+
+    def _by_call(self, reqs: List[_TimelineRequest]) -> Dict[str, Any]:
+        """Per top-level PQL call name (the root's ``calls`` attr):
+        requests, mean seconds, mean unaccounted seconds and mean
+        seconds per stage — which shapes make the time."""
+        acc: Dict[str, Dict[str, Any]] = {}
+        for req in reqs:
+            if req.kind != "request" or req.root.pc_end is None:
+                continue
+            a = acc.setdefault(str(req.root.attrs.get("calls", "-")),
+                               {"n": 0, "total": 0.0, "un": 0.0,
+                                "stages": {}})
+            a["n"] += 1
+            a["total"] += req.root.pc_end - req.root.pc_start
+            a["un"] += req.unaccounted
+            for name, s in self._stage_sums(req).items():
+                a["stages"][name] = a["stages"].get(name, 0.0) + s
+        return {call: {"requests": a["n"],
+                       "meanS": a["total"] / a["n"],
+                       "unaccountedMeanS": a["un"] / a["n"],
+                       "stageMeanS": {k: v / a["n"] for k, v in
+                                      sorted(a["stages"].items())}}
+                for call, a in sorted(acc.items())}
 
     def snapshot(self, last: Optional[int] = None,
                  trace_id: Optional[str] = None,
                  node_id: str = "local", pid: int = 0) -> Dict[str, Any]:
         """The ``GET /debug/timeline`` document: trace-event JSON
         (``traceEvents`` — the Chrome JSON object format, loadable
-        directly in Perfetto/chrome://tracing) plus a summary with the
-        dispatch-gap analysis and per-stage duration medians."""
+        directly in Perfetto/chrome://tracing) plus a summary with
+        per-stage medians and per-call-name means."""
         reqs = self.requests(last=last, trace_id=trace_id)
         counters = self._export_counters(pid)
         events = self.metadata_events(pid, node_id) \
             + counters + self._export_events(reqs, pid)
-        gap = self.gap_summary()
         return {
             "traceEvents": events,
             "displayTimeUnit": "ms",
@@ -433,9 +698,8 @@ class TimelineRecorder:
                 "counterSamples": sum(
                     1 for e in counters
                     if e["name"] == "launch_bytes_per_s"),
-                "deviceIdleRatio": gap["idleRatio"],
-                "dispatchGap": gap,
                 "stageMedianS": self._stage_medians(reqs),
+                "byCall": self._by_call(reqs),
             },
         }
 
@@ -447,12 +711,10 @@ class TimelineRecorder:
         """Estimated bytes held by the timeline ring (the memory-ledger
         ``telemetry`` registration; O(ring) under the lock)."""
         with self._lock:
-            n_events = sum(len(r.events) for r in self._ring)
-            n_reqs = len(self._ring)
-        with self._gap_lock:
+            n = sum(r.root.nbytes() + 160 for r in self._ring)
+        with self._counter_lock:
             n_counters = len(self._counters)
-        return (n_events * self.EVENT_NBYTES + n_reqs * 160
-                + n_counters * self.COUNTER_NBYTES)
+        return n + n_counters * self.COUNTER_NBYTES
 
     def register_memory(self, ledger: Optional[Any] = None) -> None:
         """Register the ring's bytes with the memory ledger (category
@@ -463,33 +725,21 @@ class TimelineRecorder:
                         owner=self, kind="timeline",
                         entries=self.ring_count())
 
-    def publish(self, stats: Optional[Any]) -> None:
-        """Export the dispatch-gap gauges: ``pilosa_device_idle_ratio``
-        plus the dispatch counter the ratio derives from."""
-        if stats is None:
-            return
-        gap = self.gap_summary()
-        stats.gauge("device_idle_ratio", gap["idleRatio"])
-        stats.gauge("timeline_window_dispatches", gap["dispatches"])
-
     def dump(self, logger: Optional[Any], last: int = 5) -> int:
-        """Write the most recent `last` request timelines to the log —
-        the SIGTERM drain calls this so buffered timelines survive a
+        """Write the most recent `last` records to the log — the
+        SIGTERM drain calls this so buffered timelines survive a
         graceful shutdown. Returns records written."""
         reqs = self.requests(last=max(0, int(last)))
         if logger is not None and reqs:
-            gap = self.gap_summary()
-            logger.printf(
-                "timeline: dumping %d request timeline(s) on shutdown "
-                "(idle ratio %.3f over %d dispatches)", len(reqs),
-                gap["idleRatio"], gap["dispatches"])
+            logger.printf("timeline: dumping %d request timeline(s) on "
+                          "shutdown", len(reqs))
             for r in reqs:
                 stages = ",".join(
-                    f"{name}={dur_s * 1e3:.2f}ms"
-                    for name, _l, _s, dur_s, _a in list(r.events)
-                    if name != "request")
-                logger.printf("timeline: trace=%s index=%s %s",
-                              r.trace_id, r.index or "-", stages)
+                    f"{name}={s * 1e3:.2f}ms"
+                    for name, s in self._stage_sums(r).items())
+                logger.printf("timeline: %s trace=%s index=%s %.2fms %s",
+                              r.kind, r.trace_id, r.index or "-",
+                              r.root.duration() * 1e3, stages)
         return len(reqs)
 
 

@@ -3,22 +3,26 @@
 Reference: /root/reference/tracing/tracing.go:18-56 — a global tracer with
 StartSpanFromContext plus HTTP header inject/extract at node boundaries,
 exported to Jaeger via server config (server/config.go:110-118).
-Here: a minimal span tree recorder with W3C-traceparent-style header
-propagation, pluggable like the reference's opentracing adapter, plus an
-OTLP/HTTP JSON exporter (ExportingTracer) — the modern wire format both
-Jaeger (:4318) and the OpenTelemetry collector ingest natively, so the
-reference's Jaeger wiring is covered without a thrift dependency.
+Here: the one ``Span`` type every request record is made of
+(utils/timeline.py opens, nests and keeps them — this module records
+nothing itself), W3C-traceparent-style trace-context propagation
+(``ContextTracer``: extract / inject / adopt), and an OTLP/HTTP JSON
+exporter (``ExportingTracer``) that ships the finished records the
+timeline offers it — the wire format both Jaeger (:4318) and the
+OpenTelemetry collector ingest natively, so the reference's Jaeger
+wiring is covered without a thrift dependency.
 """
 
 from __future__ import annotations
 
-import contextlib
+import itertools
 import json
+import os
 import threading
 from pilosa_tpu.utils.locks import make_lock
 import time
 import uuid
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, List, Optional
 
 # W3C Trace Context (https://www.w3.org/TR/trace-context/): the
 # header every OTel-aware proxy/collector understands, so traces stay
@@ -67,41 +71,69 @@ def parse_traceparent(value: str) -> Optional[str]:
     return trace_id
 
 
+# Span ids are minted lazily, at export, from a process-wide counter
+# under a per-process random prefix (unique within a trace across the
+# nodes that share it) — a request's dozen spans pay no uuid4 each.
+_SPAN_PREFIX = os.urandom(4).hex()
+_SPAN_SEQ = itertools.count(1)
+
+
 class Span:
-    """``start``/``end`` are wall-clock *export anchors*; durations are
-    pure ``time.perf_counter()`` deltas (``pc_start``/``pc_end``), so an
-    NTP step mid-span cannot corrupt them. ``end`` is derived at close
-    as ``start + duration()`` — one wall-clock read per span, never a
-    second one the clock could have stepped between."""
+    """One timed interval of one request: the only span type in the
+    program. ``pc_start``/``pc_end`` are ``time.perf_counter()``
+    readings, one pair per boundary; ``start`` is a wall-clock *export
+    anchor* that only a record's root carries (descendants export as
+    monotonic offsets from it, so an NTP step mid-request cannot
+    corrupt a trace). ``children`` are the spans this one caused;
+    ``link`` points at a span of ANOTHER record that covers the same
+    interval (a coalesced request's reference to the flush it rode);
+    ``tid`` is the small per-thread lane the Chrome export draws it
+    on."""
 
-    __slots__ = ("name", "trace_id", "span_id", "start", "end",
-                 "pc_start", "pc_end", "attrs", "children")
+    __slots__ = ("name", "trace_id", "_span_id", "start", "end",
+                 "pc_start", "pc_end", "attrs", "children", "link",
+                 "tid")
 
-    def __init__(self, name: str, trace_id: str, attrs: dict) -> None:
+    def __init__(self, name: str, trace_id: str, attrs: dict,
+                 pc_start: Optional[float] = None,
+                 wall: bool = False) -> None:
         self.name = name
         self.trace_id = trace_id
-        self.span_id = uuid.uuid4().hex[:16]
-        self.start = time.time()
+        self._span_id: Optional[str] = None
+        self.start: Optional[float] = time.time() if wall else None
         self.end: Optional[float] = None
-        self.pc_start = time.perf_counter()
+        self.pc_start = time.perf_counter() if pc_start is None \
+            else pc_start
         self.pc_end: Optional[float] = None
         self.attrs = attrs
         self.children: List["Span"] = []
+        self.link: Optional["Span"] = None
+        self.tid = 0
+
+    @property
+    def span_id(self) -> str:
+        sid = self._span_id
+        if sid is None:
+            sid = self._span_id = \
+                f"{_SPAN_PREFIX}{next(_SPAN_SEQ) & 0xFFFFFFFF:08x}"
+        return sid
 
     def duration(self) -> float:
         return (self.pc_end if self.pc_end is not None
                 else time.perf_counter()) - self.pc_start
 
-    def close(self) -> None:
-        """Stamp the monotonic end and derive the wall-clock end from
-        the span's own anchor + duration (skew-proof)."""
+    def close(self, pc_end: Optional[float] = None) -> None:
+        """Stamp the monotonic end (once) and, on an anchored span,
+        derive the wall-clock end from the anchor + duration."""
         if self.pc_end is None:
-            self.pc_end = time.perf_counter()
-        self.end = self.start + self.duration()
+            self.pc_end = time.perf_counter() if pc_end is None \
+                else pc_end
+        if self.start is not None:
+            self.end = self.start + self.duration()
 
     def nbytes(self) -> int:
         """Rough retained-memory estimate for the whole subtree (the
-        tracer ring's memory-ledger registration)."""
+        timeline ring's memory-ledger registration)."""
         n = 160 + len(self.name)
         for k, v in self.attrs.items():
             n += len(str(k)) + len(str(v)) + 32
@@ -116,12 +148,14 @@ class Span:
         spans."""
         self.attrs[key] = value
 
+    def walk(self):
+        """This span and every descendant, parents first."""
+        yield self
+        for c in self.children:
+            yield from c.walk()
+
 
 class NopTracer:
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs: Any) -> Iterator[Optional[Span]]:
-        yield None
-
     def inject(self, headers: Dict[str, str]) -> None:
         pass
 
@@ -129,81 +163,43 @@ class NopTracer:
         pass
 
 
-class RecordingTracer:
-    """Keeps the last `keep` finished root spans for inspection (the
-    in-process analog of the reference's Jaeger wiring)."""
+class ContextTracer:
+    """Trace-context propagation only: which trace id this thread's
+    request belongs to, taken from the incoming headers and stamped on
+    outgoing node-to-node requests. It keeps no spans — the request
+    record (utils/timeline.py) does, under the id handed out here."""
 
-    def __init__(self, keep: int = 128) -> None:
-        self.keep = keep
-        self.finished: List[Span] = []
+    def __init__(self) -> None:
         self._local = threading.local()
-        self._lock = make_lock("RecordingTracer._lock")
-        # Bytes retained by `finished` (span trees), maintained
-        # incrementally under _lock — the memory ledger's `telemetry`
-        # registration reads it without walking the ring.
-        self._ring_bytes = 0
-
-    def _stack(self) -> List[Span]:
-        if not hasattr(self._local, "stack"):
-            self._local.stack = []
-        return self._local.stack
-
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs: Any) -> Iterator[Span]:
-        stack = self._stack()
-        trace_id = stack[0].trace_id if stack \
-            else getattr(self._local, "trace_id", None) or uuid.uuid4().hex
-        span = Span(name, trace_id, attrs)
-        if stack:
-            stack[-1].children.append(span)
-        stack.append(span)
-        try:
-            yield span
-        finally:
-            span.close()
-            stack.pop()
-            if not stack:
-                with self._lock:
-                    self.finished.append(span)
-                    self._ring_bytes += span.nbytes()
-                    if len(self.finished) > self.keep:
-                        for old in self.finished[: -self.keep]:
-                            self._ring_bytes -= old.nbytes()
-                        del self.finished[: -self.keep]
 
     def inject(self, headers: Dict[str, str]) -> None:
         """Stamp outgoing node-to-node requests with W3C traceparent:
-        the root span's trace id + the innermost open span as parent.
-        With no span open, an adopted thread trace id (extract(), or
-        adopt() on a scatter-gather worker) still propagates — the
-        coordinator's fan-out legs run on threads that never opened a
-        span, and before this fallback their query POSTs carried no
-        trace context at all (the old cross-node stitching only worked
-        through a stale-thread-local side channel). The legacy header
-        rides along for the same one-release window extract keeps
-        accepting it — a not-yet-upgraded peer only reads X-Trace-Id,
-        and a mixed-version cluster must keep correlating in BOTH
-        directions during a rolling upgrade."""
-        stack = self._stack()
-        if stack:
-            headers[TRACEPARENT_HEADER] = format_traceparent(
-                stack[0].trace_id, stack[-1].span_id)
-            headers[TRACE_HEADER] = stack[0].trace_id
-            return
+        the thread's trace id (extract(), ensure_trace_id(), or
+        adopt() on a scatter-gather worker — the coordinator's fan-out
+        legs run on threads of their own) plus a parent span id: the
+        root span of the request record attached to this thread when
+        there is one, else a synthetic id (the W3C field is mandatory;
+        propagation-only contexts do the same in mainstream tracers).
+        The legacy header rides along for the same one-release window
+        extract keeps accepting it — a not-yet-upgraded peer only
+        reads X-Trace-Id, and a mixed-version cluster must keep
+        correlating in BOTH directions during a rolling upgrade."""
         tid = getattr(self._local, "trace_id", None)
         if tid:
-            # No open span to parent under: mint a synthetic parent id
-            # (the W3C field is mandatory; non-recording propagation-
-            # only contexts do the same in mainstream tracers).
+            parent = getattr(self._local, "parent_span", None)
             headers[TRACEPARENT_HEADER] = format_traceparent(
-                tid, uuid.uuid4().hex[:16])
+                tid, parent.span_id if parent is not None
+                else uuid.uuid4().hex[:16])
             headers[TRACE_HEADER] = tid
 
-    def adopt(self, trace_id: Optional[str]) -> None:
+    def adopt(self, trace_id: Optional[str],
+              parent: Optional[Span] = None) -> None:
         """Adopt a trace id on THIS thread (scatter-gather workers call
         it with the coordinator request's id so their outgoing legs
-        inject the same trace the request arrived under)."""
+        inject the same trace the request arrived under). `parent` is
+        the span outgoing requests name as their parent."""
         self._local.trace_id = trace_id
+        self._local.parent_span = parent
 
     def extract(self, headers: Dict[str, str]) -> None:
         """Adopt an incoming trace context: W3C traceparent first, the
@@ -213,6 +209,7 @@ class RecordingTracer:
         handler threads are reused across keep-alive requests, and a
         stale id would stitch unrelated requests into one trace."""
         self._local.trace_id = None
+        self._local.parent_span = None
         tp = headers.get(TRACEPARENT_HEADER)
         if tp:
             tid = parse_traceparent(tp)
@@ -224,55 +221,26 @@ class RecordingTracer:
             self._local.trace_id = _sanitize_trace_id(tid)
 
     def current_trace_id(self) -> Optional[str]:
-        """Trace id of the thread's open root span (or the id extracted
-        from the incoming request, before any span opened) — lets the
-        query profiler stamp its slow-query records with the same id
-        the exported spans carry."""
-        stack = self._stack()
-        if stack:
-            return stack[0].trace_id
+        """Trace id adopted on this thread (extracted from the incoming
+        request or minted by ensure_trace_id) — lets the query
+        profiler stamp its slow-query records with the same id the
+        exported spans carry."""
         return getattr(self._local, "trace_id", None)
 
     def ensure_trace_id(self) -> str:
         """The thread's current trace id, minting (and adopting) one
-        when none was extracted — so the timeline recorder, the
-        profiler AND the spans a request subsequently opens all carry
-        the SAME id even for requests that arrived without a
-        traceparent header."""
+        when none was extracted — so the request record, the profiler
+        and outgoing legs all carry the SAME id even for requests that
+        arrived without a traceparent header."""
         tid = self.current_trace_id()
         if tid is None:
             tid = uuid.uuid4().hex
             self._local.trace_id = tid
         return tid
 
-    def ring_nbytes(self) -> int:
-        with self._lock:
-            return max(0, self._ring_bytes)
-
-    def register_memory(self, ledger: Optional[Any] = None) -> None:
-        """Register the finished-span ring with the memory ledger
-        (category ``telemetry``) so /debug/memory totals stay provable."""
-        if ledger is None:
-            from pilosa_tpu.utils.memledger import LEDGER as ledger
-        with self._lock:
-            nbytes = max(0, self._ring_bytes)
-            count = len(self.finished)
-        ledger.register("telemetry", "tracer_ring", nbytes, owner=self,
-                        kind="tracer", entries=count)
-
-    def dump(self, logger: Optional[Any], last: int = 10) -> int:
-        """Write the most recent `last` finished root spans to the log
-        (the SIGTERM drain path — buffered spans that never exported
-        still leave evidence). Returns spans written."""
-        with self._lock:
-            spans = list(self.finished[-max(0, int(last)):])
-        if logger is not None and spans:
-            logger.printf("tracer: dumping %d finished span(s) on "
-                          "shutdown", len(spans))
-            for s in spans:
-                logger.printf("tracer: %.3fs %s trace=%s",
-                              s.duration(), s.name, s.trace_id)
-        return len(spans)
+    def offer(self, root: Span) -> None:
+        """A finished request record's root span (utils/timeline.py
+        calls this at finish). Nothing to do without an exporter."""
 
 
 def _sanitize_trace_id(tid: str) -> str:
@@ -315,12 +283,17 @@ def spans_to_otlp(spans: List[Span], service_name: str) -> dict:
         }
         if parent_id:
             entry["parentSpanId"] = parent_id
+        if span.link is not None:
+            entry["links"] = [{
+                "traceId": span.link.trace_id[:32].ljust(32, "0"),
+                "spanId": span.link.span_id}]
         flat.append(entry)
         for child in span.children:
             walk(child, span.span_id, anchor_wall, anchor_pc)
 
     for s in spans:
-        walk(s, "", s.start, s.pc_start)
+        walk(s, "", s.start if s.start is not None
+             else time.time() - s.duration(), s.pc_start)
     return {"resourceSpans": [{
         "resource": {"attributes": [
             {"key": "service.name",
@@ -330,20 +303,20 @@ def spans_to_otlp(spans: List[Span], service_name: str) -> dict:
     }]}
 
 
-class ExportingTracer(RecordingTracer):
-    """RecordingTracer that ships finished root span trees to an
+class ExportingTracer(ContextTracer):
+    """ContextTracer that ships finished request records to an
     OTLP/HTTP endpoint (e.g. Jaeger's :4318/v1/traces) from a background
     thread. Batches up to `batch_size` spans or `flush_interval`
     seconds, whichever first; export failures are dropped after a log
     line — tracing must never stall queries."""
 
     def __init__(self, endpoint: str, service_name: str = "pilosa-tpu",
-                 keep: int = 128, batch_size: int = 64,
+                 batch_size: int = 64,
                  flush_interval: float = 5.0,
                  logger: Optional[Any] = None,
                  sampler_type: str = "const",
                  sampler_param: float = 1.0) -> None:
-        super().__init__(keep=keep)
+        super().__init__()
         self.endpoint = endpoint
         self.service_name = service_name
         self.batch_size = batch_size
@@ -351,9 +324,9 @@ class ExportingTracer(RecordingTracer):
         self.logger = logger
         # Head sampling (reference SamplerType/SamplerParam,
         # server/config.go:110-118, jaeger sampler semantics): decides
-        # per ROOT span whether its tree exports. Exporting every span
-        # is untenable at production query rates; local recording
-        # (/debug introspection) keeps working for unsampled traces.
+        # per request record whether its tree exports. Exporting every
+        # span is untenable at production query rates; the local ring
+        # (/debug/timeline) keeps every record either way.
         if sampler_type not in ("const", "probabilistic", "ratelimiting"):
             raise ValueError(f"unknown sampler type {sampler_type!r}")
         self.sampler_type = sampler_type
@@ -396,23 +369,16 @@ class ExportingTracer(RecordingTracer):
                 return True
             return False
 
-    @contextlib.contextmanager
-    def span(self, name: str, **attrs: Any) -> Iterator[Optional[Span]]:
-        stack = self._stack()
-        s = None  # super().span may raise before yielding (ADVICE r3)
-        try:
-            with super().span(name, **attrs) as s:
-                yield s
-        finally:
-            # Queue on the error path too: traces of FAILED requests are
-            # the ones operators need most.
-            if not stack and s is not None and self._sampled(s):
-                # a root span just finished and was head-sampled in
-                with self._pending_lock:
-                    self._pending.append(s)
-                    full = len(self._pending) >= self.batch_size
-                if full:
-                    self._wake.set()
+    def offer(self, root: Span) -> None:
+        """Queue one finished record for export when head sampling
+        takes it — failed requests too: their traces are the ones
+        operators need most."""
+        if self._sampled(root):
+            with self._pending_lock:
+                self._pending.append(root)
+                full = len(self._pending) >= self.batch_size
+            if full:
+                self._wake.set()
 
     def _drain(self) -> List[Span]:
         with self._pending_lock:

@@ -127,7 +127,7 @@ def live_server(tmp_path):
     # an equivalence check of the coalesced path (test_coalescer.py
     # additionally diffs coalesced vs direct byte-for-byte).
     api.coalescer = QueryCoalescer(api.executor, window_s=0.0005,
-                                   stats=api.stats, tracer=api.tracer)
+                                   stats=api.stats)
     api.coalescer.start()
     srv = serve(api, "localhost", 0, background=True)
     yield f"http://localhost:{srv.server_address[1]}", api, h

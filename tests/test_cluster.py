@@ -4,6 +4,7 @@ servers with real HTTP on localhost, static topology (reference static
 mode, cluster.go:1939)."""
 
 import json
+import time
 import urllib.error
 import urllib.request
 
@@ -1715,15 +1716,17 @@ def test_batch_query_cluster_path(tmp_path):
 def test_traceparent_round_trip_coordinator_to_remote(tmp_path):
     """W3C traceparent propagates across a coordinator→remote query
     leg: the trace id a client sends to the coordinator stamps the
-    remote node's spans too (inject emits traceparent; extract adopts
+    remote node's record too (inject emits traceparent; extract adopts
     it), so one distributed query is one trace end to end."""
-    from pilosa_tpu.utils.tracing import RecordingTracer
+    from pilosa_tpu.utils.timeline import TIMELINE
+    from pilosa_tpu.utils.tracing import ContextTracer
 
     nodes = run_cluster(tmp_path, 2)
     try:
+        TIMELINE.reset()
         tracers = []
         for nd in nodes:
-            rt = RecordingTracer()
+            rt = ContextTracer()
             nd.api.tracer = rt
             # The internal client captured the tracer at API build
             # time; repoint it so outgoing legs inject the new one.
@@ -1742,16 +1745,24 @@ def test_traceparent_round_trip_coordinator_to_remote(tmp_path):
             headers={"traceparent": f"00-{trace_id}-{'ab' * 8}-01"})
         with urllib.request.urlopen(r, timeout=30) as resp:
             assert json.loads(resp.read())["results"] == [6]
-        # Coordinator adopted the client's trace id...
-        coord_roots = [s for s in tracers[0].finished
-                       if s.name.startswith("API.Query")]
-        assert coord_roots and all(s.trace_id == trace_id
-                                   for s in coord_roots)
-        # ...and the remote leg carried it over the node-to-node hop.
-        remote_roots = [s for s in tracers[1].finished
-                        if s.trace_id == trace_id]
-        assert remote_roots, [s.trace_id for s in tracers[1].finished]
+        # Coordinator adopted the client's trace id (its record holds
+        # the fan-out leg)... The record closes in the handler's
+        # finally block, after the reply went out: wait for both.
+        for _ in range(400):
+            recs = [r for r in TIMELINE.requests()
+                    if r.trace_id == trace_id]
+            if len(recs) >= 2:
+                break
+            time.sleep(0.005)
+        coord = [r for r in recs
+                 if any(c.name == "remote" and c.attrs["remote"]
+                        for c in r.root.children)]
+        assert coord, [r.trace_id for r in TIMELINE.requests()]
+        # ...and the remote leg carried it over the node-to-node hop:
+        # the remote's own request record rides the same trace.
+        assert [r for r in recs if r not in coord], recs
     finally:
+        TIMELINE.reset()
         for nd in nodes:
             nd.stop()
 
@@ -1868,13 +1879,13 @@ def test_cluster_timeline_stitches_nodes(tmp_path):
     remote node id and ride the coordinator's trace id, so a cross-
     node query reads as one Perfetto-loadable document."""
     from pilosa_tpu.utils.timeline import TIMELINE
-    from pilosa_tpu.utils.tracing import RecordingTracer
+    from pilosa_tpu.utils.tracing import ContextTracer
 
     nodes = run_cluster(tmp_path, 2)
     try:
         TIMELINE.reset()
         for nd in nodes:
-            rt = RecordingTracer()
+            rt = ContextTracer()
             nd.api.tracer = rt
             nd.api._client.tracer = rt
             nd.api.profiler.tracer = rt
@@ -1892,7 +1903,13 @@ def test_cluster_timeline_stitches_nodes(tmp_path):
         with urllib.request.urlopen(r, timeout=30) as resp:
             assert json.loads(resp.read())["results"] == [6]
 
-        doc = req(base, "GET", f"/cluster/timeline/{trace_id}")
+        # The coordinator's record closes after its reply went out.
+        for _ in range(400):
+            doc = req(base, "GET", f"/cluster/timeline/{trace_id}")
+            if {e["pid"] for e in doc["traceEvents"]
+                    if e["ph"] == "X"} == {0, 1}:
+                break
+            time.sleep(0.005)
         assert doc["traceId"] == trace_id
         assert doc["totalNodes"] == 2
         assert doc["respondedNodes"] == 2
@@ -1915,8 +1932,7 @@ def test_cluster_timeline_stitches_nodes(tmp_path):
         # recorded its own dispatch under the SAME trace.
         coord_names = {e["name"] for e in xs if e["pid"] == 0}
         remote_names = {e["name"] for e in xs if e["pid"] == 1}
-        assert any(nm.startswith("remote:") for nm in coord_names), \
-            coord_names
+        assert "remote" in coord_names, coord_names
         assert "dispatch" in remote_names and "request" in remote_names
         # Every event validates against the Chrome trace-event shape.
         for ev in doc["traceEvents"]:
@@ -1933,13 +1949,13 @@ def test_cluster_timeline_reports_unreachable_node(tmp_path):
     error — never silently dropped — while the survivors' slices still
     merge (same contract as /cluster/health and /cluster/hotspots)."""
     from pilosa_tpu.utils.timeline import TIMELINE
-    from pilosa_tpu.utils.tracing import RecordingTracer
+    from pilosa_tpu.utils.tracing import ContextTracer
 
     nodes = run_cluster(tmp_path, 3)
     try:
         TIMELINE.reset()
         for nd in nodes:
-            rt = RecordingTracer()
+            rt = ContextTracer()
             nd.api.tracer = rt
             nd.api._client.tracer = rt
         base = nodes[0].uri

@@ -58,7 +58,7 @@ def pair(tmp_path):
         if with_coal:
             api.coalescer = QueryCoalescer(
                 api.executor, window_s=0.002, max_batch=32,
-                stats=api.stats, tracer=api.tracer)
+                stats=api.stats)
             api.coalescer.start()
             coalescers.append(api.coalescer)
             capi = api
@@ -259,7 +259,7 @@ def gated(tmp_path):
     gate = _GatedExecutor(api.executor)
     api.coalescer = QueryCoalescer(
         gate, window_s=0.0005, max_batch=8, max_queue=2,
-        deadline_s=0.2, stats=api.stats, tracer=api.tracer)
+        deadline_s=0.2, stats=api.stats)
     api.coalescer.start()
     srv = serve(api, "localhost", 0, background=True)
     yield f"http://localhost:{srv.server_address[1]}", gate, api

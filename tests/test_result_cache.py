@@ -770,7 +770,7 @@ def test_prometheus_counter_names(ex):
     assert "pilosa_result_cache_hit_ratio" in text
 
 
-def test_timeline_cache_lane_slice_on_hit(ex):
+def test_cache_lookup_span_says_hit(ex):
     from pilosa_tpu.utils.profile import QueryProfile
     from pilosa_tpu.utils.timeline import TIMELINE
     TIMELINE.configure(enabled=True, sample_every=1)
@@ -779,10 +779,15 @@ def test_timeline_cache_lane_slice_on_hit(ex):
         tl = TIMELINE.begin(None, "i")
         prof = QueryProfile("i", "Count(Row(f=2))")
         prof.timeline = tl
-        ex.execute_full("i", "Count(Row(f=2))", profile=prof)
+        with TIMELINE.attached(tl):
+            ex.execute_full("i", "Count(Row(f=2))", profile=prof)
         TIMELINE.finish(tl)
         (req,) = TIMELINE.requests(last=1)
-        assert any(name == "cache" for name, *_ in req.events), \
-            req.events
+        (look,) = req.root.children      # answered from the lookup
+        assert look.name == "cache.lookup" and look.attrs == {"hit": True}
+        # The profile's cache op is timed by that span.
+        (op,) = prof.ops
+        assert op.attrs["cacheHit"] and 0 < op.attrs["dispatchS"] \
+            <= look.pc_end - look.pc_start
     finally:
         TIMELINE.reset()

@@ -339,17 +339,18 @@ def test_debug_history_and_slo_routes(live_server):
         clock[0] += 30.0
     doc = _get(base, "/debug/history")
     assert doc["samples"] == 3 and "node" in doc
-    assert len(doc["series"]) >= 3          # idle/roofline/caches/hbm...
+    assert len(doc["series"]) >= 3          # roofline/caches/hbm...
     for s in doc["series"].values():
         ts = [p[0] for p in s["points"]]
         assert ts == sorted(ts)
     names = set(doc["series"])
-    assert {"device_idle_ratio", "hbm_live_bytes",
-            "result_cache_hit_ratio"} <= names
+    assert {"hbm_live_bytes", "result_cache_hit_ratio"} <= names
+    # The host-clock idle gauge left with the dispatch-gap analyzer.
+    assert "device_idle_ratio" not in names
     # series= + last= narrow the document.
-    doc = _get(base, "/debug/history?series=device_idle_ratio&last=2")
-    assert set(doc["series"]) == {"device_idle_ratio"}
-    assert len(doc["series"]["device_idle_ratio"]["points"]) == 2
+    doc = _get(base, "/debug/history?series=hbm_live_bytes&last=2")
+    assert set(doc["series"]) == {"hbm_live_bytes"}
+    assert len(doc["series"]["hbm_live_bytes"]["points"]) == 2
     # Unknown query params are rejected (the surface-wide contract).
     with pytest.raises(urllib.error.HTTPError) as ei:
         _get(base, "/debug/history?bogus=1")

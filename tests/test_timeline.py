@@ -1,10 +1,14 @@
-"""Request-lifecycle timeline plane (utils/timeline.py): the
-dispatch-gap analyzer's idle-ratio math, ring bounds and sampling, the
-Chrome trace-event export shape, wall-anchor skew immunity, the HTTP
-surfaces (/debug/timeline, /cluster/timeline, the SLO histograms), the
+"""The request record (utils/timeline.py): how spans nest, how phases
+are interrupted so that stages tile, what a finished record feeds into
+the cumulative stage histograms, ring bounds and sampling, the Chrome
+trace-event export, wall-anchor skew immunity, the annotations that
+put every span on a profiler trace's clock, the live wiring through
+the API, the coalescer and the HTTP handler, the HTTP surfaces
+(/debug/timeline, /cluster/timeline, the SLO histograms), the
 memory-ledger registration, and the zero-new-fences acceptance bar."""
 
 import json
+import threading
 import time
 import urllib.request
 
@@ -13,10 +17,19 @@ import pytest
 
 from pilosa_tpu.ops.bitset import SHARD_WIDTH
 from pilosa_tpu.server.api import API
-from pilosa_tpu.utils.stats import MemStatsClient, prometheus_text
+from pilosa_tpu.utils.stats import MemStatsClient
 from pilosa_tpu.utils.timeline import (
-    LANE_DISPATCH, LANE_NAMES, LANE_PLAN, TIMELINE, TimelineRecorder,
+    STAGE_BUCKETS, TIMELINE, TimelineRecorder, union_seconds,
 )
+
+# The stages of a direct-path request, in order (PERF.md section 3);
+# `h2d` only shows when operand vectors had to be uploaded.
+DIRECT_STAGES = ["http.read", "pql.parse", "coalescer.wait",
+                 "cache.lookup", "plan", "dispatch", "d2h", "finish",
+                 "http.serialize", "http.write"]
+MEMBER_STAGES = ["http.read", "pql.parse", "coalescer.wait",
+                 "coalescer.flush", "http.serialize", "http.write"]
+FLUSH_STAGES = ["cache.lookup", "plan", "dispatch", "d2h", "finish"]
 
 
 @pytest.fixture(autouse=True)
@@ -24,12 +37,12 @@ def _reset_timeline():
     """The recorder is process-wide (like hotspots.WORKLOAD): every
     test starts clean and leaves defaults behind."""
     TIMELINE.reset()
-    TIMELINE.configure(enabled=True, ring=256, sample_every=1,
-                       gap_window_s=60.0)
+    TIMELINE.configure(enabled=True, ring=256, sample_every=1)
     yield
     TIMELINE.reset()
-    TIMELINE.configure(enabled=True, ring=256, sample_every=1,
-                       gap_window_s=60.0)
+    TIMELINE.configure(enabled=True, ring=256, sample_every=1)
+    TIMELINE.annotation = None
+    TIMELINE.exporter = None
 
 
 def _seed(holder):
@@ -40,51 +53,167 @@ def _seed(holder):
     return idx
 
 
-# ------------------------------------------------- dispatch-gap analyzer
+def _names(span):
+    return [c.name for c in span.children]
 
 
-def test_idle_ratio_exact_math():
-    rec = TimelineRecorder(gap_window_s=100.0)
-    # Three dispatches at t=0..1, 2..3, 4..5: busy 3s over span 5s.
-    for s in (0.0, 2.0, 4.0):
-        rec.note_dispatch(s, 1.0)
-    gap = rec.gap_summary(now_pc=5.0)
-    assert gap["dispatches"] == 3
-    assert gap["busyS"] == pytest.approx(3.0)
-    assert gap["idleS"] == pytest.approx(2.0)
-    assert gap["idleRatio"] == pytest.approx(2.0 / 5.0)
-    assert gap["largestGapS"] == pytest.approx(1.0)
-    assert 0.0 <= gap["idleRatio"] <= 1.0
+def _dedup(names):
+    """Consecutive repeats collapsed, then first occurrences: the order
+    in which the stages were first entered."""
+    out = []
+    for n in names:
+        if n not in out:
+            out.append(n)
+    return out
 
 
-def test_idle_ratio_overlapping_dispatches_merge():
-    """Overlapping enqueue intervals (pipelined dispatch) must not
-    double-count busy time — coverage is an interval union."""
-    rec = TimelineRecorder(gap_window_s=100.0)
-    rec.note_dispatch(0.0, 2.0)
-    rec.note_dispatch(1.0, 2.0)   # overlaps the first
-    rec.note_dispatch(5.0, 1.0)
-    gap = rec.gap_summary(now_pc=6.0)
-    assert gap["busyS"] == pytest.approx(4.0)   # [0,3] + [5,6]
-    assert gap["idleRatio"] == pytest.approx(2.0 / 6.0)
+def _assert_inside(span, lo=None, hi=None):
+    """Every span lies inside its parent, and closed."""
+    assert span.pc_end is not None and span.pc_end >= span.pc_start
+    if lo is not None:
+        assert lo - 1e-9 <= span.pc_start and span.pc_end <= hi + 1e-9, \
+            span.name
+    for c in span.children:
+        _assert_inside(c, span.pc_start, span.pc_end)
 
 
-def test_idle_ratio_degenerate_cases():
-    rec = TimelineRecorder(gap_window_s=10.0)
-    assert rec.idle_ratio(now_pc=0.0) == 0.0          # no dispatches
-    rec.note_dispatch(0.0, 0.5)
-    assert rec.idle_ratio(now_pc=1.0) == 0.0          # one dispatch
-    # Dispatches older than the window fall out of the analysis.
-    rec.note_dispatch(0.6, 0.2)
-    assert rec.gap_summary(now_pc=100.0)["dispatches"] == 0
+# ------------------------------------------------ nesting, phases, tiling
 
 
-def test_note_dispatch_disabled_is_noop():
+def test_spans_nest_under_innermost_open_span():
+    rec = TimelineRecorder()
+    req = rec.begin("a" * 32, index="i")
+    with rec.span(req, "outer") as o:
+        with rec.span(req, "inner", k=1) as i:
+            i.set("late", 2)
+    with rec.span(req, "sibling"):
+        pass
+    rec.finish(req)
+    assert _names(req.root) == ["outer", "sibling"]
+    (inner,) = o.span.children
+    assert inner.name == "inner" and inner.attrs == {"k": 1, "late": 2}
+    assert all(sp.trace_id == "a" * 32 for sp in req.root.walk())
+    _assert_inside(req.root)
+    # A handle's duration() after exit is the span's own reading.
+    assert i.duration() == inner.pc_end - inner.pc_start
+
+
+def test_phase_is_interrupted_by_sibling_stage_and_resumes():
+    """`dispatch` opened inside `plan` is plan's SIBLING: the plan
+    segment closes, dispatch runs, a fresh plan segment resumes — so
+    top-level stages never overlap and their durations add up."""
+    rec = TimelineRecorder()
+    req = rec.begin(None)
+    with rec.span(req, "plan", phase=True, op="Count") as plan:
+        time.sleep(0.002)
+        with rec.span(req, "dispatch", program="tree_count") as d:
+            time.sleep(0.003)
+        time.sleep(0.001)
+        with rec.span(req, "h2d", bytes=8, transfers=2):
+            pass
+    rec.finish(req)
+    assert _names(req.root) == ["plan", "dispatch", "plan", "h2d", "plan"]
+    segs = [c for c in req.root.children if c.name == "plan"]
+    assert segs[0].attrs == {"op": "Count"}
+    assert all(s.attrs == {"resumed": True} for s in segs[1:])
+    # No two top-level children overlap.
+    kids = req.root.children
+    for a, b in zip(kids, kids[1:]):
+        assert a.pc_end <= b.pc_start
+    # duration() = the phase's own segments; elapsed() adds what
+    # interrupted it.
+    own = sum(s.pc_end - s.pc_start for s in segs)
+    assert plan.duration() == pytest.approx(own)
+    assert plan.elapsed() == pytest.approx(
+        own + d.duration() + kids[3].pc_end - kids[3].pc_start)
+    assert d.duration() >= 0.003
+    assert plan.duration() < plan.elapsed()
+
+
+def test_dotted_child_nests_inside_its_phase():
+    rec = TimelineRecorder()
+    req = rec.begin(None)
+    with rec.span(req, "plan", phase=True):
+        with rec.span(req, "plan.lower", entries=3):
+            pass
+        with rec.span(req, "plan.verify"):
+            pass
+    with rec.span(req, "finish", phase=True):
+        with rec.span(req, "finish", phase=True):   # a phase interrupts itself
+            pass
+    rec.finish(req)
+    assert _names(req.root) == ["plan", "finish", "finish", "finish"]
+    assert _names(req.root.children[0]) == ["plan.lower", "plan.verify"]
+
+
+def test_add_records_cross_thread_interval_and_link():
+    rec = TimelineRecorder()
+    flush = rec.begin(None, name="coalescer.flush", kind="flush", batch=2)
+    rec.finish(flush)
+    req = rec.begin(None)
+    t0 = req.root.pc_start
+    rec.add(req, "coalescer.wait", t0, t0 + 0.25, reason="window")
+    rec.add(req, "coalescer.flush", flush.root.pc_start,
+            flush.root.pc_end, link=flush.root)
+    rec.add(None, "x", 0.0, 1.0)                  # no record: no-op
+    wait, ref = req.root.children
+    assert wait.pc_end - wait.pc_start == pytest.approx(0.25)
+    assert wait.attrs == {"reason": "window"} and wait.link is None
+    assert ref.link is flush.root
+    assert (ref.pc_start, ref.pc_end) == (flush.root.pc_start,
+                                          flush.root.pc_end)
+    assert wait.tid == ref.tid == req.root.tid
+
+
+def test_unaccounted_is_root_minus_union_of_children():
+    assert union_seconds([(0, 1), (0.5, 2), (3, 4)]) == pytest.approx(3.0)
+    assert union_seconds([]) == 0.0
+    rec = TimelineRecorder()
+    req = rec.begin(None)
+    t0 = req.root.pc_start
+    rec.add(req, "a", t0 + 0.0, t0 + 0.010)
+    rec.add(req, "b", t0 + 0.005, t0 + 0.020)     # overlaps a
+    rec.add(req, "c", t0 + 0.030, t0 + 0.040)
+    req.root.close(pc_end=t0 + 0.050)
+    req.root.pc_end = None                        # let finish() close it
+    real = time.perf_counter
+    try:
+        time.perf_counter = lambda: t0 + 0.050
+        rec.finish(req)
+    finally:
+        time.perf_counter = real
+    assert req.unaccounted == pytest.approx(0.050 - 0.030)
+
+
+def test_disabled_begin_is_none_and_span_is_a_bare_clock():
     rec = TimelineRecorder()
     rec.enabled = False
-    rec.note_dispatch(0.0, 1.0)
-    assert rec.dispatches_total == 0
     assert rec.begin("t" * 32) is None
+    with rec.span(None, "plan", phase=True) as s:
+        time.sleep(0.001)
+    assert s.span is None and s.duration() >= 0.001
+    assert s.elapsed() == s.duration()
+    s.set("k", 1)                                 # no-op, no error
+    rec.finish(None)
+    assert rec.ring_count() == 0
+    with rec.stage("plan", phase=True) as s2:                 # nothing attached
+        pass
+    assert s2.span is None
+
+
+def test_attached_record_is_what_stage_writes_to():
+    rec = TimelineRecorder()
+    req = rec.begin(None)
+    assert rec.current() is None
+    with rec.attached(req):
+        assert rec.current() is req
+        with rec.stage("plan", phase=True):
+            pass
+        with rec.attached(None):
+            assert rec.current() is None
+        assert rec.current() is req
+    assert rec.current() is None
+    assert _names(req.root) == ["plan"]
 
 
 # -------------------------------------------------- ring / sampling / cap
@@ -105,28 +234,169 @@ def test_ring_bound_and_sampling():
     assert rec2.requests_skipped == 5
 
 
-def test_note_serialize_cannot_attach_to_previous_request():
-    """Review regression: if a request's serialize hook never fires
-    (error path, broken pipe), the NEXT request on the thread must not
-    attach its serialize slice to the already-published timeline —
-    begin() invalidates the thread's post-finish handle."""
-    rec = TimelineRecorder(sample_every=2)
-    assert rec.begin("0" * 32) is None   # seq 1: skipped
-    a = rec.begin("a" * 32)              # seq 2: sampled
-    rec.finish(a)                        # serialize hook never fires
-    assert rec.begin("b" * 32) is None   # seq 3: unsampled request B
-    rec.note_serialize(0.0, 1.0)         # B's serialize: must go nowhere
-    assert all(name != "serialize" for name, *_ in a.events)
-
-
-def test_event_cap_counts_drops():
+def test_span_cap_counts_drops():
+    stats = MemStatsClient()
     rec = TimelineRecorder()
-    req = rec.begin("b" * 32)
-    for i in range(rec.MAX_EVENTS_PER_REQUEST + 10):
-        rec.event(req, "plan", LANE_PLAN, float(i), 0.001)
-    assert len(req.events) == rec.MAX_EVENTS_PER_REQUEST
-    assert req.dropped == 10
-    rec.event(None, "plan", LANE_PLAN, 0.0, 0.0)  # None handle: no-op
+    req = rec.begin("b" * 32, stats=stats)
+    for _ in range(rec.MAX_EVENTS_PER_REQUEST + 10):
+        with rec.span(req, "plan.stage"):
+            pass
+    assert req.n_spans == rec.MAX_EVENTS_PER_REQUEST
+    assert len(req.root.children) == rec.MAX_EVENTS_PER_REQUEST - 1
+    assert req.dropped == 11
+    rec.add(req, "late", 0.0, 1.0)
+    assert req.dropped == 12
+    rec.finish(req)
+    rec.finish(req)                               # twice: once counted
+    assert rec.ring_count() == 1
+    assert stats.snapshot()["counters"]["request.spans_dropped"] == 12
+
+
+# ------------------------------------------- cumulative stage histograms
+
+
+def _histo(stats, key):
+    return stats.snapshot()["histograms"][key]
+
+
+def test_stage_histograms_are_cumulative_and_equal_the_spans():
+    """Each finished record adds ONE observation per stage name (the
+    sum of that name's spans); sums and counts only grow, and equal
+    what the ring's records hold."""
+    stats = MemStatsClient()
+    rec = TimelineRecorder()
+    want = {}
+    totals = unacc = 0.0
+    for n in range(3):
+        req = rec.begin(None, stats=stats)
+        with rec.span(req, "plan", phase=True):
+            with rec.span(req, "plan.stage"):
+                pass
+            with rec.span(req, "dispatch"):
+                time.sleep(0.001)
+        with rec.span(req, "finish", phase=True):
+            pass
+        rec.finish(req)
+        for sp in req.root.walk():
+            if sp is not req.root:
+                want[sp.name] = want.get(sp.name, 0.0) \
+                    + sp.pc_end - sp.pc_start
+        totals += req.root.pc_end - req.root.pc_start
+        unacc += req.unaccounted
+        for name, secs in want.items():
+            h = _histo(stats, f"request.stage_seconds{{stage:{name}}}")
+            assert h["count"] == n + 1          # one per record, not
+            assert h["sum"] == pytest.approx(secs)   # one per segment
+        assert _histo(stats, "request.total_seconds")["count"] == n + 1
+    assert set(want) == {"plan", "plan.stage", "dispatch", "finish"}
+    assert _histo(stats, "request.total_seconds")["sum"] == \
+        pytest.approx(totals)
+    assert _histo(stats, "request.unaccounted_seconds")["sum"] == \
+        pytest.approx(unacc)
+    # Top-level stage sums + unaccounted = total (the stages tile).
+    top = sum(want[n] for n in ("plan", "dispatch", "finish"))
+    assert top + unacc == pytest.approx(totals, rel=1e-6)
+    # Bounds reach down to 2^-17 s.
+    h = _histo(stats, "request.stage_seconds{stage:plan.stage}")
+    assert list(h["buckets"])[0] == repr(2.0 ** -17)
+    assert len(STAGE_BUCKETS) == 22 and STAGE_BUCKETS[-1] == 16.0
+
+
+def test_flush_record_feeds_flush_histograms_and_member_suffix():
+    stats = MemStatsClient()
+    rec = TimelineRecorder()
+    flush = rec.begin(None, stats=stats, name="coalescer.flush",
+                      kind="flush", batch=2)
+    with rec.span(flush, "plan", phase=True):
+        pass
+    rec.finish(flush)
+    for _ in range(2):
+        req = rec.begin(None, stats=stats)
+        rec.add(req, "coalescer.flush", flush.root.pc_start,
+                flush.root.pc_end, link=flush.root)
+        rec.finish(req)
+    h = stats.snapshot()["histograms"]
+    # The flush itself: one observation; its riders under .member.
+    own = h["request.stage_seconds{stage:coalescer.flush}"]
+    assert own["count"] == 1
+    assert own["sum"] == pytest.approx(flush.root.duration())
+    assert h["request.stage_seconds{stage:coalescer.flush.member}"][
+        "count"] == 2
+    assert h["flush.unaccounted_seconds"]["count"] == 1
+    # A flush is not a request.
+    assert h["request.total_seconds"]["count"] == 2
+    assert h["request.stage_seconds{stage:plan}"]["count"] == 1
+
+
+def test_span_counts_reach_the_stats_with_the_record():
+    """A span's `counts` are the opener's names, not the recorder's:
+    they ride the record and land in its one stats batch."""
+    stats = MemStatsClient()
+    rec = TimelineRecorder()
+    req = rec.begin(None, stats=stats)
+    with rec.span(req, "h2d", bytes=100,
+                  counts=(("x.up_bytes", 100), ("x.ups", 2))):
+        pass
+    with rec.span(req, "h2d", bytes=20,
+                  counts=(("x.up_bytes", 20), ("x.ups", 1))):
+        pass
+    with rec.span(None, "h2d", counts=(("x.ups", 9),)):   # no record
+        pass
+    assert "x.ups" not in stats.snapshot()["counters"]    # not yet
+    rec.finish(req)
+    c = stats.snapshot()["counters"]
+    assert c["x.up_bytes"] == 120 and c["x.ups"] == 3
+    assert "request.spans_dropped" not in c
+
+
+def test_transfer_stages_feed_byte_and_transfer_counters():
+    """profile.transfer: the executor's `h2d` / `d2h` stage, with its
+    bytes and transfers as attrs and as `executor.*` counters."""
+    from pilosa_tpu.utils.profile import transfer
+    stats = MemStatsClient()
+    req = TIMELINE.begin(None, stats=stats)
+    with TIMELINE.attached(req):
+        with TIMELINE.phase("plan"):
+            with transfer("h2d", 100, 2):
+                pass
+            with transfer("h2d", 20):
+                pass
+        with transfer("d2h", 4096, 3):
+            pass
+    with transfer("d2h", 7):                    # nothing attached
+        pass
+    TIMELINE.finish(req)
+    assert _names(req.root) == ["plan", "h2d", "plan", "h2d", "plan",
+                                "d2h"]
+    assert req.root.children[-1].attrs == {"bytes": 4096, "transfers": 3}
+    c = stats.snapshot()["counters"]
+    assert c["executor.h2d_bytes"] == 120
+    assert c["executor.h2d_transfers"] == 3
+    assert c["executor.d2h_bytes"] == 4096
+    assert c["executor.d2h_transfers"] == 3
+
+
+def test_stats_lock_taken_once_per_record_not_per_span():
+    calls = []
+
+    class _Stats(MemStatsClient):
+        def batch(self, histograms=(), counts=()):
+            calls.append((len(histograms), len(counts)))
+            super().batch(histograms, counts)
+
+        def histogram(self, *a, **k):
+            raise AssertionError("per-span stats call")
+
+        count = timing = gauge = histogram
+
+    rec = TimelineRecorder()
+    req = rec.begin(None, stats=_Stats())
+    for name in ("http.read", "pql.parse", "plan", "dispatch", "d2h",
+                 "finish", "http.serialize", "http.write"):
+        with rec.span(req, name):
+            pass
+    rec.finish(req)
+    assert calls == [(10, 0)]   # 8 stages + total + unaccounted, once
 
 
 # ------------------------------------------------------ export shape
@@ -134,10 +404,13 @@ def test_event_cap_counts_drops():
 
 def test_snapshot_chrome_trace_event_shape():
     rec = TimelineRecorder()
-    req = rec.begin("c" * 32, index="i1")
-    rec.event(req, "plan", LANE_PLAN, req.t0_pc + 0.001, 0.002)
-    rec.event(req, "dispatch", LANE_DISPATCH, req.t0_pc + 0.003, 0.004,
-              shards=2)
+    req = rec.begin("c" * 32, index="i1", calls="Count")
+    with rec.span(req, "plan", phase=True):
+        with rec.span(req, "plan.stage"):
+            pass
+        with rec.span(req, "dispatch", program="tree_count",
+                      jit="hit") as d:
+            time.sleep(0.004)
     rec.finish(req)
     doc = rec.snapshot(node_id="node-a")
     evs = doc["traceEvents"]
@@ -146,26 +419,44 @@ def test_snapshot_chrome_trace_event_shape():
         for k in ("name", "ph", "ts", "dur", "pid", "tid"):
             assert k in ev, ev
     xs = [e for e in evs if e["ph"] == "X"]
-    assert {e["name"] for e in xs} == {"plan", "dispatch", "request"}
+    assert [e["name"] for e in xs] == \
+        ["request", "plan", "plan.stage", "dispatch", "plan"]
     disp = next(e for e in xs if e["name"] == "dispatch")
-    assert disp["tid"] == LANE_DISPATCH
-    assert disp["dur"] == pytest.approx(4000.0)       # µs
+    assert disp["dur"] == pytest.approx(d.duration() * 1e6)
     assert disp["args"]["trace"] == "c" * 32
-    assert disp["args"]["shards"] == 2
+    assert disp["args"]["program"] == "tree_count"
+    # Each span knows the span that caused it.
+    root = xs[0]
+    assert disp["args"]["parent"] == "request"
+    assert disp["args"]["parentSpanId"] == root["args"]["spanId"]
+    stage = next(e for e in xs if e["name"] == "plan.stage")
+    assert stage["args"]["parent"] == "plan"
+    assert "parent" not in root["args"]
+    assert root["args"]["index"] == "i1"
+    assert root["args"]["calls"] == "Count"
+    assert root["args"]["kind"] == "request"
+    assert root["args"]["unaccountedS"] == req.unaccounted
     # ts is wall-anchored: within the request's wall window.
-    assert abs(disp["ts"] / 1e6 - req.t0_wall) < 1.0
-    # Metadata names the process and every stage lane.
+    assert abs(disp["ts"] / 1e6 - req.root.start) < 1.0
+    # All on the opening thread's lane, which the metadata names.
+    assert {e["tid"] for e in xs} == {req.root.tid}
     metas = [e for e in evs if e["ph"] == "M"]
     assert {"process_name", "thread_name"} == {e["name"] for e in metas}
     assert any(e["args"]["name"] == "node-a" for e in metas)
-    assert {e["args"]["name"] for e in metas
-            if e["name"] == "thread_name"} == set(LANE_NAMES.values())
-    # Request-level slice nests everything under one trace.
-    root = next(e for e in xs if e["name"] == "request")
-    assert root["args"]["index"] == "i1"
+    assert any(e["name"] == "thread_name" and e["tid"] == req.root.tid
+               and e["args"]["name"] == threading.current_thread().name
+               for e in metas)
     summary = doc["summary"]
     assert summary["requests"] == 1
-    assert 0.0 <= summary["deviceIdleRatio"] <= 1.0
+    assert "deviceIdleRatio" not in summary
+    assert "dispatchGap" not in summary
+    assert summary["stageMedianS"]["dispatch"] == \
+        pytest.approx(d.duration())
+    by_call = summary["byCall"]["Count"]
+    assert by_call["requests"] == 1
+    assert by_call["stageMeanS"]["dispatch"] == \
+        pytest.approx(d.duration())
+    assert by_call["meanS"] == pytest.approx(req.root.duration())
 
 
 def test_bandwidth_sample_without_a_roofline_has_no_fraction_lane():
@@ -223,22 +514,22 @@ def test_snapshot_filters_last_and_trace():
 
 
 def test_wall_anchor_immune_to_clock_step(monkeypatch):
-    """One wall-clock read per request: an NTP step AFTER begin() must
+    """One wall-clock read per record: an NTP step AFTER begin() must
     not move any event timestamp or duration (they are perf_counter
-    offsets from the anchor)."""
+    offsets from the root's anchor)."""
     rec = TimelineRecorder()
     real_time = time.time
     wall = [real_time()]
     monkeypatch.setattr(time, "time", lambda: wall[0])
     req = rec.begin("d" * 32)
-    t = req.t0_pc
-    rec.event(req, "plan", LANE_PLAN, t + 0.010, 0.005)
+    t = req.root.pc_start
+    rec.add(req, "plan", t + 0.010, t + 0.015)
     wall[0] += 3600.0  # the clock steps one hour mid-request
-    rec.event(req, "dispatch", LANE_DISPATCH, t + 0.020, 0.005)
+    rec.add(req, "dispatch", t + 0.020, t + 0.025)
     rec.finish(req)
     xs = {e["name"]: e for e in rec.snapshot()["traceEvents"]
           if e["ph"] == "X"}
-    anchor_us = req.t0_wall * 1e6
+    anchor_us = req.root.start * 1e6
     assert xs["plan"]["ts"] == pytest.approx(anchor_us + 10_000, abs=1)
     # The post-step event still exports 10ms later, not an hour later.
     assert xs["dispatch"]["ts"] - xs["plan"]["ts"] == \
@@ -246,40 +537,228 @@ def test_wall_anchor_immune_to_clock_step(monkeypatch):
     assert xs["request"]["dur"] < 1e6  # the request did not "take" 1h
 
 
+# -------------------------------------------- on the profiler's clock
+
+
+def test_annotation_factory_brackets_every_span():
+    """The injected factory is entered and exited around every span,
+    segment by segment, on the span's own thread, named
+    pilosa:<stage>."""
+    log = []
+
+    class _Ann:  # what jax.profiler.TraceAnnotation looks like
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("exit", self.name))
+
+    rec = TimelineRecorder()
+    rec.annotation = _Ann
+    req = rec.begin(None)
+    with rec.span(req, "plan", phase=True):
+        with rec.span(req, "dispatch"):
+            pass
+    rec.add(req, "coalescer.wait", 0.0, 1.0)     # no thread: no event
+    rec.finish(req)
+    assert log == [("enter", "pilosa:plan"), ("exit", "pilosa:plan"),
+                   ("enter", "pilosa:dispatch"),
+                   ("exit", "pilosa:dispatch"),
+                   ("enter", "pilosa:plan"), ("exit", "pilosa:plan")]
+    # attached() puts the root on the thread's line as well.
+    del log[:]
+    with rec.attached(req):
+        with rec.stage("finish", phase=True):
+            pass
+    assert log == [("enter", "pilosa:request"),
+                   ("enter", "pilosa:finish"), ("exit", "pilosa:finish"),
+                   ("exit", "pilosa:request")]
+
+
+def test_profiler_session_holds_nested_pilosa_events(tmp_holder, tmp_path):
+    """Under a jax.profiler session on the CPU backend one request's
+    spans are `pilosa:` events on its thread's line of the host plane,
+    nested as the spans are."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    _seed(tmp_holder)
+    api = API(tmp_holder, stats=MemStatsClient())
+    api.executor.result_cache.enabled = False
+    api.query("tl", "Count(Row(f=1))")            # compile outside
+    TIMELINE.annotation = jax.profiler.TraceAnnotation
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        api.query("tl", "Count(Row(f=1))")
+    finally:
+        jax.profiler.stop_trace()
+    TIMELINE.annotation = None
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events if e.name.startswith("pilosa:")]
+             for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines]
+    (events,) = [evs for evs in lines if evs]     # one thread's line
+    # The root is there too, around everything the executor did.
+    (root_ev,) = [e for e in events if e[0] == "pilosa:request"]
+    events = [e for e in events if e is not root_ev]
+    assert all(root_ev[1] <= s and e <= root_ev[2] for n, s, e in events
+               if n != "pilosa:pql.parse")
+    rec = TIMELINE.requests(last=1)[0]
+    spans = [sp for sp in rec.root.walk() if sp is not rec.root]
+    assert [n for n, _, _ in sorted(events, key=lambda e: e[1])] == \
+        ["pilosa:" + sp.name
+         for sp in sorted(spans, key=lambda sp: sp.pc_start)]
+    # Nested as the spans are: plan.stage inside the plan segment.
+    by = {}
+    for n, s, e in events:
+        by.setdefault(n, []).append((s, e))
+    (s0, e0), = by["pilosa:plan.stage"]
+    assert any(s <= s0 and e0 <= e for s, e in by["pilosa:plan"])
+    # ... and the dispatch annotation brackets JAX's own launch event.
+    launches = [(e.start_ns, e.start_ns + e.duration_ns)
+                for plane in ProfileData.from_file(path).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for e in line.events
+                if e.name.startswith("PjitFunction(tree_count)")]
+    (d0, d1), = by["pilosa:dispatch"]
+    assert any(d0 <= s and e <= d1 for s, e in launches)
+
+
 # ------------------------------------------------------- live wiring
 
 
-def test_query_records_stage_slices(tmp_holder):
+def test_query_records_stage_spans(tmp_holder):
     _seed(tmp_holder)
     api = API(tmp_holder, stats=MemStatsClient())
     api.query("tl", "Count(Row(f=1))")
-    reqs = TIMELINE.requests()
-    assert len(reqs) == 1
-    names = [name for name, *_ in reqs[0].events]
-    assert "plan" in names and "dispatch" in names \
-        and "materialize" in names and "request" in names
-    assert "device" not in names  # unsampled: no device slice
-    assert TIMELINE.dispatches_total >= 1
+    (rec,) = TIMELINE.requests()
+    names = _dedup(_names(rec.root))
+    assert [n for n in names if n != "h2d"] == \
+        ["pql.parse", "cache.lookup", "plan", "dispatch", "d2h", "finish"]
+    assert "device" not in names  # unsampled: no device span
+    assert rec.root.attrs["calls"] == "Count"
+    (disp,) = [c for c in rec.root.children if c.name == "dispatch"]
+    assert disp.attrs["program"] == "tree_count"
+    assert disp.attrs["jit"] == "miss" and "count|" in disp.attrs["key"]
+    (d2h,) = [c for c in rec.root.children if c.name == "d2h"]
+    assert d2h.attrs == {"bytes": 8, "transfers": 1}
+    _assert_inside(rec.root)
+    # A repeat: the program is cached, the result too.
+    api.query("tl", "Count(Row(f=1))")
+    again = TIMELINE.requests()[-1]
+    assert _dedup(_names(again.root)) == ["pql.parse", "cache.lookup"]
+    assert again.root.children[1].attrs == {"hit": True}
 
 
-def test_profiled_query_gains_device_slice(tmp_holder):
+def test_profile_stage_seconds_are_the_spans_readings(tmp_holder):
+    """One pair of clock reads per boundary: what the profile tree
+    reports IS what the record's spans measured."""
+    _seed(tmp_holder)
+    api = API(tmp_holder, stats=MemStatsClient())
+    resp = api.query("tl", "Count(Row(f=1))", profile=True)
+    rec = TIMELINE.requests()[-1]
+    spans = list(rec.root.walk())
+
+    def total(name):
+        return sum(s.pc_end - s.pc_start for s in spans
+                   if s.name == name)
+
+    (op,) = resp["profile"]["ops"]
+    (ev,) = op["children"]
+    assert ev["planS"] == pytest.approx(total("plan.stage"))
+    assert ev["dispatchS"] == pytest.approx(total("dispatch"))
+    assert op["materializeS"] == pytest.approx(
+        total("d2h") + sum(s.pc_end - s.pc_start for s in spans
+                           if s.name == "finish"
+                           and s.attrs.get("op") == "Count"))
+    # The op's dispatch time: its plan segments plus what interrupted
+    # them (h2d, dispatch, the sampled device fence, cache lookups).
+    assert op["dispatchS"] == pytest.approx(
+        total("plan") - sum(s.pc_end - s.pc_start for s in spans
+                            if s.name == "plan" and not s.attrs)
+        + total("h2d") + total("dispatch") + total("device")
+        + sum(s.pc_end - s.pc_start for s in spans
+              if s.name == "cache.lookup" and "tier" in s.attrs))
+
+
+@pytest.mark.parametrize("pql,program", [
+    ("TopN(f, Row(f=1), n=2)", "topn_sweep"),
+    ("Sum(field=v)", "bsi_sum"),
+    ("GroupBy(Rows(f), Rows(g))", "groupby"),
+    ("Row(f=1)", "tree_row"),
+])
+def test_every_upload_and_fetch_of_a_query_is_a_transfer_stage(
+        tmp_holder, pql, program):
+    """Whatever the call family, the operand vectors it uploads are
+    `h2d` spans and the blocking fetches `d2h` spans, and the byte
+    counters are the spans' sums — not only the tree path's."""
+    idx = _seed(tmp_holder)
+    cols = np.array([1, 2, SHARD_WIDTH + 3], np.uint64)
+    idx.create_field("g").import_bits(np.full(3, 7, np.uint64), cols)
+    stats = MemStatsClient()
+    api = API(tmp_holder, stats=stats)
+    api.create_field("tl", "v", {"type": "int", "min": 0, "max": 100})
+    api.import_values("tl", "v", cols.tolist(), [5, 6, 7])
+    api.executor.result_cache.enabled = False
+    api.query("tl", pql)
+    rec = TIMELINE.requests()[-1]
+    spans = list(rec.root.walk())
+    assert program in {s.attrs.get("program") for s in spans}
+    up = [s for s in spans if s.name == "h2d"]
+    down = [s for s in spans if s.name == "d2h"]
+    assert up and down, _names(rec.root)
+    # (An empty params vector is a put of 0 bytes: still a transfer.)
+    assert sum(s.attrs["bytes"] for s in up) > 0
+    assert all(s.attrs["bytes"] > 0 for s in down)
+    c = stats.snapshot()["counters"]
+    assert c["executor.h2d_bytes"] == sum(s.attrs["bytes"] for s in up)
+    assert c["executor.h2d_transfers"] == sum(
+        s.attrs["transfers"] for s in up)
+    assert c["executor.d2h_bytes"] == sum(s.attrs["bytes"] for s in down)
+
+
+def test_row_words_are_fetched_only_when_columns_are_read(tmp_holder):
+    _seed(tmp_holder)
+    api = API(tmp_holder, stats=MemStatsClient())
+    api.executor.result_cache.enabled = False
+    api.query("tl", "Options(Row(f=1), excludeColumns=true)")
+    assert "d2h" not in {s.name
+                         for s in TIMELINE.requests()[-1].root.walk()}
+    assert api.query("tl", "Row(f=1)")["results"][0]["columns"] == \
+        [1, 2, SHARD_WIDTH + 3]
+    (d2h,) = [s for s in TIMELINE.requests()[-1].root.walk()
+              if s.name == "d2h"]
+    assert d2h.attrs["transfers"] == 1 and d2h.attrs["bytes"] > 0
+
+
+def test_profiled_query_gains_device_span(tmp_holder):
     _seed(tmp_holder)
     api = API(tmp_holder, stats=MemStatsClient())
     api.query("tl", "Count(Row(f=1))", profile=True)
-    names = [name for name, *_ in TIMELINE.requests()[-1].events]
+    names = _names(TIMELINE.requests()[-1].root)
     assert "device" in names  # rides the profiler's sampled fence
 
 
 def test_zero_new_fences_on_unsampled_path(tmp_holder, monkeypatch):
-    """Acceptance: the timeline plane adds NO block_until_ready fences
-    on the unsampled hot path — wall timestamps of host-side events
+    """Acceptance: the request record adds NO block_until_ready fences
+    on the unsampled hot path — clock readings of host-side events
     only (same bar as PR 3's profiler and PR 6's recorder)."""
     import pilosa_tpu.executor.executor as ex
 
     _seed(tmp_holder)
     api = API(tmp_holder, stats=MemStatsClient())
-    # Every repeat must DISPATCH (dispatches_total >= 8 below); the
-    # result cache would serve 6 of the 8 without any device work.
+    # Every repeat must DISPATCH; the result cache would serve 6 of
+    # the 8 without any device work.
     api.executor.result_cache.enabled = False
     fences = []
     monkeypatch.setattr(ex, "_fence_device",
@@ -289,31 +768,35 @@ def test_zero_new_fences_on_unsampled_path(tmp_holder, monkeypatch):
     assert fences == []
     # ...and it recorded the full stage set while staying fence-free.
     assert TIMELINE.requests_recorded == 8
-    assert TIMELINE.dispatches_total >= 8
+    assert sum(1 for r in TIMELINE.requests()
+               for c in r.root.children if c.name == "dispatch") >= 8
 
 
 def test_timeline_disabled_records_nothing(tmp_holder):
     _seed(tmp_holder)
     TIMELINE.configure(enabled=False)
-    api = API(tmp_holder, stats=MemStatsClient())
-    api.query("tl", "Count(Row(f=1))")
+    stats = MemStatsClient()
+    api = API(tmp_holder, stats=stats)
+    resp = api.query("tl", "Count(Row(f=1))", profile=True)
     assert TIMELINE.requests_recorded == 0
-    assert TIMELINE.dispatches_total == 0
+    assert not any(k.startswith("request.")
+                   for k in stats.snapshot()["histograms"])
+    # The profile still gets its seconds: a bare clock stands in.
+    assert resp["profile"]["totals"]["dispatchS"] > 0
 
 
 def test_embedded_queries_get_distinct_trace_ids(tmp_holder):
     """Review regression: library (non-HTTP) callers have no per-
     request extract() reset, so the minted trace id must be dropped at
     request end — N queries on one thread are N traces, not one."""
-    from pilosa_tpu.utils.tracing import RecordingTracer
+    from pilosa_tpu.utils.tracing import ContextTracer
 
     _seed(tmp_holder)
-    tracer = RecordingTracer()
+    tracer = ContextTracer()
     api = API(tmp_holder, stats=MemStatsClient(), tracer=tracer)
     api.query("tl", "Count(Row(f=1))")
     api.query("tl", "Count(Row(f=1))")
     assert len({r.trace_id for r in TIMELINE.requests()}) == 2
-    assert len({s.trace_id for s in tracer.finished}) == 2
     assert tracer.current_trace_id() is None  # nothing sticks around
 
 
@@ -337,11 +820,11 @@ def test_endpoint_label_is_bounded():
 def test_trace_id_links_profiler_and_timeline(tmp_holder):
     """The slow-query ring's traceId opens the same request in the
     timeline: both stamp the ONE id the tracer minted."""
-    from pilosa_tpu.utils.tracing import RecordingTracer
+    from pilosa_tpu.utils.tracing import ContextTracer
 
     _seed(tmp_holder)
     api = API(tmp_holder, stats=MemStatsClient(),
-              tracer=RecordingTracer())
+              tracer=ContextTracer())
     api.long_query_time = 1e-9  # everything is "slow"
     api.query("tl", "Count(Row(f=1))")
     rec = api.profiler.slow_queries()[0]
@@ -357,18 +840,18 @@ def test_trace_id_links_profiler_and_timeline(tmp_holder):
 def live_api(tmp_holder):
     from pilosa_tpu.server import serve
     from pilosa_tpu.server.coalescer import QueryCoalescer
-    from pilosa_tpu.utils.tracing import RecordingTracer
+    from pilosa_tpu.utils.tracing import ContextTracer
 
     _seed(tmp_holder)
     api = API(tmp_holder, stats=MemStatsClient(),
-              tracer=RecordingTracer())
-    # These tests assert plan/dispatch/materialize slices on repeated
-    # queries; the result cache would answer the repeats with a single
-    # `cache` slice instead. Cache-ON timeline attribution is pinned
-    # in tests/test_result_cache.py.
+              tracer=ContextTracer())
+    # These tests assert plan/dispatch/d2h/finish spans on repeated
+    # queries; the result cache would answer the repeats from
+    # `cache.lookup` alone. Cache-ON attribution is pinned in
+    # tests/test_result_cache.py.
     api.executor.result_cache.enabled = False
     api.coalescer = QueryCoalescer(api.executor, window_s=0.0005,
-                                   stats=api.stats, tracer=api.tracer)
+                                   stats=api.stats)
     api.coalescer.start()
     srv = serve(api, "localhost", 0, background=True)
     base = f"http://localhost:{srv.server_address[1]}"
@@ -383,38 +866,176 @@ def _get(base, path):
                                              timeout=30).read())
 
 
+def _post(base, pql):
+    return json.loads(urllib.request.urlopen(
+        base + "/index/tl/query", data=pql.encode(), timeout=60).read())
+
+
+def _wait_recorded(n):
+    """The record closes in the handler's finally block, AFTER the
+    response body went out — the client can get here first."""
+    for _ in range(400):
+        if TIMELINE.requests_recorded >= n:
+            return
+        time.sleep(0.005)
+    raise AssertionError(f"{TIMELINE.requests_recorded} < {n} records")
+
+
+def test_http_direct_request_tiles(live_api):
+    """A lone query through the HTTP handler (a single-item flush is
+    the direct path): ONE record whose children are the stages in
+    order, each inside its parent, and little left unaccounted."""
+    api, base = live_api
+    for _ in range(3):                            # warm: compile, pools
+        _post(base, "Count(Row(f=1))")
+    _wait_recorded(3)
+    TIMELINE.reset()
+    shares = []
+    for i in range(30):
+        assert _post(base, "Count(Row(f=1))") == {"results": [3]}
+        _wait_recorded(i + 1)
+        (rec,) = TIMELINE.requests(last=1)
+        assert rec.kind == "request"
+        names = [n for n in _dedup(_names(rec.root)) if n != "h2d"]
+        assert names == DIRECT_STAGES, names
+        _assert_inside(rec.root)
+        kids = rec.root.children
+        # On every thread the top-level stages follow one another.
+        for a, b in zip(kids, kids[1:]):
+            assert a.pc_start <= b.pc_start
+        total = rec.root.pc_end - rec.root.pc_start
+        assert rec.unaccounted == pytest.approx(total - union_seconds(
+            [(c.pc_start, c.pc_end) for c in kids]))
+        shares.append(rec.unaccounted / total)
+        wait = next(c for c in kids if c.name == "coalescer.wait")
+        assert wait.attrs["batch"] == 1
+        read = next(c for c in kids if c.name == "http.read")
+        assert read.attrs["bytes"] == len("Count(Row(f=1))")
+    # On a quiet CPU backend well under 15 % (thread hand-offs between
+    # the request's thread and the dispatcher's are what is left; the
+    # best of 30 so that a loaded test machine's scheduler is not what
+    # the test measures).
+    assert min(shares) < 0.15, shares
+    assert TIMELINE.ring_count() == 30            # one record each
+
+
+def test_http_coalesced_request_tiles(tmp_holder):
+    """Concurrent queries share a flush: the flush is a record of its
+    own whose children are plan ... finish, once; every member holds
+    its wait and a reference to the flush over the same interval."""
+    from pilosa_tpu.server import serve
+    from pilosa_tpu.server.coalescer import QueryCoalescer
+
+    _seed(tmp_holder)
+    stats = MemStatsClient()
+    api = API(tmp_holder, stats=stats)
+    api.executor.result_cache.enabled = False
+    api.coalescer = QueryCoalescer(api.executor, window_s=0.25,
+                                   max_batch=4, stats=stats)
+    api.coalescer.start()
+    srv = serve(api, "localhost", 0, background=True)
+    base = f"http://localhost:{srv.server_address[1]}"
+    flush_shares = []
+    try:
+        for warm in range(4):
+            TIMELINE.reset()
+            out = [None] * 4
+            ts = [threading.Thread(
+                target=lambda i=i: out.__setitem__(
+                    i, _post(base, f"Count(Row(f={i % 2}))")))
+                for i in range(4)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join()
+            assert out == [{"results": [0]}, {"results": [3]}] * 2
+            _wait_recorded(5)                     # 4 requests + 1 flush
+            flush_shares += [r.unaccounted / r.root.duration()
+                             for r in TIMELINE.requests()
+                             if r.kind == "flush"]
+    finally:
+        srv.shutdown()
+        srv.server_close()
+        api.coalescer.stop()
+    recs = TIMELINE.requests()
+    (flush,) = [r for r in recs if r.kind == "flush"]
+    members = [r for r in recs if r.kind == "request"]
+    assert len(members) == 4
+    assert flush.root.name == "coalescer.flush"
+    assert flush.root.attrs["batch"] == 4
+    assert flush.root.attrs["unique"] == 2        # identical reads dedup
+    assert flush.root.attrs["reason"] == "size"
+    assert [n for n in _dedup(_names(flush.root))
+            if n not in ("h2d", "coalescer.handoff")] == FLUSH_STAGES
+    _assert_inside(flush.root)
+    # A 2 ms flush on the CPU backend: the best of four, so that a
+    # loaded test machine's scheduler is not what the test measures.
+    assert len(flush_shares) == 4 and min(flush_shares) < 0.15, \
+        flush_shares
+    for m in members:
+        assert _dedup(_names(m.root)) == MEMBER_STAGES
+        _assert_inside(m.root)
+        ref = next(c for c in m.root.children
+                   if c.name == "coalescer.flush")
+        assert ref.link is flush.root
+        assert (ref.pc_start, ref.pc_end) == (flush.root.pc_start,
+                                              flush.root.pc_end)
+        wait = next(c for c in m.root.children
+                    if c.name == "coalescer.wait")
+        assert wait.pc_end == flush.root.pc_start
+        assert wait.attrs == {"batch": 4, "reason": "size"}
+    # What a member cannot account for is its thread being woken (four
+    # of them at once here, on a 2 ms request): small for the luckiest.
+    assert min(m.unaccounted / m.root.duration() for m in members) < 0.15
+    # Once per flush in the histograms, once per rider under .member.
+    h = stats.snapshot()["histograms"]
+    assert h["request.stage_seconds{stage:coalescer.flush.member}"][
+        "count"] == 16
+    assert h["request.stage_seconds{stage:coalescer.flush}"]["count"] == 4
+    assert h["request.stage_seconds{stage:plan}"]["count"] == 4
+    assert h["request.stage_seconds{stage:coalescer.wait}"]["count"] == 16
+
+
 def test_debug_timeline_http_surface(live_api):
     api, base = live_api
     for i in range(12):
-        r = urllib.request.urlopen(
-            base + "/index/tl/query",
-            data=f"Count(Row(f={i % 3}))".encode()).read()
-        assert "results" in json.loads(r)
+        assert "results" in _post(base, f"Count(Row(f={i % 3}))")
+    _wait_recorded(12)
     doc = _get(base, "/debug/timeline?last=6")
     for ev in doc["traceEvents"]:
         for k in ("ph", "ts", "dur", "pid", "tid"):
             assert k in ev, ev
     xs = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     names = {e["name"] for e in xs}
-    assert {"queue", "plan", "dispatch", "materialize", "serialize",
-            "request"} <= names
+    assert set(DIRECT_STAGES) | {"request"} <= names
     s = doc["summary"]
     assert s["requests"] == 6
-    assert 0.0 <= s["deviceIdleRatio"] <= 1.0
-    assert s["dispatchGap"]["dispatches"] > 0
     assert s["stageMedianS"]["dispatch"] > 0
-    # ?trace= narrows to one request; the single-node /cluster/timeline
-    # wraps the same events with node attribution.
+    assert s["byCall"]["Count"]["requests"] == 6
+    assert "deviceIdleRatio" not in s and "dispatchGap" not in s
+    # A request's stages ran on two threads: its own and the
+    # dispatcher's — two lanes, both named.
     tid = next(e["args"]["trace"] for e in xs if e["name"] == "request")
     one = _get(base, f"/debug/timeline?trace={tid}")
     assert one["summary"]["requests"] == 1
+    lanes = {e["tid"] for e in one["traceEvents"] if e["ph"] == "X"}
+    assert len(lanes) == 2
+    named = {e["tid"]: e["args"]["name"] for e in one["traceEvents"]
+             if e["name"] == "thread_name"}
+    assert lanes <= set(named)
+    assert "query-coalescer" in named.values()
+    # ?trace= narrows to one request; the single-node /cluster/timeline
+    # wraps the same events with node attribution.
     merged = _get(base, f"/cluster/timeline/{tid}")
     assert merged["respondedNodes"] == merged["totalNodes"] == 1
     mx = [e for e in merged["traceEvents"] if e["ph"] == "X"]
     assert mx and all(e["args"]["node"] for e in mx)
-    # The idle-ratio gauge is on /metrics.
+    # The host-clock idle gauge is gone from every surface.
     met = urllib.request.urlopen(base + "/metrics").read().decode()
-    assert "pilosa_device_idle_ratio" in met
+    assert "device_idle_ratio" not in met
+    assert 'pilosa_request_stage_seconds_bucket{stage="plan",' in met
+    assert "deviceIdleRatio" not in json.dumps(
+        _get(base, "/internal/health"))
 
 
 def test_slo_histograms_per_endpoint(live_api):
@@ -470,15 +1091,15 @@ def test_slow_non_query_endpoint_cross_links_ring(live_api):
 
 def test_telemetry_rings_in_memory_ledger(live_api):
     api, base = live_api
-    urllib.request.urlopen(base + "/index/tl/query",
-                           data=b"Count(Row(f=1))").read()
+    _post(base, "Count(Row(f=1))")
+    _wait_recorded(1)
     mem = _get(base, "/debug/memory")
     tel = mem["categories"].get("telemetry")
     assert tel is not None and tel["bytes"] > 0
-    # At least two registered rings: this API's tracer span ring + the
-    # process-wide timeline ring (earlier tests' tracers may not be
-    # collected yet — their owner-scoped entries purge on GC).
-    assert tel["count"] >= 2
+    # The one per-request ring: the process-wide timeline's (there
+    # is no tracer ring beside it any more).
+    assert "tracer_ring" not in json.dumps(mem)
+    assert TIMELINE.ring_nbytes() > 0
     # Telemetry is host RAM: counted in totalBytes, not deviceBytes.
     assert mem["totalBytes"] == sum(
         c["bytes"] for c in mem["categories"].values())
@@ -486,14 +1107,14 @@ def test_telemetry_rings_in_memory_ledger(live_api):
 
 
 def test_dump_and_drain(tmp_holder):
-    """drain_telemetry writes the timeline + tracer rings to the log on
+    """drain_telemetry writes the last request records to the log on
     shutdown (the SIGTERM post-mortem path)."""
     from pilosa_tpu.cli.main import drain_telemetry
-    from pilosa_tpu.utils.tracing import RecordingTracer
+    from pilosa_tpu.utils.tracing import ContextTracer
 
     _seed(tmp_holder)
     api = API(tmp_holder, stats=MemStatsClient(),
-              tracer=RecordingTracer())
+              tracer=ContextTracer())
     api.query("tl", "Count(Row(f=1))")
 
     lines = []
@@ -503,19 +1124,21 @@ def test_dump_and_drain(tmp_holder):
             lines.append(fmt % args if args else fmt)
 
     drain_telemetry(api, logger=_Log())
-    assert any("timeline:" in ln for ln in lines), lines
-    assert any("tracer:" in ln for ln in lines), lines
+    rows = [ln for ln in lines if ln.startswith("timeline: request")]
+    assert len(rows) == 1, lines
+    assert "plan=" in rows[0] and "dispatch=" in rows[0]
 
 
 def test_config_timeline_keys(tmp_path):
-    from pilosa_tpu.utils.config import load_config
+    from pilosa_tpu.utils.config import Config, load_config
     p = tmp_path / "c.toml"
     p.write_text("[timeline]\nenabled = false\nring = 64\n"
-                 "sample_every = 4\ngap_window_s = 30.0\n")
+                 "sample_every = 4\n")
     cfg = load_config(str(p))
     assert cfg.timeline_enabled is False
     assert cfg.timeline_ring == 64
     assert cfg.timeline_sample_every == 4
-    assert cfg.timeline_gap_window_s == 30.0
+    # The gap analyzer's window went with it.
+    assert not hasattr(Config(), "timeline_gap_window_s")
     with pytest.raises(ValueError):
         load_config(None, {"timeline_ring": 0})

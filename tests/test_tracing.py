@@ -1,4 +1,5 @@
-"""Tracing: span recording, OTLP/HTTP JSON export wire format.
+"""Tracing: the one Span type, trace-context propagation, OTLP/HTTP
+JSON export of request records.
 
 Reference: /root/reference/tracing/tracing.go:18-56 (opentracing facade)
 and the Jaeger wiring in server/config.go:110-118. The rebuild exports
@@ -14,11 +15,33 @@ import threading
 import numpy as np
 import pytest
 
+from pilosa_tpu.utils.timeline import TimelineRecorder
 from pilosa_tpu.utils.tracing import (
+    ContextTracer,
     ExportingTracer,
-    RecordingTracer,
     spans_to_otlp,
 )
+
+
+def _record(tracer, *names, exporter=None, **attrs):
+    """One finished request record under the tracer's trace id: root
+    `names[0]`, each further name nested in the one before. Returns
+    (record, [spans, root first])."""
+    tl = TimelineRecorder()
+    tl.exporter = exporter
+    rec = tl.begin(tracer.ensure_trace_id(), name=names[0], **attrs)
+    spans = [rec.root]
+    opened = []
+    for name in names[1:]:
+        h = tl.span(rec, name)
+        h.__enter__()
+        opened.append(h)
+        spans.append(h.span)
+    for h in reversed(opened):
+        h.__exit__(None, None, None)
+    tl.finish(rec)
+    tracer.adopt(None)
+    return rec, spans
 
 
 class _Capture(http.server.BaseHTTPRequestHandler):
@@ -50,11 +73,9 @@ def capture_server():
 
 
 def test_spans_to_otlp_wire_shape():
-    tr = RecordingTracer()
-    with tr.span("API.Query", index="i") as root:
-        with tr.span("executor.Execute"):
-            pass
-    doc = spans_to_otlp(tr.finished, "svc")
+    rec, (root, _) = _record(ContextTracer(), "API.Query",
+                             "executor.Execute", index="i")
+    doc = spans_to_otlp([rec.root], "svc")
     (rs,) = doc["resourceSpans"]
     attrs = {a["key"]: a["value"]["stringValue"]
              for a in rs["resource"]["attributes"]}
@@ -82,12 +103,9 @@ def test_exporting_tracer_posts_batches(capture_server):
     endpoint, captured = capture_server
     tr = ExportingTracer(endpoint, service_name="pilosa-test",
                          batch_size=2, flush_interval=3600)
-    with tr.span("a"):
-        pass
+    _record(tr, "a", exporter=tr)
     assert not captured  # below batch size, nothing shipped yet
-    with tr.span("b"):
-        with tr.span("b.child"):
-            pass
+    _record(tr, "b", "b.child", exporter=tr)
     tr.flush()
     assert len(captured) == 1
     path, headers, doc = captured[0]
@@ -99,33 +117,38 @@ def test_exporting_tracer_posts_batches(capture_server):
 
 
 def test_failed_spans_still_export(capture_server):
-    """Spans whose traced block raised must still reach the exporter —
+    """Records of requests that raised must still reach the exporter —
     failed-request traces are the ones operators need."""
     endpoint, captured = capture_server
     tr = ExportingTracer(endpoint, batch_size=1, flush_interval=3600)
-    with pytest.raises(RuntimeError):
-        with tr.span("boom"):
-            raise RuntimeError("query failed")
+    tl = TimelineRecorder()
+    tl.exporter = tr
+    rec = tl.begin(tr.ensure_trace_id(), name="boom")
+    tl.finish(rec, error=RuntimeError("query failed"))
     tr.flush()
-    names = [s["name"] for _, _, doc in captured
+    spans = [s for _, _, doc in captured
              for s in doc["resourceSpans"][0]["scopeSpans"][0]["spans"]]
-    assert names == ["boom"]
+    assert [s["name"] for s in spans] == ["boom"]
+    assert {a["key"]: a["value"]["stringValue"]
+            for a in spans[0]["attributes"]}["error"] == \
+        "RuntimeError: query failed"
 
 
 def test_inject_emits_w3c_traceparent():
-    """inject speaks traceparent (00-<trace>-<span>-01): the root
-    span's trace id, the innermost open span as parent."""
+    """inject speaks traceparent (00-<trace>-<span>-01): the thread's
+    trace id, the adopted request root as parent."""
     from pilosa_tpu.utils.tracing import parse_traceparent
-    tr = RecordingTracer()
+    tr = ContextTracer()
     headers = {}
-    with tr.span("root") as root:
-        with tr.span("child") as child:
-            tr.inject(headers)
+    tl = TimelineRecorder()
+    root = tl.begin(tr.ensure_trace_id()).root
+    tr.adopt(root.trace_id, root)
+    tr.inject(headers)
     tp = headers["traceparent"]
     ver, tid, sid, flags = tp.split("-")
     assert (ver, flags) == ("00", "01")
     assert tid == root.trace_id and len(tid) == 32
-    assert sid == child.span_id and len(sid) == 16
+    assert sid == root.span_id and len(sid) == 16
     assert parse_traceparent(tp) == root.trace_id
     # The legacy header rides along (same id) for the one-release
     # window, so a not-yet-upgraded receiver still correlates.
@@ -135,37 +158,32 @@ def test_inject_emits_w3c_traceparent():
 def test_extract_traceparent_round_trip():
     """A trace id injected by one tracer is adopted by another through
     the traceparent header — the same id stamps both sides' spans."""
-    a, b = RecordingTracer(), RecordingTracer()
+    a, b = ContextTracer(), ContextTracer()
     headers = {}
-    with a.span("client"):
-        a.inject(headers)
+    tid = a.ensure_trace_id()
+    a.inject(headers)
     b.extract(headers)
-    with b.span("server"):
-        pass
-    assert b.finished[0].trace_id == a.finished[0].trace_id
+    rec, _ = _record(b, "server")
+    assert rec.trace_id == rec.root.trace_id == tid
 
 
 def test_extract_accepts_legacy_header():
     """X-Trace-Id still extracts (one-release compatibility window for
     mixed-version clusters)."""
-    tr = RecordingTracer()
+    tr = ContextTracer()
     tid = "ab" * 16
     tr.extract({"X-Trace-Id": tid})
-    with tr.span("s"):
-        pass
-    assert tr.finished[0].trace_id == tid
+    assert _record(tr, "s")[0].trace_id == tid
 
 
 def test_extract_prefers_traceparent_and_rejects_malformed():
     from pilosa_tpu.utils.tracing import parse_traceparent
     # traceparent wins over the legacy header when both are present.
-    tr = RecordingTracer()
+    tr = ContextTracer()
     tp_tid = "cd" * 16
     tr.extract({"traceparent": f"00-{tp_tid}-{'12' * 8}-01",
                 "X-Trace-Id": "ab" * 16})
-    with tr.span("s"):
-        pass
-    assert tr.finished[0].trace_id == tp_tid
+    assert _record(tr, "s")[0].trace_id == tp_tid
     # Malformed traceparents parse to None instead of poisoning.
     for bad in ("junk", "00-short-1212121212121212-01",
                 f"00-{'0' * 32}-{'12' * 8}-01",       # all-zero trace
@@ -176,49 +194,46 @@ def test_extract_prefers_traceparent_and_rejects_malformed():
                 f"00-{'cd' * 16}-{'12' * 8}-01-x"):   # v00 extra field
         assert parse_traceparent(bad) is None, bad
     # ... and a malformed traceparent falls back to the legacy header.
-    tr2 = RecordingTracer()
+    tr2 = ContextTracer()
     tr2.extract({"traceparent": "junk", "X-Trace-Id": "ab" * 16})
-    with tr2.span("s"):
-        pass
-    assert tr2.finished[0].trace_id == "ab" * 16
+    assert _record(tr2, "s")[0].trace_id == "ab" * 16
 
 
 def test_non_hex_trace_header_is_sanitized():
     """Client-settable X-Trace-Id must not poison the OTLP batch: a
     non-hex value re-hashes deterministically to 32 hex chars."""
-    tr = RecordingTracer()
+    tr = ContextTracer()
     tr.extract({"X-Trace-Id": "req-abc!!"})
-    with tr.span("s"):
-        pass
-    tid = tr.finished[0].trace_id
+    tid = _record(tr, "s")[0].trace_id
     assert len(tid) == 32
     int(tid, 16)
     # Deterministic: a second node extracting the same junk correlates.
-    tr2 = RecordingTracer()
+    tr2 = ContextTracer()
     tr2.extract({"X-Trace-Id": "req-abc!!"})
-    with tr2.span("s"):
-        pass
-    assert tr2.finished[0].trace_id == tid
+    assert _record(tr2, "s")[0].trace_id == tid
 
 
 def test_export_failure_drops_without_raising():
     tr = ExportingTracer("http://127.0.0.1:9/v1/traces")  # nothing there
-    with tr.span("doomed"):
-        pass
+    _record(tr, "doomed", exporter=tr)
     assert tr.flush() is False
     assert tr.flush() is True  # dropped, not retried
 
 
 def test_live_query_spans_reach_exporter(tmp_path, capture_server):
-    """Spans from a real API.Query land in the OTLP payload (VERDICT r2
-    missing #4: 'spans from a live query visible in an exporter-format
-    fixture')."""
+    """The record of a real API.query lands in the OTLP payload, its
+    stages as children of the root (VERDICT r2 missing #4: 'spans from
+    a live query visible in an exporter-format fixture') — the SAME
+    record /debug/timeline renders."""
     endpoint, captured = capture_server
     from pilosa_tpu.core.holder import Holder
     from pilosa_tpu.server.api import API
+    from pilosa_tpu.utils.timeline import TIMELINE
 
     tr = ExportingTracer(endpoint, service_name="pilosa-test",
                          batch_size=1, flush_interval=3600)
+    TIMELINE.reset()
+    TIMELINE.exporter = tr
     holder = Holder(str(tmp_path))
     holder.open()
     api = API(holder, tracer=tr)
@@ -229,14 +244,32 @@ def test_live_query_spans_reach_exporter(tmp_path, capture_server):
                     np.array([3, 9], np.uint64))
     res = api.query("ti", "Count(Row(f=1))")
     assert res["results"] == [2]
+    TIMELINE.exporter = None
     tr.flush()
-    all_spans = [s for _, _, doc in captured
-                 for s in doc["resourceSpans"][0]["scopeSpans"][0]["spans"]]
-    by_name = {s["name"]: s for s in all_spans}
-    assert "API.Query" in by_name
+    (doc,) = [doc for _, _, doc in captured][-1:]
+    spans = doc["resourceSpans"][0]["scopeSpans"][0]["spans"]
+    root = spans[0]
+    assert root["name"] == "request" and "parentSpanId" not in root
     attrs = {a["key"]: a["value"]["stringValue"]
-             for a in by_name["API.Query"]["attributes"]}
-    assert attrs.get("index") == "ti"
+             for a in root["attributes"]}
+    assert attrs.get("calls") == "Count"
+    # The exported tree IS the ring's record: same ids, same order.
+    (rec,) = TIMELINE.requests(last=1)
+    assert [s["name"] for s in spans] == [s.name for s in rec.root.walk()]
+    assert [s["spanId"] for s in spans] == \
+        [s.span_id for s in rec.root.walk()]
+    by_id = {s["spanId"]: s for s in spans}
+    for s in spans[1:]:
+        assert s["parentSpanId"] in by_id
+        assert s["traceId"] == root["traceId"] == rec.trace_id
+    events = TIMELINE.snapshot(last=1)["traceEvents"]
+    assert [e["args"]["spanId"] for e in events if e["ph"] == "X"] == \
+        [s["spanId"] for s in spans]
+    for stage in ("pql.parse", "cache.lookup", "plan", "dispatch",
+                  "d2h", "finish"):
+        assert any(s["name"] == stage
+                   and s["parentSpanId"] == root["spanId"]
+                   for s in spans), stage
     holder.close()
 
 
@@ -247,12 +280,15 @@ def test_span_duration_immune_to_clock_step(monkeypatch):
     anchor per trace for export timestamps."""
     import time as _time
 
-    tr = RecordingTracer()
+    tl = TimelineRecorder()
     wall = [_time.time()]
     monkeypatch.setattr(_time, "time", lambda: wall[0])
-    with tr.span("outer") as outer:
-        with tr.span("inner") as inner:
-            wall[0] -= 3600.0  # the clock steps BACK an hour mid-span
+    rec = tl.begin(None, name="outer")
+    outer = rec.root
+    with tl.span(rec, "inner") as h:
+        wall[0] -= 3600.0  # the clock steps BACK an hour mid-span
+    inner = h.span
+    tl.finish(rec)
     # Durations stay tiny and non-negative despite the step...
     assert 0.0 <= inner.duration() < 5.0
     assert 0.0 <= outer.duration() < 5.0
@@ -261,7 +297,7 @@ def test_span_duration_immune_to_clock_step(monkeypatch):
     # OTLP export anchors every span of the trace on the ROOT's wall
     # clock: the child's offset from the root is monotonic, so end >=
     # start holds and the child nests inside the parent window.
-    doc = spans_to_otlp(tr.finished, "svc")
+    doc = spans_to_otlp([outer], "svc")
     spans = doc["resourceSpans"][0]["scopeSpans"][0]["spans"]
     parent, child = spans
     for s in spans:
@@ -275,7 +311,7 @@ def test_extract_without_headers_clears_stale_thread_id():
     """Handler threads are reused across keep-alive requests: a request
     with NO trace headers must clear the previous request's adopted id
     instead of stitching unrelated requests into one trace."""
-    tr = RecordingTracer()
+    tr = ContextTracer()
     tr.extract({"X-Trace-Id": "ab" * 16})
     assert tr.current_trace_id() == "ab" * 16
     tr.extract({})  # next request on the same thread, no headers
@@ -289,7 +325,7 @@ def test_inject_falls_back_to_adopted_thread_id():
     relying on a stale-thread-local side channel)."""
     from pilosa_tpu.utils.tracing import parse_traceparent
 
-    tr = RecordingTracer()
+    tr = ContextTracer()
     headers = {}
     tr.inject(headers)
     assert "traceparent" not in headers  # nothing to propagate
@@ -299,44 +335,39 @@ def test_inject_falls_back_to_adopted_thread_id():
     assert headers["X-Trace-Id"] == "cd" * 16
 
 
-def test_tracer_ring_registers_with_memory_ledger():
-    """The finished-span ring registers its bytes under the ledger's
-    `telemetry` category (host RAM: excluded from deviceBytes), and
-    the registration tracks ring churn."""
-    from pilosa_tpu.utils.memledger import MemoryLedger
-
-    ledger = MemoryLedger()
-    tr = RecordingTracer(keep=4)
-    with tr.span("a", big="x" * 100):
-        pass
-    tr.register_memory(ledger)
-    tot = ledger.totals()["telemetry"]
-    assert tot["bytes"] > 100 and tot["count"] == 1
-    first = tot["bytes"]
-    for _ in range(20):  # churn past `keep`: bytes stay bounded
-        with tr.span("b"):
-            pass
-    tr.register_memory(ledger)
-    tot = ledger.totals()["telemetry"]
-    assert tot["count"] == 1  # re-registered in place, no growth
-    assert 0 < tot["bytes"] < first + 4 * 1000
-    snap = ledger.snapshot()
-    assert snap["deviceBytes"] == 0  # telemetry is host RAM
-    assert snap["totalBytes"] == tot["bytes"]
+def test_span_ids_are_lazy_unique_and_stable():
+    """No uuid per span: an id is minted on first use (export), from a
+    process-wide counter under a per-process prefix, and stays."""
+    from pilosa_tpu.utils.tracing import Span
+    spans = [Span("s", "t" * 32, {}) for _ in range(100)]
+    assert all(sp._span_id is None for sp in spans)
+    ids = [sp.span_id for sp in spans]
+    assert len(set(ids)) == 100
+    assert all(len(i) == 16 and int(i, 16) >= 0 for i in ids)
+    assert [sp.span_id for sp in spans] == ids   # stable once minted
+    assert len({i[:8] for i in ids}) == 1        # one process prefix
+    # Only a record's root carries a wall anchor.
+    assert spans[0].start is None
+    assert Span("root", "t" * 32, {}, wall=True).start is not None
 
 
-def test_tracer_dump_writes_recent_spans():
-    tr = RecordingTracer()
-    with tr.span("API.Query", index="i"):
-        pass
-    lines = []
-
-    class _Log:
-        def printf(self, fmt, *args):
-            lines.append(fmt % args if args else fmt)
-
-    assert tr.dump(_Log()) == 1
-    assert any("API.Query" in ln for ln in lines)
+def test_linked_span_exports_an_otlp_link():
+    """A coalesced request's reference to the flush it rode is an OTLP
+    span link, naming the flush root's trace and span id."""
+    tl = TimelineRecorder()
+    flush = tl.begin(None, name="coalescer.flush", kind="flush")
+    tl.finish(flush)
+    rec = tl.begin(None)
+    tl.add(rec, "coalescer.flush", flush.root.pc_start,
+           flush.root.pc_end, link=flush.root, batch=2)
+    tl.finish(rec)
+    spans = spans_to_otlp([rec.root], "svc")[
+        "resourceSpans"][0]["scopeSpans"][0]["spans"]
+    (ref,) = [sp for sp in spans if sp["name"] == "coalescer.flush"]
+    assert ref["links"] == [{"traceId": flush.trace_id,
+                             "spanId": flush.root.span_id}]
+    assert ref["parentSpanId"] == spans[0]["spanId"]
+    assert "links" not in spans[0]
 
 
 # ---------------------------------------------------------------------------
@@ -346,13 +377,14 @@ def test_sampler_const_zero_exports_nothing(capture_server):
     endpoint, captured = capture_server
     tr = ExportingTracer(endpoint, sampler_type="const", sampler_param=0,
                          batch_size=1, flush_interval=3600)
+    tl = TimelineRecorder()
+    tl.exporter = tr
     for _ in range(5):
-        with tr.span("q"):
-            pass
+        tl.finish(tl.begin(None, name="q"))
     tr.flush()
     assert not captured
     # Local recording still works for /debug introspection.
-    assert len(tr.finished) == 5
+    assert tl.ring_count() == 5
 
 
 def test_sampler_probabilistic_is_deterministic_on_trace_id():
@@ -377,9 +409,10 @@ def test_sampler_probabilistic_fraction(capture_server):
                          sampler_param=0.25, batch_size=10**6,
                          flush_interval=3600)
     n = 400
+    tl = TimelineRecorder(ring=8)
+    tl.exporter = tr
     for _ in range(n):
-        with tr.span("q"):
-            pass
+        tl.finish(tl.begin(None, name="q"))
     with tr._pending_lock:
         kept = len(tr._pending)
     assert 0.1 * n < kept < 0.45 * n  # ~25%, generous bounds

@@ -720,7 +720,7 @@ with tempfile.TemporaryDirectory() as d:
     idx.add_existence(cols)
     api = API(h, stats=MemStatsClient())
     api.coalescer = QueryCoalescer(api.executor, window_s=0.0005,
-                                   stats=api.stats, tracer=api.tracer)
+                                   stats=api.stats)
     api.coalescer.start()
     srv = serve(api, "localhost", 0, background=True)
     base = f"http://localhost:{srv.server_address[1]}"
@@ -767,7 +767,7 @@ from pilosa_tpu.server import API, serve
 from pilosa_tpu.server.coalescer import QueryCoalescer
 from pilosa_tpu.utils.stats import MemStatsClient
 from pilosa_tpu.utils.timeline import TIMELINE
-from pilosa_tpu.utils.tracing import RecordingTracer
+from pilosa_tpu.utils.tracing import ContextTracer
 
 TIMELINE.reset()
 with tempfile.TemporaryDirectory() as d:
@@ -776,9 +776,10 @@ with tempfile.TemporaryDirectory() as d:
     cols = np.array([1, 2, SHARD_WIDTH + 3], np.uint64)
     idx.create_field("f").import_bits(np.full(3, 1, np.uint64), cols)
     idx.add_existence(cols)
-    api = API(h, stats=MemStatsClient(), tracer=RecordingTracer())
+    api = API(h, stats=MemStatsClient(), tracer=ContextTracer())
+    api.executor.result_cache.enabled = False   # every query executes
     api.coalescer = QueryCoalescer(api.executor, window_s=0.0005,
-                                   stats=api.stats, tracer=api.tracer)
+                                   stats=api.stats)
     api.coalescer.start()
     srv = serve(api, "localhost", 0, background=True)
     base = f"http://localhost:{srv.server_address[1]}"
@@ -796,16 +797,21 @@ with tempfile.TemporaryDirectory() as d:
         for k in ("ph", "ts", "dur", "pid", "tid"):
             assert k in ev, (k, ev)
     names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
-    for want in ("queue", "plan", "dispatch", "materialize",
-                 "serialize", "request"):
+    for want in ("request", "http.read", "pql.parse", "coalescer.wait",
+                 "cache.lookup", "plan", "dispatch", "d2h", "finish",
+                 "http.serialize", "http.write"):
         assert want in names, (want, names)
     s = doc["summary"]
     assert s["requests"] == 16, s
-    assert 0.0 <= s["deviceIdleRatio"] <= 1.0, s
-    assert s["dispatchGap"]["dispatches"] > 0, s
-    # The idle-ratio gauge and the per-endpoint SLO histograms export.
+    assert s["stageMedianS"]["dispatch"] > 0, s
+    assert s["byCall"]["Count"]["requests"] == 16, s
+    # The cumulative stage histograms and the per-endpoint SLO
+    # histograms export; the host-clock idle gauge is gone.
     met = urllib.request.urlopen(base + "/metrics").read().decode()
-    assert "pilosa_device_idle_ratio" in met
+    assert "# TYPE pilosa_request_stage_seconds histogram" in met
+    assert 'pilosa_request_stage_seconds_count{stage="plan"}' in met
+    assert "pilosa_request_unaccounted_seconds_sum" in met
+    assert "device_idle_ratio" not in met
     assert "# TYPE pilosa_http_request_seconds histogram" in met
     assert 'endpoint="/index/{index}/query"' in met
     srv.shutdown(); srv.server_close(); api.coalescer.stop(); h.close()
