@@ -2417,67 +2417,67 @@ class Executor:
 
     # ----------------------------------------------------------------- TopN
 
-    def _counts_fn(self, with_filter: bool, shape) -> Callable:
-        """jit: bank chunk [R, S, W] (∧ filter [S, W]) -> counts [R] and raw
-        per-row popcounts [R] (for tanimoto)."""
+    def _counts_fn(self, with_filter: bool, shape,
+                   with_raw: bool = False) -> Callable:
+        """jit: bank chunk [R, S, W] (∧ filter [S, W]) -> counts [R].
+        `with_raw` (a filtered sweep under tanimotoThreshold) adds the
+        rows' own popcounts [R], the tanimoto denominator's term: a second
+        popcount + reduction over the whole bank, so only the query that
+        reads it pays for it."""
         import jax
-        import jax.numpy as jnp
         from pilosa_tpu.ops import pallas_kernels
-        from pilosa_tpu.ops.bitset import popcount
+        from pilosa_tpu.ops.bitset import masked_row_counts, popcount
         use_pallas = pallas_kernels.enabled() and self.mesh is None
-        key = f"topn:{with_filter}:{shape}:{use_pallas}"
+        program = self._counts_program(with_filter, with_raw)
+        key = f"topn:{with_filter}:{with_raw}:{shape}:{use_pallas}"
         fn = self._jit_get(key)
         if fn is None:
-            self._note_jit_compile(self._counts_program(with_filter), key)
+            self._note_jit_compile(program, key)
             if with_filter:
                 if use_pallas:
                     def run(chunk, filt):
-                        return pallas_kernels.bank_row_counts_masked(
+                        both = pallas_kernels.bank_row_counts_masked(
                             chunk, filt)
+                        return both if with_raw else both[0]
                 else:
                     def run(chunk, filt):
-                        inter = jnp.bitwise_and(chunk, filt)
-                        return (popcount(inter, axis=(-2, -1)),
-                                popcount(chunk, axis=(-2, -1)))
+                        return masked_row_counts(chunk, filt, with_raw)
             else:
-                # Single output: the caller reuses it for both intersection
-                # and raw counts (one host fetch instead of two).
                 if use_pallas:
                     def run(chunk, filt):
                         return pallas_kernels.bank_row_counts(chunk)
                 else:
                     def run(chunk, filt):
-                        c = popcount(chunk, axis=(-2, -1))
-                        return c
-            fn = jax.jit(named(run, self._counts_program(with_filter)))
+                        return popcount(chunk, axis=(-2, -1))
+            fn = jax.jit(named(run, program))
             self._jit_put(key, fn)
         return fn
 
     @staticmethod
-    def _counts_program(with_filter: bool) -> str:
-        """The TopN bank sweep's name in traces."""
-        return "topn_sweep" if with_filter else "topn_sweep_unfiltered"
+    def _counts_program(with_filter: bool, with_raw: bool = False) -> str:
+        """The TopN bank sweep's name in traces: which of the three
+        programs a call's arguments selected."""
+        if not with_filter:
+            return "topn_sweep_unfiltered"
+        return "topn_sweep_tanimoto" if with_raw else "topn_sweep"
 
-    def _dispatch_counts(self, bank_array, filter_words):
-        """Queue the counts kernel; returns unfetched device output.
+    def _dispatch_counts(self, bank_array, filter_words,
+                         with_raw: bool = False):
+        """Queue the counts kernel; returns unfetched device output: the
+        counts [R], or (counts, raw) from a filtered sweep `with_raw`.
         Width-trimmed banks intersect against the same prefix of the
         filter: slicing a wider filter is safe (bank rows have no bits
         past their width), and padding a narrower one is safe (zeros
         cannot intersect)."""
         filter_words = _align_words(filter_words, bank_array.shape[-1])
-        fn = self._counts_fn(filter_words is not None, bank_array.shape)
+        with_filter = filter_words is not None
+        with_raw = with_raw and with_filter
+        fn = self._counts_fn(with_filter, bank_array.shape, with_raw)
         # Through the _call_program funnel: TopN sweeps are device
         # dispatches too.
         with self._dispatch_span(
-                self._counts_program(filter_words is not None)):
+                self._counts_program(with_filter, with_raw)):
             return self._call_program(fn, bank_array, filter_words)
-
-    def _fetch_counts(self, out, filter_words):
-        """Block on a _dispatch_counts output: (counts_np, raw_np)."""
-        if filter_words is not None:
-            return np.asarray(out[0]), np.asarray(out[1])
-        c = np.asarray(out)
-        return c, c
 
     def _popcount_row(self, words):
         """Dispatch a total popcount over row words [S, W] (device)."""
@@ -2529,6 +2529,9 @@ class Executor:
             allowed_rows = set(field.row_attr_store.ids_matching(
                 attr_name, call.arg("attrValues", [])))
         tanimoto = call.uint_arg("tanimotoThreshold") or 0
+        # tanimoto applies only WITH a filter; only then does the sweep
+        # compute (and the answer fetch) the rows' own popcounts.
+        with_raw = bool(tanimoto) and filter_words is not None
         # Candidate restriction + absolute count floor (reference
         # topOptions.RowIDs / MinThreshold, fragment.go:1248,
         # executor.go:698).
@@ -2637,8 +2640,8 @@ class Executor:
             bank = view.device_bank(tuple(shards), mesh=self.mesh,
                                     trim=True)
             dispatched.append(
-                (all_rows, bank, self._dispatch_counts(bank.array,
-                                                       filter_words)))
+                (all_rows, bank, self._dispatch_counts(
+                    bank.array, filter_words, with_raw)))
         else:
             if PBANK_ENABLED and self.mesh is None and len(shards) == 1 \
                     and allowed_rows is None and not ids_arg and n \
@@ -2648,10 +2651,9 @@ class Executor:
                 pb = view.positions_bank(shards[0], width)
                 if pb is not None:
                     src_pb = None
-                    if tanimoto and filter_words is not None:
+                    if with_raw:
                         src_pb = self._popcount_row(filter_words)
-                    # tanimoto applies only WITH a filter (the dense
-                    # finalize's `if tanimoto and filter_words` rule) —
+                    # tanimoto applies only WITH a filter (`with_raw`) —
                     # passing it filterless would zero every denominator
                     # and empty the result.
                     # Slice the filter row to the BANK's width: a plan
@@ -2669,8 +2671,7 @@ class Executor:
                     if filter_words is not None:
                         fw_b = [filter_words[0][:width]]
                     return self._topn_positions(
-                        pb, fw_b, n,
-                        tanimoto if filter_words is not None else 0,
+                        pb, fw_b, n, tanimoto if with_raw else 0,
                         min_threshold, src_pb)
             # Huge row sets stream through transient chunk banks to bound
             # HBM (the 50k-row ranked-cache shape). Chunks are uploaded
@@ -2680,7 +2681,7 @@ class Executor:
             chunked = [all_rows[c0:c0 + TOPN_CHUNK_ROWS]
                        for c0 in range(0, len(all_rows), TOPN_CHUNK_ROWS)]
         src_dev = None
-        if tanimoto and filter_words is not None:
+        if with_raw:
             src_dev = self._popcount_row(filter_words)
 
         # Chunk banks are admitted to the BANK_BUDGET HBM LRU only when
@@ -2700,10 +2701,11 @@ class Executor:
                                     mesh=self.mesh, trim=True,
                                     cache_rows=cache_chunks)
             return (rows, bank,
-                    self._dispatch_counts(bank.array, filter_words))
+                    self._dispatch_counts(bank.array, filter_words,
+                                          with_raw))
 
         def finalize() -> PairsResult:
-            parts = []  # (rows_arr, counts_arr, raws_arr)
+            parts = []  # (rows_arr, counts_arr[, raws_arr])
             pending = list(dispatched)
             if chunked:
                 pending.append(dispatch_chunk(chunked[0]))
@@ -2715,19 +2717,20 @@ class Executor:
                 i += 1
                 if i < len(chunked):
                     pending.append(dispatch_chunk(chunked[i]))
-                counts, raw = self._fetch_counts(out, filter_words)
+                fetched = [np.asarray(a) for a in
+                           (out if with_raw else (out,))]
                 # map(dict.get, ...) keeps the 65k-row probe loop in C.
                 slot_idx = np.fromiter(
                     map(bank.slots.get, rows,
                         itertools.repeat(bank.zero_slot)),
                     dtype=np.int64, count=len(rows))
                 parts.append((np.asarray(rows, dtype=np.uint64),
-                              counts[slot_idx].astype(np.int64),
-                              raw[slot_idx].astype(np.int64)))
+                              *(a[slot_idx].astype(np.int64)
+                                for a in fetched)))
             rows_arr = np.concatenate([p[0] for p in parts])
             counts_arr = np.concatenate([p[1] for p in parts])
-            raws_arr = np.concatenate([p[2] for p in parts])
-            if tanimoto and filter_words is not None:
+            if with_raw:
+                raws_arr = np.concatenate([p[2] for p in parts])
                 src_total = int(np.asarray(src_dev))
                 denom = raws_arr + src_total - counts_arr
                 keep = (denom > 0) & (
@@ -2765,8 +2768,8 @@ class Executor:
         return _Pending(
             finalize,
             arrays=tuple(x for _, _, out in dispatched
-                         for x in (out if isinstance(out, tuple) else (out,))
-                         ) + ((src_dev,) if src_dev is not None else ()))
+                         for x in (out if with_raw else (out,))
+                         ) + ((src_dev,) if with_raw else ()))
 
     _PBANK_KERNELS: Dict[tuple, Callable] = {}
 

@@ -29,6 +29,7 @@ equivalent of the reference's fused `intersectionCountBitmapBitmap`
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence, Tuple, Union
 
 import jax
@@ -119,6 +120,66 @@ def popcount(a: ArrayLike,
 def count_and(a: ArrayLike, b: ArrayLike) -> jax.Array:
     """|a ∧ b| fused (reference IntersectionCount, roaring.go:472/2438)."""
     return popcount(jnp.bitwise_and(a, b))
+
+
+# A filtered bank sweep's filter is cut into word-axis pieces of this many
+# words per shard, and into no more pieces than this: the one fusion that
+# reads the bank has an output a piece (two `with_raw`), and with sixteen
+# it loses a third of its rate (see masked_row_counts).
+SWEEP_PIECE_WORDS = 8192
+SWEEP_MAX_PIECES = 4
+
+
+def sweep_filter_pieces(n_words: int) -> int:
+    """How many equal word-axis pieces masked_row_counts cuts a filter of
+    `n_words` words per shard into: pieces of SWEEP_PIECE_WORDS, at least
+    two and at most SWEEP_MAX_PIECES, each whole 128-word lanes. Where no
+    such count divides the lanes (a prime number of them, say) the pieces
+    are halves, and an odd last lane is left over as a short piece of its
+    own. One — the uncut body — under two whole lanes."""
+    lanes = n_words // 128
+    if n_words % 128 or lanes < 2:
+        return 1
+    want = max(2, n_words // SWEEP_PIECE_WORDS)
+    return next((k for k in range(want, SWEEP_MAX_PIECES + 1)
+                 if lanes % k == 0), 2)
+
+
+def masked_row_counts(bank: jax.Array, filt: jax.Array,
+                      with_raw: bool = False):
+    """|row ∧ filt| per row of a bank: ([R, S, W], [S, W]) -> uint32[R];
+    `with_raw` also returns |row| per row (the tanimoto denominator's
+    term) as a second uint32[R], from the same pass over the bank.
+
+    The sum runs over word-axis pieces of the filter rather than over the
+    whole [S, W] at once. The answer is the same; the program is not.
+    Given the filter as ONE broadcast operand, XLA's TPU fusion reuses an
+    8 KiB filter tile across 512 rows, and reduces each row's two vregs to
+    a scalar in every one of 256 steps: 271 GB/s on a v5e whatever else
+    the body computes. Sliced inside the program, the pieces are
+    temporaries that XLA keeps in on-chip memory, and the one fusion that
+    reads the bank (`popcnt_reduce_fusion`, one output per piece) takes
+    windows of a few dozen rows x 64 KiB each: 755 GB/s, the rate of
+    the unfiltered sweep, with or without `with_raw` (PERF.md §6, PR 25:
+    the table of variants, shapes and piece counts). Equal pieces make
+    one fusion, a piece of another width a fusion of its own (the odd
+    lane's: a third of the bank's bytes at three lanes, 1/251 at most at
+    a shard's width). The word axis is the one to cut: under a mesh the shard axis
+    is split over devices, and a slice along it would move data between
+    them."""
+    n_words = filt.shape[-1]
+    pieces = sweep_filter_pieces(n_words)
+    step = n_words if pieces == 1 else n_words // 128 // pieces * 128
+    cuts = [slice(w0, w0 + step) for w0 in range(0, pieces * step, step)]
+    if pieces * step < n_words:
+        cuts.append(slice(pieces * step, n_words))
+    counts = functools.reduce(jnp.add, (
+        popcount(jnp.bitwise_and(bank[..., c], filt[..., c]), axis=(-2, -1))
+        for c in cuts))
+    if not with_raw:
+        return counts
+    return counts, functools.reduce(jnp.add, (
+        popcount(bank[..., c], axis=(-2, -1)) for c in cuts))
 
 
 def count_or(a: ArrayLike, b: ArrayLike) -> jax.Array:

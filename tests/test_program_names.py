@@ -61,6 +61,7 @@ QUERIES = [
     ("Count(Row(f=1))", "tree_count"),
     ("Row(f=1)", "tree_row"),
     ("TopN(f, Row(g=2), n=3)", "topn_sweep"),
+    ("TopN(f, Row(g=2), n=3, tanimotoThreshold=10)", "topn_sweep_tanimoto"),
     ("TopN(f, n=3)", "topn_sweep_unfiltered"),
     ("Sum(Row(f=1), field=v)", "bsi_sum"),
     ("Min(field=v)", "bsi_min"),
@@ -93,6 +94,8 @@ def test_lowered_module_names(ex):
     filt = jnp.zeros((2, 64), jnp.uint32)
     sweep = ex._counts_fn(True, bank.shape)
     assert _module(sweep, bank, filt) == "jit_topn_sweep"
+    assert _module(ex._counts_fn(True, bank.shape, with_raw=True),
+                   bank, filt) == "jit_topn_sweep_tanimoto"
     assert _module(ex._counts_fn(False, bank.shape), bank, None) == \
         "jit_topn_sweep_unfiltered"
     modules = [_module(sweep, bank, filt)]

@@ -1,0 +1,247 @@
+"""The filtered TopN bank sweep: three programs selected by what the call
+carries (`topn_sweep`, `topn_sweep_tanimoto`, `topn_sweep_unfiltered`),
+answers against a plain numpy recomputation on every path that reaches
+`Executor._dispatch_counts`, and structural guards on what each program
+computes and fetches."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from pilosa_tpu.core.holder import Holder
+from pilosa_tpu.executor import Executor
+from pilosa_tpu.executor import executor as ex_mod
+from pilosa_tpu.ops.bitset import (SHARD_WIDTH, SWEEP_MAX_PIECES,
+                                   WORDS_PER_SHARD, masked_row_counts,
+                                   sweep_filter_pieces)
+from pilosa_tpu.parallel import MeshContext
+from pilosa_tpu.server.api import API
+from pilosa_tpu.utils.stats import MemStatsClient
+from pilosa_tpu.utils.timeline import TIMELINE
+
+N_SHARDS = 4
+N_ROWS = 40
+FILTER_ROW = 0
+
+
+def _rows(width_cols: int) -> dict:
+    """row id -> sorted unique columns over N_SHARDS shards, every column
+    offset inside a shard below `width_cols`. Rows 1..9 are noisy copies
+    of the filter row (so a tanimoto threshold keeps some and drops
+    others); the rest are independent draws."""
+    rng = np.random.default_rng(25)
+
+    def draw(n):
+        shard = rng.integers(0, N_SHARDS, n).astype(np.uint64)
+        return np.unique(shard * np.uint64(SHARD_WIDTH)
+                         + rng.integers(0, width_cols, n).astype(np.uint64))
+
+    base = draw(400)
+    rows = {FILTER_ROW: base}
+    for r in range(1, 10):
+        keep = base[rng.random(base.size) < 1.0 - 0.07 * r]
+        rows[r] = np.unique(np.concatenate([keep, draw(12 * r)]))
+    for r in range(10, N_ROWS):
+        rows[r] = draw(int(rng.integers(20, 500)))
+    return rows
+
+
+def _reference(rows: dict, n: int, tanimoto: int) -> list:
+    """What TopN(f, Row(f=FILTER_ROW), n, tanimotoThreshold) must return."""
+    filt = rows[FILTER_ROW]
+    pairs = []
+    for r, cols in rows.items():
+        inter = np.intersect1d(cols, filt).size
+        if tanimoto:
+            denom = cols.size + filt.size - inter
+            if denom <= 0 or inter * 100 // denom < tanimoto:
+                continue
+        if inter > 0:
+            pairs.append((r, inter))
+    pairs.sort(key=lambda p: (-p[1], p[0]))
+    return pairs[:n]
+
+
+@pytest.fixture(scope="module", params=["full_width", "trimmed"])
+def sweep_holder(request, tmp_path_factory):
+    h = Holder(str(tmp_path_factory.mktemp(f"sweep_{request.param}")))
+    h.open()
+    f = h.create_index("i").create_field("f")
+    # Column offsets up to the whole shard, or inside its first container:
+    # the bank is then trimmed to 2048 of the shard's 32768 words.
+    rows = _rows(SHARD_WIDTH if request.param == "full_width" else 50_000)
+    f.import_bits(
+        np.concatenate([np.full(c.size, r, np.uint64)
+                        for r, c in rows.items()]),
+        np.concatenate(list(rows.values())))
+    width = f.view().trimmed_words()
+    assert width == (WORDS_PER_SHARD if request.param == "full_width"
+                     else 2048)
+    yield h, rows, width
+    h.close()
+
+
+@pytest.fixture(scope="module")
+def mesh4():
+    return MeshContext(jax.devices()[:4])
+
+
+@pytest.mark.parametrize("stream", ["resident_bank", "chunked_stream"])
+@pytest.mark.parametrize("placement", ["single_device", "mesh"])
+@pytest.mark.parametrize("tanimoto", [0, 60])
+def test_filtered_sweep_matches_numpy(sweep_holder, mesh4, monkeypatch,
+                                      tanimoto, placement, stream):
+    h, rows, width = sweep_holder
+    if stream == "chunked_stream":
+        monkeypatch.setattr(ex_mod, "TOPN_MAX_BANK_BYTES", 1)
+        monkeypatch.setattr(ex_mod, "TOPN_CHUNK_ROWS", 16)
+    mesh = mesh4 if placement == "mesh" else None
+    ex = Executor(h, mesh=mesh)
+    q = f"TopN(f, Row(f={FILTER_ROW}), n=12" + (
+        f", tanimotoThreshold={tanimoto})" if tanimoto else ")")
+    if mesh is not None:
+        with mesh.mesh:
+            (res,) = ex.execute("i", q)
+    else:
+        (res,) = ex.execute("i", q)
+    want = _reference(rows, 12, tanimoto)
+    assert res.pairs == want
+    assert 3 <= len(want) and (not tanimoto or len(want) < 12)
+    # The program that ran is the one the call's arguments select, at the
+    # bank's (trimmed) width, once per chunk.
+    program = "topn_sweep_tanimoto" if tanimoto else "topn_sweep"
+    with ex._jit_cache_lock:
+        keys = [k for k, fn in ex._jit_cache.items()
+                if k.startswith("topn:")]
+        names = {ex._jit_cache[k].__name__ for k in keys}
+    assert names == {program}, keys
+    assert all(f", {width})" in k for k in keys), keys
+    assert len(keys) == (1 if stream == "resident_bank" else 2), keys
+
+
+def _popcnts(fn, *args) -> int:
+    return fn.lower(*args).as_text().count("stablehlo.popcnt")
+
+
+def _n_results(fn, *args) -> int:
+    return len(jax.tree_util.tree_leaves(jax.eval_shape(fn, *args)))
+
+
+def test_each_sweep_program_computes_only_what_its_query_reads(tmp_holder):
+    """The rows' own popcounts (tanimoto's denominator) are a second
+    popcount + reduction over the whole bank: only `topn_sweep_tanimoto`
+    may carry them."""
+    ex = Executor(tmp_holder)
+    bank = jnp.zeros((8, 2, 64), jnp.uint32)
+    filt = jnp.zeros((2, 64), jnp.uint32)
+    assert sweep_filter_pieces(64) == 1
+    sweep = ex._counts_fn(True, bank.shape)
+    assert (_popcnts(sweep, bank, filt), _n_results(sweep, bank, filt)) \
+        == (1, 1)
+    tani = ex._counts_fn(True, bank.shape, with_raw=True)
+    assert (_popcnts(tani, bank, filt), _n_results(tani, bank, filt)) \
+        == (2, 2)
+    unf = ex._counts_fn(False, bank.shape)
+    assert (_popcnts(unf, bank, None), _n_results(unf, bank, None)) \
+        == (1, 1)
+    # A wide filter is cut into word-axis pieces: one popcount per piece
+    # and output, still one pass and the same number of results.
+    wide_bank = jnp.zeros((8, 16, 1024), jnp.uint32)
+    wide_filt = jnp.zeros((16, 1024), jnp.uint32)
+    k = sweep_filter_pieces(1024)
+    assert k == 2
+    wide = ex._counts_fn(True, wide_bank.shape)
+    assert (_popcnts(wide, wide_bank, wide_filt),
+            _n_results(wide, wide_bank, wide_filt)) == (k, 1)
+    wide_t = ex._counts_fn(True, wide_bank.shape, with_raw=True)
+    assert (_popcnts(wide_t, wide_bank, wide_filt),
+            _n_results(wide_t, wide_bank, wide_filt)) == (2 * k, 2)
+
+
+@pytest.mark.parametrize("n_words,pieces,left_over_lanes", [
+    (32768, 4, 0),  # a whole shard, the benchmark cell's: 4 x 8192 words
+    (16384, 2, 0),
+    (12288, 2, 0),  # under two whole pieces: halves
+    (8192, 2, 0), (2048, 2, 0), (256, 2, 0),
+    (384, 3, 0),    # three lanes do not halve into whole lanes
+    (32640, 3, 0),  # 255 lanes (max_columns): thirds, not quarters
+    (9472, 2, 0),   # 74 lanes = 2 x 37: halves, not 37 pieces
+    (4480, 2, 1),   # 35 lanes = 5 x 7: halves of 17 and the odd lane
+    (896, 2, 1),    # 7 lanes, not 7 pieces
+    (32128, 2, 1),  # 251 lanes, a prime: halves of 125 + one lane
+    (4736, 2, 1),   # 37 lanes (max_columns=150000)
+    (128, 1, 0),    # one lane: nothing to cut
+    (200, 1, 0),    # not whole lanes: left whole
+])
+def test_sweep_filter_pieces(n_words, pieces, left_over_lanes):
+    assert sweep_filter_pieces(n_words) == pieces
+    assert n_words // 128 % pieces == left_over_lanes
+
+
+def test_sweep_filter_pieces_bounds_the_pieces_at_every_width():
+    """Any multiple of 128 words can be a bank's width under max_columns:
+    none may be cut into more than SWEEP_MAX_PIECES and one short piece
+    (never a piece per lane), and only a single lane is left uncut."""
+    for n_words in range(128, WORDS_PER_SHARD + 1, 128):
+        k = sweep_filter_pieces(n_words)
+        assert k <= SWEEP_MAX_PIECES
+        assert (k == 1) == (n_words == 128)
+        left_over = n_words // 128 % k
+        assert left_over == 0 or (k, left_over) == (2, 1), n_words
+
+
+@pytest.mark.parametrize("shape", [(5, 3, 256), (7, 16, 1024), (4, 1, 128),
+                                   (3, 2, 200), (2, 4, 384), (3, 2, 896),
+                                   (2, 1, 1408), (2, 3, 640)])
+@pytest.mark.parametrize("with_raw", [False, True])
+def test_masked_row_counts_matches_numpy(shape, with_raw):
+    rng = np.random.default_rng(sum(shape))
+    bank = rng.integers(0, 2**32, shape, dtype=np.uint32)
+    filt = rng.integers(0, 2**32, shape[1:], dtype=np.uint32)
+    got = masked_row_counts(jnp.asarray(bank), jnp.asarray(filt), with_raw)
+    want = np.bitwise_count(bank & filt).sum(axis=(1, 2))
+    if with_raw:
+        assert np.asarray(got[1]).tolist() == \
+            np.bitwise_count(bank).sum(axis=(1, 2)).tolist()
+        got = got[0]
+    assert got.dtype == jnp.uint32
+    assert np.asarray(got).tolist() == want.tolist()
+
+
+@pytest.mark.parametrize("tanimoto,program,vectors", [
+    (0, "topn_sweep", 1),
+    (30, "topn_sweep_tanimoto", 2),
+])
+def test_sweep_fetches_one_vector_unless_tanimoto_reads_two(
+        tmp_holder, tanimoto, program, vectors):
+    """`dispatch program=` names which sweep ran (the specialisation's
+    engagement counter) and the answer's `d2h` is `slots x 4` bytes per
+    vector the finalize reads: counts, plus raw and the filter's own
+    popcount only under tanimotoThreshold."""
+    TIMELINE.reset()
+    TIMELINE.configure(enabled=True, ring=64, sample_every=1)
+    try:
+        idx = tmp_holder.create_index("tl")
+        cols = np.array([1, 2, SHARD_WIDTH + 3, SHARD_WIDTH + 9], np.uint64)
+        f = idx.create_field("f")
+        f.import_bits(np.array([1, 1, 1, 2], np.uint64), cols)
+        api = API(tmp_holder, stats=MemStatsClient())
+        api.executor.result_cache.enabled = False
+        q = "TopN(f, Row(f=1), n=2" + (
+            f", tanimotoThreshold={tanimoto})" if tanimoto else ")")
+        assert api.query("tl", q)["results"][0] == [{"id": 1, "count": 3}]
+        spans = list(TIMELINE.requests()[-1].root.walk())
+        programs = [s.attrs["program"] for s in spans
+                    if s.name == "dispatch"
+                    and s.attrs["program"].startswith("topn_sweep")]
+        assert programs == [program]
+        slots = f.view().device_bank((0, 1), trim=True).array.shape[0]
+        (d2h,) = [s for s in spans if s.name == "d2h"]
+        # tanimoto also fetches the filter's popcount (one uint32).
+        assert d2h.attrs["bytes"] == vectors * slots * 4 + (
+            4 if tanimoto else 0)
+        assert d2h.attrs["transfers"] == vectors + (1 if tanimoto else 0)
+    finally:
+        TIMELINE.reset()
+        TIMELINE.configure(enabled=True, ring=256, sample_every=1)
