@@ -33,12 +33,17 @@ reductions bind whatever the windows are: there a nonzero exit is PERF.md
     python benches/sweep_variants.py [--rows 1024 --shards 16 --words 32768]
         [--launches 40] [--out chiprun_out/sweep_variants]
     python benches/sweep_variants.py --describe v5e:2x2 [--rows ...]
+    python benches/sweep_variants.py --describe v5e:2x2 --mesh 4 --shards 64
 
 TPU only unless --allow-cpu (a CPU run checks exactness at a small shape
 and prints no rate). `--describe` compiles every body for a TPU that is
 described, not attached (the TPU compiler comes with jax; nothing runs, no
 chip time) and checks the shipped bodies' fusion: for a width the cells do
-not hold, or after a jax / libtpu upgrade. It loads the TPU library in
+not hold, or after a jax / libtpu upgrade. With `--mesh N` the bank is split
+over N of the described chips along its shard axis, as a server with
+`mesh_devices = N` places it: each device's program must then be the
+one-chip program at a device's share of the bank, plus ONE all-reduce of
+the counts and no other collective. It loads the TPU library in
 this process, which takes a machine-wide lock: run it alone, never from
 the tests.
 """
@@ -121,10 +126,14 @@ def _variants(R, S, W):
 
 def _bank_fusions(hlo_text):
     """[[name, window bounds, iteration bounds]] of the fusions that read
-    the bank (parameter `c`) in a compiled body's HLO."""
+    the bank (parameter `c`; the SPMD partitioner renames it and keeps
+    the name in its metadata) in a compiled body's HLO."""
     out = []
+    renamed = re.search(r'%(\S+) = \S+ parameter\(0\), sharding=[^\n]*'
+                        r'op_name="c"', hlo_text)
+    bank = re.escape(renamed.group(1)) if renamed else r"c\.\d+"
     for line in hlo_text.splitlines():
-        m = re.search(r"%(\S+) = .* fusion\(%c\.\d+[,)]", line)
+        m = re.search(rf"%(\S+) = .* fusion\(%{bank}[,)]", line)
         if m:
             out.append([m.group(1)] + [
                 [int(x) for x in re.findall(r"\d+", b.group(1))] if b else []
@@ -176,6 +185,18 @@ def _check_shipped(name, fusions, shape):
     return reduce_ms
 
 
+COLLECTIVE = re.compile(r"^\s*(?:ROOT )?%\S+ = .+? (all-reduce|all-gather|"
+                        r"reduce-scatter|collective-permute|all-to-all)"
+                        r"(?:-start)?\(")
+
+
+def _collectives(hlo_text):
+    """Names of the collective ops of a compiled SPMD body, in order (an
+    async pair counts once, by its `-start`)."""
+    return [m.group(1) for m in map(COLLECTIVE.search,
+                                    hlo_text.splitlines()) if m]
+
+
 def _first(out):
     return out[0] if isinstance(out, tuple) else out
 
@@ -218,23 +239,39 @@ def describe(args):
     from jax.sharding import SingleDeviceSharding
     topo = topologies.get_topology_desc(platform="tpu",
                                         topology_name=args.describe)
-    one_chip = SingleDeviceSharding(topo.devices[0])
     R, S, W = args.rows, args.shards, args.words
-    bank = jax.ShapeDtypeStruct((R, S, W), jnp.uint32, sharding=one_chip)
-    filt = jax.ShapeDtypeStruct((S, W), jnp.uint32, sharding=one_chip)
+    if args.mesh > 1:
+        from pilosa_tpu.parallel import MeshContext
+        mesh = MeshContext(topo.devices[:args.mesh])
+        bank_at, filt_at = mesh.bank_sharding(), mesh.row_sharding()
+    else:
+        bank_at = filt_at = SingleDeviceSharding(topo.devices[0])
+    bank = jax.ShapeDtypeStruct((R, S, W), jnp.uint32, sharding=bank_at)
+    filt = jax.ShapeDtypeStruct((S, W), jnp.uint32, sharding=filt_at)
     variants = _variants(R, S, W)
     if args.only:
         variants = {k: v for k, v in variants.items()
                     if k in args.only.split(",")}
     ok = True
     for name, body in variants.items():
-        rec = {"variant": name, "shape": [R, S, W]}
+        rec = {"variant": name, "shape": [R, S, W], "mesh": args.mesh}
         try:
-            rec["bank_fusions"] = _bank_fusions(
-                jax.jit(body).lower(bank, filt).compile().as_text())
+            hlo = jax.jit(body).lower(bank, filt).compile().as_text()
+            rec["bank_fusions"] = _bank_fusions(hlo)
+            rec["collectives"] = _collectives(hlo)
             if name in SHIPPED:
                 rec["reduce_ms_estimate"] = _check_shipped(
-                    name, rec["bank_fusions"], (R, S, W))
+                    name, rec["bank_fusions"], (R, S // args.mesh, W))
+                # One all-reduce of the counts (with the raw popcounts
+                # XLA may reduce the pair as one tuple or as two).
+                most = 2 if name == "shipped_with_raw" else 1
+                got = rec["collectives"]
+                ok = (set(got) == {"all-reduce"} and len(got) <= most) \
+                    if args.mesh > 1 else not got
+                if not ok:
+                    raise AssertionError(
+                        f"{name}: collectives {got}, not 1..{most} "
+                        "all-reduce and nothing else")
         except Exception as e:
             rec["error"] = f"{type(e).__name__}: {str(e)[:400]}"
             ok = False
@@ -251,6 +288,9 @@ def main():
     ap.add_argument("--only", default="")
     ap.add_argument("--out", default="chiprun_out/sweep_variants")
     ap.add_argument("--allow-cpu", action="store_true")
+    ap.add_argument("--mesh", type=int, default=1, metavar="N",
+                    help="with --describe: split the bank's shard axis over "
+                         "N of the described chips")
     ap.add_argument("--describe", default="", metavar="TOPOLOGY",
                     help="compile for this described TPU (v5e:2x2), run "
                          "nothing")

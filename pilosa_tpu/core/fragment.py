@@ -433,6 +433,30 @@ class Fragment:
                                        row_id * SHARD_WIDTH + aligned)
         return u64_to_words(u64)[:bits // 32]
 
+    @staticmethod
+    def _scatter_arrays(arrays, rows_at, out: np.ndarray) -> None:
+        """OR the u16 position arrays into rows `rows_at` of `out`
+        ([N, words64] u64, C-contiguous): ONE flat scatter, native when
+        the library is there. Positions at or past the row's width are
+        the caller's to trim."""
+        if not arrays:
+            return
+        from pilosa_tpu import native
+        words64 = out.shape[1]
+        lens = np.fromiter(map(len, arrays), dtype=np.int64,
+                           count=len(arrays))
+        pos16 = np.concatenate(arrays)
+        if native.scatter_rows(pos16, lens,
+                               np.asarray(rows_at, dtype=np.uint64),
+                               words64, out):
+            return
+        pos = pos16.astype(np.uint32)
+        base = np.repeat(np.asarray(rows_at, dtype=np.int64) * words64,
+                         lens)
+        np.bitwise_or.at(out.reshape(-1), base + (pos >> 6),
+                         np.left_shift(np.uint64(1),
+                                       (pos & 63).astype(np.uint64)))
+
     def rows_dense(self, row_ids, u32_words: int) -> np.ndarray:
         """Bulk [len(row_ids), u32_words] u32 prefix block — the chunk-bank
         fast path. One dict probe + one memcpy per (row, container)
@@ -453,29 +477,34 @@ class Fragment:
             # row's u16 array and do ONE flat scatter over the whole
             # block — no per-row Python work beyond the dict probe.
             if n_containers == 1:
-                flat = out.reshape(-1)
                 arrays, rows_at, dense_items = self._gather_row_arrays(
                     containers, row_ids, total64, cwords64)
                 n_dense = min(cwords64, total64)
                 for i, c in dense_items:
                     out[i, :n_dense] = c[:n_dense]
-                if arrays:
-                    from pilosa_tpu import native
-                    lens = np.fromiter(map(len, arrays),
-                                       dtype=np.int64, count=len(arrays))
-                    pos16 = np.concatenate(arrays)
-                    if not native.scatter_rows(
-                            pos16, lens,
-                            np.asarray(rows_at, dtype=np.uint64),
-                            total64, out):
-                        pos = pos16.astype(np.uint32)
-                        base = np.repeat(
-                            np.asarray(rows_at, dtype=np.int64) * total64,
-                            lens)
-                        np.bitwise_or.at(
-                            flat, base + (pos >> 6),
-                            np.left_shift(one,
-                                          (pos & 63).astype(np.uint64)))
+                self._scatter_arrays(arrays, rows_at, out)
+            elif total64 == n_containers * cwords64:
+                # Whole containers (a shard-wide bank: 16 a row): every
+                # (row, container) cell is a row of cwords64 words of
+                # `out`, so the same one probe + one flat scatter
+                # serves — a one-hot field's 1023 rows x 16 small
+                # arrays a shard were 16k numpy calls the other way.
+                cells = out.reshape(-1, cwords64)
+                keys = (np.asarray(row_ids, dtype=np.uint64)[:, None]
+                        * np.uint64(CONTAINERS_PER_ROW)
+                        + np.arange(n_containers, dtype=np.uint64)
+                        ).ravel().tolist()
+                u16dt = np.dtype(np.uint16)
+                arrays, cells_at = [], []
+                for i, c in enumerate(map(containers.get, keys)):
+                    if c is None:
+                        continue
+                    if c.dtype is u16dt:
+                        arrays.append(c)
+                        cells_at.append(i)
+                    else:
+                        cells[i] = c
+                self._scatter_arrays(arrays, cells_at, cells)
             else:
                 for i, r in enumerate(row_ids):
                     k0 = r * CONTAINERS_PER_ROW

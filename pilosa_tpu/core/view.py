@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import os
 from pilosa_tpu.utils.locks import make_lock, make_rlock
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -18,6 +18,7 @@ from pilosa_tpu.core.fragment import CONTAINER_BITS, Fragment
 from pilosa_tpu.core import cache as cache_mod
 from pilosa_tpu.utils.hotspots import WORKLOAD
 from pilosa_tpu.utils.memledger import LEDGER
+from pilosa_tpu.utils.timeline import TIMELINE
 
 VIEW_STANDARD = "standard"
 VIEW_BSI_PREFIX = "bsig_"
@@ -85,8 +86,11 @@ def _expand_sparse_chunk(pos16: np.ndarray, lens: np.ndarray,
 
 
 class BankBudget:
-    """Process-wide accounting of cached device banks, bounding total
-    HBM spent on operand banks. The reference never needs this because it
+    """Process-wide accounting of cached device banks, bounding the HBM
+    that ONE device spends on operand banks: a bank split over a mesh's
+    shard devices is accounted by the share each of them holds
+    (`device_share_bytes`), which without a mesh is the whole array.
+    The reference never needs this because it
     streams one shard at a time from mmap (executor.go:2377); here banks
     persist in HBM across queries for reuse, so an explicit budget decides
     what stays resident. Evicted banks drop out of their view's cache (the
@@ -154,7 +158,8 @@ class BankBudget:
             bank = cache.get(key)
             if bank is None:
                 return
-            nbytes = int(np.prod(bank.array.shape)) * 4
+            nbytes = device_share_bytes(bank.array.shape,
+                                        bank.array.sharding)
         ek = (id(view), key)
         with self._lock:
             old = self._entries.pop(ek, None)
@@ -190,8 +195,8 @@ class BankBudget:
             LEDGER.unregister(cat, (id(view), key))
 
 
-# Default sized for a v5e-class chip (16 GiB HBM): 12 GiB of resident
-# banks leaves ~4 GiB for transient chunk banks, filter rows, sparse
+# Default sized for a v5e-class chip (16 GiB HBM), per device: 12 GiB of
+# resident banks leaves ~4 GiB for transient chunk banks, filter rows, sparse
 # expansions, and XLA scratch. The 100M-fingerprint positions bank
 # (~9.6 GiB) must fit WITH its filter banks or the LRU thrashes it on
 # every query — the round-3 8 GiB default did exactly that.
@@ -336,6 +341,18 @@ def bank_capacity(n_rows: int) -> int:
     while cap < n_rows + 1:
         cap *= 2
     return cap
+
+
+def device_share_bytes(shape: Sequence[int], sharding=None) -> int:
+    """Bytes of a uint32 bank [rows, shards, words] that ONE device
+    holds: `shape` cut by `sharding` (a mesh's `bank_sharding()` before
+    the bank is built, a built array's own `.sharding` after), the whole
+    array when there is none. Every per-device limit prices a bank by
+    this — the resident-sweep limit, the row-subset limit and the bank
+    budget — so a bank split over four devices costs each a quarter."""
+    if sharding is not None:
+        shape = sharding.shard_shape(tuple(int(x) for x in shape))
+    return int(np.prod(shape, dtype=np.int64)) * 4
 
 
 class View:
@@ -525,6 +542,30 @@ class View:
         return min(WORDS_PER_SHARD, ((words + cwords - 1) // cwords)
                    * cwords)
 
+    def _cache_host_block(self, hb_key, host, versions, slots, cap,
+                          row_set) -> None:
+        """Keep a row-subset build's packed host block (HOST_BLOCK_BUDGET)
+        so that a device-side eviction rebuilds by re-upload. A
+        full-view build has no such key and keeps nothing."""
+        if hb_key is None:
+            return
+        shards, width = hb_key[0], hb_key[1]
+        # The slots dict is real host RAM too (~100 B/entry of dict
+        # overhead + int pair; several MB at 65k rows): account it, or a
+        # budget-full cache overshoots by the sum of its mappings
+        # (ADVICE r2).
+        entry_bytes = host.nbytes + 100 * len(row_set)
+        if not 0 < entry_bytes <= HOST_BLOCK_BUDGET.budget:
+            return
+        self._host_blocks[hb_key] = (host, versions, slots)
+        HOST_BLOCK_BUDGET.admit(self, hb_key, nbytes=entry_bytes)
+        LEDGER.register(
+            "host_block", hb_key, entry_bytes,
+            padded_bytes=max(0, cap - len(row_set) - 1)
+            * len(shards) * width * 4,
+            owner=self, index=self.index, field=self.field,
+            view=self.name, nShards=len(shards), rows=len(row_set))
+
     def device_bank(self, shards, rows=None, mesh=None,
                     trim: bool = False, cache_rows: bool = False
                     ) -> ViewBank:
@@ -542,8 +583,12 @@ class View:
         BANK_BUDGET. trim=True narrows the word axis to trimmed_words() —
         valid only for whole-row consumers since the dropped tail is
         all-zero by construction. With a MeshContext the array is
-        device_put sharded over the mesh's shard axis, which is all the
-        executor needs to run SPMD."""
+        sharded over the mesh's shard axis, which is all the executor
+        needs to run SPMD; a full-view bank is gathered and uploaded one
+        device's block at a time (`MeshContext.put_bank_blocks`), so the
+        host never stages the whole array. Each uncached dense build is
+        a `plan.bank_upload` span (`bytes`, `devices`, `blocks`) of the
+        request that caused it."""
         import jax.numpy as jnp
         from pilosa_tpu.ops.bitset import WORDS_PER_SHARD
 
@@ -648,36 +693,52 @@ class View:
                     array = _expand_sparse_chunk(*sp, cap, width)
                     slots = {r: i for i, r in enumerate(row_set)}
             if array is None:
-                if host is None:
-                    host = np.zeros((cap, len(shards), width),
-                                    dtype=np.uint32)
-                    for si, s in enumerate(shards):
+                def gather(positions):
+                    """Host block [cap, len(positions), width] of the
+                    shards at those positions of the shard list."""
+                    block = np.zeros((cap, positions.stop - positions.start,
+                                      width), dtype=np.uint32)
+                    for bi, s in enumerate(shards[positions]):
                         f = frags[s]
                         if f is not None:
-                            host[:len(row_set), si] = f.rows_dense(
+                            block[:len(row_set), bi] = f.rows_dense(
                                 row_set, width)
-                    # Cached alongside so a hit is O(1) host-side — no
-                    # 65k-entry dict rebuild per chunk per repeat query.
+                    return block
+
+                if slots is None:
+                    # Kept with a cached host block, so a hit is O(1)
+                    # host-side — no 65k-entry dict rebuild per chunk
+                    # per repeat query.
                     slots = {r: i for i, r in enumerate(row_set)}
-                    # The slots dict is real host RAM too (~100 B/entry
-                    # of dict overhead + int pair; several MB at 65k
-                    # rows): account it, or a budget-full cache
-                    # overshoots by the sum of its mappings (ADVICE r2).
-                    entry_bytes = host.nbytes + 100 * len(row_set)
-                    if hb_key is not None and \
-                            0 < entry_bytes <= HOST_BLOCK_BUDGET.budget:
-                        self._host_blocks[hb_key] = (host, versions,
-                                                     slots)
-                        HOST_BLOCK_BUDGET.admit(self, hb_key,
-                                                nbytes=entry_bytes)
-                        LEDGER.register(
-                            "host_block", hb_key, entry_bytes,
-                            padded_bytes=max(0, cap - len(row_set) - 1)
-                            * len(shards) * width * 4,
-                            owner=self, index=self.index,
-                            field=self.field, view=self.name,
-                            nShards=len(shards), rows=len(row_set))
-                array = mesh.put_bank(host) if mesh else jnp.asarray(host)
+                shape = (cap, len(shards), width)
+                nbytes = cap * len(shards) * width * 4
+                # Under a mesh a full-view bank is gathered and uploaded
+                # one device's block at a time (the whole array is 8 GiB
+                # where a device's share is 2). Row-subset builds stay
+                # whole: their packed host block is what is cached.
+                blockwise = mesh is not None and hb_key is None
+                with TIMELINE.stage(
+                        "plan.bank_upload", bytes=nbytes,
+                        devices=mesh.n_shard_devices if mesh else 1,
+                        blocks=mesh.n_shard_devices if blockwise else 1,
+                        counts=(("executor.bank_upload_bytes", nbytes),)):
+                    if blockwise:
+                        # graftlint: disable=GL009 — the wait inside is
+                        # for a block's host->device copy (a fraction of
+                        # a second, while the next block's gather takes
+                        # seconds under this same lock); it is what
+                        # bounds host staging to two blocks, and the
+                        # lock is held for the whole build either way,
+                        # as it always was, so that one thread builds a
+                        # bank and the others find it cached.
+                        array = mesh.put_bank_blocks(shape, gather)
+                    else:
+                        if host is None:
+                            host = gather(slice(0, len(shards)))
+                            self._cache_host_block(hb_key, host, versions,
+                                                   slots, cap, row_set)
+                        array = mesh.put_bank(host) if mesh \
+                            else jnp.asarray(host)
             bank = ViewBank(array, slots, cap - 1, versions)
             if rows is None or cache_rows:
                 self._bank_cache[cache_key] = bank

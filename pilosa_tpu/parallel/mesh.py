@@ -3,10 +3,12 @@
 Reference mapping:
 - shard -> node placement: fnv64a(index,shard) mod 256 partitions ->
   jump-hash -> node (cluster.go:828-913). Here placement is *static block
-  assignment onto a mesh axis*: device d owns shards where
-  (shard_position mod n_devices) == d once the shard list is padded to a
-  multiple of the mesh size. Elastic resize (cluster.go:1150's resize jobs
-  streaming fragments node-to-node) becomes: change the mesh, re-put the
+  assignment onto a mesh axis*: once the shard list is padded to a
+  multiple of the mesh size, device d owns the d-th contiguous block of
+  it (shard_position // (n_padded // n_devices) == d) — what
+  `bank_sharding`'s P(None, "shards", None) places. Elastic resize
+  (cluster.go:1150's resize jobs streaming fragments node-to-node)
+  becomes: change the mesh, re-put the
   banks — the durable store is the source of truth, so "resize" is a
   re-shard + recompile, not a data-migration protocol.
 - mapReduce scatter-gather + reduce over HTTP (executor.go:2277-2415):
@@ -54,9 +56,18 @@ class ShardPlacement:
         return shards
 
     def device_of(self, shards: Sequence[int], shard: int) -> int:
-        """Which device owns a shard (for diagnostics/routing)."""
+        """Which shard device owns a shard: the index of its block."""
         padded = self.pad(shards)
-        return padded.index(shard) % self.n
+        return padded.index(shard) // (len(padded) // self.n)
+
+    def blocks(self, n_shards: int) -> List[slice]:
+        """Device d's positions in a shard list of `n_shards` (a multiple
+        of n, as pad() leaves it): n contiguous slices, in device order."""
+        if n_shards % self.n:
+            raise ValueError(f"{n_shards} shards do not split over "
+                             f"{self.n} devices; pad the list first")
+        per = n_shards // self.n
+        return [slice(d * per, (d + 1) * per) for d in range(self.n)]
 
 
 class MeshContext:
@@ -109,6 +120,35 @@ class MeshContext:
     def put_bank(self, host):
         import jax
         return jax.device_put(host, self.bank_sharding())
+
+    def put_bank_blocks(self, shape, build_block):
+        """A [rows, shards, words] bank placed as `bank_sharding` places
+        it, built one shard device's block at a time:
+        `build_block(positions)` returns the host block
+        [rows, len(positions), words] of that slice of the shard list.
+        A block's upload (to each replica's device that holds it) is
+        started and the next block is built meanwhile; the host never
+        holds more than the block being built and the one in flight.
+        Under `jax.distributed` a process builds and places only the
+        blocks of its own devices."""
+        import jax
+        sharding = self.bank_sharding()
+        me = jax.process_index()
+        # mesh.devices is [shard] or [replica, shard].
+        by_block = np.asarray(self.mesh.devices).reshape(
+            -1, self.n_shard_devices).T
+        parts, in_flight = [], []
+        for positions, devices in zip(self.placement.blocks(shape[1]),
+                                      by_block):
+            devices = [d for d in devices if d.process_index == me]
+            if not devices:
+                continue
+            block = build_block(positions)
+            jax.block_until_ready(in_flight)
+            in_flight = [jax.device_put(block, d) for d in devices]
+            parts.extend(in_flight)
+        return jax.make_array_from_single_device_arrays(
+            tuple(shape), sharding, parts)
 
     def put_row(self, arr):
         """Commit a [shards, words] (or [k, shards, words]) array to the

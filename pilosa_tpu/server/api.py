@@ -104,7 +104,13 @@ class API:
         # window without a transfer reads 0, not "no such counter".
         self.stats.batch((), [(f"executor.{d}_{k}", 0)
                               for d in ("h2d", "d2h")
-                              for k in ("bytes", "transfers")])
+                              for k in ("bytes", "transfers")]
+                         + [("executor.bank_upload_bytes", 0)])
+        # ... and so are the TopN path counters: a share of them is
+        # read over a window in which one path may never be taken.
+        for path in Executor.TOPN_PATHS:
+            self.stats.with_tags(f"path:{path}").count(
+                "executor.topn_sweeps", 0)
         # The process-wide workload recorder (utils/hotspots.py)
         # increments its counters (pilosa_fragment_reads_total, ...)
         # straight into the stats client at record time so the
@@ -2228,9 +2234,10 @@ class API:
         import jax
 
         from pilosa_tpu import native
+        from pilosa_tpu.core.view import BANK_BUDGET
+        from pilosa_tpu.executor import executor as executor_mod
         from pilosa_tpu.utils.jaxenv import describe_devices
         native_loaded, native_error = native.status()
-        mesh = self.executor.mesh
         # tailDroppedBytes > 0 means torn op-log tails were sidecarred at
         # open — data the operator should know was dropped (ADVICE r2).
         return {"shardWidth": SHARD_WIDTH, "cpuPhysicalCores": os.cpu_count(),
@@ -2243,8 +2250,21 @@ class API:
                 # jax.distributed).
                 "devices": describe_devices(),
                 "deviceCount": jax.device_count(),
-                "meshDevices": (int(mesh.mesh.devices.size)
-                                if mesh is not None else 1),
+                "meshDevices": self.executor.mesh_devices,
+                # What a bank is priced against, per device: a bank
+                # whose share on one device is within the first is swept
+                # resident by TopN, and cached banks are evicted past
+                # the second (a bank split over a mesh counts by its
+                # share on one device).
+                "residentLimits": {
+                    "topnBankBytesPerDevice":
+                        executor_mod.TOPN_MAX_BANK_BYTES,
+                    "bankBudgetBytesPerDevice": BANK_BUDGET.budget},
+                # ... and where the budget stands: a device's bytes of
+                # cached banks, and how many banks it has evicted since
+                # the process started.
+                "bankBudget": {"bytesPerDevice": BANK_BUDGET.total,
+                               "evictions": BANK_BUDGET.evictions},
                 "compileCacheDir": jax.config.jax_compilation_cache_dir,
                 "native": {"loaded": native_loaded,
                            "error": native_error}}

@@ -486,6 +486,49 @@ def test_sub_container_row_dense_and_set_row(tmp_path):
     frag.close()
 
 
+@pytest.mark.parametrize("native_scatter", [True, False])
+@pytest.mark.parametrize("u32_words", [32768, 4096, 6144])
+def test_rows_dense_over_whole_containers_equals_row_by_row(
+        tmp_path, monkeypatch, native_scatter, u32_words):
+    """A bank-wide gather (rows x whole containers in one probe and one
+    scatter) holds the cells that `row_dense` gives row by row: array
+    and dense containers mixed, absent rows and absent containers, at a
+    shard's width, at two containers and at a width that is no whole
+    number of containers (the row-by-row path)."""
+    from pilosa_tpu import native
+    from pilosa_tpu.core.fragment import Fragment
+
+    if not native_scatter:
+        monkeypatch.setattr(native, "scatter_rows", lambda *a, **k: False)
+    rng = np.random.default_rng(26)
+    frag = Fragment(str(tmp_path / "f"), "i", "f", "standard", 0)
+    frag.open()
+    rows, cols = [], []
+    for r in (0, 1, 5, 9, 1022):
+        c = np.unique(rng.integers(0, 1 << 20, 1500).astype(np.uint64))
+        rows.append(np.full(c.size, r, np.uint64))
+        cols.append(c)
+    # Row 7: a dense-encoded container (5,000 bits of one container)
+    # beside array containers; row 3 stays absent.
+    c = np.unique(np.concatenate([
+        rng.choice(1 << 16, 5000, replace=False).astype(np.uint64)
+        + np.uint64(2 << 16),
+        rng.integers(0, 1 << 20, 300).astype(np.uint64)]))
+    rows.append(np.full(c.size, 7, np.uint64))
+    cols.append(c)
+    frag.bulk_import(np.concatenate(rows), np.concatenate(cols))
+    dtypes = {c.dtype for c in frag.storage.containers.values()}
+    assert dtypes == {np.dtype(np.uint16), np.dtype(np.uint64)}
+    ids = [0, 1, 3, 5, 7, 9, 1022]
+    got = frag.rows_dense(ids, u32_words)
+    assert got.shape == (len(ids), u32_words) and got.dtype == np.uint32
+    for i, r in enumerate(ids):
+        np.testing.assert_array_equal(
+            got[i], frag.row_dense(r, u32_words=u32_words))
+    assert not got[2].any() and got[4].any()
+    frag.close()
+
+
 def test_time_field_requires_quantum_and_bsi_bound(tmp_path):
     """Regressions from review: time fields must still demand a quantum,
     and max_columns binds BSI writes too."""

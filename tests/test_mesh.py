@@ -53,7 +53,32 @@ def test_device_of_block_assignment():
     p = ShardPlacement(4)
     shards = [10, 11, 12, 13, 14, 15, 16, 17]
     for pos, s in enumerate(shards):
-        assert p.device_of(shards, s) == pos % 4
+        assert p.device_of(shards, s) == pos // 2
+    assert p.blocks(8) == [slice(0, 2), slice(2, 4), slice(4, 6),
+                           slice(6, 8)]
+    # Six shards pad to eight: the blocks are of the padded list.
+    assert [p.device_of(shards[:6], s) for s in shards[:6]] \
+        == [0, 0, 1, 1, 2, 2]
+    with pytest.raises(ValueError, match="pad the list first"):
+        p.blocks(6)
+
+
+def test_device_of_is_where_the_bank_sharding_puts_a_shard():
+    """The truth is the placed array's: the device whose addressable
+    shard holds a shard's column is the one `device_of` names."""
+    import jax
+    devices = jax.devices()[:4]
+    mesh = MeshContext(devices)
+    shards = [3, 4, 7, 9, 12, 13, 20, 21]
+    host = np.zeros((2, len(shards), 8), np.uint32)
+    host[0, :, 0] = shards            # mark each column with its shard
+    placed = mesh.put_bank(host)
+    seen = {}
+    for sh in placed.addressable_shards:
+        for s in np.asarray(sh.data)[0, :, 0].tolist():
+            seen[s] = devices.index(sh.device)
+    assert seen == {s: mesh.placement.device_of(shards, s) for s in shards}
+    assert seen == {3: 0, 4: 0, 7: 1, 9: 1, 12: 2, 13: 2, 20: 3, 21: 3}
 
 
 # -------------------------------------------------------- mesh context
