@@ -101,10 +101,14 @@ def _peel_options(call: "Call") -> "Call":
         call = call.children[0]
     return call
 
-# Expand time-range unions statically up to this many views; beyond it the
-# union is precomputed eagerly into a literal operand (keeps compile sizes
-# bounded for hour-grain multi-year ranges).
-MAX_STATIC_RANGE_VIEWS = 8
+# A time-range union of up to this many views is an OR-fold of slot leaves
+# inside the tree program, padded to a power of two (1, 2, 4, ... this) so
+# the view count moves the compile key six times, not once per count.
+# Beyond it (hour-grain multi-year ranges) groups of this many views run
+# the same fold as a jitted program of its own, chained through an
+# accumulator, and the union enters the tree as one literal operand: the
+# tree program's size stays bounded and nothing is indexed eagerly.
+MAX_STATIC_RANGE_VIEWS = 32
 
 # TopN uses the cached full view bank while ONE DEVICE's share of it
 # (`Executor._bank_device_bytes`: under a mesh the shard axis is split
@@ -410,6 +414,11 @@ def _topn_candidates(rows_arr: np.ndarray, counts_arr: np.ndarray,
     return rows_arr[sel], counts_arr[sel]
 
 
+def _pow2(n: int) -> int:
+    """The smallest power of two >= n (n >= 1)."""
+    return 1 << (n - 1).bit_length()
+
+
 def _align_words(words, width: int):
     """Slice or zero-pad the trailing word axis to exactly `width`
     (None passes through). Both directions are semantically safe for
@@ -445,7 +454,7 @@ class _Plan:
     bank_pos: Dict[Tuple[str, str], int] = dc_field(default_factory=dict)
     idxs: List[int] = dc_field(default_factory=list)       # traced gather slots
     params: List[int] = dc_field(default_factory=list)     # traced u32 scalars
-    literals: List[Any] = dc_field(default_factory=list)   # eager [S, W] ops
+    literals: List[Any] = dc_field(default_factory=list)   # [S, W] operands
     widths: List[int] = dc_field(default_factory=list)     # operand widths
     # slot placeholders: (position in idxs, bank key, row id), resolved
     # once banks exist; rows_for[key] = every row the tree reads from it.
@@ -457,7 +466,7 @@ class _Plan:
     # Megakernel IR (ops/megakernel.py): a postfix record of the same
     # tree the closures trace, appended by the _plan_* recursion so a
     # heterogeneous flush can lower N different staged programs into
-    # ONE opcode plan buffer. `ir_ok=False` (eager literals, Shift)
+    # ONE opcode plan buffer. `ir_ok=False` (literal operands, Shift)
     # means the staged eval is not lowerable and takes the per-group
     # fusion path instead.
     ir: List[tuple] = dc_field(default_factory=list)
@@ -477,11 +486,19 @@ class _Plan:
     # time, after staging resolved every width).
     sparse_widths: Dict[int, int] = dc_field(default_factory=dict)
 
-    def bank(self, key: Tuple[str, str]) -> int:
-        pos = self.bank_pos.get(key)
+    # Time-range leaves staged by this plan, (path, view count) each:
+    # counted once the staging settles (executor.range_leaves{path:}).
+    range_leaves: List[Tuple[str, int]] = dc_field(default_factory=list)
+
+    def bank(self, key: Tuple[str, str], fresh: bool = False) -> int:
+        """The operand position of `key`'s bank. `fresh` takes a new
+        position for a bank the plan may hold already (the pad operands
+        of a bucketed range fold): the same array at two positions, so
+        the program's operand list is as long as its bucket."""
+        pos = None if fresh else self.bank_pos.get(key)
         if pos is None:
             pos = len(self.bank_keys)
-            self.bank_pos[key] = pos
+            self.bank_pos.setdefault(key, pos)
             self.bank_keys.append(key)
         return pos
 
@@ -515,7 +532,7 @@ class _StagedEval:
     bank_arrays: tuple     # device operand banks (shared, not stacked)
     idxs: List[int]        # traced gather slots (host values)
     params: List[int]      # traced u32 scalars (host values)
-    lits: Any              # stacked [L, S, W] device literals or None
+    lits: Any              # tuple of [S, W] device literals or None
     # Workload-recorder AND result-cache identity: the semantic
     # fingerprint (sig + row ids + params — row IDS, not bank slots,
     # so it is stable across bank rebuilds), and the operand banks'
@@ -525,10 +542,11 @@ class _StagedEval:
     # workload recorder and the result cache are off.
     fp: Any = None
     gen: Any = None
-    # False when the plan carries eager literal operands (the
+    # False when the plan carries literal operands (the
     # >MAX_STATIC_RANGE_VIEWS time-range union): literal content is
     # not named by fp/gen, so such evals must never be served from or
-    # fill the result cache.
+    # fill the result cache — nor enter a fusion group, whose members
+    # share every operand but idxs/params.
     cacheable: bool = True
     # Megakernel IR: the postfix opcode record _Plan collected, or
     # None when the tree is not lowerable — such evals keep the
@@ -862,6 +880,19 @@ class Executor:
         if self.stats is not None:
             self.stats.with_tags(f"path:{path}").count(
                 "executor.topn_sweeps", 1)
+
+    # How a time-range leaf's union was staged, one of these per leaf,
+    # counted under `executor.range_leaves{path:<p>}`: `fold`, slot
+    # leaves OR-ed inside the tree program (up to
+    # MAX_STATIC_RANGE_VIEWS views), or `grouped`, programs of their
+    # own ahead of it; `executor.range_views` sums the views.
+    RANGE_PATHS = ("fold", "grouped")
+
+    def _note_range(self, path: str, n_views: int) -> None:
+        if self.stats is not None:
+            self.stats.with_tags(f"path:{path}").count(
+                "executor.range_leaves", 1)
+            self.stats.count("executor.range_views", n_views)
 
     def _note_mesh(self, n_devices: int, collective_bytes: int) -> None:
         """Account one mesh cohort launch: the plan buffer ran SPMD
@@ -1737,7 +1768,7 @@ class Executor:
                                      plan_s, 0, staged.n_shards)
                     node.attrs["cacheHit"] = True
                 return hit
-        if fusible and FUSION_ENABLED and (
+        if fusible and FUSION_ENABLED and staged.lits is None and (
                 self.mesh is None or self._mesh_fusion_enabled()):
             fuser = getattr(self._tls, "fuser", None)
             if fuser is not None:
@@ -1766,8 +1797,6 @@ class Executor:
         the shape signature. Stages everything the compiled program
         needs without running (or even compiling) it — the seam the
         batch fusion pass groups on."""
-        import jax.numpy as jnp
-
         from pilosa_tpu.core.view import SparseBank
 
         # Hybrid layout restage loop: a sparse-planned key whose
@@ -1810,12 +1839,11 @@ class Executor:
         bank_arrays = tuple(
             b.arrays if isinstance(b, SparseBank) else b.array
             for b in banks)
-        lits = None
-        if plan.literals:
-            lits = jnp.stack([_align_words(a, plan.width)
-                              for a in plan.literals])
-            if self.mesh is not None:
-                lits = self.mesh.put_row(lits)
+        # Literal operands go in as they are: their leaves align them to
+        # the plan width inside the program.
+        lits = tuple(plan.literals) or None
+        for path, n in plan.range_leaves:
+            self._note_range(path, n)
         # Sparse operands show as their (pos, starts) shape pair: a
         # layout flip must land in a DIFFERENT signature (different
         # program) even when the dense bank shape matches. Their dense
@@ -1831,7 +1859,7 @@ class Executor:
         xw = sorted(plan.sparse_widths.items())
         sig = (f"{mode}|{''.join(plan.sig_parts)}|W{plan.width}"
                f"|B{bshapes}{f'|XW{xw}' if xw else ''}"
-               f"|L{None if lits is None else lits.shape}|S{len(shards)}")
+               f"|L{lits and [a.shape for a in lits]}|S{len(shards)}")
         fp = gen = None
         if WORKLOAD.enabled or self.result_cache.enabled:
             # The fingerprint uses ROW IDS from slot_refs (bank slots
@@ -1849,7 +1877,7 @@ class Executor:
             WORKLOAD.record_query(fp, gen, index=idx.name, mode=mode,
                                   n_shards=len(shards), sig=sig)
             prof = self._profile()
-            for key in plan.bank_keys:
+            for key in plan.bank_pos:
                 WORKLOAD.record_read(idx.name, key[0], key[1], shards,
                                      rows=plan.rows_for.get(key))
                 if prof is not None:
@@ -1880,7 +1908,7 @@ class Executor:
         cap = getattr(self._tls, "deps", None)
         if cap is None:
             return
-        for key in plan.bank_keys:
+        for key in plan.bank_pos:
             dk = ("view", idx.name, key[0], key[1])
             if dk not in cap:
                 f = idx.field(key[0])
@@ -1898,19 +1926,25 @@ class Executor:
         the rest. Returns (banks, retry): retry=True means a sparse
         build bailed, the offending key is now in `force_dense`, and
         the caller must replan."""
+        built: Dict[Tuple[str, str], Any] = {}
         banks: List[Any] = []
-        for key in plan.bank_keys:
-            if plan.bank_sparse.get(key):
-                bank = self._get_sparse_bank(idx, key, shards)
-                if bank is None:
-                    force_dense.add(key)
-                    return banks, True
-                plan.sparse_widths[plan.bank_pos[key]] = bank.width
-                banks.append(bank)
-            else:
-                banks.append(self._get_bank(
-                    idx, key, shards,
-                    rows_needed=plan.rows_for.get(key)))
+        for pos, key in enumerate(plan.bank_keys):
+            sparse = plan.bank_sparse.get(key)
+            bank = built.get(key)   # a range fold's pads repeat a key
+            if bank is None:
+                if sparse:
+                    bank = self._get_sparse_bank(idx, key, shards)
+                    if bank is None:
+                        force_dense.add(key)
+                        return banks, True
+                else:
+                    bank = self._get_bank(
+                        idx, key, shards,
+                        rows_needed=plan.rows_for.get(key))
+                built[key] = bank
+            if sparse:
+                plan.sparse_widths[pos] = bank.width
+            banks.append(bank)
         return banks, False
 
     def _get_sparse_bank(self, idx: Index, key: Tuple[str, str],
@@ -2004,8 +2038,7 @@ class Executor:
         if prof is None:
             return out
         dispatch_s = ds.duration()
-        h2d = (transfer_nbytes((idxs, params)) if uploaded else 0) \
-            + (staged.lits.nbytes if staged.lits is not None else 0)
+        h2d = transfer_nbytes((idxs, params)) if uploaded else 0
         node = prof.tree(staged.mode, staged.sig, jit_hit, plan_s, h2d,
                          staged.n_shards)
         prof.tree_dispatch(node, dispatch_s)
@@ -2156,7 +2189,7 @@ class Executor:
         return view.trimmed_words() * 32 <= CONTAINER_BITS
 
     def _plan_slot_leaf(self, field: Field, view_name: str, row_id: int,
-                        shards, plan: _Plan):
+                        shards, plan: _Plan, fresh: bool = False):
         """A single-row leaf: bank[slot] with the slot traced, padded to
         the plan width (banks are width-trimmed per view). The slot value
         is a placeholder until _eval_tree builds the bank. Over a
@@ -2165,9 +2198,10 @@ class Executor:
         dense register on device (ops/megakernel.expand_positions) —
         bit-identical to the dense gather, under a distinct signature
         so the two layouts never share a compiled program or a cached
-        result entry."""
+        result entry. `fresh` gives the leaf an operand position of its
+        own (_Plan.bank)."""
         key = (field.name, view_name)
-        pos = plan.bank(key)
+        pos = plan.bank(key, fresh)
         sparse = plan.bank_sparse.get(key)
         if sparse is None:
             sparse = self._leaf_sparse(field, view_name, key, plan)
@@ -2215,32 +2249,74 @@ class Executor:
                 plan.ir.append(("zero",))
                 return (lambda b, i, p, l:
                         jnp.zeros((len(shards), plan.width), jnp.uint32))
-            if len(views) <= MAX_STATIC_RANGE_VIEWS:
+            n = len(views)
+            if n <= MAX_STATIC_RANGE_VIEWS:
+                # An OR-fold of slot leaves, padded to a power of two
+                # with the last view's leaf again (x | x = x) at operand
+                # positions of its own: the signature and the operand
+                # list then move with the bucket, not with n.
+                plan.range_leaves.append(("fold", n))
                 subs = [self._plan_slot_leaf(field, vn, row_id, shards, plan)
                         for vn in views]
+                subs += [self._plan_slot_leaf(field, views[-1], row_id,
+                                              shards, plan, fresh=True)
+                         for _ in range(_pow2(n) - n)]
                 plan.sig_parts.append(f"U{len(subs)}")
                 plan.ir.append(("fold", "or", len(subs)))
                 return lambda b, i, p, l: functools.reduce(
                     jnp.bitwise_or, [s(b, i, p, l) for s in subs])
-            # Literal: precompute the union eagerly, pass as one operand.
-            # Subset banks of exactly one row per time view — a multi-year
-            # hourly range must not materialize every row of every view.
-            from pilosa_tpu.ops.bitset import union_many
-            stacks = [self._get_bank_for(field, vn, shards,
-                                         rows_needed={row_id})
-                      for vn in views]
-            wmax = max(bk.array.shape[-1] for bk in stacks)
-            plan.widths.append(wmax)
-            arr = union_many(jnp.stack(
-                [_pad_words(bk.array[bk.slot(row_id)], wmax)
-                 for bk in stacks]), axis=0)
+            # Literal: the grouped fold's union, passed as one operand.
+            plan.range_leaves.append(("grouped", n))
+            arr = self._range_union_grouped(field, views, row_id, shards)
+            plan.widths.append(arr.shape[-1])
             k = len(plan.literals)
             plan.literals.append(arr)
             plan.sig_parts.append(f"l{k}")
             plan.ir_ok = False  # literal content is not plan-buffer data
-            return lambda b, i, p, l: l[k]
+            return lambda b, i, p, l: _align_words(l[k], plan.width)
         return self._plan_slot_leaf(field, VIEW_STANDARD, row_id, shards,
                                     plan)
+
+    def _range_union_grouped(self, field: Field, views: List[str],
+                             row_id: int, shards):
+        """The [S, W] union of `row_id` over more views than one tree
+        program folds: groups of MAX_STATIC_RANGE_VIEWS run the same
+        OR-fold (bank operand + traced slot, a short last group padded
+        to a power of two) as a jitted program of its own, each taking
+        the one before's result as its accumulator. Subset banks of
+        exactly one row per time view — a multi-year hourly range must
+        not materialize every row of every view."""
+        import jax
+        import jax.numpy as jnp
+        banks = [self._get_bank_for(field, vn, shards, rows_needed={row_id})
+                 for vn in views]
+        width = max(bk.array.shape[-1] for bk in banks)
+
+        def fold(arrays, slots, acc):
+            rows = [_pad_words(a[slots[j]], width)
+                    for j, a in enumerate(arrays)]
+            return functools.reduce(
+                jnp.bitwise_or, rows if acc is None else [acc] + rows)
+
+        acc = None
+        for g in range(0, len(banks), MAX_STATIC_RANGE_VIEWS):
+            group = banks[g:g + MAX_STATIC_RANGE_VIEWS]
+            group += group[-1:] * (_pow2(len(group)) - len(group))
+            arrays = tuple(bk.array for bk in group)
+            key = (f"range_fold|W{width}|B{[a.shape for a in arrays]}"
+                   f"|A{acc is not None}")
+            fn = self._jit_get(key)
+            if fn is None:
+                self._note_jit_compile("range_fold", key)
+                fn = jax.jit(named(fold, "range_fold"))
+                self._jit_put(key, fn)
+            # graftlint: disable=GL003 — host slot list marshalled for
+            # upload; nothing is fetched.
+            slots = upload(np.asarray([bk.slot(row_id) for bk in group],
+                                      dtype=np.int32))
+            with self._dispatch_span("range_fold"):
+                acc = self._call_program(fn, arrays, slots, acc)
+        return acc
 
     def _plan_bsi_leaf(self, field: Field, cond: Condition, shards,
                        plan: _Plan):
@@ -3082,7 +3158,7 @@ class Executor:
                 # duplicate scatter writes the same recount) so patch
                 # kernels compile O(log churn) shapes, the fused-batch
                 # padding idiom.
-                pad = 1 << (len(sel) - 1).bit_length()
+                pad = _pow2(len(sel))
                 sel = sel + [sel[0]] * (pad - len(sel))
                 sel_dev = upload(np.asarray(sel, np.int32))
                 pkey = f"rankpatch:{bank.array.shape}:{pad}"

@@ -11,10 +11,12 @@ et al., arXiv:1402.6407) wins by amortizing per-op overhead across
 batched bitmap operations; this module is the dispatch-level analog.
 
 A compiled tree program is fully parameterized by its traced operand
-vectors (``idxs``, ``params``, ``lits``) under a shape signature
-``sig`` (Executor._stage_tree). So N staged evals with the same
+vectors (``idxs``, ``params``) under a shape signature ``sig``
+(Executor._stage_tree); an eval that also carries literal operands
+(``lits``, a time range past MAX_STATIC_RANGE_VIEWS) never enters a
+group. So N staged evals with the same
 ``(sig, bank identity)`` — same tree shape over the same device banks,
-different row ids / BSI predicates / literals — can stack their
+different row ids / BSI predicates — can stack their
 operand vectors along a new leading batch axis and run through ONE
 jitted ``vmap`` of the representative's program, returning ``[B, S]``
 counts or ``[B, S, W]`` row words that finalize slices per query.
@@ -182,11 +184,10 @@ class _FuseGroup:
             # arg cache) so a lone query costs nothing extra.
             fn, jit_hit = ex._tree_fn(rep)
             idxs, params, uploaded = ex._staged_args(rep)
-            h2d = ((idxs.nbytes + params.nbytes) if uploaded else 0) \
-                + (rep.lits.nbytes if rep.lits is not None else 0)
+            h2d = (idxs.nbytes + params.nbytes) if uploaded else 0
             with ex._dispatch_span(rep.program) as ds:
                 self.out = ex._call_program(fn, rep.bank_arrays, idxs,
-                                            params, rep.lits)
+                                            params, None)
             self._attribute(jit_hit, ds.duration(), h2d, fused=False)
             return
         # Pad to the next power of two with the first entry's operands
@@ -212,22 +213,19 @@ class _FuseGroup:
         akey = (key, tuple(tuple(e.idxs) for e in rows),
                 tuple(tuple(e.params) for e in rows))
         (idxs, params), uploaded = ex._cached_args(akey, build)
-        lits = None
-        if rep.lits is not None:
-            lits = jnp.stack([e.lits for e in rows])
         fn = ex._jit_get(key)
         jit_hit = fn is not None
         program = "fused_" + rep.program
         if fn is None:
             ex._note_jit_compile(program, key)
-            in_axes = (None, 0, 0, 0 if rep.lits is not None else None)
+            in_axes = (None, 0, 0, None)
             fn = jax.jit(named(jax.vmap(rep.runner(), in_axes=in_axes),
                                program))
             ex._jit_put(key, fn)
         with ex._dispatch_span(program) as ds:
             ds.set("fusedBatch", B)
             out = ex._call_program(fn, rep.bank_arrays, idxs, params,
-                                   lits)
+                                   None)
         dispatch_s = ds.duration()
         if bp != B:
             out = out[:B]  # drop pad lanes before anything reads them
@@ -246,8 +244,7 @@ class _FuseGroup:
         ex._note_fused(B)
         # Whole stacked upload (pad lanes included) spread over the B
         # real members, so the per-query sum equals the real traffic.
-        h2d = ((idxs.nbytes + params.nbytes) // B if uploaded else 0) \
-            + (rep.lits.nbytes if rep.lits is not None else 0)
+        h2d = (idxs.nbytes + params.nbytes) // B if uploaded else 0
         self._attribute(jit_hit, dispatch_s, h2d, fused=True)
 
     def _attribute(self, jit_hit: bool, dispatch_s: float, h2d: int,

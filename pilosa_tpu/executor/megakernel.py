@@ -203,8 +203,7 @@ class _MegaLaunch:
 
 def _eligible(group: Any) -> bool:
     rep = group.entries[0]
-    return rep.ir is not None and rep.mode in ("count", "row") \
-        and rep.lits is None
+    return rep.ir is not None and rep.mode in ("count", "row")
 
 
 def run_megakernel(executor: Any, groups: Dict[tuple, Any]

@@ -106,11 +106,16 @@ class API:
                               for d in ("h2d", "d2h")
                               for k in ("bytes", "transfers")]
                          + [("executor.bank_upload_bytes", 0)])
-        # ... and so are the TopN path counters: a share of them is
-        # read over a window in which one path may never be taken.
+        # ... and so are the TopN path and time-range leaf counters: a
+        # share of them is read over a window in which one path may
+        # never be taken.
         for path in Executor.TOPN_PATHS:
             self.stats.with_tags(f"path:{path}").count(
                 "executor.topn_sweeps", 0)
+        for path in Executor.RANGE_PATHS:
+            self.stats.with_tags(f"path:{path}").count(
+                "executor.range_leaves", 0)
+        self.stats.count("executor.range_views", 0)
         # The process-wide workload recorder (utils/hotspots.py)
         # increments its counters (pilosa_fragment_reads_total, ...)
         # straight into the stats client at record time so the
