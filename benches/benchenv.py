@@ -1,5 +1,5 @@
-"""Device-time measurement helpers shared by bench.py and benches/*:
-the salted chain-slope method and its physical-validity guard."""
+"""Device-time measurement helpers shared by the benches/ scripts: the
+salted chain-slope method and its physical-validity guard."""
 
 import time
 

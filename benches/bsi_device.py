@@ -1,13 +1,10 @@
-"""BSI device-time bench — the chain-slope companion to benches/bsi.py.
-
-benches/bsi.py measures BASELINE config 3 (int field, 10M columns)
-END-TO-END through the executor, where each op pays a dispatch and a
-blocking fetch on the host clock on top of its device work.
-This harness measures the DEVICE time of the same four fused BSI query
+"""BSI device-time bench: the DEVICE time of the four fused BSI query
 programs (Range >, Sum, Min, Max — reference fragment.go:767,794,827,
-857-1035) with the salted-chain slope method (utils/benchenv.py), which
+857-1035) with the salted-chain slope method (benches/benchenv.py), which
 cancels all host<->device round trips. The device time is the serving
-ceiling; together the two benches bracket reality from both sides.
+ceiling; end to end (a dispatch and a blocking fetch on top, per op) is
+`benchmark/run.py`'s `point-serial` cell, whose shapes carry Sum and
+BSI ranges.
 
 Bank shape matches config 3: depth+1 planes x 10 shards x 32768 words
 (10M columns of a 0..100k int field). Operands are generated on device
@@ -71,7 +68,7 @@ def main():
     import jax.numpy as jnp
     from pilosa_tpu.executor import bsi as B
     from pilosa_tpu.ops.bitset import WORDS_PER_SHARD, popcount
-    from pilosa_tpu.utils.benchenv import timed_fetch, validated_chain_slope
+    from benches.benchenv import timed_fetch, validated_chain_slope
 
     shape = (DEPTH + 1, N_SHARDS, WORDS_PER_SHARD)
     planes = jax.block_until_ready(
@@ -117,8 +114,7 @@ def main():
         mean_s = sum(op_seconds.values()) / len(op_seconds)
         emit({"metric": "bsi_device_ops_per_sec", "value": 1.0 / mean_s,
               "unit": "ops/sec", "backend": dev.platform,
-              "note": "device time only (chain slope); end-to-end with "
-              "dispatch is benches/bsi.py", "ops_measured":
+              "note": "device time only (chain slope)", "ops_measured":
               sorted(op_seconds)})
 
 

@@ -191,7 +191,7 @@ def bench_device_time_table():
     import jax
     import jax.numpy as jnp
     from pilosa_tpu.ops.bitset import popcount, WORDS_PER_SHARD
-    from pilosa_tpu.utils.benchenv import (make_salted_chain, timed_fetch,
+    from benches.benchenv import (make_salted_chain, timed_fetch,
                                            validated_chain_slope)
 
     rows = int(os.environ.get("PILOSA_MICRO_ROWS", 255))
@@ -220,27 +220,13 @@ def bench_device_time_table():
             jnp.bitwise_and((x + sx),
                             jnp.bitwise_not((y + sy))),
             axis=(-2, -1))),
-    }
-
-    from pilosa_tpu.ops import pallas_kernels
-    if pallas_kernels.available():
-        # Same sweeps through the hand-tiled Pallas kernels, so the
-        # XLA-vs-Pallas decision rests on device-time (slope) evidence.
-        kernels["pallas_sweep_popcount"] = (1, lambda x, y, sx, sy: (
-            pallas_kernels.bank_row_counts((x + sx))))
-        # Filter-mask sweep: streams ONE bank plus a broadcast [S, W]
+        # The filtered TopN's shape: ONE bank plus a broadcast [S, W]
         # filter row (nbanks=1 — crediting two banks would inflate its
-        # GB/s ~2x vs what it actually moves). Compare against the
-        # XLA equivalent of the same workload below, not against the
-        # two-full-bank sweep_and_popcount.
-        kernels["pallas_sweep_filter_popcount"] = (1, lambda x, y, sx, sy: (
-            pallas_kernels.bank_row_counts_masked(
-                (x + sx),
-                (y[0] + sy))[0]))
-        kernels["sweep_filter_popcount"] = (1, lambda x, y, sx, sy: popcount(
-            jnp.bitwise_and((x + sx),
-                            (y[0] + sy)),
-            axis=(-2, -1)))
+        # GB/s ~2x vs what it actually moves).
+        "sweep_filter_popcount": (1, lambda x, y, sx, sy: popcount(
+            jnp.bitwise_and((x + sx), (y[0] + sy)),
+            axis=(-2, -1))),
+    }
 
     dev = jax.devices()[0]
     for name, (nbanks, kern) in kernels.items():

@@ -23,7 +23,7 @@ Q = 64
 
 
 def main():
-    from pilosa_tpu.utils.benchenv import (timed_fetch,
+    from benches.benchenv import (timed_fetch,
                                            validated_chain_slope)
     from pilosa_tpu.utils.jaxenv import enable_compile_cache
     enable_compile_cache()

@@ -11,7 +11,7 @@ three ways, in ONE process:
 - `device_ms`: the body's XLA module duration in a profiler trace of those
   launches, with the names of the device ops inside it (the op name is
   what `topn_sweep_roofline` matches);
-- `chain_ms`: the salted chain-slope method of `utils/benchenv.py` (the
+- `chain_ms`: the salted chain-slope method of `benches/benchenv.py` (the
   filter is salted, the bank is loop-invariant as in serving; the
   unfiltered body salts the bank, one more VPU add per word).
 
@@ -301,7 +301,7 @@ def main():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from pilosa_tpu.utils.benchenv import (make_salted_chain, timed_fetch,
+    from benches.benchenv import (make_salted_chain, timed_fetch,
                                            validated_chain_slope)
     from pilosa_tpu.utils.jaxenv import enable_compile_cache
     enable_compile_cache()

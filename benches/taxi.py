@@ -1,4 +1,11 @@
-"""NYC-taxi-shaped multi-field workload — BASELINE.md config 5 (scaled).
+"""KEPT FOR ONE REASON ONLY: `benchmark/configs/taxi-chip.json` and
+`taxi-host4.json` cite this file under `assumed.extras`, and only a
+`benchmark` PR may edit them. It is not an instrument, nothing runs it,
+and its numbers are not the repo's: the taxi deployment is
+`benchmark/datasets/taxi.py`, measured by `benchmark/run.py`. The next
+`benchmark` issue re-cites that file and deletes this one (ROADMAP C12).
+
+NYC-taxi-shaped multi-field workload — BASELINE.md config 5 (scaled).
 
 The reference's flagship example (docs/examples.md:15-209): one index of
 rides with low-cardinality set fields (cab_type, passenger_count), BSI

@@ -24,7 +24,6 @@ prints no result line, when the server does not come up on --platform
 
     python chip_smoke.py                      # on the chip, full size
     python chip_smoke.py --platform cpu --shards 1 --grid-rows 15
-    python chip_smoke.py --pallas             # Pallas kernels, non-interpret
 
 The last stdout line is exactly {"ok": true, "device": {"platform":
 ..., "kind": ..., "count": ...}} — the device as the server's JAX
@@ -84,7 +83,7 @@ class Server:
     started: list = []   # every child ever started, for the deadline
 
     def __init__(self, data_dir: str, platform: str, mesh_devices: int,
-                 log_path: str, extra_env: dict):
+                 log_path: str):
         with socket.socket() as s:
             s.bind(("127.0.0.1", 0))
             self.port = s.getsockname()[1]
@@ -92,8 +91,7 @@ class Server:
         self.log_path = log_path
         # The caller's environment passes through untouched, so a
         # JAX_COMPILATION_CACHE_DIR set from outside places the cache.
-        env = dict(os.environ, PILOSA_TPU_MESH_DEVICES=str(mesh_devices),
-                   **extra_env)
+        env = dict(os.environ, PILOSA_TPU_MESH_DEVICES=str(mesh_devices))
         self._log = open(log_path, "wb")
         self.t_spawn = time.monotonic()
         self.proc = subprocess.Popen(
@@ -492,8 +490,7 @@ def serve_leg(name: str, data_dir: str, args, mesh_devices: int,
     """Start a server, run body(srv, info), SIGTERM it; every failure
     path stops the child."""
     log_path = os.path.join(out_dir, f"server_{name}.log")
-    extra = {"PILOSA_TPU_PALLAS": "1"} if args.pallas else {}
-    srv = Server(data_dir, args.platform, mesh_devices, log_path, extra)
+    srv = Server(data_dir, args.platform, mesh_devices, log_path)
     try:
         info = srv.wait_ready()
         start_s = time.monotonic() - srv.t_spawn
@@ -533,11 +530,6 @@ def main() -> int:
         HERE, "chiprun_out", "chip_smoke"))
     ap.add_argument("--deadline", type=int, default=1150,
                     help="give up (non-zero) after this many seconds")
-    ap.add_argument("--pallas", action="store_true",
-                    help="instead of the serving run: compile every "
-                         "Pallas kernel non-interpret at these shapes "
-                         "(tools/pallas_chip_check.py) and serve the "
-                         "same queries with PILOSA_TPU_PALLAS=1")
     args = ap.parse_args()
 
     def on_deadline(signum, frame):
@@ -567,24 +559,7 @@ def main() -> int:
 def run(args, data_dir: str) -> dict:
     t_run = time.monotonic()
     record = {"ok": False, "device": None, "versions": versions(),
-              "seed": args.seed, "pallas": args.pallas}
-    if args.pallas:
-        # Kernel-by-kernel first, in a child of its own (it holds the
-        # chip until it exits); the serving legs below then reach the
-        # same kernels through the executor.
-        out = subprocess.run(
-            [sys.executable, os.path.join(HERE, "tools",
-                                          "pallas_chip_check.py"),
-             "--platform", args.platform, "--shards", str(args.shards),
-             "--grid-rows", str(args.grid_rows)],
-            cwd=HERE, stdout=subprocess.PIPE, check=False)
-        sys.stderr.write(out.stdout.decode("utf-8", "replace"))
-        if out.returncode != 0:
-            raise SmokeFailure(f"pallas_chip_check exited "
-                               f"{out.returncode}")
-        record["pallas_kernels"] = json.loads(
-            out.stdout.decode().strip().splitlines()[-1])
-
+              "seed": args.seed}
     state = {}
 
     def cold(srv: Server, info: dict) -> dict:

@@ -2534,30 +2534,18 @@ class Executor:
         popcount + reduction over the whole bank, so only the query that
         reads it pays for it."""
         import jax
-        from pilosa_tpu.ops import pallas_kernels
         from pilosa_tpu.ops.bitset import masked_row_counts, popcount
-        use_pallas = pallas_kernels.enabled() and self.mesh is None
         program = self._counts_program(with_filter, with_raw)
-        key = f"topn:{with_filter}:{with_raw}:{shape}:{use_pallas}"
+        key = f"topn:{with_filter}:{with_raw}:{shape}"
         fn = self._jit_get(key)
         if fn is None:
             self._note_jit_compile(program, key)
             if with_filter:
-                if use_pallas:
-                    def run(chunk, filt):
-                        both = pallas_kernels.bank_row_counts_masked(
-                            chunk, filt)
-                        return both if with_raw else both[0]
-                else:
-                    def run(chunk, filt):
-                        return masked_row_counts(chunk, filt, with_raw)
+                def run(chunk, filt):
+                    return masked_row_counts(chunk, filt, with_raw)
             else:
-                if use_pallas:
-                    def run(chunk, filt):
-                        return pallas_kernels.bank_row_counts(chunk)
-                else:
-                    def run(chunk, filt):
-                        return popcount(chunk, axis=(-2, -1))
+                def run(chunk, filt):
+                    return popcount(chunk, axis=(-2, -1))
             fn = jax.jit(named(run, program))
             self._jit_put(key, fn)
         return fn

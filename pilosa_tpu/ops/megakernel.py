@@ -698,9 +698,7 @@ def verify_plan(plan: Plan, n_shards: int, w_mega: int,
       reads (per-opcode: ZERO reads nothing, COPY reads ``a``) is
       either a gathered slot or a scratch register some earlier
       instruction wrote. The interpreter zero-fills scratch, so a RAW
-      violation doesn't crash — it silently computes on zeros, the
-      exact hazard class that sank the grid-per-entry Pallas
-      formulation.
+      violation doesn't crash — it silently computes on zeros.
     * **Pad-tail no-ops** — instructions past ``n_instrs`` must be
       ``ZERO`` into a non-slot register that no real output lane
       reads: provably invisible to every result.

@@ -1,7 +1,7 @@
 """Process-level JAX set-up shared by every entry point that compiles
-(cmd_server, bench.py, benches/*): where the persistent compile cache
-lives, the description of the devices the process ended up on, and
-the log of every XLA compile the process makes."""
+(cmd_server, benches/*): where the persistent compile cache lives, the
+description of the devices the process ended up on, and the log of
+every XLA compile the process makes."""
 
 from __future__ import annotations
 

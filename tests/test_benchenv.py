@@ -1,18 +1,12 @@
-"""The device-time measurement helpers (pilosa_tpu/utils/benchenv.py),
-the HBM peak table they judge against (utils/roofline.resolve_roofline)
-and bench.py's contract off the chip: one process, no CPU record."""
+"""The device-time measurement helpers (benches/benchenv.py) and the HBM
+peak table they judge against (utils/roofline.resolve_roofline)."""
 
-import os
-import subprocess
-import sys
 from types import SimpleNamespace
 
 import pytest
 
-from pilosa_tpu.utils import benchenv
+from benches import benchenv
 from pilosa_tpu.utils.roofline import UnknownDeviceKind, resolve_roofline
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _dev(kind):
@@ -65,24 +59,3 @@ def test_validated_chain_slope_marks_above_roofline_invalid():
                                         _dev("TPU v5 lite"), reps=1)
     assert "invalid" not in ok
     assert ok["roofline_frac"] == pytest.approx(500.0 / 819.0)
-
-
-def test_bench_py_off_chip_exits_nonzero_with_no_record():
-    """No TPU: bench.py fails and prints nothing on stdout — no
-    cpu-fallback record, no CPU figure under the device metric."""
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    r = subprocess.run([sys.executable, os.path.join(REPO, "bench.py")],
-                       env=env, capture_output=True, text=True,
-                       timeout=120)
-    assert r.returncode != 0
-    assert r.stdout.strip() == ""
-    assert "not a tpu" in r.stderr
-
-
-def test_bench_py_is_one_process():
-    """A parent that has touched jax holds the chip: bench.py starts
-    no child, and has no child mode to be started in."""
-    with open(os.path.join(REPO, "bench.py")) as f:
-        src = f.read()
-    assert "subprocess" not in src and "--tpu-child" not in src
-    assert "cpu-fallback" not in src

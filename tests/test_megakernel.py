@@ -561,22 +561,17 @@ def test_pipelined_flushes_overlap_and_record_both_halves(ex, monkeypatch):
 
 def test_a_device_that_cannot_initialise_is_an_error(monkeypatch):
     """`auto` asks the backend which platform it is; a backend that
-    cannot start must raise, not read as "megakernel off" / "Pallas
-    off" and let the server carry on somewhere else."""
+    cannot start must raise, not read as "megakernel off" and let the
+    server carry on somewhere else."""
     import jax
-
-    from pilosa_tpu.ops import pallas_kernels
 
     def no_backend():
         raise RuntimeError("Unable to initialize backend 'tpu'")
 
     monkeypatch.setattr(jax, "devices", no_backend)
     monkeypatch.delenv("PILOSA_TPU_MEGAKERNEL", raising=False)
-    monkeypatch.delenv("PILOSA_TPU_NO_PALLAS", raising=False)
     with pytest.raises(RuntimeError, match="Unable to initialize"):
         megamod._default_enabled()
-    with pytest.raises(RuntimeError, match="Unable to initialize"):
-        pallas_kernels.available()
     # An explicit setting never asks the backend.
     monkeypatch.setenv("PILOSA_TPU_MEGAKERNEL", "0")
     assert megamod._default_enabled() is False

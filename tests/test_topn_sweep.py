@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pilosa_tpu.core.field import FieldOptions
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.executor import executor as ex_mod
@@ -191,14 +192,32 @@ def test_sweep_filter_pieces_bounds_the_pieces_at_every_width():
         assert left_over == 0 or (k, left_over) == (2, 1), n_words
 
 
-@pytest.mark.parametrize("shape", [(5, 3, 256), (7, 16, 1024), (4, 1, 128),
-                                   (3, 2, 200), (2, 4, 384), (3, 2, 896),
-                                   (2, 1, 1408), (2, 3, 640)])
+def _words(rng, shape, density) -> np.ndarray:
+    """uint32 words with about `density` of their bits set; 0 and 1 are
+    the empty and the full operand."""
+    if density in (0, 1):
+        return np.full(shape, 0xFFFFFFFF * density, np.uint32)
+    w = rng.integers(0, 2**32, shape, dtype=np.uint32)
+    if density < 0.5:
+        w &= rng.integers(0, 2**32, shape, dtype=np.uint32)
+    return w
+
+
+_SHAPES = [(5, 3, 256), (7, 16, 1024), (4, 1, 128), (3, 2, 200),
+           (2, 4, 384), (3, 2, 896), (2, 1, 1408), (2, 3, 640)]
+# Every shape at a uniform draw, and the edge densities (both operands
+# empty, sparse, full) at one wide (cut in pieces) and one narrow shape.
+_DRAWS = [(shape, 0.5) for shape in _SHAPES] + \
+    [(shape, d) for shape in [(7, 16, 1024), (4, 1, 128)]
+     for d in (0, 0.25, 1)]
+
+
+@pytest.mark.parametrize("shape,density", _DRAWS)
 @pytest.mark.parametrize("with_raw", [False, True])
-def test_masked_row_counts_matches_numpy(shape, with_raw):
+def test_masked_row_counts_matches_numpy(shape, density, with_raw):
     rng = np.random.default_rng(sum(shape))
-    bank = rng.integers(0, 2**32, shape, dtype=np.uint32)
-    filt = rng.integers(0, 2**32, shape[1:], dtype=np.uint32)
+    bank = _words(rng, shape, density)
+    filt = _words(rng, shape[1:], density)
     got = masked_row_counts(jnp.asarray(bank), jnp.asarray(filt), with_raw)
     want = np.bitwise_count(bank & filt).sum(axis=(1, 2))
     if with_raw:
@@ -207,6 +226,19 @@ def test_masked_row_counts_matches_numpy(shape, with_raw):
         got = got[0]
     assert got.dtype == jnp.uint32
     assert np.asarray(got).tolist() == want.tolist()
+
+
+@pytest.mark.parametrize("density", [0, 0.25, 0.5, 1])
+def test_unfiltered_sweep_matches_numpy(tmp_holder, density):
+    """`topn_sweep_unfiltered` as the executor builds it, against numpy,
+    on empty, sparse, uniform and full banks."""
+    shape = (7, 16, 1024)
+    bank = _words(np.random.default_rng(3), shape, density)
+    got = Executor(tmp_holder)._counts_fn(False, shape)(
+        jnp.asarray(bank), None)
+    assert got.dtype == jnp.uint32
+    assert np.asarray(got).tolist() == \
+        np.bitwise_count(bank).sum(axis=(1, 2)).tolist()
 
 
 @pytest.mark.parametrize("tanimoto,program,vectors", [
@@ -245,3 +277,67 @@ def test_sweep_fetches_one_vector_unless_tanimoto_reads_two(
     finally:
         TIMELINE.reset()
         TIMELINE.configure(enabled=True, ring=256, sample_every=1)
+
+
+def test_pbank_search_membership_matches_compare(tmp_path, monkeypatch):
+    """The searchsorted membership form answers identically to the
+    compare form through the full executor tanimoto path."""
+    def build(d):
+        h = Holder(d)
+        h.open()
+        idx = h.create_index("m")
+        f = idx.create_field("fp", FieldOptions(max_columns=512))
+        view = f.create_view_if_not_exists("standard")
+        frag = view.create_fragment_if_not_exists(0)
+        rng = np.random.default_rng(9)
+        cpr = SHARD_WIDTH // 65536
+        for i in range(3000):
+            frag.storage.containers[i * cpr] = np.unique(
+                rng.integers(0, 512, 24, dtype=np.uint16))
+            frag._touch_row(i)
+        return h
+
+    monkeypatch.setattr(ex_mod, "TOPN_MAX_BANK_BYTES", 1)
+    q = ("TopN(fp, Row(fp=7), n=20, tanimotoThreshold=30)")
+    # Pin the baseline to "compare": the module default is "auto",
+    # which resolves to "search" on the CPU test mesh — without the
+    # pin this test would compare search against itself.
+    monkeypatch.setattr(ex_mod, "PBANK_MEMBERSHIP", "compare")
+    h1 = build(str(tmp_path / "a"))
+    (want,) = Executor(h1).execute("m", q)
+    h1.close()
+    monkeypatch.setattr(ex_mod, "PBANK_MEMBERSHIP", "search")
+    h2 = build(str(tmp_path / "b"))
+    (got,) = Executor(h2).execute("m", q)
+    h2.close()
+    assert got.pairs == want.pairs and want.pairs
+
+
+def test_pbank_membership_auto_resolves_per_backend(tmp_path,
+                                                    monkeypatch):
+    """'auto' (the default) must resolve to 'search' on the XLA CPU
+    backend and be cached under the RESOLVED name, so
+    an explicit-'search' run shares the same compiled kernel."""
+    assert jax.devices()[0].platform == "cpu"  # test mesh is CPU-forced
+    monkeypatch.setattr(ex_mod, "PBANK_MEMBERSHIP", "auto")
+    monkeypatch.setattr(ex_mod, "TOPN_MAX_BANK_BYTES", 1)
+    monkeypatch.setattr(ex_mod.Executor, "_PBANK_KERNELS", {})
+    h = Holder(str(tmp_path / "auto"))
+    h.open()
+    idx = h.create_index("m")
+    f = idx.create_field("fp", FieldOptions(max_columns=512))
+    view = f.create_view_if_not_exists("standard")
+    frag = view.create_fragment_if_not_exists(0)
+    rng = np.random.default_rng(11)
+    cpr = SHARD_WIDTH // 65536
+    for i in range(512):
+        frag.storage.containers[i * cpr] = np.unique(
+            rng.integers(0, 512, 24, dtype=np.uint16))
+        frag._touch_row(i)
+    (res,) = Executor(h).execute(
+        "m", "TopN(fp, Row(fp=3), n=5, tanimotoThreshold=20)")
+    h.close()
+    assert res.pairs
+    forms = {key[3] for key in ex_mod.Executor._PBANK_KERNELS}
+    assert "search" in forms
+    assert "auto" not in forms

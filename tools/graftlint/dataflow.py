@@ -25,8 +25,7 @@ from tools.graftlint.engine import SourceFile, dotted_name
 
 SYNC_METHODS = {"item", "tolist", "block_until_ready"}
 DEVICE_MODULE_PREFIXES = ("jnp.", "jax.")
-OPS_MODULES = ("pilosa_tpu.ops.bitset", "pilosa_tpu.ops.pallas_kernels",
-               "pilosa_tpu.ops")
+OPS_MODULES = ("pilosa_tpu.ops.bitset", "pilosa_tpu.ops")
 # ops.bitset exports that compute ON THE HOST (numpy in, numpy/int
 # out): packing/unpacking, byte accounting, numpy mask builders. Their
 # results carry no device taint — treating them as device producers
@@ -55,7 +54,7 @@ def imports_jax(sf: SourceFile) -> bool:
 
 def imported_device_fns(sf: SourceFile) -> Set[str]:
     """Names imported from pilosa_tpu.ops.* — calls to these produce
-    device arrays (b_and, popcount, pallas kernels, ...)."""
+    device arrays (b_and, popcount, ...)."""
     fns: Set[str] = set()
     for node in ast.walk(sf.tree):
         if isinstance(node, ast.ImportFrom) \

@@ -62,8 +62,7 @@ class Config:
         "pilosa_tpu/ops/", "pilosa_tpu/executor/",
         "pilosa_tpu/storage/roaring.py")
     # GL005: files whose array dtypes are constrained to bitset words.
-    word_dtype_paths: Tuple[str, ...] = (
-        "pilosa_tpu/ops/bitset.py", "pilosa_tpu/ops/pallas_kernels.py")
+    word_dtype_paths: Tuple[str, ...] = ("pilosa_tpu/ops/bitset.py",)
     # GL001 (module-state sub-rule): packages where module-level mutable
     # state must be lock-guarded.
     state_paths: Tuple[str, ...] = (

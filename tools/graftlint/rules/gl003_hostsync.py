@@ -3,7 +3,7 @@
 In the configured hot-path files (ops/, executor/, storage/roaring.py)
 every device->host materialization must happen at an explicitly
 allow-listed boundary. The paper-side invariant: bitmap loops stay on
-device as packed-word XLA/Pallas ops; a stray ``.item()`` or
+device as packed-word XLA ops; a stray ``.item()`` or
 ``np.asarray`` mid-pipeline serializes the dispatch queue and drags a
 128 KiB shard row through the host per call.
 

@@ -1,7 +1,7 @@
 """GL005 — bitset word dtype invariant.
 
-In the word-kernel files (ops/bitset.py, ops/pallas_kernels.py) every
-array creation and cast must stay on the packed-word dtype lattice:
+In the word-kernel file (ops/bitset.py) every array creation and cast
+must stay on the packed-word dtype lattice:
 
 - allowed: uint8/uint16/uint32/uint64 (words and sub-word views),
   int32 (popcount accumulators — the TPU VPU's native reduce dtype),
@@ -80,7 +80,7 @@ class GL005DtypeInvariant(Rule):
                             sf, node, f"scalar cast `{fn}(...)`"))
                     elif name in _CREATORS:
                         self._check_creator(sf, node, fn, out)
-            # dtype= keyword on any other call (pallas ShapeDtypeStruct,
+            # dtype= keyword on any other call (jax.ShapeDtypeStruct,
             # jnp.sum(dtype=...), ...)
             for kw in node.keywords:
                 if kw.arg == "dtype":
