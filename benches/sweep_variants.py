@@ -19,8 +19,14 @@ three ways, in ONE process:
 candidate's counts are compared with the first variant's on the device
 and with numpy on a few rows; a mismatch fails the run.
 
+A group's body (`ops/bitset.masked_row_counts_multi`, the program
+`topn_sweep_multi`) takes K filters and is timed the same way, filters
+rotating through its K operands; its rows also carry `device_ms_per_filter`,
+and every lane is compared with the one-filter body's counts.
+
 The run also fails when the SHIPPED bodies (`ops/bitset.masked_row_counts`,
-with and without the raw popcounts) stop compiling to what their rate
+with and without the raw popcounts, and `masked_row_counts_multi` at 2 and
+4 filters) stop compiling to what their rate
 rests on: one fusion reading the bank, named `popcnt_reduce_fusion` (the
 op `topn_sweep_roofline` matches), whose windows — wherever the filter is
 cut — are wide enough that the row reductions hide behind the bank's bytes
@@ -63,15 +69,42 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 SHIPPED = ("shipped", "shipped_with_raw")
+# The shipped body of a group of K filters (`topn_sweep_multi`), by K.
+SHIPPED_MULTI = {f"shipped_multi{k}": k for k in (2, 4)}
+CHECKED = SHIPPED + tuple(SHIPPED_MULTI)    # bodies _check_shipped holds
+N_FILTERS = 8
+
+
+def _n_filters(body):
+    """How many filter operands a body takes: one, or the K of a
+    multi-filter body (its `filters` attribute)."""
+    return getattr(body, "filters", 1)
 
 
 def _variants(R, S, W):
-    """name -> body(c, f); every body names the bank `c` (_bank_fusions
-    finds the fusions that read it by that name)."""
+    """name -> body(c, f) or, with a `filters` attribute K, body(c, f0 ..
+    f{K-1}) -> [K, R]; every body names the bank `c` (_bank_fusions finds
+    the fusions that read it by that name)."""
     import jax.numpy as jnp
     from jax import lax
-    from pilosa_tpu.ops.bitset import (masked_row_counts, popcount,
+    from pilosa_tpu.ops.bitset import (masked_row_counts,
+                                       masked_row_counts_multi, popcount,
                                        sweep_filter_pieces)
+
+    def multi(k, body):
+        def run(c, *fs):
+            return body(c, *fs)
+        run.filters = k
+        return run
+
+    def multi_pieces(p):           # K filters x p word pieces
+        cuts = [slice(w0, w0 + W // p) for w0 in range(0, W, W // p)]
+
+        def run(c, *fs):
+            return jnp.stack([sum(popcount(c[..., w] & f[..., w],
+                                           axis=(-2, -1)) for w in cuts)
+                              for f in fs])
+        return run
 
     def two_output(c, f):          # the parent's body
         return popcount(c & f, axis=(-2, -1)), popcount(c, axis=(-2, -1))
@@ -121,6 +154,14 @@ def _variants(R, S, W):
             2 * served, True)
     if S % 8 == 0 and S > 8:
         out["shard_slabs8"] = shard_slabs(8)
+    for name, k in SHIPPED_MULTI.items():
+        out[name] = multi(k, masked_row_counts_multi)
+    # Other piece counts than the rule's, and the eight lanes not built.
+    for k, p in ((2, 4), (4, 1), (8, 1), (8, 2)):
+        shipped = k in SHIPPED_MULTI.values() \
+            and p == sweep_filter_pieces(W, k)
+        if W % (128 * p) == 0 and not shipped:
+            out[f"multi{k}_pieces{p}"] = multi(k, multi_pieces(p))
     return out
 
 
@@ -162,7 +203,8 @@ def _check_shipped(name, fusions, shape):
     Returns the reductions' estimated ms."""
     from pilosa_tpu.ops.bitset import sweep_filter_pieces
     R, S, W = shape
-    pieces = sweep_filter_pieces(W)
+    filters = SHIPPED_MULTI.get(name, 1)
+    pieces = sweep_filter_pieces(W, filters)
     expect = 2 if W // 128 % pieces else 1
     if len(fusions) != expect:
         raise AssertionError(f"{name}: {len(fusions)} fusions read the "
@@ -172,9 +214,10 @@ def _check_shipped(name, fusions, shape):
             raise AssertionError(f"{name}: the bank's fusion is `{op}`")
     # The widest fusion is the equal pieces'; the other has one piece.
     widest = max(fusions, key=lambda f: f[1][2] * f[2][2])
+    outputs = 2 if name == "shipped_with_raw" else filters
     reduce_ms = sum(
         R * iters[1] * iters[2] * (pieces if f is widest else 1)
-        * (2 if name == "shipped_with_raw" else 1) * ROW_STEP_NS / 1e6
+        * outputs * ROW_STEP_NS / 1e6
         for f in fusions for iters in (f[2],))
     stream_ms = R * S * W * 4 / SWEEP_GBPS / 1e6
     if pieces > 1 and reduce_ms > stream_ms / 0.7:
@@ -256,10 +299,11 @@ def describe(args):
     for name, body in variants.items():
         rec = {"variant": name, "shape": [R, S, W], "mesh": args.mesh}
         try:
-            hlo = jax.jit(body).lower(bank, filt).compile().as_text()
+            hlo = jax.jit(body).lower(
+                bank, *[filt] * _n_filters(body)).compile().as_text()
             rec["bank_fusions"] = _bank_fusions(hlo)
             rec["collectives"] = _collectives(hlo)
-            if name in SHIPPED:
+            if name in CHECKED:
                 rec["reduce_ms_estimate"] = _check_shipped(
                     name, rec["bank_fusions"], (R, S // args.mesh, W))
                 # One all-reduce of the counts (with the raw popcounts
@@ -319,8 +363,14 @@ def main():
     # ~25 % density, as an AND of two draws; the rate does not depend on it.
     bank = bits(k_bank, (R, S, W)) & bits(jax.random.fold_in(k_bank, 1),
                                           (R, S, W))
-    filts = [bits(jax.random.fold_in(k_filt, i), (S, W)) for i in range(4)]
+    filts = [bits(jax.random.fold_in(k_filt, i), (S, W))
+             for i in range(N_FILTERS)]
     jax.block_until_ready((bank, filts))
+
+    def operands(body, i=0):
+        """The filter operands of launch `i` of `body`: K of them,
+        rotating, so lane 0 of launch 0 is filts[0]'s."""
+        return [filts[(i + j) % N_FILTERS] for j in range(_n_filters(body))]
 
     sample = [0, 1, R // 2, R - 1]
     host_rows = np.asarray(bank[np.asarray(sample)])
@@ -329,6 +379,7 @@ def main():
     want_u = np.bitwise_count(host_rows).sum(axis=(1, 2))
 
     variants = _variants(R, S, W)
+    jitted_ref = jax.jit(variants["one_output"])
     if args.only:
         variants = {k: v for k, v in variants.items()
                     if k in args.only.split(",")}
@@ -340,16 +391,28 @@ def main():
             body.__name__ = body.__qualname__ = f"sv_{name}"
             fn = jax.jit(body)
             t0 = time.perf_counter()
-            compiled = fn.lower(bank, filts[0]).compile()
+            compiled = fn.lower(bank, *operands(body)).compile()
             rec["compile_s"] = time.perf_counter() - t0
             cost = compiled.cost_analysis() or {}
             rec["bytes_accessed"] = cost.get("bytes accessed")
             if on_tpu:
                 rec["bank_fusions"] = _bank_fusions(compiled.as_text())
-                if name in SHIPPED:
+                if name in CHECKED:
                     rec["reduce_ms_estimate"] = _check_shipped(
                         name, rec["bank_fusions"], (R, S, W))
-            got = np.asarray(_first(fn(bank, filts[0])))
+            out = fn(bank, *operands(body))
+            if _n_filters(body) > 1:
+                # Lane k is filter k's counts, bit for bit the one-filter
+                # body's.
+                lanes = np.asarray(out)
+                for k, f in enumerate(operands(body)):
+                    one = np.asarray(_first(jitted_ref(bank, f)))
+                    if not np.array_equal(lanes[k], one):
+                        raise AssertionError(f"{name}: lane {k} differs "
+                                             "from one_output")
+                got = lanes[0]
+            else:
+                got = np.asarray(_first(out))
             want = want_u if name == "unfiltered" else want_f
             if got[sample].tolist() != want.tolist():
                 raise AssertionError(f"{name}: {got[sample]} != {want}")
@@ -368,25 +431,27 @@ def main():
         print(json.dumps({"platform": dev.platform, "rows": rows}))
         return 0 if all("error" not in r for r in rows) else 1
 
-    def launches(fn, n):
-        out = None
+    def launches(name, n):
+        fn, out = jitted[name], None
+        # The operand lists are made before the clock starts.
+        ops = [operands(variants[name], i) for i in range(N_FILTERS)]
         t0 = time.perf_counter()
         for i in range(n):
-            out = fn(bank, filts[i % len(filts)])
+            out = fn(bank, *ops[i % N_FILTERS])
         jax.block_until_ready(out)
         return (time.perf_counter() - t0) / n * 1e3
 
     by_name = {r["variant"]: r for r in rows}
-    for name, fn in jitted.items():
-        launches(fn, 4)
-        ts = sorted(launches(fn, args.launches) for _ in range(3))
+    for name in jitted:
+        launches(name, 4)
+        ts = sorted(launches(name, args.launches) for _ in range(3))
         by_name[name]["launch_ms"] = ts[1]
         by_name[name]["launch_ms_min"] = ts[0]
 
     trace_dir = os.path.join(args.out, "trace")
     with jax.profiler.trace(trace_dir):
-        for fn in jitted.values():
-            launches(fn, 8)
+        for name in jitted:
+            launches(name, 8)
     for mod, (durs, names) in _device_times(trace_dir).items():
         rec = by_name.get(mod.replace("jit_sv_", "", 1))
         if rec is not None:
@@ -394,12 +459,14 @@ def main():
             rec["device_ops"] = names
             sweeps = sum(n for op, n in names.items() if re.fullmatch(
                 r"popcnt_reduce_fusion(\.\d+)*", op))
-            if rec["variant"] in SHIPPED and \
+            if rec["variant"] in CHECKED and \
                     sweeps != len(durs) * len(rec["bank_fusions"]):
                 rec["error"] = (f"{sweeps} popcnt_reduce_fusion ops in "
                                 f"{len(durs)} launches: {names}")
 
     for name, fn in jitted.items():
+        if _n_filters(variants[name]) > 1:
+            continue    # the chain salts one filter: launches and the trace
         if name == "unfiltered":
             chain = make_salted_chain(
                 lambda x, y, sx, sy: _first(fn(x + sx, y)))
@@ -420,6 +487,10 @@ def main():
         for k in ("launch_ms", "device_ms", "chain_ms"):
             if k in rec:
                 rec[k.replace("_ms", "_gbps")] = bank_bytes / rec[k] / 1e6
+        filters = _n_filters(variants[rec["variant"]])
+        if filters > 1 and "device_ms" in rec:
+            rec["filters"] = filters
+            rec["device_ms_per_filter"] = rec["device_ms"] / filters
     record = {"platform": dev.platform, "device_kind": dev.device_kind,
               "shape": [R, S, W], "bank_bytes": bank_bytes,
               "launches": args.launches, "rows": rows}

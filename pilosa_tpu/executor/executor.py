@@ -881,6 +881,24 @@ class Executor:
             self.stats.with_tags(f"path:{path}").count(
                 "executor.topn_sweeps", 1)
 
+    def _note_sweep_launch(self) -> None:
+        """One bank-sweep program launched, whichever of the four:
+        `executor.sweep_launches`."""
+        if self.stats is not None:
+            self.stats.count("executor.sweep_launches", 1)
+
+    def _note_sweep_group(self, lanes: int, members: int) -> None:
+        """One launch swept a resident bank for `members` filtered TopN
+        calls in `lanes` lanes: `executor.sweep_group_filters{k:<lanes>}`
+        counts the members (over every k: the filtered resident TopN
+        calls), `executor.sweep_pad_lanes` the lanes nobody reads."""
+        if self.stats is not None:
+            self.stats.with_tags(f"k:{lanes}").count(
+                "executor.sweep_group_filters", members)
+            if lanes > members:
+                self.stats.count("executor.sweep_pad_lanes",
+                                 lanes - members)
+
     # How a time-range leaf's union was staged, one of these per leaf,
     # counted under `executor.range_leaves{path:<p>}`: `fold`, slot
     # leaves OR-ed inside the tree program (up to
@@ -2553,10 +2571,60 @@ class Executor:
     @staticmethod
     def _counts_program(with_filter: bool, with_raw: bool = False) -> str:
         """The TopN bank sweep's name in traces: which of the three
-        programs a call's arguments selected."""
+        one-filter programs a call's arguments selected (a group of
+        sweeps runs a fourth, `topn_sweep_multi`)."""
         if not with_filter:
             return "topn_sweep_unfiltered"
         return "topn_sweep_tanimoto" if with_raw else "topn_sweep"
+
+    def _counts_multi_fn(self, bank_array, filt, lanes: int) -> Callable:
+        """jit: bank [R, S, W], `lanes` filters [S, W] each -> counts
+        [lanes, R] from one pass over the bank. Every lane count of a
+        bank shape is built, and compiled, the first time a group of
+        that shape forms (one discarded launch each, on that group's
+        bank and `filt`): a flush's remainders reach the rarer ones
+        late, and a compile belongs to warm-up, where `retraces` says
+        it happened."""
+        import jax
+        from pilosa_tpu.executor.fusion import SWEEP_LANES
+        from pilosa_tpu.ops.bitset import masked_row_counts_multi
+        fn = self._jit_get(f"topn_multi:{lanes}:{bank_array.shape}")
+        if fn is None:
+            for k in SWEEP_LANES:
+                key = f"topn_multi:{k}:{bank_array.shape}"
+                self._note_jit_compile("topn_sweep_multi", key)
+                built = jax.jit(
+                    named(masked_row_counts_multi, "topn_sweep_multi"))
+                self._jit_put(key, built)
+                if k == lanes:
+                    fn = built
+                else:
+                    built(bank_array, *[filt] * k)
+        return fn
+
+    def _dispatch_sweep_group(self, bank_array, filters):
+        """Queue ONE program for the filtered sweeps of one bank that a
+        begin half staged (fusion.FusionCollector.add_sweep); returns
+        (unfetched device output, lanes). One filter: `topn_sweep`, as
+        outside a batch, output [R], lanes 1. More: `topn_sweep_multi`
+        at the next lane count, output [lanes, R]; the last filter
+        fills the pad lanes again, at operand positions of their own,
+        and nobody reads them."""
+        from pilosa_tpu.executor.fusion import SWEEP_LANES
+        n = len(filters)
+        if n == 1:
+            self._note_sweep_group(1, 1)
+            return self._dispatch_counts(bank_array, filters[0]), 1
+        lanes = next(k for k in SWEEP_LANES if k >= n)
+        fn = self._counts_multi_fn(bank_array, filters[0], lanes)
+        with self._dispatch_span("topn_sweep_multi") as ds:
+            ds.set("filters", n)
+            ds.set("lanes", lanes)
+            out = self._call_program(
+                fn, bank_array, *filters, *[filters[-1]] * (lanes - n))
+        self._note_sweep_launch()
+        self._note_sweep_group(lanes, n)
+        return out, lanes
 
     def _dispatch_counts(self, bank_array, filter_words,
                          with_raw: bool = False):
@@ -2574,7 +2642,9 @@ class Executor:
         # dispatches too.
         with self._dispatch_span(
                 self._counts_program(with_filter, with_raw)):
-            return self._call_program(fn, bank_array, filter_words)
+            out = self._call_program(fn, bank_array, filter_words)
+        self._note_sweep_launch()
+        return out
 
     def _popcount_row(self, words):
         """Dispatch a total popcount over row words [S, W] (device)."""
@@ -2740,9 +2810,21 @@ class Executor:
             self._note_topn("resident")
             bank = view.device_bank(tuple(shards), mesh=self.mesh,
                                     trim=True)
-            dispatched.append(
-                (all_rows, bank, self._dispatch_counts(
-                    bank.array, filter_words, with_raw)))
+            fuser = getattr(self._tls, "fuser", None)
+            if fuser is not None and filter_words is not None \
+                    and not with_raw:
+                # Inside a batch the sweep waits for its bankmates: the
+                # filtered sweeps of one bank array share one pass
+                # (fusion.FusionCollector.add_sweep). The lane holds the
+                # array read here, as a dispatch would.
+                out = fuser.add_sweep(bank.array, _align_words(
+                    filter_words, bank.array.shape[-1]))
+            else:
+                out = self._dispatch_counts(bank.array, filter_words,
+                                            with_raw)
+                if filter_words is not None:
+                    self._note_sweep_group(1, 1)
+            dispatched.append((all_rows, bank, out))
         else:
             if PBANK_ENABLED and self.mesh is None and len(shards) == 1 \
                     and allowed_rows is None and not ids_arg and n \

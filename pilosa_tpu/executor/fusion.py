@@ -32,6 +32,15 @@ Write fencing is the collector's caller's job: ``execute_batch``
 flushes the collector before dispatching any write-containing request
 and dispatches that request uncollected, so no read fuses across a
 write that orders between them (tests/test_fusion.py pins this).
+
+The same collector holds the batch's filtered TopN sweeps (``add_sweep``).
+They are not a ``_FuseGroup``: a fusion group's members are one program
+over the same operands with different scalars, stacked and vmapped; a
+sweep group's members each bring a filter of their own, the output of
+whatever tree program made it, and share only the bank. So there is no
+signature to equate and nothing to stack: the group's one program takes
+the bank and K filter operands and reads the bank once
+(``ops/bitset.masked_row_counts_multi``; tests/test_sweep_groups.py).
 """
 
 from __future__ import annotations
@@ -287,15 +296,82 @@ class _FuseGroup:
                 WORKLOAD.note_eval_seconds(e.fp, per_eval)
 
 
+# A sweep group launches as soon as it holds this many filters: the
+# chip starts on them while the host stages the rest of the flush. Its
+# program exists in these lane counts; a group of one runs the
+# one-filter sweep, as a call outside a batch does. Eight lanes are not among them:
+# XLA splits sixteen outputs into two fusions, each reading the bank.
+SWEEP_GROUP_MAX = 4
+SWEEP_LANES = (2, 4)
+
+
+class SweepLane(FusedEval):
+    """One TopN's lane of a sweep group's `[K, R]` counts: the
+    FusedEval surface over a `_SweepGroup`. The group's array is
+    fetched once, pad lanes and all, so the pad lanes' bytes ride on
+    its first member and a `d2h` span counts the array once."""
+
+    __slots__ = ()
+
+    @property
+    def nbytes(self) -> int:
+        pads = self.group.pad_lanes if self.b == 0 else 0
+        return self.slice_nbytes * (1 + pads)
+
+
+class _SweepGroup:
+    """The filtered TopN sweeps of one begin half that read the same
+    bank array. It holds the array object its members read at staging,
+    so a later write in the batch, which builds a new array, changes
+    nothing they sweep."""
+
+    __slots__ = ("executor", "bank", "filters", "out", "host", "batched",
+                 "error", "pad_lanes", "__weakref__")
+
+    def __init__(self, executor: Any, bank: Any) -> None:
+        self.executor = executor
+        self.bank = bank                  # [R, S, W] device array
+        self.filters: List[Any] = []      # [S, W] device arrays
+        self.out = None                   # [K, R] (or [R] alone)
+        self.host: Optional[np.ndarray] = None
+        self.batched = False
+        self.error: Optional[Exception] = None
+        self.pad_lanes = 0
+
+    def add(self, filt: Any) -> SweepLane:
+        self.filters.append(filt)
+        return SweepLane(self, len(self.filters) - 1,
+                         (self.bank.shape[0],))
+
+    def run(self) -> None:
+        """Launch the group's one program. Never raises (the
+        _FuseGroup.run contract): a failure surfaces per member when
+        its request finalizes, and harms no batchmate."""
+        if self.out is not None or self.error is not None:
+            return
+        try:
+            self.out, lanes = self.executor._dispatch_sweep_group(
+                self.bank, self.filters)
+            self.batched = lanes > 1
+            self.pad_lanes = lanes - len(self.filters)
+        except Exception as e:
+            self.error = e
+        finally:
+            self.bank = None
+            self.filters = []
+
+
 class FusionCollector:
     """Per-batch registry of staged terminal evals, grouped by fusion
-    key. Installed thread-locally by execute_batch (Executor._fusing);
-    `flush()` runs every open group — called before a write-containing
-    request dispatches (the fence) and once after the dispatch loop."""
+    key, and of staged bank sweeps, grouped by bank. Installed
+    thread-locally by execute_batch (Executor._fusing); `flush()` runs
+    every open group — called before a write-containing request
+    dispatches (the fence) and once after the dispatch loop."""
 
     def __init__(self, executor: Any) -> None:
         self.executor = executor
         self.groups: Dict[tuple, _FuseGroup] = {}
+        self.sweeps: Dict[tuple, _SweepGroup] = {}
 
     def add(self, staged: Any, prof: Any, plan_s: float) -> FusedEval:
         """Stage one eval; returns its FusedEval handle. Grouping is
@@ -309,7 +385,29 @@ class FusionCollector:
             group = self.groups[key] = _FuseGroup(self.executor)
         return group.add(staged, prof, plan_s)
 
+    def add_sweep(self, bank: Any, filt: Any) -> SweepLane:
+        """Stage one filtered sweep of `bank` ([R, S, W]) under `filt`
+        ([S, W], aligned to the bank's width); returns its lane.
+        Grouping is by the bank ARRAY's identity, as in `add`: what
+        decides a group's size is how many sweeps of this begin half
+        hold the same array, and nothing else. A full group launches
+        here."""
+        key = (id(bank), bank.shape)
+        group = self.sweeps.get(key)
+        if group is None:
+            group = self.sweeps[key] = _SweepGroup(self.executor, bank)
+        lane = group.add(filt)
+        if lane.b + 1 == SWEEP_GROUP_MAX:
+            del self.sweeps[key]
+            group.run()
+        return lane
+
     def flush(self) -> None:
+        # The sweeps first: their filters are already in flight, and
+        # they are the long device work of the flush.
+        sweeps, self.sweeps = self.sweeps, {}
+        for sweep in sweeps.values():
+            sweep.run()
         groups, self.groups = self.groups, {}
         if not groups:
             return

@@ -124,25 +124,46 @@ def count_and(a: ArrayLike, b: ArrayLike) -> jax.Array:
 
 # A filtered bank sweep's filter is cut into word-axis pieces of this many
 # words per shard, and into no more pieces than this: the one fusion that
-# reads the bank has an output a piece (two `with_raw`), and with sixteen
-# it loses a third of its rate (see masked_row_counts).
+# reads the bank has an output a piece and filter (two `with_raw`), and
+# with sixteen it loses a third of its rate (see masked_row_counts).
 SWEEP_PIECE_WORDS = 8192
 SWEEP_MAX_PIECES = 4
 
 
-def sweep_filter_pieces(n_words: int) -> int:
+def sweep_filter_pieces(n_words: int, filters: int = 1) -> int:
     """How many equal word-axis pieces masked_row_counts cuts a filter of
     `n_words` words per shard into: pieces of SWEEP_PIECE_WORDS, at least
     two and at most SWEEP_MAX_PIECES, each whole 128-word lanes. Where no
     such count divides the lanes (a prime number of them, say) the pieces
     are halves, and an odd last lane is left over as a short piece of its
-    own. One — the uncut body — under two whole lanes."""
+    own. One — the uncut body — under two whole lanes. A sweep of several
+    `filters` in one pass has an output a filter and piece, all of them
+    reduced in every window step, so it takes that many times fewer
+    pieces, and never under two."""
     lanes = n_words // 128
     if n_words % 128 or lanes < 2:
         return 1
-    want = max(2, n_words // SWEEP_PIECE_WORDS)
-    return next((k for k in range(want, SWEEP_MAX_PIECES + 1)
-                 if lanes % k == 0), 2)
+    most = max(2, SWEEP_MAX_PIECES // filters)
+    want = max(2, min(most, n_words // SWEEP_PIECE_WORDS))
+    return next((k for k in range(want, most + 1) if lanes % k == 0), 2)
+
+
+def _sweep_cuts(n_words: int, filters: int = 1):
+    """The word-axis slices of sweep_filter_pieces' pieces, the odd last
+    lane's short piece included."""
+    pieces = sweep_filter_pieces(n_words, filters)
+    step = n_words if pieces == 1 else n_words // 128 // pieces * 128
+    cuts = [slice(w0, w0 + step) for w0 in range(0, pieces * step, step)]
+    if pieces * step < n_words:
+        cuts.append(slice(pieces * step, n_words))
+    return cuts
+
+
+def _masked_counts(bank: jax.Array, filt: jax.Array, cuts) -> jax.Array:
+    """|row ∧ filt| per row, summed over the word-axis pieces `cuts`."""
+    return functools.reduce(jnp.add, (
+        popcount(jnp.bitwise_and(bank[..., c], filt[..., c]), axis=(-2, -1))
+        for c in cuts))
 
 
 def masked_row_counts(bank: jax.Array, filt: jax.Array,
@@ -167,19 +188,24 @@ def masked_row_counts(bank: jax.Array, filt: jax.Array,
     a shard's width). The word axis is the one to cut: under a mesh the shard axis
     is split over devices, and a slice along it would move data between
     them."""
-    n_words = filt.shape[-1]
-    pieces = sweep_filter_pieces(n_words)
-    step = n_words if pieces == 1 else n_words // 128 // pieces * 128
-    cuts = [slice(w0, w0 + step) for w0 in range(0, pieces * step, step)]
-    if pieces * step < n_words:
-        cuts.append(slice(pieces * step, n_words))
-    counts = functools.reduce(jnp.add, (
-        popcount(jnp.bitwise_and(bank[..., c], filt[..., c]), axis=(-2, -1))
-        for c in cuts))
+    cuts = _sweep_cuts(filt.shape[-1])
+    counts = _masked_counts(bank, filt, cuts)
     if not with_raw:
         return counts
     return counts, functools.reduce(jnp.add, (
         popcount(bank[..., c], axis=(-2, -1)) for c in cuts))
+
+
+def masked_row_counts_multi(bank: jax.Array, *filts: jax.Array):
+    """masked_row_counts of K filters from ONE pass over the bank:
+    ([R, S, W], K x [S, W]) -> uint32[K, R], lane k bit for bit the
+    counts of filter k. The same algorithm with K the caller's: every
+    piece of the bank is ANDed with that piece of each filter while it is
+    on chip, so the fusion that reads the bank has K outputs a piece and
+    the pieces are fewer (sweep_filter_pieces). The filters come in as K
+    operands, each where its own program left it, and meet here."""
+    cuts = _sweep_cuts(bank.shape[-1], len(filts))
+    return jnp.stack([_masked_counts(bank, f, cuts) for f in filts])
 
 
 def count_or(a: ArrayLike, b: ArrayLike) -> jax.Array:

@@ -116,6 +116,8 @@ class API:
             self.stats.with_tags(f"path:{path}").count(
                 "executor.range_leaves", 0)
         self.stats.count("executor.range_views", 0)
+        # ... and the bank-sweep launches, read per answer.
+        self.stats.count("executor.sweep_launches", 0)
         # The process-wide workload recorder (utils/hotspots.py)
         # increments its counters (pilosa_fragment_reads_total, ...)
         # straight into the stats client at record time so the

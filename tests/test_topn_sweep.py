@@ -15,6 +15,7 @@ from pilosa_tpu.executor import Executor
 from pilosa_tpu.executor import executor as ex_mod
 from pilosa_tpu.ops.bitset import (SHARD_WIDTH, SWEEP_MAX_PIECES,
                                    WORDS_PER_SHARD, masked_row_counts,
+                                   masked_row_counts_multi,
                                    sweep_filter_pieces)
 from pilosa_tpu.parallel import MeshContext
 from pilosa_tpu.server.api import API
@@ -226,6 +227,63 @@ def test_masked_row_counts_matches_numpy(shape, density, with_raw):
         got = got[0]
     assert got.dtype == jnp.uint32
     assert np.asarray(got).tolist() == want.tolist()
+
+
+@pytest.mark.parametrize("n_words,by_filters", [
+    (32768, {1: 4, 2: 2, 3: 2, 4: 2, 8: 2}),  # the cell's: outputs <= 4..
+    (16384, {1: 2, 2: 2, 4: 2}),              # ..and never under 2 pieces
+    (32640, {1: 3, 2: 2, 4: 2}),  # 255 lanes: thirds alone, else halves
+    (384, {1: 3, 2: 2, 4: 2}),    # of 127 (of 1) and the odd lane
+    (4480, {1: 2, 2: 2, 4: 2}),               # halves + the odd lane
+    (128, {1: 1, 2: 1, 4: 1}), (200, {1: 1, 4: 1}),
+])
+def test_sweep_filter_pieces_of_a_multi_filter_pass(n_words, by_filters):
+    """A pass for K filters has K outputs a piece, all reduced in every
+    window step: it takes fewer pieces, the same rule otherwise."""
+    for filters, pieces in by_filters.items():
+        assert sweep_filter_pieces(n_words, filters) == pieces, filters
+        assert pieces <= SWEEP_MAX_PIECES
+
+
+# Wide (cut in pieces), one lane (uncut), an odd lane left over (896: 7
+# lanes, 1408: 11), three lanes, and not whole lanes at all.
+_MULTI_SHAPES = [(7, 16, 1024), (4, 1, 128), (3, 2, 896), (2, 1, 1408),
+                 (2, 4, 384), (3, 2, 200)]
+
+
+@pytest.mark.parametrize("shape", _MULTI_SHAPES)
+@pytest.mark.parametrize("k", [2, 3, 4, 8])
+def test_masked_row_counts_multi_lanes_match_the_one_filter_body(shape, k):
+    """Lane j of the K-filter pass is `masked_row_counts` of filter j bit
+    for bit, and numpy's, with an empty, a sparse and a full filter
+    among the K."""
+    rng = np.random.default_rng(sum(shape) + k)
+    bank = _words(rng, shape, 0.5)
+    densities = ([0, 0.25, 1] + [0.5] * k)[:k]
+    filts = [_words(rng, shape[1:], d) for d in densities]
+    got = masked_row_counts_multi(jnp.asarray(bank),
+                                  *map(jnp.asarray, filts))
+    assert got.dtype == jnp.uint32 and got.shape == (k, shape[0])
+    for j, f in enumerate(filts):
+        one = masked_row_counts(jnp.asarray(bank), jnp.asarray(f))
+        assert np.asarray(got[j]).tolist() == np.asarray(one).tolist()
+        assert np.asarray(got[j]).tolist() == \
+            np.bitwise_count(bank & f).sum(axis=(1, 2)).tolist()
+
+
+def test_multi_sweep_program_reads_each_piece_once_per_filter(tmp_holder):
+    """`topn_sweep_multi` as the executor builds it: K x pieces popcounts
+    over one bank operand, one [K, R] result."""
+    ex = Executor(tmp_holder)
+    bank = jnp.zeros((8, 16, 1024), jnp.uint32)
+    filt = jnp.zeros((16, 1024), jnp.uint32)
+    for k in (2, 4):
+        fn = ex._counts_multi_fn(bank, filt, k)
+        assert fn.__name__ == "topn_sweep_multi"
+        args = (bank,) + (filt,) * k
+        assert _popcnts(fn, *args) == k * sweep_filter_pieces(1024, k)
+        assert _n_results(fn, *args) == 1
+        assert jax.eval_shape(fn, *args).shape == (k, 8)
 
 
 @pytest.mark.parametrize("density", [0, 0.25, 0.5, 1])
