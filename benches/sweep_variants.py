@@ -398,8 +398,15 @@ def main():
             if on_tpu:
                 rec["bank_fusions"] = _bank_fusions(compiled.as_text())
                 if name in CHECKED:
-                    rec["reduce_ms_estimate"] = _check_shipped(
-                        name, rec["bank_fusions"], (R, S, W))
+                    # A shipped body that fails its check is timed all
+                    # the same: on a bank of narrow rows the reading IS
+                    # the finding (the exit code still says it failed).
+                    try:
+                        rec["reduce_ms_estimate"] = _check_shipped(
+                            name, rec["bank_fusions"], (R, S, W))
+                    except AssertionError as e:
+                        rec["error"] = f"AssertionError: {str(e)[:400]}"
+                        print(f"{name}: {rec['error']}", file=sys.stderr)
             out = fn(bank, *operands(body))
             if _n_filters(body) > 1:
                 # Lane k is filter k's counts, bit for bit the one-filter
@@ -459,7 +466,7 @@ def main():
             rec["device_ops"] = names
             sweeps = sum(n for op, n in names.items() if re.fullmatch(
                 r"popcnt_reduce_fusion(\.\d+)*", op))
-            if rec["variant"] in CHECKED and \
+            if rec["variant"] in CHECKED and "error" not in rec and \
                     sweeps != len(durs) * len(rec["bank_fusions"]):
                 rec["error"] = (f"{sweeps} popcnt_reduce_fusion ops in "
                                 f"{len(durs)} launches: {names}")

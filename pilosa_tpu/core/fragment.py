@@ -786,10 +786,11 @@ class Fragment:
             self.bulk_import(np.asarray(row_ids, np.uint64),
                              np.asarray(column_ids, np.uint64))
 
-    def import_roaring(self, data: bytes, clear: bool = False) -> None:
+    def import_roaring(self, data: bytes, clear: bool = False) -> Bitmap:
         """Union (or overwrite-clear) a pre-serialized roaring bitmap into
         storage — the fastest import path (reference ImportRoaring,
-        fragment.go:1721)."""
+        fragment.go:1721). Returns the payload as parsed, for a caller
+        that needs its columns."""
         other = Bitmap.from_bytes(data)
         with self._lock:
             if clear:
@@ -808,6 +809,7 @@ class Fragment:
             for r in rows:
                 self._cache_update(int(r))
             self._snapshot()
+        return other
 
     def replace_with_bytes(self, data: bytes) -> None:
         """Overwrite the whole fragment from serialized roaring bytes —

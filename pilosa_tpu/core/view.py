@@ -228,9 +228,30 @@ class ViewBank:
         self.slots = slots          # row id -> slot
         self.zero_slot = zero_slot
         self.versions = versions    # {shard: fragment.version} at build time
+        self._slot_rows = None
+        # The rows' own popcounts [Rcap] on the host, once a tanimoto
+        # sweep of this bank has fetched them (executor._execute_topn).
+        # A write builds a new ViewBank, so they live one bank version.
+        self.popcounts = None
 
     def slot(self, row_id: int) -> int:
         return self.slots.get(row_id, self.zero_slot)
+
+    def slot_rows(self) -> np.ndarray:
+        """Row ids in slot order, uint64 [len(slots)]: slot i holds row
+        `slot_rows()[i]` (slots are dense from 0; a patched bank
+        appends its new rows). What a sweep of the whole bank maps its
+        count vector by — one array per bank version instead of a dict
+        probe per row per query. Built on first use, from the dict, so
+        every way a bank is made agrees with its `slots`."""
+        rows = self._slot_rows
+        if rows is None:
+            n = len(self.slots)
+            rows = np.empty(n, dtype=np.uint64)
+            rows[np.fromiter(self.slots.values(), dtype=np.int64, count=n)] \
+                = np.fromiter(self.slots.keys(), dtype=np.uint64, count=n)
+            self._slot_rows = rows
+        return rows
 
 
 class PositionsBank:

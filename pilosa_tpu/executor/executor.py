@@ -899,6 +899,15 @@ class Executor:
                 self.stats.count("executor.sweep_pad_lanes",
                                  lanes - members)
 
+    def _note_topn_rows(self, what: str, n: int) -> None:
+        """What a TopN call's sweep costs by the row: `swept`, the bank
+        slots its programs covered (`executor.topn_rows_swept`), and
+        `fetched`, the elements of per-row count and popcount vectors
+        its finalize brought to the host
+        (`executor.topn_rows_fetched`)."""
+        if self.stats is not None and n:
+            self.stats.count(f"executor.topn_rows_{what}", n)
+
     # How a time-range leaf's union was staged, one of these per leaf,
     # counted under `executor.range_leaves{path:<p>}`: `fold`, slot
     # leaves OR-ed inside the tree program (up to
@@ -2641,7 +2650,9 @@ class Executor:
         # Through the _call_program funnel: TopN sweeps are device
         # dispatches too.
         with self._dispatch_span(
-                self._counts_program(with_filter, with_raw)):
+                self._counts_program(with_filter, with_raw)) as ds:
+            ds.set("rows", bank_array.shape[0])
+            ds.set("words", bank_array.shape[-1])
             out = self._call_program(fn, bank_array, filter_words)
         self._note_sweep_launch()
         return out
@@ -2824,7 +2835,11 @@ class Executor:
                                             with_raw)
                 if filter_words is not None:
                     self._note_sweep_group(1, 1)
-            dispatched.append((all_rows, bank, out))
+            self._note_topn_rows("swept", bank.array.shape[0])
+            # A sweep of the whole view reads the bank's own rows in
+            # slot order (None): no per-row mapping in finalize.
+            dispatched.append((None if all_rows is view_rows else all_rows,
+                               bank, out))
         else:
             if PBANK_ENABLED and self.mesh is None and len(shards) == 1 \
                     and allowed_rows is None and not ids_arg and n \
@@ -2868,6 +2883,8 @@ class Executor:
         src_dev = None
         if with_raw:
             src_dev = self._popcount_row(filter_words)
+            if self.stats is not None:
+                self.stats.count("executor.tanimoto_sweeps", 1)
 
         # Chunk banks are admitted to the BANK_BUDGET HBM LRU only when
         # the WHOLE stream fits in half the budget: a repeat query over
@@ -2885,7 +2902,9 @@ class Executor:
             bank = view.device_bank(tuple(shards), rows=rows,
                                     mesh=self.mesh, trim=True,
                                     cache_rows=cache_chunks)
-            return (rows, bank,
+            self._note_topn_rows("swept", bank.array.shape[0])
+            # A chunk bank holds exactly its chunk's rows.
+            return (None, bank,
                     self._dispatch_counts(bank.array, filter_words,
                                           with_raw))
 
@@ -2895,6 +2914,7 @@ class Executor:
             if chunked:
                 pending.append(dispatch_chunk(chunked[0]))
             i = 0
+            fetched_rows = 0
             while pending:
                 rows, bank, out = pending.pop(0)
                 # One-chunk lookahead: overlap the next upload+sweep with
@@ -2902,35 +2922,57 @@ class Executor:
                 i += 1
                 if i < len(chunked):
                     pending.append(dispatch_chunk(chunked[i]))
-                fetched = [np.asarray(a) for a in
-                           (out if with_raw else (out,))]
-                # map(dict.get, ...) keeps the 65k-row probe loop in C.
-                slot_idx = np.fromiter(
-                    map(bank.slots.get, rows,
-                        itertools.repeat(bank.zero_slot)),
-                    dtype=np.int64, count=len(rows))
-                parts.append((np.asarray(rows, dtype=np.uint64),
-                              *(a[slot_idx].astype(np.int64)
-                                for a in fetched)))
-            rows_arr = np.concatenate([p[0] for p in parts])
-            counts_arr = np.concatenate([p[1] for p in parts])
-            if with_raw:
-                raws_arr = np.concatenate([p[2] for p in parts])
-                src_total = int(np.asarray(src_dev))
-                denom = raws_arr + src_total - counts_arr
-                keep = (denom > 0) & (
-                    (counts_arr * 100) // np.maximum(denom, 1) >= tanimoto)
+                counts_out, raw_out = out if with_raw else (out, None)
+                with TIMELINE.stage("finish.slot_map") as sm:
+                    # The bank's rows in slot order ARE the sweep's rows:
+                    # slots past them are zero rows. A restricted call
+                    # (attrName, ids) keeps its candidates among them.
+                    rows_arr = bank.slot_rows()
+                    sm.set("rows", len(rows_arr))
+                    vectors = [np.asarray(counts_out)]
+                    fetched_rows += vectors[0].size
+                    if with_raw:
+                        # The rows' own popcounts are the bank's, not the
+                        # query's: fetched by the first tanimoto answer
+                        # of a bank version and kept with the bank.
+                        if bank.popcounts is None:
+                            bank.popcounts = np.asarray(raw_out)
+                            fetched_rows += bank.popcounts.size
+                        vectors.append(bank.popcounts)
+                    sel = slice(0, len(rows_arr))
+                    if rows is not None:
+                        sel = np.flatnonzero(np.isin(
+                            rows_arr, np.asarray(rows, dtype=np.uint64)))
+                        rows_arr = rows_arr[sel]
+                    parts.append((rows_arr, *(v[sel].astype(np.int64)
+                                              for v in vectors)))
+            self._note_topn_rows("fetched", fetched_rows)
+            with TIMELINE.stage("finish.select") as fs:
+                # One sweep (the resident bank) is one part: no copy of
+                # its million-row vectors.
+                rows_arr, counts_arr, *raws = parts[0] if len(parts) == 1 \
+                    else (np.concatenate(col) for col in zip(*parts))
+                fs.set("rows", len(rows_arr))
+                if with_raw:
+                    raws_arr = raws[0]
+                    src_total = int(np.asarray(src_dev))
+                    # The reference's rule (fragment.go:1146-1150): a row
+                    # stays when ceil(100 * |A and B| / |A or B|) exceeds
+                    # the threshold, so a ratio of exactly T is out.
+                    keep = counts_arr * 100 > tanimoto * (
+                        raws_arr + src_total - counts_arr)
+                    rows_arr, counts_arr = rows_arr[keep], counts_arr[keep]
+                keep = counts_arr > max(0, min_threshold - 1)
                 rows_arr, counts_arr = rows_arr[keep], counts_arr[keep]
-            keep = counts_arr > max(0, min_threshold - 1)
-            rows_arr, counts_arr = rows_arr[keep], counts_arr[keep]
-            rows_arr, counts_arr = _topn_candidates(rows_arr, counts_arr,
-                                                    n)
-            # Sort by (-count, row) — vectorized; Python-loop-free even
-            # for 10^5-row fingerprint sweeps.
-            order = np.lexsort((rows_arr, -counts_arr))
-            if n:
-                order = order[:n]
-            pairs = [(int(rows_arr[o]), int(counts_arr[o])) for o in order]
+                rows_arr, counts_arr = _topn_candidates(rows_arr,
+                                                        counts_arr, n)
+                # Sort by (-count, row) — vectorized; Python-loop-free
+                # even for 10^6-row fingerprint sweeps.
+                order = np.lexsort((rows_arr, -counts_arr))
+                if n:
+                    order = order[:n]
+                pairs = [(int(rows_arr[o]), int(counts_arr[o]))
+                         for o in order]
             if selfcheck_pairs is not None and selfcheck_pairs != pairs:
                 self.topn_selfcheck_mismatches += 1
                 _LOG.error(
@@ -2950,11 +2992,15 @@ class Executor:
             # the full-bank path needs no such care because its device
             # arrays snapshot at dispatch.
             return finalize()
-        return _Pending(
-            finalize,
-            arrays=tuple(x for _, _, out in dispatched
-                         for x in (out if with_raw else (out,))
-                         ) + ((src_dev,) if with_raw else ()))
+        # What finalize will fetch: each sweep's counts; under tanimoto
+        # the filter's popcount and, until the bank holds them, the
+        # rows' own.
+        arrays = tuple(out[0] if with_raw else out
+                       for _, _, out in dispatched)
+        if with_raw:
+            arrays += tuple(out[1] for _, bank, out in dispatched
+                            if bank.popcounts is None) + (src_dev,)
+        return _Pending(finalize, arrays=arrays)
 
     _PBANK_KERNELS: Dict[tuple, Callable] = {}
 
@@ -3070,7 +3116,7 @@ class Executor:
             keep = c >= jnp.maximum(1, thresh)
             denom = raw + src - c
             keep &= jnp.where(tani > 0,
-                              (denom > 0) & (c * 100 >= tani * denom),
+                              c * 100 > tani * denom,
                               True)
             score = jnp.where(keep, c, -1)
             return jax.lax.top_k(score, k)

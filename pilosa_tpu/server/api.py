@@ -770,10 +770,14 @@ class API:
         frag = f.create_view_if_not_exists(view) \
             .create_fragment_if_not_exists(shard)
         try:
-            frag.import_roaring(data, clear=clear)
+            payload = frag.import_roaring(data, clear=clear)
         except ValueError as e:
             raise ApiError(f"invalid roaring payload: {e}")
-        cols = frag.storage.slice() % np.uint64(SHARD_WIDTH) \
+        # A union marks the payload's columns, not the fragment's: a
+        # shard imported in row blocks holds 100 M bits by the last body.
+        # A clear names bits that went, so it reads what stayed.
+        bits = frag.storage if clear else payload
+        cols = bits.slice() % np.uint64(SHARD_WIDTH) \
             + np.uint64(shard * SHARD_WIDTH)
         if len(cols):
             idx.add_existence(np.unique(cols))

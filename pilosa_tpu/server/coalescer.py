@@ -79,6 +79,15 @@ _PENDING, _CLAIMED, _EJECTED = 0, 1, 2
 # identical to PILOSA_TPU_PIPELINE=0.
 PIPELINE_ENABLED = os.environ.get("PILOSA_TPU_PIPELINE", "1") != "0"
 
+# A flush answers its members when the LAST of them is finalised, so a
+# flush is held to about this long: the next one claims no more members
+# than the last pipelined flush's wall time a member lets finish inside
+# it (never fewer than two: a lone member leaves the pipeline). Flushes
+# of cheap queries never meet it — 64 members inside it are 15.6 ms
+# each — while 64 bank sweeps of 126 ms each held every answer for 8 s
+# and let them go in one lump (PERF.md §6, PR 30).
+FLUSH_TARGET_S = 1.0
+
 
 class CoalescerStopped(RuntimeError):
     """Raised by submit() when the coalescer is stopped (or its
@@ -178,6 +187,11 @@ class QueryCoalescer:
         self._pl_stop = False
         self._pl_thread: Optional[threading.Thread] = None
         self.pipelined_flushes = 0
+        # Wall seconds a member of the last pipelined flush, begin to
+        # last answer (0 until one has finished): what FLUSH_TARGET_S
+        # is divided by. Written by the finalizer, read by the
+        # dispatcher.
+        self._member_s = 0.0
 
     # ------------------------------------------------------------ lifecycle
 
@@ -404,7 +418,10 @@ class QueryCoalescer:
         self._flush_now = None
         now = time.monotonic()
         batch = self._inflight = []
-        while self._queue and len(batch) < self.max_batch:
+        limit = self.max_batch
+        if self._member_s > 0:
+            limit = min(limit, max(2, int(FLUSH_TARGET_S / self._member_s)))
+        while self._queue and len(batch) < limit:
             item = self._queue.pop(0)
             if item.state != _PENDING:  # ejected by its requester
                 continue
@@ -605,6 +622,7 @@ class QueryCoalescer:
         build and H2D run concurrently: the overlap that buys back the
         per-flush host time. Both halves are stages of ONE flush
         record; `coalescer.handoff` is the wait for the finalizer."""
+        began = time.perf_counter()
         self.stats.count(f"coalescer.flush.{reason}", 1)
         self.stats.histogram("coalescer.batch_size", len(batch))
         self._note_workload(batch)
@@ -639,7 +657,7 @@ class QueryCoalescer:
             while self._pl_pending is not None:
                 self._pl_cond.wait()
             self._pl_pending = (batch, owner, sh, rec, profiles,
-                                handoff)
+                                handoff, began)
             self._pl_cond.notify_all()
 
     def _finalize_loop(self) -> None:
@@ -671,7 +689,8 @@ class QueryCoalescer:
 
     def _finish_pipelined(self, batch: List[_Item],
                           owner: List[List[_Item]], sh: Any, rec: Any,
-                          profiles: List[Any], handoff: float) -> None:
+                          profiles: List[Any], handoff: float,
+                          began: float) -> None:
         # The wait for the finalizer's slot (the previous flush still
         # draining): one more stage of the flush, so that it tiles.
         TIMELINE.add(rec, "coalescer.handoff", handoff,
@@ -685,6 +704,7 @@ class QueryCoalescer:
             raise
         finally:
             self._close_flush(rec, batch, profiles, err)
+            self._member_s = (time.perf_counter() - began) / len(batch)
         for res, items in zip(shaped, owner):
             for item in items:
                 item.result = res

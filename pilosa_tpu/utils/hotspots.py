@@ -51,6 +51,7 @@ queue (graftlint GL003 stays clean by construction, pinned by test).
 
 from __future__ import annotations
 
+import itertools
 import math
 import time
 from collections import deque
@@ -310,7 +311,9 @@ class WorkloadRecorder:
         hl = self.half_life_s
         row_ids: List[int] = []
         if rows is not None:
-            row_ids = list(rows)[:ROW_CAP_PER_CALL]
+            # Cut before copying: a whole-view TopN hands over millions
+            # of rows.
+            row_ids = list(itertools.islice(rows, ROW_CAP_PER_CALL))
             if len(rows) > ROW_CAP_PER_CALL:
                 rows_scanned += len(rows) - ROW_CAP_PER_CALL
         n_shards = len(shards)

@@ -573,3 +573,58 @@ def test_pipelined_finalizer_base_exception_wrapped(plex):
     finally:
         Executor.execute_batch_shaped_finish = orig_finish
         co.stop()
+
+
+def test_a_slow_flush_caps_the_next_ones_at_the_target(plex, monkeypatch):
+    """A pipelined flush answers its members when the last is finalised,
+    so the coalescer holds a flush to FLUSH_TARGET_S: after one whose
+    wall time a member was long, the next flushes claim only as many
+    members as fit the target (never fewer than two); once flushes are
+    quick again the cap is max_batch's."""
+    from pilosa_tpu.executor import Executor
+    from pilosa_tpu.server import coalescer as co_mod
+
+    monkeypatch.setattr(co_mod, "FLUSH_TARGET_S", 0.2)
+    direct = {i: plex.execute_full("c", q)
+              for i, q in enumerate(_PL_QUERIES)}
+    orig_finish = Executor.execute_batch_shaped_finish
+    slow = {"on": True}
+
+    def slow_finish(self, sh):
+        if slow["on"]:
+            time.sleep(0.4)         # >= 0.05 s a member of a flush of 8
+        return orig_finish(self, sh)
+
+    monkeypatch.setattr(Executor, "execute_batch_shaped_finish", slow_finish)
+    sizes = []
+    orig_pipelined = QueryCoalescer._execute_pipelined
+
+    def recording(self, batch, reason):
+        sizes.append(len(batch))
+        return orig_pipelined(self, batch, reason)
+
+    monkeypatch.setattr(QueryCoalescer, "_execute_pipelined", recording)
+    co = QueryCoalescer(plex, window_s=0.05, max_batch=8,
+                        stats=MemStatsClient(), pipeline=True)
+    co.start()
+    try:
+        results, errors = _pl_burst(co, _PL_QUERIES)
+        assert not errors and results == direct
+        # Those flushes knew no member time (the second was claimed
+        # while the first drained); the next burst's are held to
+        # 0.2 s / (>= 0.05 s a member) = at most four members.
+        assert max(sizes) > 4
+        sizes.clear()
+        results, errors = _pl_burst(co, _PL_QUERIES)
+        assert not errors and results == direct
+        assert max(sizes) <= 4 and min(sizes) >= 2
+        slow["on"] = False
+        for _ in range(3):          # quick flushes lift the cap again
+            results, errors = _pl_burst(co, _PL_QUERIES)
+            assert not errors and results == direct
+        assert co._member_s < 0.2 / 8
+        sizes.clear()
+        _pl_burst(co, _PL_QUERIES)
+        assert max(sizes) > 4
+    finally:
+        co.stop()

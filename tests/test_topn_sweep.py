@@ -57,7 +57,7 @@ def _reference(rows: dict, n: int, tanimoto: int) -> list:
         inter = np.intersect1d(cols, filt).size
         if tanimoto:
             denom = cols.size + filt.size - inter
-            if denom <= 0 or inter * 100 // denom < tanimoto:
+            if inter * 100 <= tanimoto * denom:    # upstream: == T is out
                 continue
         if inter > 0:
             pairs.append((r, inter))
