@@ -24,17 +24,22 @@ A group's body (`ops/bitset.masked_row_counts_multi`, the program
 rotating through its K operands; its rows also carry `device_ms_per_filter`,
 and every lane is compared with the one-filter body's counts.
 
-The run also fails when the SHIPPED bodies (`ops/bitset.masked_row_counts`,
-with and without the raw popcounts, and `masked_row_counts_multi` at 2 and
-4 filters) stop compiling to what their rate
-rests on: one fusion reading the bank, named `popcnt_reduce_fusion` (the
-op `topn_sweep_roofline` matches), whose windows — wherever the filter is
+The run also fails when the SHIPPED bodies (`ops/bitset.masked_row_counts`
+= `topn_sweep`, `popcount` over a row's shards and words =
+`topn_sweep_unfiltered`, and `masked_row_counts_multi` at 2 and 4 filters
+= `topn_sweep_multi`; every row carries its `program`) stop compiling to
+what their rate rests on: one fusion reading the bank, named
+`popcnt_reduce_fusion` (the op `topn_sweep_roofline` and
+`tanimoto_sweep_roofline` match), whose windows — wherever the filter is
 cut — are wide enough that the row reductions hide behind the bank's bytes
 (`bank_fusions`, read from the compiled HLO; `_check_shipped`), and that
 op once per launch in the device trace (twice where an odd lane is left
-over). On a bank of narrow rows (one shard a row, a few lanes) the
-reductions bind whatever the windows are: there a nonzero exit is PERF.md
-§7 row 10's finding, not a fault.
+over). On a bank of narrow rows (one shard a row, one lane: `--rows
+2097152 --shards 1 --words 128`, the chem cell's) the reductions bind
+whatever the windows are; the uncut bodies are one fusion there all the
+same, and `--only shipped,unfiltered` — a tanimoto answer's two programs —
+exits 0 (a group's body is a fusion a filter there, and is printed
+unchecked).
 
     python benches/sweep_variants.py [--rows 1024 --shards 16 --words 32768]
         [--launches 40] [--out chiprun_out/sweep_variants]
@@ -68,11 +73,23 @@ import time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
-SHIPPED = ("shipped", "shipped_with_raw")
 # The shipped body of a group of K filters (`topn_sweep_multi`), by K.
 SHIPPED_MULTI = {f"shipped_multi{k}": k for k in (2, 4)}
-CHECKED = SHIPPED + tuple(SHIPPED_MULTI)    # bodies _check_shipped holds
+# The bodies _check_shipped holds, by the program the executor jits them
+# as.
+CHECKED = {"shipped": "topn_sweep", "unfiltered": "topn_sweep_unfiltered",
+           **dict.fromkeys(SHIPPED_MULTI, "topn_sweep_multi")}
 N_FILTERS = 8
+
+
+def _checked(name, n_words):
+    """Whether _check_shipped holds body `name` at this width: the
+    one-filter bodies always, a group's where its filters are cut. On
+    rows under two lanes XLA gives a group one fusion a FILTER — K bank
+    reads, which the row still prints and times (PERF.md §7 row 12)."""
+    from pilosa_tpu.ops.bitset import sweep_filter_pieces
+    return name in CHECKED and (name not in SHIPPED_MULTI
+                                or sweep_filter_pieces(n_words) > 1)
 
 
 def _n_filters(body):
@@ -106,9 +123,6 @@ def _variants(R, S, W):
                               for f in fs])
         return run
 
-    def two_output(c, f):          # the parent's body
-        return popcount(c & f, axis=(-2, -1)), popcount(c, axis=(-2, -1))
-
     def one_output(c, f):
         return popcount(c & f, axis=(-2, -1))
 
@@ -125,33 +139,24 @@ def _variants(R, S, W):
                                 axis=(-2, -1)) for s0 in range(0, S, g))
         return run
 
-    def word_pieces(k, with_raw=False):   # ... along the word axis
+    def word_pieces(k):            # ... along the word axis
         cuts = [slice(w0, w0 + W // k) for w0 in range(0, W, W // k)]
 
         def run(c, f):
-            counts = sum(popcount(c[..., w] & f[..., w], axis=(-2, -1))
-                         for w in cuts)
-            if not with_raw:
-                return counts
-            return counts, sum(popcount(c[..., w], axis=(-2, -1))
-                               for w in cuts)
+            return sum(popcount(c[..., w] & f[..., w], axis=(-2, -1))
+                       for w in cuts)
         return run
 
     out = {
-        "two_output": two_output,
         "one_output": one_output,
         "unfiltered": unfiltered,
         "shipped": lambda c, f: masked_row_counts(c, f),
-        "shipped_with_raw": lambda c, f: masked_row_counts(c, f, True),
         "words_then_shards": words_then_shards,
     }
     served = sweep_filter_pieces(W)     # "shipped" is this many pieces
     for k in (2, 4, 8):
         if W % (128 * k) == 0 and k != served:
             out[f"word_pieces{k}"] = word_pieces(k)
-    if W % (256 * served) == 0:         # twice the outputs of the served
-        out[f"word_pieces{2 * served}_with_raw"] = word_pieces(
-            2 * served, True)
     if S % 8 == 0 and S > 8:
         out["shard_slabs8"] = shard_slabs(8)
     for name, k in SHIPPED_MULTI.items():
@@ -204,7 +209,8 @@ def _check_shipped(name, fusions, shape):
     from pilosa_tpu.ops.bitset import sweep_filter_pieces
     R, S, W = shape
     filters = SHIPPED_MULTI.get(name, 1)
-    pieces = sweep_filter_pieces(W, filters)
+    # The unfiltered body has no filter to cut: one piece, the whole row.
+    pieces = 1 if name == "unfiltered" else sweep_filter_pieces(W, filters)
     expect = 2 if W // 128 % pieces else 1
     if len(fusions) != expect:
         raise AssertionError(f"{name}: {len(fusions)} fusions read the "
@@ -214,10 +220,9 @@ def _check_shipped(name, fusions, shape):
             raise AssertionError(f"{name}: the bank's fusion is `{op}`")
     # The widest fusion is the equal pieces'; the other has one piece.
     widest = max(fusions, key=lambda f: f[1][2] * f[2][2])
-    outputs = 2 if name == "shipped_with_raw" else filters
     reduce_ms = sum(
         R * iters[1] * iters[2] * (pieces if f is widest else 1)
-        * outputs * ROW_STEP_NS / 1e6
+        * filters * ROW_STEP_NS / 1e6
         for f in fusions for iters in (f[2],))
     stream_ms = R * S * W * 4 / SWEEP_GBPS / 1e6
     if pieces > 1 and reduce_ms > stream_ms / 0.7:
@@ -238,10 +243,6 @@ def _collectives(hlo_text):
     async pair counts once, by its `-start`)."""
     return [m.group(1) for m in map(COLLECTIVE.search,
                                     hlo_text.splitlines()) if m]
-
-
-def _first(out):
-    return out[0] if isinstance(out, tuple) else out
 
 
 def _device_times(trace_dir):
@@ -304,18 +305,16 @@ def describe(args):
             rec["bank_fusions"] = _bank_fusions(hlo)
             rec["collectives"] = _collectives(hlo)
             if name in CHECKED:
+                rec["program"] = CHECKED[name]
+            if _checked(name, W):
                 rec["reduce_ms_estimate"] = _check_shipped(
                     name, rec["bank_fusions"], (R, S // args.mesh, W))
-                # One all-reduce of the counts (with the raw popcounts
-                # XLA may reduce the pair as one tuple or as two).
-                most = 2 if name == "shipped_with_raw" else 1
+                # One all-reduce of the counts.
                 got = rec["collectives"]
-                ok = (set(got) == {"all-reduce"} and len(got) <= most) \
-                    if args.mesh > 1 else not got
-                if not ok:
+                if got != (["all-reduce"] if args.mesh > 1 else []):
                     raise AssertionError(
-                        f"{name}: collectives {got}, not 1..{most} "
-                        "all-reduce and nothing else")
+                        f"{name}: collectives {got}, not one all-reduce "
+                        "and nothing else")
         except Exception as e:
             rec["error"] = f"{type(e).__name__}: {str(e)[:400]}"
             ok = False
@@ -398,6 +397,8 @@ def main():
             if on_tpu:
                 rec["bank_fusions"] = _bank_fusions(compiled.as_text())
                 if name in CHECKED:
+                    rec["program"] = CHECKED[name]
+                if _checked(name, W):
                     # A shipped body that fails its check is timed all
                     # the same: on a bank of narrow rows the reading IS
                     # the finding (the exit code still says it failed).
@@ -413,13 +414,13 @@ def main():
                 # body's.
                 lanes = np.asarray(out)
                 for k, f in enumerate(operands(body)):
-                    one = np.asarray(_first(jitted_ref(bank, f)))
+                    one = np.asarray(jitted_ref(bank, f))
                     if not np.array_equal(lanes[k], one):
                         raise AssertionError(f"{name}: lane {k} differs "
                                              "from one_output")
                 got = lanes[0]
             else:
-                got = np.asarray(_first(out))
+                got = np.asarray(out)
             want = want_u if name == "unfiltered" else want_f
             if got[sample].tolist() != want.tolist():
                 raise AssertionError(f"{name}: {got[sample]} != {want}")
@@ -466,7 +467,7 @@ def main():
             rec["device_ops"] = names
             sweeps = sum(n for op, n in names.items() if re.fullmatch(
                 r"popcnt_reduce_fusion(\.\d+)*", op))
-            if rec["variant"] in CHECKED and "error" not in rec and \
+            if _checked(rec["variant"], W) and "error" not in rec and \
                     sweeps != len(durs) * len(rec["bank_fusions"]):
                 rec["error"] = (f"{sweeps} popcnt_reduce_fusion ops in "
                                 f"{len(durs)} launches: {names}")
@@ -476,10 +477,10 @@ def main():
             continue    # the chain salts one filter: launches and the trace
         if name == "unfiltered":
             chain = make_salted_chain(
-                lambda x, y, sx, sy: _first(fn(x + sx, y)))
+                lambda x, y, sx, sy: fn(x + sx, y))
         else:
             chain = make_salted_chain(
-                lambda x, y, sx, sy: _first(fn(x, y + sy)))
+                lambda x, y, sx, sy: fn(x, y + sy))
         try:
             r = validated_chain_slope(
                 lambda k: timed_fetch(lambda: chain(bank, filts[0], k)),

@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import os
 from pilosa_tpu.utils.locks import make_lock, make_rlock
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -229,13 +229,45 @@ class ViewBank:
         self.zero_slot = zero_slot
         self.versions = versions    # {shard: fragment.version} at build time
         self._slot_rows = None
-        # The rows' own popcounts [Rcap] on the host, once a tanimoto
-        # sweep of this bank has fetched them (executor._execute_topn).
-        # A write builds a new ViewBank, so they live one bank version.
+        # The rows' own popcounts [Rcap], computed FROM `array` by the
+        # first tanimoto TopN that meets this bank (one unfiltered
+        # sweep, executor._bank_popcounts): None until then, that
+        # sweep's device vector until a finalize fetches it, the host's
+        # np.ndarray from then on. A write builds a new ViewBank, so
+        # they live one bank version and are never patched.
         self.popcounts = None
+        self._popcounts_lock = make_lock("ViewBank._popcounts_lock")
 
     def slot(self, row_id: int) -> int:
         return self.slots.get(row_id, self.zero_slot)
+
+    def row_popcounts(self, sweep) -> Tuple[Any, bool]:
+        """(the rows' own popcounts, whether this call swept them): the
+        kept vector, pending or fetched, which `sweep(array)` launches
+        the first time anybody asks. A bank version is swept once,
+        however many calls meet it before the first fetch."""
+        with self._popcounts_lock:
+            swept = self.popcounts is None
+            if swept:
+                self.popcounts = sweep(self.array)
+            return self.popcounts, swept
+
+    def host_popcounts(self) -> Tuple[np.ndarray, bool]:
+        """(the swept vector on the host, whether this call fetched
+        it): the first finalize to get here swaps the device's vector
+        for its host copy. The wait is outside the lock, so a call
+        that meets the bank meanwhile takes the pending vector."""
+        host = self.popcounts
+        if isinstance(host, np.ndarray):
+            return host, False
+        # graftlint: materialize — the one fetch of a bank version's
+        # popcounts, inside the finalize that reads them.
+        host = np.asarray(host)
+        with self._popcounts_lock:
+            fetched = not isinstance(self.popcounts, np.ndarray)
+            if fetched:
+                self.popcounts = host
+            return self.popcounts, fetched
 
     def slot_rows(self) -> np.ndarray:
         """Row ids in slot order, uint64 [len(slots)]: slot i holds row

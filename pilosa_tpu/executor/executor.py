@@ -882,7 +882,7 @@ class Executor:
                 "executor.topn_sweeps", 1)
 
     def _note_sweep_launch(self) -> None:
-        """One bank-sweep program launched, whichever of the four:
+        """One bank-sweep program launched, whichever of the three:
         `executor.sweep_launches`."""
         if self.stats is not None:
             self.stats.count("executor.sweep_launches", 1)
@@ -901,7 +901,8 @@ class Executor:
 
     def _note_topn_rows(self, what: str, n: int) -> None:
         """What a TopN call's sweep costs by the row: `swept`, the bank
-        slots its programs covered (`executor.topn_rows_swept`), and
+        slots its programs covered, the sweep for a bank version's
+        popcounts among them (`executor.topn_rows_swept`), and
         `fetched`, the elements of per-row count and popcount vectors
         its finalize brought to the host
         (`executor.topn_rows_fetched`)."""
@@ -2553,23 +2554,22 @@ class Executor:
 
     # ----------------------------------------------------------------- TopN
 
-    def _counts_fn(self, with_filter: bool, shape,
-                   with_raw: bool = False) -> Callable:
-        """jit: bank chunk [R, S, W] (∧ filter [S, W]) -> counts [R].
-        `with_raw` (a filtered sweep under tanimotoThreshold) adds the
-        rows' own popcounts [R], the tanimoto denominator's term: a second
-        popcount + reduction over the whole bank, so only the query that
-        reads it pays for it."""
+    def _counts_fn(self, with_filter: bool, shape) -> Callable:
+        """jit: bank chunk [R, S, W] (∧ filter [S, W]) -> counts [R]:
+        |row ∧ filter| per row, or without a filter |row| — every
+        unfiltered TopN's counts, and the rows' own popcounts that a
+        tanimoto call reads, which this program computes once a bank
+        version (`_bank_popcounts`) and the bank keeps."""
         import jax
         from pilosa_tpu.ops.bitset import masked_row_counts, popcount
-        program = self._counts_program(with_filter, with_raw)
-        key = f"topn:{with_filter}:{with_raw}:{shape}"
+        program = self._counts_program(with_filter)
+        key = f"topn:{with_filter}:{shape}"
         fn = self._jit_get(key)
         if fn is None:
             self._note_jit_compile(program, key)
             if with_filter:
                 def run(chunk, filt):
-                    return masked_row_counts(chunk, filt, with_raw)
+                    return masked_row_counts(chunk, filt)
             else:
                 def run(chunk, filt):
                     return popcount(chunk, axis=(-2, -1))
@@ -2578,13 +2578,11 @@ class Executor:
         return fn
 
     @staticmethod
-    def _counts_program(with_filter: bool, with_raw: bool = False) -> str:
-        """The TopN bank sweep's name in traces: which of the three
+    def _counts_program(with_filter: bool) -> str:
+        """The TopN bank sweep's name in traces: which of the two
         one-filter programs a call's arguments selected (a group of
-        sweeps runs a fourth, `topn_sweep_multi`)."""
-        if not with_filter:
-            return "topn_sweep_unfiltered"
-        return "topn_sweep_tanimoto" if with_raw else "topn_sweep"
+        sweeps runs a third, `topn_sweep_multi`)."""
+        return "topn_sweep" if with_filter else "topn_sweep_unfiltered"
 
     def _counts_multi_fn(self, bank_array, filt, lanes: int) -> Callable:
         """jit: bank [R, S, W], `lanes` filters [S, W] each -> counts
@@ -2635,27 +2633,44 @@ class Executor:
         self._note_sweep_group(lanes, n)
         return out, lanes
 
-    def _dispatch_counts(self, bank_array, filter_words,
-                         with_raw: bool = False):
+    def _dispatch_counts(self, bank_array, filter_words):
         """Queue the counts kernel; returns unfetched device output: the
-        counts [R], or (counts, raw) from a filtered sweep `with_raw`.
-        Width-trimmed banks intersect against the same prefix of the
-        filter: slicing a wider filter is safe (bank rows have no bits
-        past their width), and padding a narrower one is safe (zeros
-        cannot intersect)."""
+        counts [R]. Width-trimmed banks intersect against the same
+        prefix of the filter: slicing a wider filter is safe (bank rows
+        have no bits past their width), and padding a narrower one is
+        safe (zeros cannot intersect)."""
         filter_words = _align_words(filter_words, bank_array.shape[-1])
         with_filter = filter_words is not None
-        with_raw = with_raw and with_filter
-        fn = self._counts_fn(with_filter, bank_array.shape, with_raw)
+        fn = self._counts_fn(with_filter, bank_array.shape)
         # Through the _call_program funnel: TopN sweeps are device
         # dispatches too.
-        with self._dispatch_span(
-                self._counts_program(with_filter, with_raw)) as ds:
+        with self._dispatch_span(self._counts_program(with_filter)) as ds:
             ds.set("rows", bank_array.shape[0])
             ds.set("words", bank_array.shape[-1])
             out = self._call_program(fn, bank_array, filter_words)
         self._note_sweep_launch()
         return out
+
+    # Where a tanimoto call's |row| came from, one of these per call
+    # and bank, counted under `executor.bank_popcounts{path:<p>}`.
+    POPCOUNT_PATHS = ("kept", "swept")
+
+    def _bank_popcounts(self, bank):
+        """The rows' own popcounts of `bank` (the tanimoto denominator's
+        |row|) for one tanimoto call, and whether this call launched
+        their sweep. They are the bank version's, not the query's: the
+        first call to meet a version launches `topn_sweep_unfiltered`
+        over it (`swept`), every later one finds the pending or the
+        fetched vector (`kept`)."""
+        raw, swept = bank.row_popcounts(
+            lambda array: self._dispatch_counts(array, None))
+        if swept:
+            self._note_topn_rows("swept", bank.array.shape[0])
+        if self.stats is not None:
+            self.stats.with_tags(
+                f"path:{'swept' if swept else 'kept'}").count(
+                "executor.bank_popcounts", 1)
+        return raw, swept
 
     def _popcount_row(self, words):
         """Dispatch a total popcount over row words [S, W] (device)."""
@@ -2707,9 +2722,10 @@ class Executor:
             allowed_rows = set(field.row_attr_store.ids_matching(
                 attr_name, call.arg("attrValues", [])))
         tanimoto = call.uint_arg("tanimotoThreshold") or 0
-        # tanimoto applies only WITH a filter; only then does the sweep
-        # compute (and the answer fetch) the rows' own popcounts.
-        with_raw = bool(tanimoto) and filter_words is not None
+        # The rule applies only WITH a filter (filterless it would zero
+        # every denominator and empty the result); only then does the
+        # answer read the rows' own popcounts.
+        similar = bool(tanimoto) and filter_words is not None
         # Candidate restriction + absolute count floor (reference
         # topOptions.RowIDs / MinThreshold, fragment.go:1248,
         # executor.go:698).
@@ -2806,7 +2822,9 @@ class Executor:
         # The HBM bound must consider the *bank* size (all view rows), not
         # the attr-filtered subset — the full-bank path materializes every
         # view row.
-        dispatched = []  # (rows, bank, device_out)
+        dispatched = []  # (rows, bank, counts_out, the bank's popcounts)
+        arrays = ()
+        swept = False   # this call's `arrays` list the bank's popcounts
         chunked: List[List[int]] = []
         # Banks are width-trimmed for the sweep: only whole-row popcounts
         # are computed, and the dropped word tail is all-zero.
@@ -2823,23 +2841,31 @@ class Executor:
                                     trim=True)
             fuser = getattr(self._tls, "fuser", None)
             if fuser is not None and filter_words is not None \
-                    and not with_raw:
+                    and not tanimoto:
                 # Inside a batch the sweep waits for its bankmates: the
                 # filtered sweeps of one bank array share one pass
                 # (fusion.FusionCollector.add_sweep). The lane holds the
-                # array read here, as a dispatch would.
+                # array read here, as a dispatch would. A tanimoto
+                # sweep stays a launch of its own: a group on one-lane
+                # rows is not measured (ROADMAP A8).
                 out = fuser.add_sweep(bank.array, _align_words(
                     filter_words, bank.array.shape[-1]))
             else:
-                out = self._dispatch_counts(bank.array, filter_words,
-                                            with_raw)
+                out = self._dispatch_counts(bank.array, filter_words)
                 if filter_words is not None:
                     self._note_sweep_group(1, 1)
             self._note_topn_rows("swept", bank.array.shape[0])
             # A sweep of the whole view reads the bank's own rows in
             # slot order (None): no per-row mapping in finalize.
+            raw = None
+            if similar:
+                raw, swept = self._bank_popcounts(bank)
             dispatched.append((None if all_rows is view_rows else all_rows,
-                               bank, out))
+                               bank, out, raw))
+            # What finalize will fetch: the sweep's counts and, when this
+            # call is the one that swept them, the bank's popcounts (a
+            # call that found them pending leaves the fetch to that one).
+            arrays = (out, raw) if swept else (out,)
         else:
             if PBANK_ENABLED and self.mesh is None and len(shards) == 1 \
                     and allowed_rows is None and not ids_arg and n \
@@ -2849,11 +2875,8 @@ class Executor:
                 pb = view.positions_bank(shards[0], width)
                 if pb is not None:
                     src_pb = None
-                    if with_raw:
+                    if similar:
                         src_pb = self._popcount_row(filter_words)
-                    # tanimoto applies only WITH a filter (`with_raw`) —
-                    # passing it filterless would zero every denominator
-                    # and empty the result.
                     # Slice the filter row to the BANK's width: a plan
                     # can be wider than the bank (Not() rides the
                     # existence view, Shift(), a wider sibling field),
@@ -2870,7 +2893,7 @@ class Executor:
                         fw_b = [filter_words[0][:width]]
                     self._note_topn("positions")
                     return self._topn_positions(
-                        pb, fw_b, n, tanimoto if with_raw else 0,
+                        pb, fw_b, n, tanimoto if similar else 0,
                         min_threshold, src_pb)
             # Huge row sets stream through transient chunk banks to bound
             # HBM (the 50k-row ranked-cache shape). Chunks are uploaded
@@ -2881,7 +2904,7 @@ class Executor:
             chunked = [all_rows[c0:c0 + TOPN_CHUNK_ROWS]
                        for c0 in range(0, len(all_rows), TOPN_CHUNK_ROWS)]
         src_dev = None
-        if with_raw:
+        if similar:
             src_dev = self._popcount_row(filter_words)
             if self.stats is not None:
                 self.stats.count("executor.tanimoto_sweeps", 1)
@@ -2905,8 +2928,8 @@ class Executor:
             self._note_topn_rows("swept", bank.array.shape[0])
             # A chunk bank holds exactly its chunk's rows.
             return (None, bank,
-                    self._dispatch_counts(bank.array, filter_words,
-                                          with_raw))
+                    self._dispatch_counts(bank.array, filter_words),
+                    self._bank_popcounts(bank)[0] if similar else None)
 
         def finalize() -> PairsResult:
             parts = []  # (rows_arr, counts_arr[, raws_arr])
@@ -2916,13 +2939,12 @@ class Executor:
             i = 0
             fetched_rows = 0
             while pending:
-                rows, bank, out = pending.pop(0)
+                rows, bank, counts_out, raw = pending.pop(0)
                 # One-chunk lookahead: overlap the next upload+sweep with
                 # this fetch while keeping at most two chunk banks live.
                 i += 1
                 if i < len(chunked):
                     pending.append(dispatch_chunk(chunked[i]))
-                counts_out, raw_out = out if with_raw else (out, None)
                 with TIMELINE.stage("finish.slot_map") as sm:
                     # The bank's rows in slot order ARE the sweep's rows:
                     # slots past them are zero rows. A restricted call
@@ -2931,14 +2953,22 @@ class Executor:
                     sm.set("rows", len(rows_arr))
                     vectors = [np.asarray(counts_out)]
                     fetched_rows += vectors[0].size
-                    if with_raw:
+                    if similar:
                         # The rows' own popcounts are the bank's, not the
                         # query's: fetched by the first tanimoto answer
                         # of a bank version and kept with the bank.
-                        if bank.popcounts is None:
-                            bank.popcounts = np.asarray(raw_out)
-                            fetched_rows += bank.popcounts.size
-                        vectors.append(bank.popcounts)
+                        if not isinstance(raw, np.ndarray):
+                            if dispatched and not swept and \
+                                    not isinstance(bank.popcounts,
+                                                   np.ndarray):
+                                # The call that swept them was dropped
+                                # before its finalize: the fetch is this
+                                # one's, a `d2h` of its own.
+                                fetch_host((raw,))
+                            raw, fetched = bank.host_popcounts()
+                            if fetched:
+                                fetched_rows += raw.size
+                        vectors.append(raw)
                     sel = slice(0, len(rows_arr))
                     if rows is not None:
                         sel = np.flatnonzero(np.isin(
@@ -2953,7 +2983,7 @@ class Executor:
                 rows_arr, counts_arr, *raws = parts[0] if len(parts) == 1 \
                     else (np.concatenate(col) for col in zip(*parts))
                 fs.set("rows", len(rows_arr))
-                if with_raw:
+                if similar:
                     raws_arr = raws[0]
                     src_total = int(np.asarray(src_dev))
                     # The reference's rule (fragment.go:1146-1150): a row
@@ -2992,14 +3022,8 @@ class Executor:
             # the full-bank path needs no such care because its device
             # arrays snapshot at dispatch.
             return finalize()
-        # What finalize will fetch: each sweep's counts; under tanimoto
-        # the filter's popcount and, until the bank holds them, the
-        # rows' own.
-        arrays = tuple(out[0] if with_raw else out
-                       for _, _, out in dispatched)
-        if with_raw:
-            arrays += tuple(out[1] for _, bank, out in dispatched
-                            if bank.popcounts is None) + (src_dev,)
+        if similar:
+            arrays += (src_dev,)  # the filter's own popcount
         return _Pending(finalize, arrays=arrays)
 
     _PBANK_KERNELS: Dict[tuple, Callable] = {}

@@ -124,8 +124,8 @@ def count_and(a: ArrayLike, b: ArrayLike) -> jax.Array:
 
 # A filtered bank sweep's filter is cut into word-axis pieces of this many
 # words per shard, and into no more pieces than this: the one fusion that
-# reads the bank has an output a piece and filter (two `with_raw`), and
-# with sixteen it loses a third of its rate (see masked_row_counts).
+# reads the bank has an output a piece and filter, and with sixteen it
+# loses a third of its rate (see masked_row_counts).
 SWEEP_PIECE_WORDS = 8192
 SWEEP_MAX_PIECES = 4
 
@@ -166,11 +166,8 @@ def _masked_counts(bank: jax.Array, filt: jax.Array, cuts) -> jax.Array:
         for c in cuts))
 
 
-def masked_row_counts(bank: jax.Array, filt: jax.Array,
-                      with_raw: bool = False):
-    """|row ∧ filt| per row of a bank: ([R, S, W], [S, W]) -> uint32[R];
-    `with_raw` also returns |row| per row (the tanimoto denominator's
-    term) as a second uint32[R], from the same pass over the bank.
+def masked_row_counts(bank: jax.Array, filt: jax.Array) -> jax.Array:
+    """|row ∧ filt| per row of a bank: ([R, S, W], [S, W]) -> uint32[R].
 
     The sum runs over word-axis pieces of the filter rather than over the
     whole [S, W] at once. The answer is the same; the program is not.
@@ -181,19 +178,14 @@ def masked_row_counts(bank: jax.Array, filt: jax.Array,
     temporaries that XLA keeps in on-chip memory, and the one fusion that
     reads the bank (`popcnt_reduce_fusion`, one output per piece) takes
     windows of a few dozen rows x 64 KiB each: 755 GB/s, the rate of
-    the unfiltered sweep, with or without `with_raw` (PERF.md §6, PR 25:
-    the table of variants, shapes and piece counts). Equal pieces make
+    the unfiltered sweep (PERF.md §6, PR 25: the table of variants, shapes
+    and piece counts). Equal pieces make
     one fusion, a piece of another width a fusion of its own (the odd
     lane's: a third of the bank's bytes at three lanes, 1/251 at most at
     a shard's width). The word axis is the one to cut: under a mesh the shard axis
     is split over devices, and a slice along it would move data between
     them."""
-    cuts = _sweep_cuts(filt.shape[-1])
-    counts = _masked_counts(bank, filt, cuts)
-    if not with_raw:
-        return counts
-    return counts, functools.reduce(jnp.add, (
-        popcount(bank[..., c], axis=(-2, -1)) for c in cuts))
+    return _masked_counts(bank, filt, _sweep_cuts(filt.shape[-1]))
 
 
 def masked_row_counts_multi(bank: jax.Array, *filts: jax.Array):

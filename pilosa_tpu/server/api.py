@@ -106,9 +106,9 @@ class API:
                               for d in ("h2d", "d2h")
                               for k in ("bytes", "transfers")]
                          + [("executor.bank_upload_bytes", 0)])
-        # ... and so are the TopN path and time-range leaf counters: a
-        # share of them is read over a window in which one path may
-        # never be taken.
+        # ... and so are the TopN path, time-range leaf and bank
+        # popcount counters: a share of them is read over a window in
+        # which one path may never be taken.
         for path in Executor.TOPN_PATHS:
             self.stats.with_tags(f"path:{path}").count(
                 "executor.topn_sweeps", 0)
@@ -116,6 +116,9 @@ class API:
             self.stats.with_tags(f"path:{path}").count(
                 "executor.range_leaves", 0)
         self.stats.count("executor.range_views", 0)
+        for path in Executor.POPCOUNT_PATHS:
+            self.stats.with_tags(f"path:{path}").count(
+                "executor.bank_popcounts", 0)
         # ... and the bank-sweep launches, read per answer.
         self.stats.count("executor.sweep_launches", 0)
         # The process-wide workload recorder (utils/hotspots.py)

@@ -145,7 +145,7 @@ def test_the_sources_query_and_the_span_of_its_sweep(bench, library, served):
     spans = [e for e in served.get("/debug/timeline?last=4")["traceEvents"]
              if e.get("ph") == "X"]
     sweep = [e["args"] for e in spans if e["name"] == "dispatch"
-             and e["args"].get("program") == "topn_sweep_tanimoto"]
+             and e["args"].get("program") == "topn_sweep"]
     # 4,096 molecules + the zero slot pad to 8,192 slots of 128 words.
     assert sweep and sweep[-1]["rows"] == 8192 and sweep[-1]["words"] == 128
     names = {e["name"] for e in spans}
@@ -254,9 +254,9 @@ def test_restricted_candidates_map_through_the_same_array(ex):
 
 
 def test_popcounts_live_one_bank_version(ex):
-    """The rows' own popcounts ride to the host with the first tanimoto
-    answer of a bank version and stay with the bank; a write to the
-    field makes a new bank, which fetches its own."""
+    """The rows' own popcounts are swept for, and ride to the host with,
+    the first tanimoto answer of a bank version and stay with the bank;
+    a write to the field makes a new bank, which sweeps for its own."""
     q = "TopN(fingerprint, Row(fingerprint=3), n=10, tanimotoThreshold=50)"
     slots = _bank(ex).array.shape[0]
     assert _bank(ex).popcounts is None
@@ -267,17 +267,23 @@ def test_popcounts_live_one_bank_version(ex):
     _topn(ex, q)
     assert _bank(ex).popcounts is kept
     assert _counters(ex)["executor.topn_rows_fetched"] == 3 * slots
+    assert _counters(ex)["executor.bank_popcounts{path:kept}"] == 1
     ex.execute("mole", "Set(41, fingerprint=70001)")
     assert _bank(ex).popcounts is None
     assert _topn(ex, q) == [(3, 10), (70001, 8), (9, 7), (4000, 7)]
     assert _bank(ex).popcounts[_bank(ex).slot(70001)] == 10
     assert _counters(ex)["executor.topn_rows_fetched"] == 5 * slots
+    assert _counters(ex)["executor.bank_popcounts{path:swept}"] == 2
+    # A tanimoto answer is one `topn_sweep`; each bank version one
+    # unfiltered sweep more.
+    assert _counters(ex)["executor.sweep_launches"] == 3 + 2
 
 
 def test_the_three_counters_add_up(ex):
-    """Per TopN call: `topn_rows_swept` the slots of each bank it swept,
-    `topn_rows_fetched` the vector elements its finalize read,
-    `tanimoto_sweeps` the calls that also swept the rows' popcounts."""
+    """Per TopN call: `topn_rows_swept` the slots of each bank it swept
+    (the one for the rows' popcounts included), `topn_rows_fetched` the vector elements its finalize read,
+    `tanimoto_sweeps` the calls that read the rows' popcounts — which
+    the batch's two share: one sweep, one fetch."""
     slots = _bank(ex).array.shape[0]
     tani = "TopN(fingerprint, Row(fingerprint=3), n=3, tanimotoThreshold=60)"
     plain = "TopN(fingerprint, Row(fingerprint=3), n=3)"
@@ -289,11 +295,13 @@ def test_the_three_counters_add_up(ex):
     assert c["executor.tanimoto_sweeps"] == 2
     swept = c["executor.topn_sweeps{path:resident}"]
     assert swept == 5
-    assert c["executor.topn_rows_swept"] == swept * slots
-    # A count vector a call, and the popcounts while no answer had kept
-    # them: both tanimoto calls of the batch were staged before either
-    # finished.
-    assert c["executor.topn_rows_fetched"] in (6 * slots, 7 * slots)
+    assert c["executor.topn_rows_swept"] == (swept + 1) * slots
+    # A count vector a call, and the popcounts once: both tanimoto calls
+    # of the batch were staged before either finished, and the second
+    # found the first's vector pending.
+    assert c["executor.topn_rows_fetched"] == 6 * slots
+    assert c["executor.bank_popcounts{path:swept}"] == 1
+    assert c["executor.bank_popcounts{path:kept}"] == 1
 
 
 def test_tanimoto_before_and_after_a_write_in_one_query(ex):

@@ -124,17 +124,15 @@ class _EnvSpy:
 def test_no_environment_variable_picks_the_sweep_body(tmp_holder,
                                                       monkeypatch):
     """`_counts_fn` chooses its program from the call's arguments alone:
-    building all three reads no switch, and nothing but the arguments
+    building both reads no switch, and nothing but the arguments
     is in a jit key."""
     ex = Executor(tmp_holder)
     spy = _EnvSpy(os.environ)
     monkeypatch.setattr(os, "environ", spy)
     shape = (8, 2, 64)
     ex._counts_fn(True, shape)
-    ex._counts_fn(True, shape, with_raw=True)
     ex._counts_fn(False, shape)
     monkeypatch.undo()
     assert [k for k in spy.read if k.startswith("PILOSA_TPU_")] == []
     assert sorted(ex._jit_cache) == [
-        f"topn:{f}:{r}:(8, 2, 64)"
-        for f, r in [(False, False), (True, False), (True, True)]]
+        f"topn:{f}:(8, 2, 64)" for f in (False, True)]

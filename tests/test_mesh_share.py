@@ -104,7 +104,7 @@ def _topn_paths(stats) -> dict:
 
 
 def _query(kind: str) -> tuple:
-    """(pql, filtered, tanimoto) of one of the three sweep programs."""
+    """(pql, filtered, tanimoto) of one of the three kinds of sweep call."""
     if kind == "unfiltered":
         return "TopN(f, n=12)", False, 0
     if kind == "filtered":
@@ -150,6 +150,15 @@ def test_a_bank_whose_share_fits_takes_the_resident_sweep(
     assert res.pairs == want
     assert _topn_paths(ex.stats) == dict.fromkeys(
         Executor.TOPN_PATHS, 0) | {"resident": 1}
+    # One sweep program an answer under the mesh too; a tanimoto call
+    # reads the sharded bank's own popcounts, swept for once (the bank
+    # outlives this test: another may have left them).
+    c = ex.stats.snapshot()["counters"]
+    asked = sum(c.get(f"executor.bank_popcounts{{path:{p}}}", 0)
+                for p in Executor.POPCOUNT_PATHS)
+    assert asked == (1 if tanimoto else 0)
+    assert c["executor.sweep_launches"] == 1 + c.get(
+        "executor.bank_popcounts{path:swept}", 0)
     single = Executor(h)
     single.stats = MemStatsClient()
     (res,) = single.execute("i", pql)

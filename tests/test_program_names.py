@@ -61,7 +61,9 @@ QUERIES = [
     ("Count(Row(f=1))", "tree_count"),
     ("Row(f=1)", "tree_row"),
     ("TopN(f, Row(g=2), n=3)", "topn_sweep"),
-    ("TopN(f, Row(g=2), n=3, tanimotoThreshold=10)", "topn_sweep_tanimoto"),
+    # A tanimoto call runs the filtered sweep, and the unfiltered one
+    # for the bank's popcounts: no program of its own.
+    ("TopN(f, Row(g=2), n=3, tanimotoThreshold=10)", "topn_sweep"),
     ("TopN(f, n=3)", "topn_sweep_unfiltered"),
     ("Sum(Row(f=1), field=v)", "bsi_sum"),
     ("Min(field=v)", "bsi_min"),
@@ -94,8 +96,8 @@ def test_lowered_module_names(ex):
     filt = jnp.zeros((2, 64), jnp.uint32)
     sweep = ex._counts_fn(True, bank.shape)
     assert _module(sweep, bank, filt) == "jit_topn_sweep"
-    assert _module(ex._counts_fn(True, bank.shape, with_raw=True),
-                   bank, filt) == "jit_topn_sweep_tanimoto"
+    ex.execute("i", "TopN(f, Row(g=2), n=3, tanimotoThreshold=10)")
+    assert not any("tanimoto" in name for name in _programs(ex).values())
     assert _module(ex._counts_fn(False, bank.shape), bank, None) == \
         "jit_topn_sweep_unfiltered"
     modules = [_module(sweep, bank, filt)]

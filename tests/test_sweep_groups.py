@@ -138,7 +138,7 @@ def test_lone_sweep_in_a_batch_runs_the_one_filter_program(
     with ex._jit_cache_lock:
         after = {k for k in ex._jit_cache if k.startswith("topn")}
     assert after == before and len(after) == 1
-    assert next(iter(after)).startswith("topn:True:False:(")
+    assert next(iter(after)).startswith("topn:True:(")
     assert _counters(ex)["executor.sweep_group_filters{k:1}"] == 2
 
 
@@ -227,12 +227,17 @@ def test_other_sweeps_never_join_a_group(ex, monkeypatch, kind):
     calls = sweep_calls(monkeypatch)
     out = ex.execute_batch([("i", q, None) for q in queries])
     assert _answers(out) == direct
-    program = {"tanimoto": "topn_sweep_tanimoto",
+    # The `tanimoto` kind launches one-filter `topn_sweep`s (the bank's
+    # popcounts were swept by the direct answers above: all four kept).
+    program = {"tanimoto": "topn_sweep",
                "unfiltered": "topn_sweep_unfiltered",
                "streamed": "topn_sweep"}[kind]
     assert calls == [(program, 1)] * 4
     c = _counters(ex)
     assert c["executor.sweep_launches"] == 4
+    assert c.get("executor.bank_popcounts{path:kept}", 0) == (
+        4 if kind == "tanimoto" else 0)
+    assert "executor.bank_popcounts{path:swept}" not in c
     assert not any(k.startswith("executor.sweep_group_filters{k:")
                    and not k.endswith("{k:1}") for k in c)
 
@@ -255,7 +260,10 @@ def test_group_counters_add_up_to_the_filtered_resident_calls(
                       "executor.sweep_group_filters{k:4}": 7}
     assert sum(groups.values()) == c["executor.topn_sweeps{path:resident}"]
     assert c["executor.sweep_pad_lanes"] == 1
-    assert c["executor.sweep_launches"] == 1 + 2 + 1 + 1
+    # ... and the tanimoto call's bank is new to it: one unfiltered
+    # sweep for the rows' own popcounts.
+    assert c["executor.sweep_launches"] == 1 + 2 + 1 + 1 + 1
+    assert c["executor.bank_popcounts{path:swept}"] == 1
 
 
 def test_group_array_is_fetched_and_counted_once(ex, monkeypatch):

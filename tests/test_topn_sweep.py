@@ -1,6 +1,7 @@
-"""The filtered TopN bank sweep: three programs selected by what the call
-carries (`topn_sweep`, `topn_sweep_tanimoto`, `topn_sweep_unfiltered`),
-answers against a plain numpy recomputation on every path that reaches
+"""The TopN bank sweep: two one-filter programs selected by what the call
+carries (`topn_sweep`, `topn_sweep_unfiltered`; a tanimoto call launches
+the first, and the second once a bank version for the rows' own
+popcounts), answers against a plain numpy recomputation on every path that reaches
 `Executor._dispatch_counts`, and structural guards on what each program
 computes and fetches."""
 
@@ -11,11 +12,12 @@ import pytest
 
 from pilosa_tpu.core.field import FieldOptions
 from pilosa_tpu.core.holder import Holder
+from pilosa_tpu.core.view import ViewBank
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.executor import executor as ex_mod
 from pilosa_tpu.ops.bitset import (SHARD_WIDTH, SWEEP_MAX_PIECES,
                                    WORDS_PER_SHARD, masked_row_counts,
-                                   masked_row_counts_multi,
+                                   masked_row_counts_multi, popcount,
                                    sweep_filter_pieces)
 from pilosa_tpu.parallel import MeshContext
 from pilosa_tpu.server.api import API
@@ -110,16 +112,19 @@ def test_filtered_sweep_matches_numpy(sweep_holder, mesh4, monkeypatch,
     want = _reference(rows, 12, tanimoto)
     assert res.pairs == want
     assert 3 <= len(want) and (not tanimoto or len(want) < 12)
-    # The program that ran is the one the call's arguments select, at the
-    # bank's (trimmed) width, once per chunk.
-    program = "topn_sweep_tanimoto" if tanimoto else "topn_sweep"
+    # The programs that ran are the ones the call's arguments select, at
+    # the bank's (trimmed) width, once per chunk shape: `topn_sweep`, and
+    # under tanimoto the unfiltered sweep that computes a bank's |row|.
+    programs = {"topn_sweep"} | (
+        {"topn_sweep_unfiltered"} if tanimoto else set())
     with ex._jit_cache_lock:
         keys = [k for k, fn in ex._jit_cache.items()
                 if k.startswith("topn:")]
         names = {ex._jit_cache[k].__name__ for k in keys}
-    assert names == {program}, keys
+    assert names == programs, keys
     assert all(f", {width})" in k for k in keys), keys
-    assert len(keys) == (1 if stream == "resident_bank" else 2), keys
+    assert len(keys) == len(programs) * (
+        1 if stream == "resident_bank" else 2), keys
 
 
 def _popcnts(fn, *args) -> int:
@@ -132,8 +137,9 @@ def _n_results(fn, *args) -> int:
 
 def test_each_sweep_program_computes_only_what_its_query_reads(tmp_holder):
     """The rows' own popcounts (tanimoto's denominator) are a second
-    popcount + reduction over the whole bank: only `topn_sweep_tanimoto`
-    may carry them."""
+    popcount + reduction over the whole bank: no filtered sweep carries
+    them. A tanimoto call launches `topn_sweep`, and
+    `topn_sweep_unfiltered` once a bank version."""
     ex = Executor(tmp_holder)
     bank = jnp.zeros((8, 2, 64), jnp.uint32)
     filt = jnp.zeros((2, 64), jnp.uint32)
@@ -141,9 +147,15 @@ def test_each_sweep_program_computes_only_what_its_query_reads(tmp_holder):
     sweep = ex._counts_fn(True, bank.shape)
     assert (_popcnts(sweep, bank, filt), _n_results(sweep, bank, filt)) \
         == (1, 1)
-    tani = ex._counts_fn(True, bank.shape, with_raw=True)
-    assert (_popcnts(tani, bank, filt), _n_results(tani, bank, filt)) \
-        == (2, 2)
+    launched = []
+    ex._call_program = lambda fn, *args: launched.append(fn.__name__) \
+        or fn(*args)
+    vbank = ViewBank(bank, {}, 7, {})
+    for _ in range(3):                       # three tanimoto calls' worth
+        ex._dispatch_counts(vbank.array, filt)
+        ex._bank_popcounts(vbank)
+    assert launched == ["topn_sweep", "topn_sweep_unfiltered",
+                        "topn_sweep", "topn_sweep"]
     unf = ex._counts_fn(False, bank.shape)
     assert (_popcnts(unf, bank, None), _n_results(unf, bank, None)) \
         == (1, 1)
@@ -156,9 +168,9 @@ def test_each_sweep_program_computes_only_what_its_query_reads(tmp_holder):
     wide = ex._counts_fn(True, wide_bank.shape)
     assert (_popcnts(wide, wide_bank, wide_filt),
             _n_results(wide, wide_bank, wide_filt)) == (k, 1)
-    wide_t = ex._counts_fn(True, wide_bank.shape, with_raw=True)
-    assert (_popcnts(wide_t, wide_bank, wide_filt),
-            _n_results(wide_t, wide_bank, wide_filt)) == (2 * k, 2)
+    wide_u = ex._counts_fn(False, wide_bank.shape)
+    assert (_popcnts(wide_u, wide_bank, None),
+            _n_results(wide_u, wide_bank, None)) == (1, 1)
 
 
 @pytest.mark.parametrize("n_words,pieces,left_over_lanes", [
@@ -214,18 +226,21 @@ _DRAWS = [(shape, 0.5) for shape in _SHAPES] + \
 
 
 @pytest.mark.parametrize("shape,density", _DRAWS)
-@pytest.mark.parametrize("with_raw", [False, True])
-def test_masked_row_counts_matches_numpy(shape, density, with_raw):
+@pytest.mark.parametrize("body", ["filtered", "rows_own"])
+def test_masked_row_counts_matches_numpy(shape, density, body):
+    """The two vectors a tanimoto answer reads, each from its own
+    program's body: |row ∧ filter| (`masked_row_counts`) and |row|
+    (`popcount` over the shard and word axes, what the bank keeps)."""
     rng = np.random.default_rng(sum(shape))
     bank = _words(rng, shape, density)
     filt = _words(rng, shape[1:], density)
-    got = masked_row_counts(jnp.asarray(bank), jnp.asarray(filt), with_raw)
-    want = np.bitwise_count(bank & filt).sum(axis=(1, 2))
-    if with_raw:
-        assert np.asarray(got[1]).tolist() == \
-            np.bitwise_count(bank).sum(axis=(1, 2)).tolist()
-        got = got[0]
-    assert got.dtype == jnp.uint32
+    if body == "filtered":
+        got = masked_row_counts(jnp.asarray(bank), jnp.asarray(filt))
+        want = np.bitwise_count(bank & filt).sum(axis=(1, 2))
+    else:
+        got = popcount(jnp.asarray(bank), axis=(-2, -1))
+        want = np.bitwise_count(bank).sum(axis=(1, 2))
+    assert got.dtype == jnp.uint32 and got.shape == shape[:1]
     assert np.asarray(got).tolist() == want.tolist()
 
 
@@ -299,16 +314,20 @@ def test_unfiltered_sweep_matches_numpy(tmp_holder, density):
         np.bitwise_count(bank).sum(axis=(1, 2)).tolist()
 
 
-@pytest.mark.parametrize("tanimoto,program,vectors", [
-    (0, "topn_sweep", 1),
-    (30, "topn_sweep_tanimoto", 2),
+@pytest.mark.parametrize("tanimoto,answers", [
+    # (sweep programs launched, [R] vectors fetched) per answer in turn
+    (0, [(["topn_sweep"], 1), (["topn_sweep"], 1)]),
+    # The first tanimoto answer of a bank version also sweeps and
+    # fetches the rows' own popcounts; the second finds them kept.
+    (30, [(["topn_sweep", "topn_sweep_unfiltered"], 2),
+          (["topn_sweep"], 1)]),
 ])
-def test_sweep_fetches_one_vector_unless_tanimoto_reads_two(
-        tmp_holder, tanimoto, program, vectors):
-    """`dispatch program=` names which sweep ran (the specialisation's
-    engagement counter) and the answer's `d2h` is `slots x 4` bytes per
-    vector the finalize reads: counts, plus raw and the filter's own
-    popcount only under tanimotoThreshold."""
+def test_sweep_fetches_one_vector_and_a_bank_versions_popcounts_once(
+        tmp_holder, tanimoto, answers):
+    """`dispatch program=` names which sweeps ran and the answer's `d2h`
+    is `slots x 4` bytes per vector the finalize reads: counts; under
+    tanimotoThreshold the filter's own popcount (one uint32) and, on the
+    first answer of a bank version only, the bank's popcounts."""
     TIMELINE.reset()
     TIMELINE.configure(enabled=True, ring=64, sample_every=1)
     try:
@@ -320,18 +339,49 @@ def test_sweep_fetches_one_vector_unless_tanimoto_reads_two(
         api.executor.result_cache.enabled = False
         q = "TopN(f, Row(f=1), n=2" + (
             f", tanimotoThreshold={tanimoto})" if tanimoto else ")")
-        assert api.query("tl", q)["results"][0] == [{"id": 1, "count": 3}]
-        spans = list(TIMELINE.requests()[-1].root.walk())
-        programs = [s.attrs["program"] for s in spans
-                    if s.name == "dispatch"
-                    and s.attrs["program"].startswith("topn_sweep")]
-        assert programs == [program]
         slots = f.view().device_bank((0, 1), trim=True).array.shape[0]
-        (d2h,) = [s for s in spans if s.name == "d2h"]
-        # tanimoto also fetches the filter's popcount (one uint32).
-        assert d2h.attrs["bytes"] == vectors * slots * 4 + (
-            4 if tanimoto else 0)
-        assert d2h.attrs["transfers"] == vectors + (1 if tanimoto else 0)
+        for programs, vectors in answers:
+            assert api.query("tl", q)["results"][0] == [
+                {"id": 1, "count": 3}]
+            spans = list(TIMELINE.requests()[-1].root.walk())
+            assert [s.attrs["program"] for s in spans
+                    if s.name == "dispatch"
+                    and s.attrs["program"].startswith("topn_sweep")] \
+                == programs
+            (d2h,) = [s for s in spans if s.name == "d2h"]
+            assert d2h.attrs["bytes"] == vectors * slots * 4 + (
+                4 if tanimoto else 0)
+            assert d2h.attrs["transfers"] == vectors + (
+                1 if tanimoto else 0)
+    finally:
+        TIMELINE.reset()
+        TIMELINE.configure(enabled=True, ring=256, sample_every=1)
+
+
+def test_a_dropped_sweepers_vector_is_a_d2h_of_the_call_that_fetches_it(
+        tmp_holder):
+    """The call that swept a bank's popcounts never reached its finalize:
+    the next answer's own `d2h` is its counts and the filter's popcount,
+    and the pending vector crosses as a second `d2h`, inside `finish`."""
+    TIMELINE.reset()
+    TIMELINE.configure(enabled=True, ring=64, sample_every=1)
+    try:
+        idx = tmp_holder.create_index("tl")
+        f = idx.create_field("f")
+        f.import_bits(np.array([1, 1, 1, 2], np.uint64),
+                      np.array([1, 2, 3, 9], np.uint64))
+        api = API(tmp_holder, stats=MemStatsClient())
+        api.executor.result_cache.enabled = False
+        bank = f.view().device_bank((0,), trim=True)
+        slots = bank.array.shape[0]
+        assert api.executor._bank_popcounts(bank)[1]
+        for vectors in ([slots * 4 + 4, slots * 4], [slots * 4 + 4]):
+            assert api.query("tl", "TopN(f, Row(f=1), n=2, "
+                             "tanimotoThreshold=30)")["results"][0] == [
+                {"id": 1, "count": 3}]
+            spans = list(TIMELINE.requests()[-1].root.walk())
+            assert [s.attrs["bytes"] for s in spans
+                    if s.name == "d2h"] == vectors
     finally:
         TIMELINE.reset()
         TIMELINE.configure(enabled=True, ring=256, sample_every=1)
