@@ -3537,6 +3537,36 @@ class Executor:
     GROUPBY_CHUNK_BYTES = int(os.environ.get("PILOSA_TPU_GROUPBY_CHUNK_BYTES",
                                              256 << 20))
 
+    # Bytes of group masks one `groupby_sum` launch gathers ([g, S, W]
+    # u32): 512 groups of 16 shards. A launch's masks are read
+    # ceil(planes / GROUPSUM_PLANES) times; four filters a pass is what
+    # one bank-reading fusion takes before it splits (PERF.md §6, PR 29).
+    GROUPSUM_CHUNK_BYTES = 1 << 30
+    GROUPSUM_PLANES = 4
+
+    def _group_by_aggregate(self, idx: Index, call: Call):
+        """(field, bsiGroup) of a GroupBy's `aggregate=Sum(field=f)`,
+        None without the argument; any other value is an error, never a
+        silent count."""
+        agg = call.arg("aggregate")
+        if agg is None:
+            return None
+        if not isinstance(agg, Call) or agg.name != "Sum" or agg.children \
+                or set(agg.args) - {"field", "_field"}:
+            raise ExecutionError(
+                "GroupBy aggregate must be Sum(field=<int field>)")
+        fname = agg.arg("field") or agg.arg("_field")
+        if not isinstance(fname, str):
+            raise ExecutionError(
+                "GroupBy aggregate must be Sum(field=<int field>)")
+        field = idx.field(fname)
+        if field is None:
+            raise ExecutionError(f"field not found: {fname}")
+        bsig = field.bsi_groups.get(fname)
+        if bsig is None:
+            raise ExecutionError(f"field {fname} is not an int field")
+        return field, bsig
+
     # graftlint: materialize — GroupBy is level-synchronous by design:
     # the host reads each depth's [P, R] count matrix to prune empty
     # prefixes, page (`previous`), and decide HBM spills before
@@ -3551,13 +3581,38 @@ class Executor:
         [P, R, S, W] AND+popcount kernel (chunked over P to bound HBM),
         instead of one device dispatch per prefix row. Empty prefixes are
         pruned between levels, which the reference's iterator cannot do
-        (it re-walks the full cross product, executor.go:2820-2996)."""
+        (it re-walks the full cross product, executor.go:2820-2996).
+
+        `aggregate=Sum(field=f)` (upstream's argument from v1.4 on) adds
+        a `sum` to every group: the signed sum of int field `f` over the
+        group's columns THAT HAVE A VALUE in `f`. A group is what it is
+        without the argument — the columns in the intersection of its
+        rows and the filter — and so is its `count`: a column with no
+        value in `f` counts and adds nothing (assumed of upstream, whose
+        tree this sandbox does not hold; docs/query-language.md says
+        so). Groups with count 0 are left out, the order is row ids
+        ascending child by child, `limit` and `previous` act on groups
+        as ever. Anything but `Sum(field=<int field>)` is an error.
+        The sums are computed on the device after the last level, for
+        the groups the answer holds and no others: their masks
+        (prefix ∧ row ∧ not-null) are gathered once, GROUPSUM_CHUNK_BYTES
+        of them a launch, and every pass over them counts
+        GROUPSUM_PLANES bit planes of `f` at once (`groupby_sum`:
+        ceil(planes / GROUPSUM_PLANES) passes over a launch's masks,
+        never a launch a group); the host weighs the plane counts.
+
+        With a filter, a child whose row stack is larger than a chunk
+        (GROUPBY_CHUNK_BYTES) is first counted against the filter in ONE
+        pass over its bank (`groupby_prune`, the sweep cells' kernel)
+        and keeps the rows the filter meets: a level then expands the
+        40 brands of a category, not the field's 1,000."""
         import jax
         import jax.numpy as jnp
         from pilosa_tpu.ops.bitset import popcount
 
         if not call.children or any(c.name != "Rows" for c in call.children):
             raise ExecutionError("GroupBy requires Rows() arguments")
+        aggregate = self._group_by_aggregate(idx, call)
         shards = self._shards(idx, shards, pad=False)
         # GroupBy only ANDs, so a group's count is zero on any shard
         # some child field doesn't cover — restrict to the INTERSECTION
@@ -3614,7 +3669,9 @@ class Executor:
             wmin = min(wmin, filter_words.shape[-1])
             filter_words = filter_words[..., :wmin]
 
-        def _jit(key, builder):
+        levels = [0]    # level programs launched (executor.groupby_levels)
+
+        def _jit(key, builder, span="groupby"):
             fn = self._jit_get(key)
             if fn is None:
                 # "gb_cnt0:(3, 16, 48)" -> program "groupby_cnt0".
@@ -3624,7 +3681,8 @@ class Executor:
                 self._jit_put(key, fn)
 
             def call(*args):
-                with self._dispatch_span("groupby"):
+                levels[0] += span == "groupby"
+                with self._dispatch_span(span):
                     return fn(*args)
             return call
 
@@ -3637,14 +3695,38 @@ class Executor:
                 # depth gates which prefixes expand.
                 return np.asarray(dev)
 
-        def stacks_at(depth):
-            _, ids = child_rows[depth]
-            bank = banks[depth]
-            sel = upload(np.asarray([bank.slot(r) for r in ids],
-                                    dtype=np.int32))
-            return bank.array[sel][..., :wmin]  # [R, S, Wmin]
-
         n_shards, depth_n = len(shards), len(child_rows)
+        # child_slots[d]: the bank slots of child d's rows, beside
+        # child_rows[d]'s ids. A child pruned by the filter keeps the
+        # rows the filter meets, padded to a multiple of eight with the
+        # bank's zero slot (row id -1: an all-zero row is in no group),
+        # so that the level programs meet few shapes.
+        child_slots = [np.asarray([b.slot(r) for r in ids], dtype=np.int32)
+                       for b, (_, ids) in zip(banks, child_rows)]
+        if filter_words is not None:
+            from pilosa_tpu.ops.bitset import masked_row_counts
+            for d, (bank, (fname, ids)) in enumerate(zip(banks, child_rows)):
+                if len(ids) * n_shards * wmin * 4 <= self.GROUPBY_CHUNK_BYTES:
+                    continue
+                sweep = _jit(
+                    f"gb_prune:{bank.array.shape}:{wmin}",
+                    lambda b, f: masked_row_counts(b[..., :f.shape[-1]], f))
+                met = _host(sweep(bank.array, filter_words))[
+                    child_slots[d]] > 0
+                kept = [r for r, m in zip(ids, met) if m]
+                if not kept:
+                    self._note_group_by(0, levels[0])
+                    return []
+                pad = -len(kept) % 8
+                child_rows[d] = (fname, kept + [-1] * pad)
+                child_slots[d] = np.concatenate(
+                    [child_slots[d][met],
+                     np.full(pad, bank.zero_slot, np.int32)])
+
+        def stacks_at(depth):
+            return banks[depth].array[upload(child_slots[depth])][
+                ..., :wmin]  # [R, S, Wmin]
+
         # prefixes: the surviving frontier [P, S, W] — a jnp array while
         # its total bytes fit GROUPBY_CHUNK_BYTES, spilled to a host
         # numpy array beyond that and re-uploaded chunk by chunk (the
@@ -3689,7 +3771,18 @@ class Executor:
                     keep_idx = np.where(nz)[0]
                     if len(keep_idx) == 0:
                         continue
-                    kept = new[upload(keep_idx.astype(np.int32))]
+                    # Where some pair died, one of the dead rows of
+                    # `new` — all zeros — pads the survivors to a
+                    # multiple of eight: the next level's programs meet
+                    # a few frontier sizes, not one a draw (a shape met
+                    # first under load compiles there, for seconds). A
+                    # pad prefix counts zero against every row and is in
+                    # no group.
+                    pad = -len(keep_idx) % 8 if len(keep_idx) < len(nz) \
+                        else 0
+                    take = np.concatenate(
+                        [keep_idx, np.full(pad, np.argmin(nz))])
+                    kept = new[upload(take.astype(np.int32))]
                     kept_bytes += kept.nbytes
                     if not spilled and kept_bytes > self.GROUPBY_CHUNK_BYTES:
                         # Survivors exceed the device budget: collect
@@ -3703,7 +3796,9 @@ class Executor:
                     kept_rows.extend(
                         prefix_rows[c0 + int(k) // R] + (int(ids[k % R]),)
                         for k in keep_idx)
+                    kept_rows.extend([(-1,) * (depth + 1)] * pad)
                 if not kept_words:
+                    self._note_group_by(0, levels[0])
                     return []
                 if len(kept_words) == 1:
                     prefixes = kept_words[0]
@@ -3719,17 +3814,24 @@ class Executor:
         ids = child_rows[depth_n - 1][1]
         fields = [f for f, _ in child_rows]
         results: List[GroupCount] = []
+        sums = None if aggregate is None else self._GroupSums(
+            self, aggregate, shards, stacks, _jit)
         if prefixes is None:
             cnt = _jit(f"gb_cnt0:{stacks.shape}",
                        lambda st: popcount(st, axis=(-2, -1)))
             counts = _host(cnt(stacks))[None, :]  # [1, R]
         else:
             counts = None
+        # The count program fuses its AND into the reduction — one
+        # fusion and no [p, R, S, W] temporary (compiled for a described
+        # v5e at [20, 16, 32768] x [40, 16, 32768]) — so a chunk is
+        # bounded by the prefixes it reads, not by prefixes x rows.
         chunk_p = max(1, self.GROUPBY_CHUNK_BYTES //
-                      max(1, n_shards * wmin * 4 * R))
+                      max(1, n_shards * wmin * 4))
         for c0 in range(0, len(prefix_rows), chunk_p):
             if limit and len(results) >= limit:
                 break
+            sub = None
             if counts is None:
                 sub = frontier_chunk(prefixes, c0, c0 + chunk_p)
                 cntk = _jit(
@@ -3740,6 +3842,8 @@ class Executor:
                 chunk_counts = _host(cntk(sub, stacks))  # [p, R]
             else:
                 chunk_counts = counts[c0:c0 + chunk_p]
+            first = len(results)
+            picked = []     # (prefix in the chunk, row) of each group kept
             for pi in range(chunk_counts.shape[0]):
                 row_pre = prefix_rows[c0 + pi]
                 # Paging: results are lexicographic by row-id tuple, so a
@@ -3759,7 +3863,127 @@ class Executor:
                     group = [FieldRow(f, rid) for f, rid in
                              zip(fields, tup)]
                     results.append(GroupCount(group, int(crow[ri])))
-        return results
+                    picked.append((pi, int(ri)))
+            if sums is not None and picked:
+                sums.launch(sub, picked, results[first:])
+        self._note_group_by(len(results), levels[0])
+        if sums is None or not results:
+            return results
+
+        def finalize() -> List[GroupCount]:
+            sums.finalize()
+            return results
+
+        return _Pending(finalize, arrays=sums.arrays())
+
+    def _count(self, name: str, n: int) -> None:
+        if self.stats is not None:
+            self.stats.count(name, n)
+
+    def _note_group_by(self, groups: int, levels: int) -> None:
+        """A GroupBy's counters: groups answered, level programs
+        launched (a pruning sweep, an expansion, a count)."""
+        self._count("executor.groupby_groups", groups)
+        self._count("executor.groupby_levels", levels)
+
+    class _GroupSums:
+        """The `sum` of a GroupBy's groups (`aggregate=Sum(field=f)`):
+        launched chunk by chunk of the last level as its groups are
+        picked, fetched together when the answer is finalized.
+
+        One `groupby_sum` launch takes up to GROUPSUM_CHUNK_BYTES of
+        groups: it gathers their masks — prefix ∧ row ∧ f's not-null
+        plane, [g, S, W] — and counts |mask ∧ plane| for every bit plane
+        of f, GROUPSUM_PLANES planes a pass over the masks
+        (`masked_row_counts_multi`, the sweep group's kernel with the
+        masks as its bank and the planes as its filters). g is padded to
+        a few sizes, so a family of queries meets a few shapes. The
+        counts come back as u32 [planes + 1, g] (the last row |mask|,
+        the columns with a value); the host weighs them: a plane's
+        count << its bit, plus the field's offset (bsiGroup.min, which
+        a signed field's is negative) times the columns with a value."""
+
+        def __init__(self, ex, aggregate, shards, stacks, jit):
+            field, self.bsig = aggregate
+            self.ex, self.jit, self.stacks = ex, jit, stacks
+            self.depth = self.bsig.bit_depth
+            bank = ex._get_bank_for(field, view_bsi_name(field.name),
+                                    shards)
+            self.planes = bank.array
+            self.sel = upload(np.asarray(
+                [bank.slot(r) for r in range(self.depth + 1)],
+                dtype=np.int32))
+            # Every operand is ANDed: the narrowest width is enough.
+            self.width = min(stacks.shape[-1], bank.array.shape[-1])
+            self.pending = []   # (device counts [planes + 1, g], groups)
+
+        def launch(self, sub, picked, groups) -> None:
+            import jax.numpy as jnp
+            from pilosa_tpu.ops.bitset import (masked_row_counts_multi,
+                                               popcount)
+            depth, w, k = self.depth, self.width, Executor.GROUPSUM_PLANES
+            n_shards = self.stacks.shape[-2]
+            g_max = max(1, Executor.GROUPSUM_CHUNK_BYTES
+                        // (n_shards * w * 4))
+
+            def run(pre, pi, st, ri, bank, sel):
+                planes = bank[sel][..., :w]      # [depth + 1, S, w]
+                mask = st[ri][..., :w]
+                if pre is not None:
+                    mask = jnp.bitwise_and(mask, pre[pi][..., :w])
+                mask = jnp.bitwise_and(mask, planes[-1][None])
+                rows = [masked_row_counts_multi(
+                    mask, *(planes[j] for j in range(i, min(i + k, depth))))
+                    for i in range(0, depth, k)]
+                rows.append(popcount(mask, axis=(-2, -1))[None])
+                return jnp.concatenate(rows)     # [depth + 1, g]
+
+            with TIMELINE.stage("groupby.aggregate", groups=len(picked),
+                                planes=depth + 1) as span:
+                launches = 0
+                for g0 in range(0, len(picked), g_max):
+                    part = picked[g0:g0 + g_max]
+                    g = len(part)
+                    # g is padded to a power of two up to 128 and to a
+                    # multiple of 128 past it: a family of queries
+                    # meets a few shapes (this program compiles for
+                    # seconds). Pad lanes recompute the chunk's first
+                    # group; nobody reads them.
+                    lanes = max(8, _pow2(g)) if g <= 128 \
+                        else -(-g // 128) * 128
+                    idx = np.zeros((2, lanes), np.int32)
+                    idx[:, :g] = np.asarray(part, dtype=np.int32).T
+                    fn = self.jit(
+                        f"gb_sum:{idx.shape[1]}:"
+                        f"{None if sub is None else sub.shape}:"
+                        f"{self.stacks.shape}:{self.planes.shape}:d{depth}",
+                        run, span="groupby_sum")
+                    out = fn(sub, upload(idx[0]), self.stacks,
+                             upload(idx[1]), self.planes, self.sel)
+                    self.pending.append((out, groups[g0:g0 + g]))
+                    launches += 1
+                span.set("launches", launches)
+            self.ex._count("executor.groupsum_launches", launches)
+            self.ex._count("executor.groupsum_plane_rows",
+                           len(picked) * (depth + 1))
+
+        def arrays(self) -> tuple:
+            return tuple(out for out, _ in self.pending)
+
+        def finalize(self) -> None:
+            for out, groups in self.pending:
+                counts = np.asarray(out)[:, :len(groups)]
+                valued = counts[-1].astype(np.int64)
+                if self.depth <= 32:    # 2^30 columns << 32 fits int64
+                    base = (counts[:-1].astype(np.int64)
+                            << np.arange(self.depth,
+                                         dtype=np.int64)[:, None]).sum(0)
+                    base = base.tolist()
+                else:
+                    base = [sum(c << i for i, c in enumerate(col))
+                            for col in counts[:-1].T.tolist()]
+                for gc, b, n in zip(groups, base, valued.tolist()):
+                    gc.sum = b + self.bsig.min * n
 
     # -------------------------------------------------------- Sum/Min/Max
 

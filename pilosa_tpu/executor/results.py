@@ -140,10 +140,17 @@ class GroupCount:
     """One GroupBy group (reference GroupCount, executor.go:1009)."""
     group: List[FieldRow]
     count: int
+    # GroupBy(..., aggregate=Sum(field=f)): the signed sum of f over the
+    # group's columns that have a value; None (and no JSON key) without
+    # the argument.
+    sum: Optional[int] = None
 
     def to_json(self):
-        return {"group": [g.to_json() for g in self.group],
-                "count": int(self.count)}
+        out = {"group": [g.to_json() for g in self.group],
+               "count": int(self.count)}
+        if self.sum is not None:
+            out["sum"] = int(self.sum)
+        return out
 
 
 def result_to_json(result) -> Any:

@@ -109,6 +109,8 @@ def merge_results(call: Call, parts: List[Any]) -> Any:
                 key = str(gc["group"])
                 if key in acc:
                     acc[key]["count"] += gc["count"]
+                    if "sum" in gc:     # aggregate=Sum(field=f)
+                        acc[key]["sum"] = acc[key].get("sum", 0) + gc["sum"]
                 else:
                     acc[key] = dict(gc)
         out = sorted(acc.values(), key=lambda g: str(g["group"]))

@@ -121,6 +121,12 @@ class API:
                 "executor.bank_popcounts", 0)
         # ... and the bank-sweep launches, read per answer.
         self.stats.count("executor.sweep_launches", 0)
+        # ... and a GroupBy's: groups answered, level programs, and the
+        # group-sum launches of `aggregate=Sum(field=f)` with the
+        # (group, plane) rows they counted.
+        for name in ("groupby_groups", "groupby_levels",
+                     "groupsum_launches", "groupsum_plane_rows"):
+            self.stats.count(f"executor.{name}", 0)
         # The process-wide workload recorder (utils/hotspots.py)
         # increments its counters (pilosa_fragment_reads_total, ...)
         # straight into the stats client at record time so the

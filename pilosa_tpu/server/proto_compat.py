@@ -322,6 +322,8 @@ def _encode_result(result) -> bytes:
                         frb += _varint_field(2, int(fr.get("rowID", 0)))
                     g += _len_field(1, frb)
                 g += _varint_field(2, int(gc["count"]))
+                if "sum" in gc:     # upstream v1.4's GroupCount.Sum
+                    g += _varint_field(3, int(gc["sum"]))
                 out += _len_field(8, g)
             return out
         # Pairs (TopN); an EMPTY list also encodes as empty Pairs — the
