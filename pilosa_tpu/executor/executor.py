@@ -419,6 +419,104 @@ def _pow2(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
+def _bsi_sel(bank, depth: int):
+    """The slots of a BSI plane bank's rows 0..depth (the bit planes,
+    then not-null) as a device vector, memoized on the bank object:
+    banks rebuild when fragment versions change, so the memo
+    invalidates with them, and repeat Sum/Min/Max calls and GroupBy
+    sums skip a host build + device upload (~1 ms/call, comparable to
+    a whole device sweep)."""
+    sel = getattr(bank, "_bsi_sel", None)
+    if sel is None or int(sel.shape[0]) != depth + 1:
+        sel = upload(np.asarray(
+            [bank.slot(r) for r in range(depth + 1)], dtype=np.int32))
+        bank._bsi_sel = sel
+    return sel
+
+
+def _gb_shape(operand) -> str:
+    """A GroupBy program operand in its jit key: the shape of an array
+    (of each, for a tuple), None for an operand left out. An index
+    vector's shape is its length."""
+    if isinstance(operand, tuple):
+        return "+".join(str(a.shape) for a in operand)
+    return str(None if operand is None else operand.shape)
+
+
+def _gb_rows(w: int, *picks, fixed=()):
+    """Inside a GroupBy program: [n, S, w] whose row i is the AND of
+    arr[idx[i]][:, :w] over the `picks` (arr [N, S, W], idx int32 [n])
+    and of every [S, W] array of `fixed` — one row an iteration of a
+    loop, each operand row read in place (a dynamic slice) and the
+    result written once. Not a gather: for rows past 1 MiB XLA's TPU
+    gather first copies its WHOLE operand in two halves of the word
+    axis (2 GiB of temporaries and ~9 ms for the 2 GiB `p_brand1` bank,
+    what an eager `bank[slots]` paid; PERF.md §6 PR 33)."""
+    import jax.numpy as jnp
+    from jax import lax
+    n = picks[0][1].shape[0]
+    s = picks[0][0].shape[-2]
+
+    def body(i, out):
+        row = None
+        for arr, idx in picks:
+            r = lax.dynamic_slice(arr, (idx[i], 0, 0), (1, s, w))[0]
+            row = r if row is None else jnp.bitwise_and(row, r)
+        for f in fixed:
+            row = jnp.bitwise_and(row, f[..., :w])
+        return lax.dynamic_update_index_in_dim(out, row, i, 0)
+    return lax.fori_loop(0, n, body,
+                         jnp.zeros((n, s, w), picks[0][0].dtype))
+
+
+def _gb_prefixes(w: int, src, pi, prev, si):
+    """Inside a GroupBy program: the prefixes [p, S, w] of one chunk of
+    a `_Frontier`, gathered and ANDed from its operands —
+
+    - `src`: the filter's words [S, W] (one prefix, broadcast), or the
+      prefix arrays [p_k, S, w] the level before wrote, as a tuple read
+      end to end, `pi` [p] picking among them (None: all, in order);
+    - `prev`, `si`: the level before's resident bank array and, per
+      prefix, the slot of its row there.
+
+    Either pair may be None; not both."""
+    import jax.numpy as jnp
+    picks = [] if prev is None else [(prev, si)]
+    if not isinstance(src, tuple):      # the filter's words, or nothing
+        fixed = () if src is None else (src,)
+        return _gb_rows(w, *picks, fixed=fixed) if picks \
+            else src[None, :, :w]
+    src = src[0] if len(src) == 1 else jnp.concatenate(src)
+    if pi is not None:
+        return _gb_rows(w, (src, pi), *picks)
+    src = src[..., :w]
+    return jnp.bitwise_and(src, _gb_rows(w, *picks)) if picks else src
+
+
+@dataclass
+class _Frontier:
+    """The prefixes of a GroupBy that survived the levels so far, as
+    operands of the next level's programs (`_gb_prefixes`): prefix i is
+    src[pi[i]] ∧ prev[si[i]], and rows[i] its row-id tuple. `src` is
+    the filter's words, a tuple of device arrays, or — spilled — ONE
+    host array, whose chunks are gathered on the host and uploaded."""
+    src: Any
+    pi: Optional[np.ndarray]
+    prev: Any
+    si: Optional[np.ndarray]
+    rows: List[tuple]
+
+    def chunk(self, c0: int, c1: int) -> tuple:
+        """(src, pi, prev, si) of prefixes c0:c1, index vectors
+        uploaded."""
+        src = self.src
+        pi = None if self.pi is None else self.pi[c0:c1]
+        if isinstance(src, np.ndarray):
+            src, pi = (upload(src[pi]),), None
+        return (src, None if pi is None else upload(pi), self.prev,
+                None if self.si is None else upload(self.si[c0:c1]))
+
+
 def _align_words(words, width: int):
     """Slice or zero-pad the trailing word axis to exactly `width`
     (None passes through). Both directions are semantically safe for
@@ -3605,10 +3703,25 @@ class Executor:
         (GROUPBY_CHUNK_BYTES) is first counted against the filter in ONE
         pass over its bank (`groupby_prune`, the sweep cells' kernel)
         and keeps the rows the filter meets: a level then expands the
-        40 brands of a category, not the field's 1,000."""
+        40 brands of a category, not the field's 1,000.
+
+        From the filter's words on, the level loop launches its own
+        programs and nothing else (`groupby_prune`, `groupby_cnt0`,
+        `groupby_exp`, `groupby_cntN`, `groupby_sum`), and what it moves
+        is index vectors up and count matrices down. A level program
+        takes arrays that are already resident — a child's bank, the
+        filter's words, the prefixes the level before wrote — and int32
+        index vectors; the row gathers, the cut to the narrowest width
+        and the ANDs happen inside it. `groupby_exp` / `groupby_cntN`
+        return the chunk's prefixes [p, S, W] beside the counts [p, R]:
+        the survivors of a level are (prefix, row) index pairs into
+        them and into the level's bank (`_Frontier`), and the [p*R, S, W]
+        cross product is never written. No `jnp` call and no indexing
+        of a device array happens outside a jit here
+        (tests/test_groupby_programs.py holds it to that)."""
         import jax
         import jax.numpy as jnp
-        from pilosa_tpu.ops.bitset import popcount
+        from pilosa_tpu.ops.bitset import masked_row_counts, popcount
 
         if not call.children or any(c.name != "Rows" for c in call.children):
             raise ExecutionError("GroupBy requires Rows() arguments")
@@ -3664,10 +3777,10 @@ class Executor:
                                             rows_needed=set(ids_)))
         # GroupBy only intersects, so all operands can slice down to the
         # NARROWEST width: bits past the narrowest operand AND to zero.
+        # Every program cuts its operands to it inside the jit.
         wmin = min(b.array.shape[-1] for b in banks)
         if filter_words is not None:
             wmin = min(wmin, filter_words.shape[-1])
-            filter_words = filter_words[..., :wmin]
 
         levels = [0]    # level programs launched (executor.groupby_levels)
 
@@ -3696,6 +3809,7 @@ class Executor:
                 return np.asarray(dev)
 
         n_shards, depth_n = len(shards), len(child_rows)
+        per_prefix = max(1, n_shards * wmin * 4)    # bytes of [S, wmin]
         # child_slots[d]: the bank slots of child d's rows, beside
         # child_rows[d]'s ids. A child pruned by the filter keeps the
         # rows the filter meets, padded to a multiple of eight with the
@@ -3704,13 +3818,13 @@ class Executor:
         child_slots = [np.asarray([b.slot(r) for r in ids], dtype=np.int32)
                        for b, (_, ids) in zip(banks, child_rows)]
         if filter_words is not None:
-            from pilosa_tpu.ops.bitset import masked_row_counts
             for d, (bank, (fname, ids)) in enumerate(zip(banks, child_rows)):
-                if len(ids) * n_shards * wmin * 4 <= self.GROUPBY_CHUNK_BYTES:
+                if len(ids) * per_prefix <= self.GROUPBY_CHUNK_BYTES:
                     continue
                 sweep = _jit(
                     f"gb_prune:{bank.array.shape}:{wmin}",
-                    lambda b, f: masked_row_counts(b[..., :f.shape[-1]], f))
+                    lambda b, f: masked_row_counts(b[..., :wmin],
+                                                   f[..., :wmin]))
                 met = _host(sweep(bank.array, filter_words))[
                     child_slots[d]] > 0
                 kept = [r for r, m in zip(ids, met) if m]
@@ -3723,123 +3837,128 @@ class Executor:
                     [child_slots[d][met],
                      np.full(pad, bank.zero_slot, np.int32)])
 
-        def stacks_at(depth):
-            return banks[depth].array[upload(child_slots[depth])][
-                ..., :wmin]  # [R, S, Wmin]
+        def count_rows(bank, slots):
+            """`groupby_cnt0`: |row| of a child's rows, no prefix."""
+            return _jit(
+                f"gb_cnt0:{bank.shape}:{slots.shape[0]}:{wmin}",
+                lambda b, sl: popcount(_gb_rows(wmin, (b, sl)),
+                                       axis=(-2, -1))
+            )(bank, slots)
 
-        # prefixes: the surviving frontier [P, S, W] — a jnp array while
-        # its total bytes fit GROUPBY_CHUNK_BYTES, spilled to a host
-        # numpy array beyond that and re-uploaded chunk by chunk (the
-        # frontier of a deep high-cardinality GroupBy is P*S*W words and
-        # must not live unbudgeted in HBM; the reference iterates
-        # host-side throughout, executor.go:2820-2996). None means the
-        # full universe. prefix_rows[i] = row-id tuple.
-        prefixes = filter_words[None] if filter_words is not None else None
-        prefix_rows: List[tuple] = [()]
+        def count_level(name, chunk, bank, slots):
+            """One level program over one chunk of the frontier:
+            (prefixes [p, S, wmin], counts [p, R]) — the chunk's
+            prefixes gathered and ANDed (`_gb_prefixes`) from `chunk`'s
+            resident operands, and |prefix ∧ row| for the R rows at
+            `slots` of the level's resident `bank`. Keyed by every
+            operand's shape and every index vector's length."""
+            shapes = ":".join(_gb_shape(a) for a in (*chunk, bank, slots))
 
-        def frontier_chunk(frontier, c0, c1):
-            sub = frontier[c0:c1]
-            return sub if isinstance(sub, jnp.ndarray) else upload(sub)
+            def run(src, pi, prev, si, b, sl):
+                pre = _gb_prefixes(wmin, src, pi, prev, si)
+                return pre, popcount(
+                    jnp.bitwise_and(pre[:, None],
+                                    _gb_rows(wmin, (b, sl))[None]),
+                    axis=(-2, -1))
+            return _jit(f"gb_{name}:{shapes}:{wmin}", run)(
+                *chunk, bank, slots)
+
+        # The frontier: the prefixes that survived the levels so far, as
+        # index vectors into arrays that are already resident
+        # (`_Frontier`) — a level's program gathers its chunk's
+        # prefixes itself. None means the full universe.
+        frontier = None if filter_words is None else _Frontier(
+            filter_words, None, None, None, [()])
 
         for depth in range(depth_n - 1):
-            stacks = stacks_at(depth)
-            R = stacks.shape[0]
-            if prefixes is None:
-                cnt = _jit(f"gb_cnt0:{stacks.shape}",
-                           lambda st: popcount(st, axis=(-2, -1)))
-                nz = _host(cnt(stacks)) > 0
-                keep_idx = np.where(nz)[0]
-                prefixes = stacks[upload(keep_idx.astype(np.int32))]
-                prefix_rows = [(int(child_rows[depth][1][i]),)
-                               for i in keep_idx]
-            else:
-                per_new = n_shards * wmin * 4
-                chunk_p = max(1, self.GROUPBY_CHUNK_BYTES // (per_new * R))
-                kept_words, kept_rows = [], []
-                kept_bytes = 0
-                spilled = False
-                for c0 in range(0, len(prefix_rows), chunk_p):
-                    sub = frontier_chunk(prefixes, c0, c0 + chunk_p)
-                    expand = _jit(
-                        f"gb_exp:{sub.shape}:{stacks.shape}",
-                        lambda s, st: (
-                            lambda new: (new, popcount(new, axis=(-2, -1))))(
-                            jnp.bitwise_and(s[:, None], st[None]).reshape(
-                                -1, st.shape[-2], st.shape[-1])))
-                    new, counts = expand(sub, stacks)
-                    nz = _host(counts) > 0
-                    keep_idx = np.where(nz)[0]
-                    if len(keep_idx) == 0:
-                        continue
-                    # Where some pair died, one of the dead rows of
-                    # `new` — all zeros — pads the survivors to a
-                    # multiple of eight: the next level's programs meet
-                    # a few frontier sizes, not one a draw (a shape met
-                    # first under load compiles there, for seconds). A
-                    # pad prefix counts zero against every row and is in
-                    # no group.
-                    pad = -len(keep_idx) % 8 if len(keep_idx) < len(nz) \
-                        else 0
-                    take = np.concatenate(
-                        [keep_idx, np.full(pad, np.argmin(nz))])
-                    kept = new[upload(take.astype(np.int32))]
-                    kept_bytes += kept.nbytes
-                    if not spilled and kept_bytes > self.GROUPBY_CHUNK_BYTES:
-                        # Survivors exceed the device budget: collect
-                        # the rest of this depth's frontier in host
-                        # memory (chunks re-upload at the next depth).
-                        spilled = True
-                        self.groupby_spill_events += 1
-                        kept_words = [np.asarray(w) for w in kept_words]
-                    kept_words.append(np.asarray(kept) if spilled else kept)
-                    ids = child_rows[depth][1]
-                    kept_rows.extend(
-                        prefix_rows[c0 + int(k) // R] + (int(ids[k % R]),)
-                        for k in keep_idx)
-                    kept_rows.extend([(-1,) * (depth + 1)] * pad)
-                if not kept_words:
-                    self._note_group_by(0, levels[0])
-                    return []
-                if len(kept_words) == 1:
-                    prefixes = kept_words[0]
-                elif spilled:
-                    prefixes = np.concatenate(kept_words)
-                else:
-                    prefixes = jnp.concatenate(kept_words)
-                prefix_rows = kept_rows
+            bank, ids = banks[depth].array, child_rows[depth][1]
+            R = len(ids)
+            slots = upload(child_slots[depth])
+            if frontier is None:
+                keep_idx = np.flatnonzero(_host(count_rows(bank, slots)))
+                frontier = _Frontier(
+                    None, None, bank, child_slots[depth][keep_idx],
+                    [(int(ids[i]),) for i in keep_idx])
+                continue
+            # A chunk's (prefix, row) pairs fit GROUPBY_CHUNK_BYTES as
+            # prefixes: what the level after may have to hold of them.
+            chunk_p = max(1, self.GROUPBY_CHUNK_BYTES // (per_prefix * R))
+            # What this level hands on: the prefixes its chunks'
+            # programs wrote (`outs`: this level's frontier, dense) and,
+            # per surviving (prefix, row) pair, the prefix's place in
+            # them and the row's place in `ids`. They stay on the
+            # device while their bytes fit GROUPBY_CHUNK_BYTES and are
+            # collected in host memory beyond that (the frontier of a
+            # deep high-cardinality GroupBy is P*S*W words and must not
+            # live unbudgeted in HBM; the reference iterates host-side
+            # throughout, executor.go:2820-2996).
+            outs, kept_pi, kept_ri, kept_rows = [], [], [], []
+            n_out = kept_bytes = 0
+            spilled = False
+            for c0 in range(0, len(frontier.rows), chunk_p):
+                pre, counts = count_level(
+                    "exp", frontier.chunk(c0, c0 + chunk_p), bank, slots)
+                nz = _host(counts).ravel() > 0
+                keep_idx = np.flatnonzero(nz)
+                if len(keep_idx) == 0:
+                    continue
+                # Where some pair died, one of the dead pairs — its AND
+                # is all zeros — pads the survivors to a multiple of
+                # eight: the next level's programs meet a few frontier
+                # sizes, not one a draw (a shape met first under load
+                # compiles there, for seconds). A pad prefix counts
+                # zero against every row and is in no group.
+                pad = -len(keep_idx) % 8 if len(keep_idx) < len(nz) \
+                    else 0
+                take = np.concatenate(
+                    [keep_idx, np.full(pad, np.argmin(nz))])
+                kept_pi.append(n_out + take // R)
+                kept_ri.append(take % R)
+                kept_rows.extend(
+                    frontier.rows[c0 + int(k) // R] + (int(ids[k % R]),)
+                    for k in keep_idx)
+                kept_rows.extend([(-1,) * (depth + 1)] * pad)
+                n_out += pre.shape[0]
+                kept_bytes += pre.nbytes
+                if not spilled and kept_bytes > self.GROUPBY_CHUNK_BYTES:
+                    spilled = True
+                    self.groupby_spill_events += 1
+                    outs = [_host(o) for o in outs]
+                outs.append(_host(pre) if spilled else pre)
+            if not outs:
+                self._note_group_by(0, levels[0])
+                return []
+            frontier = _Frontier(
+                np.concatenate(outs) if spilled else tuple(outs),
+                np.concatenate(kept_pi).astype(np.int32), bank,
+                child_slots[depth][np.concatenate(kept_ri)], kept_rows)
 
         # Final depth: count every (prefix × row) pair in chunked batches.
-        stacks = stacks_at(depth_n - 1)
-        R = stacks.shape[0]
-        ids = child_rows[depth_n - 1][1]
+        bank, ids = banks[-1].array, child_rows[-1][1]
+        slots = upload(child_slots[-1])
         fields = [f for f, _ in child_rows]
         results: List[GroupCount] = []
         sums = None if aggregate is None else self._GroupSums(
-            self, aggregate, shards, stacks, _jit)
-        if prefixes is None:
-            cnt = _jit(f"gb_cnt0:{stacks.shape}",
-                       lambda st: popcount(st, axis=(-2, -1)))
-            counts = _host(cnt(stacks))[None, :]  # [1, R]
+            self, aggregate, shards, bank, child_slots[-1], wmin, _jit)
+        if frontier is None:
+            counts = _host(count_rows(bank, slots))[None, :]  # [1, R]
+            prefix_rows = [()]
         else:
             counts = None
+            prefix_rows = frontier.rows
         # The count program fuses its AND into the reduction — one
         # fusion and no [p, R, S, W] temporary (compiled for a described
         # v5e at [20, 16, 32768] x [40, 16, 32768]) — so a chunk is
         # bounded by the prefixes it reads, not by prefixes x rows.
-        chunk_p = max(1, self.GROUPBY_CHUNK_BYTES //
-                      max(1, n_shards * wmin * 4))
+        chunk_p = max(1, self.GROUPBY_CHUNK_BYTES // per_prefix)
         for c0 in range(0, len(prefix_rows), chunk_p):
             if limit and len(results) >= limit:
                 break
-            sub = None
+            pre = None
             if counts is None:
-                sub = frontier_chunk(prefixes, c0, c0 + chunk_p)
-                cntk = _jit(
-                    f"gb_cntN:{sub.shape}:{stacks.shape}",
-                    lambda s, st: popcount(
-                        jnp.bitwise_and(s[:, None], st[None]),
-                        axis=(-2, -1)))
-                chunk_counts = _host(cntk(sub, stacks))  # [p, R]
+                pre, dev = count_level(
+                    "cntN", frontier.chunk(c0, c0 + chunk_p), bank, slots)
+                chunk_counts = _host(dev)  # [p, R]
             else:
                 chunk_counts = counts[c0:c0 + chunk_p]
             first = len(results)
@@ -3865,7 +3984,7 @@ class Executor:
                     results.append(GroupCount(group, int(crow[ri])))
                     picked.append((pi, int(ri)))
             if sums is not None and picked:
-                sums.launch(sub, picked, results[first:])
+                sums.launch(pre, picked, results[first:])
         self._note_group_by(len(results), levels[0])
         if sums is None or not results:
             return results
@@ -3893,7 +4012,9 @@ class Executor:
 
         One `groupby_sum` launch takes up to GROUPSUM_CHUNK_BYTES of
         groups: it gathers their masks — prefix ∧ row ∧ f's not-null
-        plane, [g, S, W] — and counts |mask ∧ plane| for every bit plane
+        plane, [g, S, W] — from the last level's prefixes, the last
+        child's resident bank and f's plane bank by three index
+        vectors, and counts |mask ∧ plane| for every bit plane
         of f, GROUPSUM_PLANES planes a pass over the masks
         (`masked_row_counts_multi`, the sweep group's kernel with the
         masks as its bank and the planes as its filters). g is padded to
@@ -3903,35 +4024,32 @@ class Executor:
         count << its bit, plus the field's offset (bsiGroup.min, which
         a signed field's is negative) times the columns with a value."""
 
-        def __init__(self, ex, aggregate, shards, stacks, jit):
+        def __init__(self, ex, aggregate, shards, bank, slots, wmin, jit):
             field, self.bsig = aggregate
-            self.ex, self.jit, self.stacks = ex, jit, stacks
+            self.ex, self.jit = ex, jit
+            self.bank, self.slots = bank, slots   # the last child's
             self.depth = self.bsig.bit_depth
-            bank = ex._get_bank_for(field, view_bsi_name(field.name),
-                                    shards)
-            self.planes = bank.array
-            self.sel = upload(np.asarray(
-                [bank.slot(r) for r in range(self.depth + 1)],
-                dtype=np.int32))
+            planes = ex._get_bank_for(field, view_bsi_name(field.name),
+                                      shards)
+            self.planes, self.sel = planes.array, _bsi_sel(planes,
+                                                           self.depth)
             # Every operand is ANDed: the narrowest width is enough.
-            self.width = min(stacks.shape[-1], bank.array.shape[-1])
+            self.width = min(wmin, planes.array.shape[-1])
             self.pending = []   # (device counts [planes + 1, g], groups)
 
-        def launch(self, sub, picked, groups) -> None:
+        def launch(self, pre, picked, groups) -> None:
             import jax.numpy as jnp
             from pilosa_tpu.ops.bitset import (masked_row_counts_multi,
                                                popcount)
             depth, w, k = self.depth, self.width, Executor.GROUPSUM_PLANES
-            n_shards = self.stacks.shape[-2]
+            n_shards = self.bank.shape[-2]
             g_max = max(1, Executor.GROUPSUM_CHUNK_BYTES
                         // (n_shards * w * 4))
 
-            def run(pre, pi, st, ri, bank, sel):
-                planes = bank[sel][..., :w]      # [depth + 1, S, w]
-                mask = st[ri][..., :w]
-                if pre is not None:
-                    mask = jnp.bitwise_and(mask, pre[pi][..., :w])
-                mask = jnp.bitwise_and(mask, planes[-1][None])
+            def run(pre, pi, bank, si, plane_bank, sel):
+                planes = _gb_rows(w, (plane_bank, sel))  # [depth + 1, S, w]
+                picks = [(bank, si)] + ([] if pre is None else [(pre, pi)])
+                mask = _gb_rows(w, *picks, fixed=(planes[-1],))
                 rows = [masked_row_counts_multi(
                     mask, *(planes[j] for j in range(i, min(i + k, depth))))
                     for i in range(0, depth, k)]
@@ -3953,13 +4071,15 @@ class Executor:
                         else -(-g // 128) * 128
                     idx = np.zeros((2, lanes), np.int32)
                     idx[:, :g] = np.asarray(part, dtype=np.int32).T
+                    idx[:, g:] = idx[:, :1]
                     fn = self.jit(
-                        f"gb_sum:{idx.shape[1]}:"
-                        f"{None if sub is None else sub.shape}:"
-                        f"{self.stacks.shape}:{self.planes.shape}:d{depth}",
+                        f"gb_sum:{lanes}:{_gb_shape(pre)}:"
+                        f"{self.bank.shape}:{self.planes.shape}:"
+                        f"d{depth}:{w}",
                         run, span="groupby_sum")
-                    out = fn(sub, upload(idx[0]), self.stacks,
-                             upload(idx[1]), self.planes, self.sel)
+                    out = fn(pre, None if pre is None else upload(idx[0]),
+                             self.bank, upload(self.slots[idx[1]]),
+                             self.planes, self.sel)
                     self.pending.append((out, groups[g0:g0 + g]))
                     launches += 1
                 span.set("launches", launches)
@@ -4006,15 +4126,7 @@ class Executor:
         shards = self._shards(idx, shards)
         depth = bsig.bit_depth
         bank = self._get_bank_for(field, view_bsi_name(field_name), shards)
-        # Plane-slot vector memoized on the bank object: banks rebuild
-        # when fragment versions change, so the memo invalidates with
-        # them, and repeat Sum/Min/Max calls skip a host build + device
-        # upload (~1 ms/call, comparable to the whole device sweep).
-        sel = getattr(bank, "_bsi_sel", None)
-        if sel is None or int(sel.shape[0]) != depth + 1:
-            sel = upload(np.asarray(
-                [bank.slot(r) for r in range(depth + 1)], dtype=np.int32))
-            bank._bsi_sel = sel
+        sel = _bsi_sel(bank, depth)
         filter_words = None
         if call.children:
             filter_words = _align_words(

@@ -290,6 +290,11 @@ def test_group_by_frontier_spills_to_host(ex, monkeypatch):
                     if not k.startswith("gb_")}
     (got,) = e.execute("gs", q)
     assert e.groupby_spill_events > 0  # frontier really left the device
+    # ... and came back chunk by chunk: a spilled chunk's prefixes are
+    # gathered on the host, so its program takes no index vector for
+    # them (None where the key holds that vector's length).
+    assert any(k.startswith("gb_cntN:") and k.split(":")[2] == "None"
+               for k in e._jit_cache)
     as_map = lambda res: {tuple(fr.row_id for fr in gc.group): gc.count
                           for gc in res}
     assert as_map(got) == as_map(want) and len(got) > 0

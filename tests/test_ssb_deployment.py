@@ -317,6 +317,9 @@ def test_pruned_children_and_chunked_launches_answer_the_same(
     assert c["executor.groupby_groups"] == len(want)
     assert 1 < c["executor.groupsum_launches"] < len(want) / 4
     assert any(k.startswith("gb_prune:") for k in e._jit_cache)
+    # A level of one prefix a chunk hands on several prefix arrays: the
+    # last level's program reads them end to end ("+" in its key).
+    assert any(k.startswith("gb_cntN:") and "+" in k for k in e._jit_cache)
 
 
 def test_rows_that_share_columns_each_get_the_columns_value(tmp_holder):
