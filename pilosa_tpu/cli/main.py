@@ -488,7 +488,7 @@ def cmd_server(args) -> int:
         diagnostics.stop()
         api.layout.stop()
         if runtime_monitor is not None:
-            runtime_monitor.stop()
+            runtime_monitor.stop(logger)
         # Telemetry drain: watchdog ring + slow-query ring dump to the
         # log, tracer stop/flush — buffered telemetry survives SIGTERM.
         drain_telemetry(api, watchdog=watchdog, logger=logger)

@@ -512,12 +512,8 @@ def _attribute(ex: Any, cohort: List[Any], launch: _MegaLaunch,
             prof.tree_device(node, device_s)
         if cost is not None:
             # Bytes ÷ the fence we already paid = achieved bandwidth:
-            # per-cohort EWMA + drift detection in the recorder, and a
-            # ph:"C" counter sample for the timeline export.
-            bw = ROOFLINE.note_device(ckey, cost["totalBytes"],
-                                      device_s)
-            if bw is not None:
-                TIMELINE.note_bandwidth(bw["bytesPerS"], bw["frac"])
+            # per-cohort EWMA + drift detection in the recorder.
+            ROOFLINE.note_device(ckey, cost["totalBytes"], device_s)
     # Cache-opportunity attribution AFTER the (sampled) fence — the
     # per-entry share of one launch, same cost basis as the fused and
     # unfused paths.

@@ -994,16 +994,18 @@ class API:
         return self.holder.node_id, ""
 
     def debug_timeline(self, last: Optional[int] = None,
-                       trace: Optional[str] = None) -> Dict[str, Any]:
+                       trace: Optional[str] = None,
+                       slowest: bool = False) -> Dict[str, Any]:
         """The GET /debug/timeline document (utils/timeline.py):
         Chrome trace-event JSON for the last N recorded requests (or
-        one trace id), loadable directly in Perfetto/chrome://tracing,
-        plus per-stage medians and per-call-name stage means."""
+        one trace id, or with `slowest` the longest records since
+        start), loadable directly in Perfetto/chrome://tracing, plus
+        per-stage medians and per-call-name stage means."""
         from pilosa_tpu.utils.timeline import TIMELINE
         node_id, _ = self._node_ident()
         self.refresh_memory_gauges()
         return TIMELINE.snapshot(last=last, trace_id=trace,
-                                 node_id=node_id)
+                                 node_id=node_id, slowest=slowest)
 
     def debug_roofline(self) -> Dict[str, Any]:
         """The GET /debug/roofline document (utils/roofline.py): the

@@ -583,7 +583,7 @@ class QueryCoalescer:
             rec.root.attrs["unique"] = len(reqs)
         err = None
         try:
-            with TIMELINE.attached(rec):
+            with TIMELINE.attached(rec, "thread.batch"):
                 shaped = self.executor.execute_batch_shaped(
                     reqs, profiles=profiles)
         except Exception as e:
@@ -633,7 +633,9 @@ class QueryCoalescer:
             rec = self._open_flush(batch, reason, pipelined=True)
             if rec is not None:
                 rec.root.attrs["unique"] = len(reqs)
-            with TIMELINE.attached(rec):
+            # This thread's section of the flush, and the finalizer's
+            # below: whether each ran while it held the flush's work.
+            with TIMELINE.attached(rec, "thread.begin"):
                 sh = self.executor.execute_batch_shaped_begin(
                     reqs, profiles=profiles)
         except Exception as e:  # dispatch failed: resolve everyone now
@@ -697,7 +699,7 @@ class QueryCoalescer:
                      time.perf_counter())
         err = None
         try:
-            with TIMELINE.attached(rec):
+            with TIMELINE.attached(rec, "thread.finish"):
                 shaped = self.executor.execute_batch_shaped_finish(sh)
         except BaseException as e:
             err = e

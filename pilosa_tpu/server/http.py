@@ -453,11 +453,13 @@ class Handler(BaseHTTPRequestHandler):
             elif path == "/debug/timeline":
                 # Request-lifecycle timeline plane (utils/timeline.py):
                 # Chrome trace-event JSON for the last N requests —
-                # open it directly in Perfetto/chrome://tracing.
-                self._check_args(q, "last", "trace")
+                # open it directly in Perfetto/chrome://tracing;
+                # ?slowest=1: the longest records kept beside the ring.
+                self._check_args(q, "last", "trace", "slowest")
                 self._json(api.debug_timeline(
                     last=int(q["last"]) if q.get("last") else None,
-                    trace=q.get("trace")))
+                    trace=q.get("trace"),
+                    slowest=q.get("slowest") == "1"))
             elif path == "/debug/roofline":
                 # Kernel cost & roofline attribution plane
                 # (utils/roofline.py): per-opcode byte/instruction

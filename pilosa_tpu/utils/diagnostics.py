@@ -9,21 +9,25 @@ GC notifications, gcnotify/gcnotify.go:30).
 Rebuild divergences: reporting is OFF unless an interval AND endpoint are
 configured (the reference defaults to pilosa.com; this build runs in
 zero-egress environments, so the default must be inert), and the runtime
-monitor samples on a plain timer — Python exposes gc stats without a
-GC-notify channel."""
+monitor samples its gauges on a plain timer but times every garbage
+collection where it happens (one `gc.callbacks` hook: a full collection
+stops every Python thread for its length)."""
 
 from __future__ import annotations
 
+from collections import deque
 import gc
 import json
 import os
 import platform
 import threading
+import time
 from pilosa_tpu.utils.locks import make_lock
 import urllib.request
 from typing import Any, Dict, Optional
 
 from pilosa_tpu import __version__
+from pilosa_tpu.utils.timeline import STAGE_BUCKETS, TIMELINE
 
 
 class DiagnosticsCollector:
@@ -128,7 +132,26 @@ class DiagnosticsCollector:
 class RuntimeMonitor:
     """Samples process/runtime gauges into the stats client (reference
     monitorRuntime, server.go:726-770: goroutines, heap, open FDs,
-    mmaps)."""
+    mmaps) and, from start() to stop(), times the collector.
+
+    The hook runs hundreds of times a second on a busy server, on
+    whichever thread tripped the collector and whatever lock that
+    thread holds, so it takes no lock and calls no stats client: the
+    interpreter runs one collection at a time, and the hook adds to
+    plain attributes that `published` hands the stats client when a
+    snapshot is built (`/debug/vars`, `/metrics`):
+
+        runtime.gc_pause_seconds           every generation, cumulative
+        runtime.gc_collections{gen:0|1|2}
+        runtime.gc_pause_seconds{gen:2}    histogram, one observation a
+                                           full collection (observed
+                                           when the snapshot is built)
+        runtime.cpu_seconds                time.process_time()
+        runtime.uptime_seconds             monotonic, since start()
+
+    A full collection — the one that walks the whole tracked heap — is
+    also a `pilosa:gc` event on the profiler's trace and an interval of
+    `TIMELINE.gc_pauses`, drawn into the records it fell in."""
 
     def __init__(self, stats, interval: float = 10.0, holder=None):
         self.stats = stats
@@ -136,18 +159,84 @@ class RuntimeMonitor:
         self.holder = holder
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
+        self.gc_pause_seconds = 0.0
+        self.gc_collections = [0, 0, 0]
+        # Full collections' lengths the stats client has not seen yet
+        # (a deque append takes no lock; drained every sample and every
+        # snapshot), and the longest since start.
+        self._gen2_pending: deque = deque(maxlen=4096)
+        self.gc_longest = 0.0
+        self._gc_t0 = 0.0
+        self._gc_pc0 = 0.0
+        self._gc_ann: Any = None
+        self._t_start = time.monotonic()
+
+    def _on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        # The collecting thread's CPU clock, not the wall clock: the
+        # interpreter may hand the GIL over on entry to the `stop` call
+        # (a Python function like any other), and a wall pair would
+        # then count other threads' turns as the collector's — three
+        # times over with three busy threads. The collection itself
+        # holds the GIL and blocks on nothing, so its CPU seconds are
+        # how long it stopped everyone.
+        if phase == "start":
+            if info["generation"] == 2:
+                factory = TIMELINE.annotation
+                if factory is not None:
+                    ann = self._gc_ann = factory("pilosa:gc")
+                    ann.__enter__()
+                self._gc_pc0 = time.perf_counter()
+            self._gc_t0 = time.thread_time()
+            return
+        dt = time.thread_time() - self._gc_t0
+        gen = info["generation"]
+        self.gc_pause_seconds += dt
+        # graftlint: disable=GL008 — three counts, one a generation.
+        self.gc_collections[gen] += 1
+        if gen == 2:
+            self._gen2_pending.append(dt)
+            self.gc_longest = max(self.gc_longest, dt)
+            TIMELINE.gc_pauses.append((self._gc_pc0, self._gc_pc0 + dt))
+            ann, self._gc_ann = self._gc_ann, None
+            if ann is not None:
+                ann.__exit__(None, None, None)
+
+    def published(self) -> Dict[str, float]:
+        """What a stats snapshot reads from here (StatsClient.add_source):
+        cumulative counters; the full collections since the last call
+        go to their histogram on the way."""
+        self._observe_full_collections()
+        counters: Dict[str, float] = {
+            "runtime.gc_pause_seconds": self.gc_pause_seconds,
+            "runtime.cpu_seconds": time.process_time(),
+            "runtime.uptime_seconds": time.monotonic() - self._t_start}
+        for gen, n in enumerate(self.gc_collections):
+            counters[f"runtime.gc_collections{{gen:{gen}}}"] = n
+        return counters
+
+    def _observe_full_collections(self) -> None:
+        # The sampler and any number of snapshots drain this with no
+        # lock between them: a popleft is atomic, and the one who finds
+        # the deque emptied under it stops.
+        pending = self._gen2_pending
+        if pending:
+            gen2 = self.stats.with_tags("gen:2")
+            try:
+                while True:
+                    gen2.histogram("runtime.gc_pause_seconds",
+                                   pending.popleft(),
+                                   buckets=STAGE_BUCKETS)
+            except IndexError:
+                pass
 
     def sample(self) -> None:
+        self._observe_full_collections()
         self.stats.gauge("threads", threading.active_count())
         if self.holder is not None:
             # Torn op-log tails sidecarred at open: operators must see
             # dropped-data events in metrics, not only a log line.
             self.stats.gauge("tailDroppedBytes",
                              self.holder.tail_dropped_bytes())
-        counts = gc.get_count()
-        self.stats.gauge("gcGen0", counts[0])
-        self.stats.gauge("garbageCollection", gc.get_stats()[-1].get(
-            "collections", 0))
         try:
             with open("/proc/self/status") as f:
                 for line in f:
@@ -165,6 +254,9 @@ class RuntimeMonitor:
     def start(self) -> None:
         if self._thread is not None:
             return
+        self._t_start = time.monotonic()
+        gc.callbacks.append(self._on_gc)
+        self.stats.add_source(self.published)
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name="runtime-monitor")
         self._thread.start()
@@ -176,8 +268,23 @@ class RuntimeMonitor:
             except Exception:  # noqa: BLE001 — monitoring must not crash
                 pass
 
-    def stop(self) -> None:
+    def stop(self, logger=None) -> None:
+        """Stop sampling and take the hook out; with a logger, leave the
+        collector's and the process's totals in the log (a benchmark run
+        stops its server before anyone can ask `/debug/vars`)."""
         self._stop.set()
-        if self._thread is not None:
-            self._thread.join(timeout=5)
-            self._thread = None
+        if self._thread is None:
+            return
+        self._thread.join(timeout=5)
+        self._thread = None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+        self.published()        # the last full collections, observed
+        self.stats.remove_source(self.published)
+        if logger is not None:
+            logger.printf(
+                "runtime: gc pauses %.4fs in %s collections (gen 0/1/2); "
+                "longest full collection %.4fs; process cpu %.2fs in "
+                "%.2fs up", self.gc_pause_seconds, self.gc_collections,
+                self.gc_longest, time.process_time(),
+                time.monotonic() - self._t_start)

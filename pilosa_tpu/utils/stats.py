@@ -48,6 +48,15 @@ class StatsClient:
         for name, value in counts:
             self.count(name, value)
 
+    def add_source(self, source: Any) -> None:
+        """`source()` -> {key: number}: cumulative counters their owner
+        keeps itself and a client with a snapshot() reads when one is
+        built (the collector's hook may not take a stats lock; the
+        process's CPU seconds are read, not counted)."""
+
+    def remove_source(self, source: Any) -> None:
+        pass
+
 
 class NopStatsClient(StatsClient):
     pass
@@ -90,6 +99,7 @@ class MemStatsClient(StatsClient):
             # semantics.
             self.histos: Dict[str, dict] = {}
             self.sets: Dict[str, set] = defaultdict(set)
+            self.sources: List[Any] = []
             self._lock = make_lock("MemStatsClient._lock")
 
     def _key(self, name: str) -> str:
@@ -166,12 +176,25 @@ class MemStatsClient(StatsClient):
             if len(vals) > 1000:
                 del vals[:-1000]
 
+    def add_source(self, source: Any) -> None:
+        self._parent.sources.append(source)
+
+    def remove_source(self, source: Any) -> None:
+        sources = self._parent.sources
+        if source in sources:
+            sources.remove(source)
+
     def snapshot(self) -> dict:
         root = self._parent
+        # Outside the lock: a source reads its owner's plain attributes
+        # (and may observe what it held back into this client).
+        extra = [source() for source in list(root.sources)]
         with root._lock:
             out = {"counters": dict(root.counters),
                    "gauges": dict(root.gauges),
                    "sets": {k: sorted(v) for k, v in root.sets.items()}}
+            for counters in extra:
+                out["counters"].update(counters)
             out["histograms"] = {}
             for k, h in root.histos.items():
                 bounds = h.get("buckets", HISTOGRAM_BUCKETS)
@@ -213,6 +236,14 @@ class MultiStatsClient(StatsClient):
         for c in self.clients:
             if hasattr(c, "flush"):
                 c.flush()
+
+    def add_source(self, source: Any) -> None:
+        for c in self.clients:
+            c.add_source(source)
+
+    def remove_source(self, source: Any) -> None:
+        for c in self.clients:
+            c.remove_source(source)
 
     def count(self, name: str, value: int = 1, rate: float = 1.0) -> None:
         for c in self.clients:
@@ -427,6 +458,12 @@ METRIC_HELP: Dict[str, str] = {
         "Live entries in the TopN rank cache.",
     "pilosa_request_spans_dropped_total":
         "Spans past a request record's cap, left out of its tree.",
+    "pilosa_request_stage_cpu_seconds":
+        "CPU seconds (user + system) a thread used inside its section "
+        "of a record (stage thread.begin / thread.finish / "
+        "thread.batch of a coalesced flush), beside that section's "
+        "pilosa_request_stage_seconds; wall minus cpu is time the "
+        "thread did not run.",
     "pilosa_request_stage_seconds":
         "Seconds per request (or per coalesced flush) in each stage "
         "of the request record, labeled by stage (utils/timeline.py).",
@@ -435,6 +472,17 @@ METRIC_HELP: Dict[str, str] = {
     "pilosa_request_unaccounted_seconds":
         "Per request: root duration minus the union of its stage "
         "spans.",
+    "pilosa_runtime_cpu_seconds_total":
+        "CPU seconds (user + system) of the whole process.",
+    "pilosa_runtime_gc_collections_total":
+        "Garbage collections of the Python runtime, by generation.",
+    "pilosa_runtime_gc_pause_seconds":
+        "Length of each full (generation 2) collection: every Python "
+        "thread stands still for it.",
+    "pilosa_runtime_gc_pause_seconds_total":
+        "Seconds spent in garbage collections, every generation.",
+    "pilosa_runtime_uptime_seconds_total":
+        "Monotonic seconds since the runtime monitor started.",
     "pilosa_roofline_achieved_gbps":
         "Fence-sampled achieved HBM bandwidth, GB/s.",
     "pilosa_roofline_cohorts":

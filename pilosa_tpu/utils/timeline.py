@@ -17,6 +17,24 @@ each finished record adds its stage durations to the cumulative
 ``request.stage_seconds{stage:<name>}`` histograms of ``/debug/vars``
 (one stats-lock acquisition per record, not per span).
 
+Whether the thread ran. Where a thread attaches to a record under a
+section name (``TIMELINE.attached(rec, "thread.begin")``: the
+dispatcher's half of a coalesced flush, the finalizer's) it reads its
+own CPU clock (``time.thread_time()``) at the section's two ends, and
+the record keeps a span of that name beside its tree
+(``rec.sections``) with ``cpu``: the user + system seconds between.
+wall − cpu is the time that thread was off the CPU while it held the
+record's work — waiting for the GIL, for a lock, in a blocking call. A
+finished record feeds a section like a stage — ``request.stage_seconds
+{stage:<section>}`` — and beside it ``request.stage_cpu_seconds{stage:
+<section>}``, in the same batch. One pair of readings a thread a
+record, not one a span: a reading is a system call, 0.4 us on a plain
+kernel and ~15 us under the sandboxed one the benchmark's machines run,
+where ~1,200 of them a flush cost a fifth of the throughput (PERF.md
+section 6, PR 34). The stages themselves carry no such reading, nor
+does a span laid in with ``add``; a None record and an attachment
+without a name make no such call.
+
 Tiling. A span opened with ``TIMELINE.phase(name)`` (the executor's
 ``plan`` and ``finish``) is a *phase*: a span opened inside it whose
 name is not ``<phase>.<x>`` (``h2d``, ``dispatch``, ``d2h``,
@@ -54,6 +72,7 @@ lock at finish (graftlint GL003 clean by construction).
 
 from __future__ import annotations
 
+import heapq
 import threading
 import time
 import uuid
@@ -66,14 +85,27 @@ from pilosa_tpu.utils.tracing import Span
 # request.stage_seconds bucket bounds: 2^-17 s (7.6 us) .. 16 s.
 STAGE_BUCKETS = tuple(2.0 ** e for e in range(-17, 5))
 
+_thread_time = time.thread_time
+
+
+class ThreadSection(Span):
+    """A thread's section of a record: the interval it was attached to
+    it under a name, with ``cpu`` — the seconds (user + system) of the
+    thread's CPU clock between its two ends."""
+
+    __slots__ = ("cpu",)
+
 
 class _TimelineRequest:
     """One record: a root span plus what finish() needs. ``stats`` is
     the client its stage durations go to (the opener's); ``n_spans``
-    bounds the tree."""
+    bounds the tree; ``sections`` are the threads' named attachments,
+    each a span with the thread's CPU seconds, kept beside the tree (they
+    overlap the stages their thread ran inside them)."""
 
     __slots__ = ("trace_id", "index", "seq", "kind", "root", "stats",
-                 "n_spans", "dropped", "counts", "error", "unaccounted")
+                 "n_spans", "dropped", "counts", "error", "unaccounted",
+                 "sections")
 
     def __init__(self, trace_id: str, index: str, seq: int, kind: str,
                  name: str, stats: Any, attrs: dict) -> None:
@@ -88,6 +120,7 @@ class _TimelineRequest:
         self.counts: Dict[str, int] = {}
         self.error: Optional[str] = None
         self.unaccounted = 0.0
+        self.sections: List[ThreadSection] = []
 
 
 class _Clock:
@@ -213,13 +246,18 @@ class _Open:
 
 
 class _Attached:
-    __slots__ = ("tl", "rec", "prev", "ann")
+    __slots__ = ("tl", "rec", "prev", "ann", "section", "tt")
 
-    def __init__(self, tl: "TimelineRecorder", rec: Any) -> None:
+    def __init__(self, tl: "TimelineRecorder", rec: Any,
+                 section: Optional[str] = None) -> None:
         self.tl = tl
         self.rec = rec
         self.prev = None
         self.ann = None
+        self.section: Optional[ThreadSection] = None
+        if rec is not None and section is not None:
+            self.section = ThreadSection(section, rec.trace_id, {})
+        self.tt = 0.0
 
     def __enter__(self) -> Any:
         tls = self.tl._tls
@@ -232,9 +270,25 @@ class _Attached:
             # flush's), not as nobody's.
             self.ann = factory("pilosa:" + rec.root.name)
             self.ann.__enter__()
+        sp = self.section
+        if sp is not None:
+            sp.tid = self.tl._lane()
+            sp.pc_start = time.perf_counter()
+            self.tt = _thread_time()
         return rec
 
     def __exit__(self, *exc: object) -> None:
+        sp = self.section
+        if sp is not None:
+            sp.cpu = _thread_time() - self.tt
+            sp.close()
+            rec = self.rec
+            if rec.n_spans < self.tl.MAX_EVENTS_PER_REQUEST:
+                rec.n_spans += 1
+                # graftlint: disable=GL008 — bounded by n_spans above.
+                rec.sections.append(sp)
+            else:
+                rec.dropped += 1
         ann, self.ann = self.ann, None
         if ann is not None:
             ann.__exit__(None, None, None)
@@ -270,11 +324,13 @@ class TimelineRecorder:
     # segments between, d2h, finish) without letting a 1024-call query
     # bloat the ring. What is past it is counted, not kept.
     MAX_EVENTS_PER_REQUEST = 1024
-    # Roofline counter-track samples kept (ph:"C" lanes in the export);
-    # fed only by sampled device fences, so the ring turns over slowly.
-    MAX_COUNTER_SAMPLES = 512
-    # Rough per-sample ledger cost (tuple of three floats).
-    COUNTER_NBYTES = 48
+    # Longest records kept per kind beside the ring, which at hundreds
+    # of answers a second has turned over before anyone can ask it
+    # which stage was open in a stall.
+    SLOWEST_PER_KIND = 8
+    # Generation-2 collections kept (a few a minute): drawn into the
+    # export of the records they fell in.
+    MAX_GC_PAUSES = 64
 
     def __init__(self, ring: int = 256, sample_every: int = 1) -> None:
         self.enabled = True
@@ -293,13 +349,14 @@ class TimelineRecorder:
         self.exporter: Any = None
         # Thread lanes of the Chrome export: ident -> (lane, name).
         self._lanes: Dict[int, Tuple[int, str]] = {}
-        # Roofline counter track: (wall_s, bytes_per_s, fraction)
-        # samples from the megakernel's sampled device fences
-        # (executor/megakernel._attribute via roofline.note_device) —
-        # exported as ph:"C" Perfetto counter lanes.
-        self._counter_lock = make_lock("TimelineRecorder._counter_lock")
-        self._counters: deque = deque(maxlen=self.MAX_COUNTER_SAMPLES)
-        self.counters_total = 0
+        # kind -> min-heap of (seconds, seq, record): the longest
+        # SLOWEST_PER_KIND records of each kind since reset().
+        self._slowest: Dict[str, list] = {}
+        # (pc_start, pc_end) of the newest full collections, appended
+        # by the collector's hook (utils/diagnostics.RuntimeMonitor)
+        # with no lock: a deque append is atomic, and the hook may run
+        # while any lock of this process is held.
+        self.gc_pauses: deque = deque(maxlen=self.MAX_GC_PAUSES)
 
     # ------------------------------------------------------------ configure
 
@@ -315,15 +372,14 @@ class TimelineRecorder:
                 self.sample_every = max(1, int(sample_every))
 
     def reset(self) -> None:
-        """Tests only: drop every recorded timeline and counter."""
+        """Tests only: drop every recorded timeline."""
         with self._lock:
             self._ring.clear()
+            self._slowest.clear()
             self._seq = 0
             self.requests_recorded = 0
             self.requests_skipped = 0
-        with self._counter_lock:
-            self._counters.clear()
-            self.counters_total = 0
+        self.gc_pauses.clear()
 
     # -------------------------------------------------------- thread state
 
@@ -358,12 +414,15 @@ class TimelineRecorder:
             self._tls.lane = lane
             return lane
 
-    def attached(self, rec: Optional[_TimelineRequest]) -> _Attached:
+    def attached(self, rec: Optional[_TimelineRequest],
+                 section: Optional[str] = None) -> _Attached:
         """``with TIMELINE.attached(rec):`` — `rec` is what
         ``current()`` returns on this thread inside the block: how the
         executor finds the record (a request's, or the flush's) without
-        a parameter on every call."""
-        return _Attached(self, rec)
+        a parameter on every call. With a `section` name the block is
+        also timed as this thread's section of the record, with the
+        thread's CPU clock read at its two ends (module docstring)."""
+        return _Attached(self, rec, section)
 
     def current(self) -> Optional[_TimelineRequest]:
         return getattr(self._tls, "rec", None)
@@ -483,6 +542,11 @@ class TimelineRecorder:
         with self._lock:
             self._ring.append(rec)
             self.requests_recorded += 1
+            heap = self._slowest.setdefault(rec.kind, [])
+            if len(heap) < self.SLOWEST_PER_KIND:
+                heapq.heappush(heap, (total, rec.seq, rec))
+            elif total > heap[0][0]:
+                heapq.heapreplace(heap, (total, rec.seq, rec))
         exporter = self.exporter
         if exporter is not None:
             exporter.offer(root)
@@ -491,8 +555,12 @@ class TimelineRecorder:
         """One observation per stage name per record (the sum of that
         name's spans), so a stage's histogram count is the number of
         requests — or flushes — that went through it, and a record's
-        top-level sums plus its unaccounted time equal its total."""
+        top-level sums plus its unaccounted time equal its total. A
+        thread's section feeds the same family under its own name and
+        its CPU seconds go beside it, so Σcpu ÷ Σwall of a section is
+        the share of it that its thread ran."""
         sums: Dict[str, float] = {}
+        cpus: Dict[str, float] = {}
         for sp in rec.root.walk():
             if sp is rec.root:
                 continue
@@ -500,8 +568,14 @@ class TimelineRecorder:
             sums[name] = sums.get(name, 0.0) + (
                 (sp.pc_end if sp.pc_end is not None
                  else rec.root.pc_end) - sp.pc_start)
+        for sp in rec.sections:
+            name = sp.name
+            sums[name] = sums.get(name, 0.0) + sp.pc_end - sp.pc_start
+            cpus[name] = cpus.get(name, 0.0) + sp.cpu
         histos = [("request.stage_seconds", (f"stage:{n}",), v,
                    STAGE_BUCKETS) for n, v in sums.items()]
+        histos += [("request.stage_cpu_seconds", (f"stage:{n}",), v,
+                    STAGE_BUCKETS) for n, v in cpus.items()]
         if rec.kind == "flush":
             histos.append(("request.stage_seconds",
                            ("stage:" + rec.root.name,), total,
@@ -520,49 +594,6 @@ class TimelineRecorder:
             counts.append(("request.spans_dropped", rec.dropped))
         rec.stats.batch(histos, counts)
 
-    # ---------------------------------------------- roofline counter track
-
-    def note_bandwidth(self, bytes_per_s: float,
-                       roofline_frac: Optional[float]) -> None:
-        """One achieved-bandwidth sample (a megakernel launch that hit
-        a sampled device fence): feeds the ph:"C" counter lanes in the
-        export. Independent of request sampling — the fence already
-        happened, recording it costs one append. roofline_frac is None
-        on a device with no roofline on record; that sample then has
-        no fraction lane."""
-        if not self.enabled:
-            return
-        with self._counter_lock:
-            self._counters.append((
-                time.time(), float(bytes_per_s),
-                None if roofline_frac is None else float(roofline_frac)))
-            self.counters_total += 1
-
-    def counter_samples(
-            self) -> List[Tuple[float, float, Optional[float]]]:
-        with self._counter_lock:
-            return list(self._counters)
-
-    def _export_counters(self, pid: int) -> List[Dict[str, Any]]:
-        """Chrome ``ph:"C"`` counter events — one bytes/s lane and one
-        roofline-fraction lane per sample. ``dur``/``tid`` ride along
-        as 0 so every event in the document carries the full
-        ph/ts/dur/pid/tid shape (the CI smoke validates exactly
-        that)."""
-        events: List[Dict[str, Any]] = []
-        for wall_s, bps, frac in self.counter_samples():
-            ts = wall_s * 1e6
-            events.append({"name": "launch_bytes_per_s", "ph": "C",
-                           "cat": "pilosa", "ts": ts, "dur": 0,
-                           "pid": pid, "tid": 0,
-                           "args": {"bytes_per_s": bps}})
-            if frac is not None:
-                events.append({"name": "roofline_fraction", "ph": "C",
-                               "cat": "pilosa", "ts": ts, "dur": 0,
-                               "pid": pid, "tid": 0,
-                               "args": {"fraction": frac}})
-        return events
-
     # -------------------------------------------------------------- reading
 
     def _export_events(self, reqs: List[_TimelineRequest], pid: int
@@ -578,6 +609,8 @@ class TimelineRecorder:
             def emit(sp: Span, parent: Optional[Span]) -> None:
                 end = sp.pc_end if sp.pc_end is not None else root.pc_end
                 args: Dict[str, Any] = dict(sp.attrs)
+                if isinstance(sp, ThreadSection):
+                    args["cpu"] = sp.cpu
                 args["trace"] = req.trace_id
                 args["spanId"] = sp.span_id
                 if parent is not None:
@@ -597,6 +630,23 @@ class TimelineRecorder:
                     emit(c, sp)
 
             emit(root, None)
+            # A thread's section lies over the stages it ran: on its
+            # lane the UI stacks them under it.
+            for sec in req.sections:
+                emit(sec, root)
+        # Full collections stop every thread: each is drawn once, on
+        # lane 0, anchored on the first exported record it fell in.
+        for t0, t1 in list(self.gc_pauses):
+            for req in reqs:
+                root = req.root
+                end = root.pc_end if root.pc_end is not None else t1
+                if t0 < end and t1 > root.pc_start:
+                    events.append({
+                        "name": "gc", "ph": "X", "cat": "pilosa",
+                        "ts": (root.start + t0 - root.pc_start) * 1e6,
+                        "dur": (t1 - t0) * 1e6, "pid": pid, "tid": 0,
+                        "args": {"gen": 2}})
+                    break
         return events
 
     @staticmethod
@@ -622,11 +672,17 @@ class TimelineRecorder:
         return meta
 
     def requests(self, last: Optional[int] = None,
-                 trace_id: Optional[str] = None) -> List[_TimelineRequest]:
+                 trace_id: Optional[str] = None,
+                 slowest: bool = False) -> List[_TimelineRequest]:
         """Most-recent-last records, optionally filtered by trace id
-        and bounded to the last N."""
+        and bounded to the last N; with `slowest`, the longest records
+        of each kind since reset() instead of the ring's."""
         with self._lock:
-            reqs = list(self._ring)
+            if slowest:
+                reqs = sorted((r for heap in self._slowest.values()
+                               for _, _, r in heap), key=lambda r: r.seq)
+            else:
+                reqs = list(self._ring)
         if trace_id:
             reqs = [r for r in reqs if r.trace_id == trace_id]
         if last is not None and last >= 0:
@@ -641,6 +697,16 @@ class TimelineRecorder:
                 out[sp.name] = out.get(sp.name, 0.0) \
                     + sp.pc_end - sp.pc_start
         return out
+
+    @classmethod
+    def _stage_line(cls, req: _TimelineRequest) -> str:
+        """`stage=ms` of a record for the log, then its threads'
+        sections as `section=wall/cpu ms`."""
+        return ",".join(
+            [f"{name}={s * 1e3:.2f}ms"
+             for name, s in cls._stage_sums(req).items()]
+            + [f"{sp.name}={sp.duration() * 1e3:.2f}/{sp.cpu * 1e3:.2f}ms"
+               for sp in req.sections])
 
     def _stage_medians(self, reqs: List[_TimelineRequest]
                        ) -> Dict[str, float]:
@@ -676,15 +742,17 @@ class TimelineRecorder:
 
     def snapshot(self, last: Optional[int] = None,
                  trace_id: Optional[str] = None,
-                 node_id: str = "local", pid: int = 0) -> Dict[str, Any]:
+                 node_id: str = "local", pid: int = 0,
+                 slowest: bool = False) -> Dict[str, Any]:
         """The ``GET /debug/timeline`` document: trace-event JSON
         (``traceEvents`` — the Chrome JSON object format, loadable
         directly in Perfetto/chrome://tracing) plus a summary with
-        per-stage medians and per-call-name means."""
-        reqs = self.requests(last=last, trace_id=trace_id)
-        counters = self._export_counters(pid)
+        per-stage medians and per-call-name means. ``slowest`` =
+        ``?slowest=1``: the longest records kept beside the ring."""
+        reqs = self.requests(last=last, trace_id=trace_id,
+                             slowest=slowest)
         events = self.metadata_events(pid, node_id) \
-            + counters + self._export_events(reqs, pid)
+            + self._export_events(reqs, pid)
         return {
             "traceEvents": events,
             "displayTimeUnit": "ms",
@@ -695,9 +763,6 @@ class TimelineRecorder:
                 "requestsSkipped": self.requests_skipped,
                 "ringCapacity": self._ring.maxlen,
                 "sampleEvery": self.sample_every,
-                "counterSamples": sum(
-                    1 for e in counters
-                    if e["name"] == "launch_bytes_per_s"),
                 "stageMedianS": self._stage_medians(reqs),
                 "byCall": self._by_call(reqs),
             },
@@ -711,10 +776,12 @@ class TimelineRecorder:
         """Estimated bytes held by the timeline ring (the memory-ledger
         ``telemetry`` registration; O(ring) under the lock)."""
         with self._lock:
-            n = sum(r.root.nbytes() + 160 for r in self._ring)
-        with self._counter_lock:
-            n_counters = len(self._counters)
-        return n + n_counters * self.COUNTER_NBYTES
+            held = {id(r): r for r in self._ring}
+            for heap in self._slowest.values():
+                held.update((id(r), r) for _, _, r in heap)
+            return sum(r.root.nbytes() + 160
+                       + sum(sp.nbytes() for sp in r.sections)
+                       for r in held.values())
 
     def register_memory(self, ledger: Optional[Any] = None) -> None:
         """Register the ring's bytes with the memory ledger (category
@@ -726,21 +793,24 @@ class TimelineRecorder:
                         entries=self.ring_count())
 
     def dump(self, logger: Optional[Any], last: int = 5) -> int:
-        """Write the most recent `last` records to the log — the
-        SIGTERM drain calls this so buffered timelines survive a
-        graceful shutdown. Returns records written."""
-        reqs = self.requests(last=max(0, int(last)))
-        if logger is not None and reqs:
+        """Write the most recent `last` records and the slowest kept
+        to the log, stage by stage, their threads' sections as wall/cpu
+        ms — the SIGTERM drain calls
+        this so buffered timelines survive a graceful shutdown.
+        Returns records written."""
+        groups = [("", self.requests(last=max(0, int(last)))),
+                  ("slowest ", self.requests(slowest=True))]
+        n = sum(len(reqs) for _, reqs in groups)
+        if logger is not None and n:
             logger.printf("timeline: dumping %d request timeline(s) on "
-                          "shutdown", len(reqs))
-            for r in reqs:
-                stages = ",".join(
-                    f"{name}={s * 1e3:.2f}ms"
-                    for name, s in self._stage_sums(r).items())
-                logger.printf("timeline: %s trace=%s index=%s %.2fms %s",
-                              r.kind, r.trace_id, r.index or "-",
-                              r.root.duration() * 1e3, stages)
-        return len(reqs)
+                          "shutdown", n)
+            for which, reqs in groups:
+                for r in reqs:
+                    logger.printf(
+                        "timeline: %s%s trace=%s index=%s %.2fms %s",
+                        which, r.kind, r.trace_id, r.index or "-",
+                        r.root.duration() * 1e3, self._stage_line(r))
+        return n
 
 
 # The process-wide recorder every serving-path seam reports into (the

@@ -8,6 +8,10 @@ import pytest
 
 from pilosa_tpu.utils.diagnostics import DiagnosticsCollector, RuntimeMonitor
 from pilosa_tpu.utils.stats import MemStatsClient
+# The step of the thread CPU clock that times the collector: 10 ms under
+# a sandboxed kernel (gVisor, as on the benchmark's machines), where a
+# short collection reads 0 — lengths are held above 0 only below 1 ms.
+from tests.test_timeline import _TICK
 
 
 def test_disabled_by_default():
@@ -81,6 +85,141 @@ def test_runtime_monitor_samples_gauges():
     snap = stats.snapshot()
     assert snap["gauges"]["threads"] >= 1
     assert snap["gauges"].get("heapInuse", 0) > 0  # /proc available on linux
+
+
+def test_runtime_monitor_no_longer_samples_the_collector():
+    stats = MemStatsClient()
+    RuntimeMonitor(stats, interval=1000).sample()
+    assert {"gcGen0", "garbageCollection"}.isdisjoint(
+        stats.snapshot()["gauges"])
+
+
+@pytest.fixture
+def monitor():
+    import gc
+    stats = MemStatsClient()
+    mon = RuntimeMonitor(stats, interval=1000)
+    before = list(gc.callbacks)
+    mon.start()
+    try:
+        yield mon, stats
+    finally:
+        mon.stop()
+    assert gc.callbacks == before        # the hook went at shutdown
+    assert "runtime.gc_pause_seconds" not in stats.snapshot()["counters"]
+
+
+def _gc_numbers(stats):
+    snap = stats.snapshot()
+    c = snap["counters"]
+    return (c["runtime.gc_pause_seconds"],
+            [c[f"runtime.gc_collections{{gen:{g}}}"] for g in range(3)],
+            snap["histograms"].get("runtime.gc_pause_seconds{gen:2}",
+                                   {"count": 0, "sum": 0.0}))
+
+
+def test_full_collection_is_timed_counted_and_bucketed(monitor):
+    import gc
+    from pilosa_tpu.utils.timeline import STAGE_BUCKETS, TIMELINE
+    mon, stats = monitor
+    pause0, n0, h0 = _gc_numbers(stats)
+    marks = []
+
+    class _Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            marks.append(("in", self.name))
+
+        def __exit__(self, *exc):
+            marks.append(("out", self.name))
+
+    TIMELINE.annotation = _Ann
+    n_pauses = len(TIMELINE.gc_pauses)
+    try:
+        gc.collect()
+    finally:
+        TIMELINE.annotation = None
+    pause1, n1, h1 = _gc_numbers(stats)
+    assert n1[2] == n0[2] + 1
+    assert h1["count"] == h0["count"] + 1
+    length = h1["sum"] - h0["sum"]
+    assert length > 0 or _TICK > 1e-3
+    # Its length is in the cumulative counter too (younger collections
+    # may have run beside it: never less).
+    assert pause1 - pause0 >= length - 1e-9
+    assert len(h1["buckets"]) == len(STAGE_BUCKETS) + 1
+    assert marks == [("in", "pilosa:gc"), ("out", "pilosa:gc")]
+    t0, t1 = TIMELINE.gc_pauses[-1]
+    assert len(TIMELINE.gc_pauses) == n_pauses + 1
+    assert t1 - t0 == pytest.approx(length)
+
+
+def test_young_collection_adds_to_counters_and_to_no_histogram(monitor):
+    import gc
+    from pilosa_tpu.utils.timeline import TIMELINE
+    mon, stats = monitor
+    pause0, n0, h0 = _gc_numbers(stats)
+    n_pauses = len(TIMELINE.gc_pauses)
+    was = gc.isenabled()
+    gc.disable()                 # nothing but the asked-for collection
+    try:
+        gc.collect(0)
+    finally:
+        if was:
+            gc.enable()
+    pause1, n1, h1 = _gc_numbers(stats)
+    assert n1 == [n0[0] + 1, n0[1], n0[2]]
+    assert pause1 > pause0 or _TICK > 1e-3
+    assert h1 == h0
+    assert len(TIMELINE.gc_pauses) == n_pauses
+
+
+def test_process_cpu_and_uptime_are_read_when_a_snapshot_is_built(monitor):
+    import time
+    mon, stats = monitor
+    a = stats.snapshot()["counters"]
+    t0 = time.thread_time()          # 30 ms of CPU, whatever the load
+    while time.thread_time() - t0 < 0.03:
+        pass
+    b = stats.snapshot()["counters"]
+    up = b["runtime.uptime_seconds"] - a["runtime.uptime_seconds"]
+    cpu = b["runtime.cpu_seconds"] - a["runtime.cpu_seconds"]
+    assert 0.03 <= up < 5.0
+    assert cpu >= 0.03 - _TICK
+    import gc
+    from pilosa_tpu.utils.stats import prometheus_text
+    gc.collect()        # the histogram is there once one has run
+    text = prometheus_text(stats)
+    assert "pilosa_runtime_cpu_seconds_total" in text
+    assert 'pilosa_runtime_gc_collections_total{gen="2"}' in text
+    assert 'pilosa_runtime_gc_pause_seconds_bucket{gen="2",le="+Inf"}' \
+        in text
+
+
+def test_monitor_stop_leaves_the_totals_in_the_log():
+    import gc
+    stats = MemStatsClient()
+    mon = RuntimeMonitor(stats, interval=1000)
+    mon.start()
+    gc.collect()
+    lines = []
+
+    class _Log:
+        def printf(self, fmt, *args):
+            lines.append(fmt % args)
+
+    mon.stop(_Log())
+    assert len(lines) == 1 and lines[0].startswith("runtime: gc pauses")
+    assert "process cpu" in lines[0]
+    assert f"longest full collection {mon.gc_longest:.4f}s" in lines[0]
+    assert mon.gc_longest > 0 or _TICK > 1e-3
+    # ... and the collections no snapshot had asked for are observed.
+    assert stats.snapshot()["histograms"][
+        "runtime.gc_pause_seconds{gen:2}"]["count"] >= 1
+    mon.stop(_Log())                     # a second stop says nothing
+    assert len(lines) == 1
 
 
 def test_slow_query_logged(tmp_path):

@@ -8,6 +8,7 @@ depth, pruning, chunked launches, a mesh); and what it must refuse."""
 import json
 import os
 import sys
+import time
 import urllib.error
 import urllib.request
 
@@ -114,8 +115,15 @@ def test_the_counters_and_the_span_move(served):
     from pilosa_tpu.utils.timeline import TIMELINE
     srv, lo, api = served
     before = dict(api.stats.snapshot()["counters"])
+    recorded = TIMELINE.requests_recorded
     fam = ssb.FAMILIES["q2.1"]
     got = srv.query(ssb.INDEX, fam.pql(fam.fixed))
+    # The record closes in the handler's finally block, AFTER the
+    # response body went out: the client can get here first.
+    for _ in range(400):
+        if TIMELINE.requests_recorded > recorded:
+            break
+        time.sleep(0.005)
     after = api.stats.snapshot()["counters"]
 
     def moved(name):

@@ -183,3 +183,27 @@ def test_statsd_close_via_tagged_clone():
     assert not c._shared["thread"].is_alive() or \
         c._shared["thread"].join(timeout=5) is None
     assert c._shared["stop"].is_set()
+
+
+def test_snapshot_reads_its_sources_and_forgets_a_removed_one():
+    stats = MemStatsClient()
+    stats.count("own", 1)
+    held_back = [0.5]
+
+    def source():
+        # A source may observe what it held back on the way: it is
+        # called outside the client's lock.
+        while held_back:
+            stats.with_tags("gen:2").histogram(
+                "runtime.gc_pause_seconds", held_back.pop(),
+                buckets=(0.1, 1.0))
+        return {"runtime.cpu_seconds": 1.5}
+
+    stats.with_tags("x:y").add_source(source)     # lands on the root
+    snap = stats.snapshot()
+    assert snap["counters"] == {"own": 1, "runtime.cpu_seconds": 1.5}
+    assert snap["histograms"]["runtime.gc_pause_seconds{gen:2}"] == {
+        "buckets": {"0.1": 0, "1": 1, "+Inf": 1}, "sum": 0.5, "count": 1}
+    stats.remove_source(source)
+    stats.remove_source(source)                   # twice is harmless
+    assert stats.snapshot()["counters"] == {"own": 1}

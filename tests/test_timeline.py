@@ -10,6 +10,7 @@ memory-ledger registration, and the zero-new-fences acceptance bar."""
 import json
 import threading
 import time
+import urllib.error
 import urllib.request
 
 import numpy as np
@@ -391,12 +392,15 @@ def test_stats_lock_taken_once_per_record_not_per_span():
 
     rec = TimelineRecorder()
     req = rec.begin(None, stats=_Stats())
-    for name in ("http.read", "pql.parse", "plan", "dispatch", "d2h",
-                 "finish", "http.serialize", "http.write"):
-        with rec.span(req, name):
-            pass
+    with rec.attached(req, "thread.batch"):
+        for name in ("http.read", "pql.parse", "plan", "dispatch", "d2h",
+                     "finish", "http.serialize", "http.write"):
+            with rec.span(req, name):
+                pass
     rec.finish(req)
-    assert calls == [(10, 0)]   # 8 stages + total + unaccounted, once
+    # 8 stages + the thread's section, wall and CPU seconds, + total +
+    # unaccounted: once
+    assert calls == [(12, 0)]
 
 
 # ------------------------------------------------------ export shape
@@ -457,48 +461,6 @@ def test_snapshot_chrome_trace_event_shape():
     assert by_call["stageMeanS"]["dispatch"] == \
         pytest.approx(d.duration())
     assert by_call["meanS"] == pytest.approx(req.root.duration())
-
-
-def test_bandwidth_sample_without_a_roofline_has_no_fraction_lane():
-    """On a device with no peak on record the recorder passes None:
-    the bytes/s lane is kept, no fraction is invented."""
-    rec = TimelineRecorder()
-    rec.note_bandwidth(2.5e9, None)
-    doc = rec.snapshot()
-    cs = [e for e in doc["traceEvents"] if e["ph"] == "C"]
-    assert [e["name"] for e in cs] == ["launch_bytes_per_s"]
-    assert doc["summary"]["counterSamples"] == 1
-
-
-def test_bandwidth_counter_track_shape():
-    """Roofline plane counter tracks: note_bandwidth exports two
-    Perfetto ph:"C" samples (launch_bytes_per_s + roofline_fraction)
-    with the full event shape, bounded by MAX_COUNTER_SAMPLES."""
-    rec = TimelineRecorder()
-    rec.note_bandwidth(2.5e9, 0.8)
-    rec.note_bandwidth(1.0e9, 0.3)
-    doc = rec.snapshot()
-    cs = [e for e in doc["traceEvents"] if e["ph"] == "C"]
-    assert len(cs) == 4                      # 2 samples x 2 tracks
-    for ev in cs:
-        for k in ("name", "ph", "ts", "dur", "pid", "tid"):
-            assert k in ev, ev
-    by_name = {}
-    for ev in cs:
-        by_name.setdefault(ev["name"], []).append(ev)
-    assert set(by_name) == {"launch_bytes_per_s", "roofline_fraction"}
-    assert [e["args"]["bytes_per_s"]
-            for e in by_name["launch_bytes_per_s"]] == [2.5e9, 1.0e9]
-    assert [e["args"]["fraction"]
-            for e in by_name["roofline_fraction"]] == [0.8, 0.3]
-    assert doc["summary"]["counterSamples"] == 2
-    # Bounded ring: the counter deque never outgrows the cap.
-    for _ in range(rec.MAX_COUNTER_SAMPLES + 50):
-        rec.note_bandwidth(1.0, 0.5)
-    assert len(rec.counter_samples()) == rec.MAX_COUNTER_SAMPLES
-    assert rec.counters_total == 2 + rec.MAX_COUNTER_SAMPLES + 50
-    rec.reset()
-    assert len(rec.counter_samples()) == 0
 
 
 def test_snapshot_filters_last_and_trace():
@@ -994,6 +956,22 @@ def test_http_coalesced_request_tiles(tmp_holder):
     assert h["request.stage_seconds{stage:coalescer.flush}"]["count"] == 4
     assert h["request.stage_seconds{stage:plan}"]["count"] == 4
     assert h["request.stage_seconds{stage:coalescer.wait}"]["count"] == 16
+    # The flush's two halves, each a section of the thread that ran it
+    # (dispatcher, finalizer) with that thread's readings; the members,
+    # whose threads were parked, have none.
+    assert [sp.name for sp in flush.sections] == ["thread.begin",
+                                                  "thread.finish"]
+    begin, fin = flush.sections
+    assert begin.tid != fin.tid
+    assert begin.pc_end <= fin.pc_start
+    for sp in flush.sections:
+        assert 0.0 <= sp.cpu <= sp.duration() + 1e-3
+        assert flush.root.pc_start <= sp.pc_start <= sp.pc_end \
+            <= flush.root.pc_end
+        assert h[f"request.stage_cpu_seconds{{stage:{sp.name}}}"][
+            "count"] == 4
+        assert h[f"request.stage_seconds{{stage:{sp.name}}}"]["count"] == 4
+    assert all(m.sections == [] for m in members)
 
 
 def test_debug_timeline_http_surface(live_api):
@@ -1127,6 +1105,9 @@ def test_dump_and_drain(tmp_holder):
     rows = [ln for ln in lines if ln.startswith("timeline: request")]
     assert len(rows) == 1, lines
     assert "plan=" in rows[0] and "dispatch=" in rows[0]
+    # ... and the longest kept beside the ring.
+    slow = [ln for ln in lines if ln.startswith("timeline: slowest request")]
+    assert len(slow) == 1 and "plan=" in slow[0], lines
 
 
 def test_config_timeline_keys(tmp_path):
@@ -1142,3 +1123,264 @@ def test_config_timeline_keys(tmp_path):
     assert not hasattr(Config(), "timeline_gap_window_s")
     with pytest.raises(ValueError):
         load_config(None, {"timeline_ring": 0})
+
+
+# ------------------------------------ whether a record's threads ran
+
+def _thread_clock_step():
+    """The step of this kernel's thread CPU clock, probed once: under a
+    microsecond on a plain Linux, 10 ms under a sandboxed kernel that
+    accounts CPU time by the scheduler tick (gVisor: the benchmark's
+    machines). The smallest step seen in 50 ms of reading it; the whole
+    50 ms where it never moved."""
+    steps = []
+    last = time.thread_time()
+    deadline = time.perf_counter() + 0.05
+    while time.perf_counter() < deadline:
+        t = time.thread_time()
+        if t != last:
+            steps.append(t - last)
+            last = t
+    return min(steps, default=0.05)
+
+
+# What a reading of `cpu` may be off by at each of a section's ends.
+_TICK = _thread_clock_step()
+
+
+def _spin(seconds):
+    """Burn `seconds` of this thread's CPU (not of the wall clock: a
+    loaded test machine takes the CPU away meanwhile) — by the clock
+    the sections read, so at least its next step."""
+    t0 = time.thread_time()
+    while time.thread_time() - t0 < seconds:
+        pass
+
+
+def _section(req, name):
+    return next(sp for sp in req.sections if sp.name == name)
+
+
+def test_sleeping_section_reads_little_cpu():
+    rec = TimelineRecorder()
+    req = rec.begin(None)
+    with rec.attached(req, "thread.finish"):
+        with rec.stage("d2h"):
+            time.sleep(0.05)
+    sp = _section(req, "thread.finish")
+    assert sp.duration() >= 0.05
+    assert 0.0 <= sp.cpu < 0.010 + _TICK
+
+
+def test_spinning_section_reads_its_cpu():
+    rec = TimelineRecorder()
+    req = rec.begin(None)
+    with rec.attached(req, "thread.begin"):
+        with rec.stage("plan"):
+            _spin(0.05)
+    sp = _section(req, "thread.begin")
+    assert 0.05 <= sp.cpu < 0.06 + _TICK
+    assert sp.cpu <= sp.duration() + 1e-3 + _TICK
+
+
+def test_section_covers_every_stage_its_thread_ran_inside_it():
+    rec = TimelineRecorder()
+    req = rec.begin(None)
+    with rec.attached(req, "thread.begin"):
+        with rec.phase("plan") as plan:
+            _spin(0.02)
+            with rec.stage("dispatch") as d:     # interrupts the phase
+                _spin(0.02)
+            with rec.stage("d2h"):
+                time.sleep(0.03)
+            _spin(0.01)
+    sp = _section(req, "thread.begin")
+    # 50 ms of CPU and a 30 ms sleep: the stages' wall adds up to the
+    # section's, the section's CPU leaves the sleep out.
+    # (each spin runs on to the clock's next step).
+    assert 0.05 <= sp.cpu < 0.065 + 3 * _TICK
+    assert sp.duration() >= plan.duration() + d.duration() + 0.03 - 1e-3
+    assert sp.duration() >= sp.cpu + 0.03 - 1e-3 - _TICK
+    # The stages themselves carry no reading: one pair a thread a
+    # record, not one a span.
+    assert not any(hasattr(c, "cpu") for c in req.root.walk())
+    assert sp not in list(req.root.walk())
+
+
+def test_stages_and_added_spans_feed_no_cpu_histogram():
+    stats = MemStatsClient()
+    rec = TimelineRecorder()
+    req = rec.begin(None, stats=stats)
+    t0 = time.perf_counter()
+    rec.add(req, "coalescer.wait", t0, t0 + 0.004)
+    with rec.attached(req, "thread.batch"):
+        with rec.stage("plan"):
+            pass
+    rec.finish(req)
+    h = stats.snapshot()["histograms"]
+    assert h["request.stage_seconds{stage:coalescer.wait}"]["count"] == 1
+    assert h["request.stage_seconds{stage:plan}"]["count"] == 1
+    assert sorted(k for k in h if k.startswith("request.stage_cpu")) == \
+        ["request.stage_cpu_seconds{stage:thread.batch}"]
+
+
+def test_cpu_histograms_count_as_the_wall_ones_and_never_sum_above():
+    stats = MemStatsClient()
+    rec = TimelineRecorder()
+    for _ in range(5):
+        req = rec.begin(None, stats=stats, kind="flush",
+                        name="coalescer.flush")
+        with rec.attached(req, "thread.begin"):
+            with rec.phase("plan"):
+                _spin(0.002)
+                with rec.stage("dispatch"):
+                    _spin(0.001)
+        with rec.attached(req, "thread.finish"):
+            with rec.stage("d2h"):
+                time.sleep(0.004 + 2 * _TICK)
+            with rec.phase("finish"):
+                _spin(0.001)
+        rec.finish(req)
+    h = stats.snapshot()["histograms"]
+    for section in ("thread.begin", "thread.finish"):
+        wall = h[f"request.stage_seconds{{stage:{section}}}"]
+        cpu = h[f"request.stage_cpu_seconds{{stage:{section}}}"]
+        assert cpu["count"] == wall["count"] == 5
+        assert 0.0 <= cpu["sum"] <= wall["sum"] + 1e-3 + 5 * _TICK
+    # The half that slept ran for a small share of its wall time.
+    assert h["request.stage_cpu_seconds{stage:thread.finish}"]["sum"] < \
+        0.5 * h["request.stage_seconds{stage:thread.finish}"]["sum"]
+    # ... and the half that spun read the CPU it burnt (5 x 3 ms).
+    assert h["request.stage_cpu_seconds{stage:thread.begin}"]["sum"] >= \
+        0.015
+
+
+@pytest.mark.parametrize("how", ["disabled", "unsampled", "unnamed"])
+def test_no_section_reads_no_thread_clock(monkeypatch, how):
+    from pilosa_tpu.utils import timeline as tl_mod
+    calls = []
+    real = tl_mod._thread_time
+    monkeypatch.setattr(tl_mod, "_thread_time",
+                        lambda: calls.append("tt") or real())
+    rec = TimelineRecorder()
+    if how == "disabled":
+        rec.configure(enabled=False)
+    elif how == "unsampled":
+        rec.configure(sample_every=1000)
+    req = rec.begin(None)
+    assert (req is None) == (how != "unnamed")
+    section = None if how == "unnamed" else "thread.begin"
+    with rec.attached(req, section):
+        with rec.span(req, "pql.parse"), rec.phase("plan"), \
+                rec.stage("dispatch"):
+            pass
+    rec.finish(req)
+    assert calls == []
+    # ... and a named section of a record reads it twice, however many
+    # stages run inside it.
+    rec.configure(enabled=True, sample_every=1)
+    req = rec.begin(None)
+    with rec.attached(req, "thread.begin"):
+        for _ in range(10):
+            with rec.stage("dispatch"):
+                pass
+    assert len(calls) == 2
+
+
+def test_section_cpu_rides_the_chrome_export_and_the_dump():
+    rec = TimelineRecorder()
+    req = rec.begin("ab" * 16)
+    with rec.attached(req, "thread.begin"):
+        with rec.stage("plan"):
+            _spin(0.002)
+    rec.finish(req)
+    evs = {e["name"]: e for e in rec.snapshot()["traceEvents"]
+           if e["ph"] == "X"}
+    sec, plan = evs["thread.begin"], evs["plan"]
+    assert "cpu" not in plan["args"] and "cpu" not in evs["request"]["args"]
+    assert sec["args"]["cpu"] > 0.001
+    assert sec["args"]["parentSpanId"] == evs["request"]["args"]["spanId"]
+    # It lies over the stage its thread ran, on that thread's lane.
+    assert sec["tid"] == plan["tid"]
+    assert sec["ts"] <= plan["ts"]
+    assert sec["ts"] + sec["dur"] >= plan["ts"] + plan["dur"]
+    lines = []
+
+    class _Log:
+        def printf(self, fmt, *args):
+            lines.append(fmt % args)
+
+    rec.dump(_Log(), last=1)
+    row = next(ln for ln in lines if ln.startswith("timeline: request"))
+    assert "plan=" in row and "thread.begin=" in row
+    assert "/" in row.split("thread.begin=")[1]
+    assert rec.ring_nbytes() > req.root.nbytes()
+
+
+# ------------------------------------------ the slowest outlive the ring
+
+def _finish_lasting(rec, seconds, kind="request", **begin):
+    req = rec.begin(None, kind=kind, **begin)
+    req.root.pc_start -= seconds             # as if begun that long ago
+    rec.finish(req)
+    return req
+
+
+def test_slowest_records_outlive_the_ring_eight_a_kind():
+    rec = TimelineRecorder(ring=4)
+    slow = [_finish_lasting(rec, 2.0 + i) for i in range(3)]
+    slow_flush = _finish_lasting(rec, 1.5, kind="flush",
+                                 name="coalescer.flush")
+    for i in range(40):
+        _finish_lasting(rec, 0.001 * (i % 7), kind="request")
+        _finish_lasting(rec, 0.001 * (i % 5), kind="flush",
+                        name="coalescer.flush")
+    assert all(r not in rec.requests() for r in slow + [slow_flush])
+    kept = rec.requests(slowest=True)
+    assert sum(r.kind == "request" for r in kept) == 8
+    assert sum(r.kind == "flush" for r in kept) == 8
+    assert set(map(id, slow + [slow_flush])) <= set(map(id, kept))
+    assert [r.seq for r in kept] == sorted(r.seq for r in kept)
+    doc = rec.snapshot(slowest=True)
+    assert doc["summary"]["requests"] == 16
+    roots = [e for e in doc["traceEvents"]
+             if e["ph"] == "X" and "kind" in e["args"]]
+    assert max(e["dur"] for e in roots) == pytest.approx(4.0e6, rel=0.01)
+    assert rec.ring_nbytes() > 0
+    rec.reset()
+    assert rec.requests(slowest=True) == []
+
+
+def test_full_collection_is_drawn_into_the_record_it_fell_in():
+    rec = TimelineRecorder()
+    before = _finish_lasting(rec, 0.01)
+    req = rec.begin(None)
+    t0 = time.perf_counter()
+    rec.gc_pauses.append((t0, t0 + 0.25))     # the pause is inside it
+    time.sleep(0.001)
+    rec.finish(req)
+    after = _finish_lasting(rec, 0.0)
+    evs = [e for e in rec.snapshot()["traceEvents"] if e["name"] == "gc"]
+    assert len(evs) == 1                     # once, however many overlap
+    assert evs[0]["dur"] == pytest.approx(0.25e6)
+    assert evs[0]["args"] == {"gen": 2}
+    assert evs[0]["ts"] == pytest.approx(req.root.start * 1e6, abs=5e3)
+    only = [e for e in rec._export_events([before], 0) if e["name"] == "gc"]
+    assert only == [] and after is not None
+
+
+def test_debug_timeline_slowest_http_surface(live_api):
+    api, base = live_api
+    TIMELINE.configure(ring=4)
+    for i in range(12):
+        assert "results" in _post(base, f"Count(Row(f={i % 3}))")
+    _wait_recorded(12)
+    assert len(TIMELINE.requests()) == 4
+    doc = _get(base, "/debug/timeline?slowest=1")
+    assert doc["summary"]["requests"] == 8
+    assert any(e["name"] == "plan" for e in doc["traceEvents"])
+    longest = max(r.root.duration() for r in TIMELINE.requests(slowest=True))
+    assert max(e["dur"] for e in doc["traceEvents"]
+               if e["name"] == "request") == pytest.approx(longest * 1e6)
+    with pytest.raises(urllib.error.HTTPError):
+        _get(base, "/debug/timeline?slow=1")
