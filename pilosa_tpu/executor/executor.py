@@ -540,6 +540,27 @@ def _pad_words(words, width: int):
     return jnp.pad(words, [(0, 0)] * (words.ndim - 1) + [(0, d)])
 
 
+class _SlicedRows:
+    """A dense bank operand as a lane of a filter group's program sees
+    it (`Executor._filter_group_fn`): `bank[slot]` is ONE dynamic slice
+    and `bank[[slot, ...]]` a slice a row, stacked — where indexing the
+    array itself by a traced vector is a gather over the whole bank,
+    and by a traced scalar several equations a leaf more to trace."""
+
+    __slots__ = ("array",)
+
+    def __init__(self, array) -> None:
+        self.array = array
+
+    def __getitem__(self, slots):
+        import jax.numpy as jnp
+        from jax import lax
+        if isinstance(slots, list):
+            return jnp.stack([self[s] for s in slots])
+        return lax.dynamic_index_in_dim(self.array, slots, 0,
+                                        keepdims=False)
+
+
 @dataclass
 class _Plan:
     """Everything the jitted tree program needs, gathered in one host pass.
@@ -995,6 +1016,20 @@ class Executor:
                 "executor.sweep_group_filters", members)
             if lanes > members:
                 self.stats.count("executor.sweep_pad_lanes",
+                                 lanes - members)
+
+    def _note_filter_launch(self, lanes: int, members: int) -> None:
+        """One filter program launched for the sweeps of `members`
+        staged TopN calls, in `lanes` lanes (1: `tree_row` alone):
+        `executor.filter_launches`, the members under
+        `executor.filter_group_members{k:<lanes>}`, and
+        `executor.filter_pad_lanes`, the lanes nobody reads."""
+        if self.stats is not None:
+            self.stats.count("executor.filter_launches", 1)
+            self.stats.with_tags(f"k:{lanes}").count(
+                "executor.filter_group_members", members)
+            if lanes > members:
+                self.stats.count("executor.filter_pad_lanes",
                                  lanes - members)
 
     def _note_topn_rows(self, what: str, n: int) -> None:
@@ -1860,11 +1895,7 @@ class Executor:
         signature stages from different batched queries later run as
         ONE vmapped XLA program (executor/fusion.py) and the returned
         FusedEval handle resolves to this query's slice."""
-        prof = self._profile()
-        # planS of the eval node: tree staging, one reading.
-        with TIMELINE.stage("plan.stage", mode=mode) as ps:
-            staged = self._stage_tree(idx, call, shards, mode)
-        plan_s = ps.duration()
+        staged, prof, plan_s = self._stage_eval(idx, call, shards, mode)
         ckey = None
         rc = self.result_cache
         forced = prof is not None and getattr(prof, "forced", False)
@@ -1904,6 +1935,16 @@ class Executor:
         out = self._run_staged(staged, prof, plan_s)
         return _CacheFillEval(out, rc, ckey, staged.gen) \
             if ckey is not None else out
+
+    def _stage_eval(self, idx: Index, call: Call, shards: List[int],
+                    mode: str):
+        """Plan the call tree under a `plan.stage` span; returns what
+        `_run_staged` and the batch's collector take: (staged eval,
+        this thread's profile, the staging seconds)."""
+        # planS of the eval node: tree staging, one reading.
+        with TIMELINE.stage("plan.stage", mode=mode) as ps:
+            staged = self._stage_tree(idx, call, shards, mode)
+        return staged, self._profile(), ps.duration()
 
     def _mesh_fusion_enabled(self) -> bool:
         """Mesh requests enter the fusion collector exactly when the
@@ -2098,6 +2139,75 @@ class Executor:
             fn = jax.jit(staged.runner())
             self._jit_put(staged.sig, fn)
         return fn, hit
+
+    def _filter_group_fn(self, rep: "_StagedEval", lanes: int,
+                         width: int) -> Tuple[Optional[Callable], bool]:
+        """jit: (a bank tuple a lane, operands [lanes, n]) -> `lanes`
+        filter rows [S, width], one array each: `rep`'s tree as ONE
+        lane body, called a lane — lane b over its own banks and row b
+        of the operands (its slots, then its u32 scalars), so a leaf's
+        read by a slot is a dynamic slice as in `tree_row` and never a
+        gather over a bank. Returns (fn, jit_hit); `lanes` = 1 asks
+        for nothing but the rule below (the caller runs `tree_row`).
+
+        Every lane count of a signature — one, the solo program, among
+        them — is built, and compiled, the first time a resident
+        TopN's filter of that signature is met, in a batch or outside
+        one (one discarded launch each, on `rep`'s operands): a
+        flush's remainders reach the rarer ones late, and a compile
+        belongs to warm-up, where `retraces` says it happened."""
+        import jax
+        from pilosa_tpu.executor.fusion import FILTER_LANES
+        head = f"filters{{}}|W{width}|{rep.sig}"
+        fn = self._jit_get(head.format(max(lanes, FILTER_LANES[0])))
+        if fn is not None:
+            return fn, True
+        run, n_idx = rep.runner(), len(rep.idxs)
+
+        def lane(lane_banks, row):
+            # The slots go in as a list of scalars (u32: a dynamic
+            # slice by an unsigned index wraps nothing around) and the
+            # dense banks as _SlicedRows (a sparse bank is a tuple of
+            # arrays, read as it is), so that no leaf indexes a bank by
+            # a vector.
+            return _align_words(run(
+                [a if isinstance(a, tuple) else _SlicedRows(a)
+                 for a in lane_banks],
+                [row[j] for j in range(n_idx)], row[n_idx:], None), width)
+
+        # One traced body for every lane of every lane count: a jit
+        # inside the program's is a call to ONE sub-computation, which
+        # XLA inlines — K unrolled copies would cost K times the
+        # tracing and keep K times the equations alive for the
+        # collector to walk.
+        lane = jax.jit(named(lane, "tree_row_lane"))
+
+        def multi(banks, ops):
+            return tuple(lane(lane_banks, ops[b])
+                         for b, lane_banks in enumerate(banks))
+
+        if lanes > 1:
+            solo, hit = self._tree_fn(rep)
+            if not hit:
+                idxs, params, _ = self._staged_args(rep)
+                solo(rep.bank_arrays, idxs, params, None)
+        # graftlint: disable=GL003 — host lists marshalled for upload.
+        row = np.asarray([*rep.idxs, *rep.params], np.uint32)
+        for k in FILTER_LANES:
+            self._note_jit_compile("tree_row_multi", head.format(k))
+            built = jax.jit(named(multi, "tree_row_multi"))
+            self._jit_put(head.format(k), built)
+            if k == lanes:
+                fn = built
+            else:
+                built((rep.bank_arrays,) * k, upload(np.tile(row, (k, 1))))
+        # The caller's `dispatch` span pays for the one compile left:
+        # that of the program it launches.
+        if fn is None:
+            self._tls.__dict__.pop("jit_miss", None)
+        else:
+            self._tls.jit_miss = head.format(lanes)[:200]
+        return fn, False
 
     def _cached_args(self, akey: tuple, build: Callable):
         """LRU arg-cache get-or-build: returns (arrays, uploaded).
@@ -2810,16 +2920,29 @@ class Executor:
         if len(covered) < len(shards):
             shards = self._shards(idx, covered)
 
+        tanimoto = call.uint_arg("tanimotoThreshold") or 0
+        fuser = getattr(self._tls, "fuser", None)
         filter_words = None
+        # A filter whose only reader is a staged sweep is staged too:
+        # inside a batch a filter tree that carries no literal operand
+        # waits, planned, for the resident branch below, where the
+        # batch's collector takes it with the sweep
+        # (fusion.FusionCollector.add_filter). Any other branch, and a
+        # call outside a batch, runs it at once. A tanimoto call reads
+        # its filter at once (`_popcount_row`), so it never waits.
+        staged_filter = None    # (staged eval, profile, plan seconds)
+        groupable = False       # a filter a batch stages with its sweep
         if call.children:
-            filter_words = self._eval_tree(idx, call.children[0], shards,
-                                           mode="row")
+            staged_filter = self._stage_eval(idx, call.children[0],
+                                             shards, "row")
+            groupable = not tanimoto and staged_filter[0].lits is None
+            if fuser is None or not groupable:
+                filter_words = self._run_staged(*staged_filter)
         attr_name = call.arg("attrName")
         allowed_rows = None
         if attr_name is not None:
             allowed_rows = set(field.row_attr_store.ids_matching(
                 attr_name, call.arg("attrValues", [])))
-        tanimoto = call.uint_arg("tanimotoThreshold") or 0
         # The rule applies only WITH a filter (filterless it would zero
         # every denominator and empty the result); only then does the
         # answer read the rows' own popcounts.
@@ -2865,7 +2988,7 @@ class Executor:
         # device work at all. Filters and tanimoto need real bitmaps, so
         # they always take the sweep.
         selfcheck_pairs = None  # warm answer being verified this query
-        if filter_words is None and not tanimoto:
+        if not call.children and not tanimoto:
             cached = self._topn_cached_counts(view, shards)
             if cached is not None:
                 self.topn_cache_hits += 1
@@ -2905,7 +3028,7 @@ class Executor:
         # with the sweep this path would have paid anyway. The sampled
         # self-check deliberately bypasses it — its exact leg must
         # exercise the real sweep.
-        if filter_words is None and not tanimoto and self.mesh is None \
+        if not call.children and not tanimoto and self.mesh is None \
                 and selfcheck_pairs is None:
             from pilosa_tpu.core.cache import RANK_CACHE
             if RANK_CACHE.enabled:
@@ -2937,18 +3060,25 @@ class Executor:
             self._note_topn("resident")
             bank = view.device_bank(tuple(shards), mesh=self.mesh,
                                     trim=True)
-            fuser = getattr(self._tls, "fuser", None)
-            if fuser is not None and filter_words is not None \
-                    and not tanimoto:
+            if fuser is not None and call.children and not tanimoto:
                 # Inside a batch the sweep waits for its bankmates: the
                 # filtered sweeps of one bank array share one pass
                 # (fusion.FusionCollector.add_sweep). The lane holds the
                 # array read here, as a dispatch would. A tanimoto
                 # sweep stays a launch of its own: a group on one-lane
                 # rows is not measured (ROADMAP A8).
-                out = fuser.add_sweep(bank.array, _align_words(
-                    filter_words, bank.array.shape[-1]))
+                if filter_words is None:
+                    filter_words = fuser.add_filter(
+                        *staged_filter, bank.array.shape[-1])
+                out = fuser.add_sweep(bank.array, filter_words)
             else:
+                if groupable:
+                    # Outside a batch the programs a batch would group
+                    # this filter's signature into are built all the
+                    # same, once: a server's first requests come one by
+                    # one, and its compiles belong there.
+                    self._filter_group_fn(staged_filter[0], 1,
+                                          bank.array.shape[-1])
                 out = self._dispatch_counts(bank.array, filter_words)
                 if filter_words is not None:
                     self._note_sweep_group(1, 1)
@@ -2965,6 +3095,8 @@ class Executor:
             # call that found them pending leaves the fetch to that one).
             arrays = (out, raw) if swept else (out,)
         else:
+            if call.children and filter_words is None:
+                filter_words = self._run_staged(*staged_filter)
             if PBANK_ENABLED and self.mesh is None and len(shards) == 1 \
                     and allowed_rows is None and not ids_arg and n \
                     and selfcheck_pairs is None:

@@ -33,14 +33,34 @@ flushes the collector before dispatching any write-containing request
 and dispatches that request uncollected, so no read fuses across a
 write that orders between them (tests/test_fusion.py pins this).
 
-The same collector holds the batch's filtered TopN sweeps (``add_sweep``).
-They are not a ``_FuseGroup``: a fusion group's members are one program
-over the same operands with different scalars, stacked and vmapped; a
-sweep group's members each bring a filter of their own, the output of
-whatever tree program made it, and share only the bank. So there is no
-signature to equate and nothing to stack: the group's one program takes
-the bank and K filter operands and reads the bank once
-(``ops/bitset.masked_row_counts_multi``; tests/test_sweep_groups.py).
+The same collector holds the batch's filtered TopN calls, in two kinds
+of group that launch in turn.
+
+A TopN's filter tree is a staged eval like any other, and its only
+reader is the sweep the collector already holds back. So it waits too
+(``add_filter``): the filters of one begin half that share a signature
+and the width their sweeps want form a ``_FilterGroup``, and the group
+launches ONE ``tree_row_multi`` program for up to ``FILTER_GROUP_MAX``
+of them, from ONE operand upload (the members' ``idxs`` and ``params``,
+a row a lane). The program is the representative's tree as one lane
+body, called a lane: lane ``b`` reads row ``b`` of the operands and its
+own member's banks, so a leaf's read by a slot is a dynamic slice (a
+``vmap`` would make it a gather over the bank) and members over
+different arrays of one shape, a time range's day views, still share a
+launch. Its lanes
+come out as separate ``[S, W]`` arrays at the sweep's width. A group of
+one runs ``tree_row`` as a call outside a batch does. These groups
+never enter ``run_megakernel``: they are not in ``self.groups``.
+
+The sweeps (``add_sweep``) are not a ``_FuseGroup`` at all: a sweep
+group's members each bring a filter of their own, a lane of whatever
+filter group made it, and share only the bank. The group's one program
+takes the bank and K filter operands and reads the bank once
+(``ops/bitset.masked_row_counts_multi``). A sweep lane holds a handle
+to its filter's lane and no device op touches one in between: a sweep
+group launches after the filter groups its lanes come from, at
+``flush()`` or, when it is full and they are all in flight, at once
+(tests/test_sweep_groups.py, tests/test_filter_groups.py).
 """
 
 from __future__ import annotations
@@ -296,6 +316,65 @@ class _FuseGroup:
                 WORKLOAD.note_eval_seconds(e.fp, per_eval)
 
 
+# A filter group launches as soon as it holds FILTER_GROUP_MAX members,
+# and at flush(). Its program exists in these lane counts; a group of
+# one runs the solo tree program, as a call outside a batch does.
+FILTER_LANES = (2, 4, 8)
+FILTER_GROUP_MAX = FILTER_LANES[-1]
+
+
+class _FilterGroup(_FuseGroup):
+    """The staged TopN filter trees of one begin half that share a
+    signature and the word width their sweeps want. `out` is a tuple
+    of `[S, W]` arrays, a lane each, so a member's `device_words()` is
+    a tuple index on the host; alone, it is the solo program's array.
+    Every member holds the bank arrays it was staged against: a later
+    write in the batch, which builds new ones, changes nothing it
+    reads."""
+
+    __slots__ = ("width",)
+
+    def __init__(self, executor: Any, width: int) -> None:
+        super().__init__(executor)
+        self.width = width                # the sweep's word width
+
+    @property
+    def launched(self) -> bool:
+        return self.out is not None or self.error is not None
+
+    def _run(self) -> None:
+        from pilosa_tpu.executor.executor import upload
+
+        ex = self.executor
+        n = len(self.entries)
+        rep = self.entries[0]
+        lanes = 1 if n == 1 else next(k for k in FILTER_LANES if k >= n)
+        fn, jit_hit = ex._filter_group_fn(rep, lanes, self.width)
+        if n == 1:
+            # The exact unfused path (same program, same arg cache).
+            super()._run()
+            ex._note_filter_launch(1, 1)
+            return
+        # The last member fills the pad lanes again; nobody reads them.
+        rows = self.entries + self.entries[-1:] * (lanes - n)
+        # One row a lane: its slots, then its u32 scalars.
+        # graftlint: disable=GL003 — host lists marshalled for the one
+        # stacked upload; nothing is fetched.
+        ops = np.asarray([[*e.idxs, *e.params] for e in rows], np.uint32)
+        akey = (f"filters{lanes}|{self.width}|{rep.sig}", ops.tobytes())
+        ops_dev, uploaded = ex._cached_args(akey, lambda: upload(ops))
+        with ex._dispatch_span("tree_row_multi") as ds:
+            ds.set("filters", n)
+            ds.set("lanes", lanes)
+            self.out = ex._call_program(
+                fn, tuple(e.bank_arrays for e in rows), ops_dev)
+        self.batched = True
+        ex._note_filter_launch(lanes, n)
+        # The one upload (pad lanes included) spread over the members.
+        self._attribute(jit_hit, ds.duration(),
+                        ops.nbytes // n if uploaded else 0, fused=True)
+
+
 # A sweep group launches as soon as it holds this many filters: the
 # chip starts on them while the host stages the rest of the flush. Its
 # program exists in these lane counts; a group of one runs the
@@ -318,30 +397,67 @@ class SweepLane(FusedEval):
         pads = self.group.pad_lanes if self.b == 0 else 0
         return self.slice_nbytes * (1 + pads)
 
+    def _out(self) -> Any:
+        error = self.group.lane_errors[self.b]
+        if error is not None:
+            raise error
+        return super()._out()
+
 
 class _SweepGroup:
     """The filtered TopN sweeps of one begin half that read the same
     bank array. It holds the array object its members read at staging,
     so a later write in the batch, which builds a new array, changes
-    nothing they sweep."""
+    nothing they sweep. A filter is a device array or a lane of a
+    filter group, read when the sweep launches."""
 
     __slots__ = ("executor", "bank", "filters", "out", "host", "batched",
-                 "error", "pad_lanes", "__weakref__")
+                 "error", "lane_errors", "pad_lanes", "__weakref__")
 
     def __init__(self, executor: Any, bank: Any) -> None:
         self.executor = executor
         self.bank = bank                  # [R, S, W] device array
-        self.filters: List[Any] = []      # [S, W] device arrays
+        self.filters: List[Any] = []      # [S, W] arrays or FusedEvals
         self.out = None                   # [K, R] (or [R] alone)
         self.host: Optional[np.ndarray] = None
         self.batched = False
         self.error: Optional[Exception] = None
+        # By lane, what its filter's launch raised: that member's alone.
+        self.lane_errors: List[Optional[Exception]] = \
+            [None] * SWEEP_GROUP_MAX
         self.pad_lanes = 0
 
     def add(self, filt: Any) -> SweepLane:
         self.filters.append(filt)
         return SweepLane(self, len(self.filters) - 1,
                          (self.bank.shape[0],))
+
+    def ready(self) -> bool:
+        """Every filter is in flight: the sweep can be queued behind
+        them without launching anything else first."""
+        return all(f.group.launched for f in self.filters
+                   if isinstance(f, FusedEval))
+
+    def _filter_words(self) -> List[Any]:
+        """The filters as the sweep program's operands. A lane whose
+        filter group failed keeps that error to itself and sweeps a
+        neighbour's filter again, as a pad lane does."""
+        from pilosa_tpu.executor.executor import _align_words
+        width = self.bank.shape[-1]
+        words: List[Any] = []
+        for b, f in enumerate(self.filters):
+            try:
+                # A group's lanes come out at `width`: nothing to align.
+                words.append(_align_words(
+                    f.device_words() if isinstance(f, FusedEval) else f,
+                    width))
+            except Exception as e:
+                self.lane_errors[b] = e
+                words.append(None)
+        good = [w for w in words if w is not None]
+        if not good:
+            raise next(e for e in self.lane_errors if e is not None)
+        return [good[-1] if w is None else w for w in words]
 
     def run(self) -> None:
         """Launch the group's one program. Never raises (the
@@ -351,7 +467,7 @@ class _SweepGroup:
             return
         try:
             self.out, lanes = self.executor._dispatch_sweep_group(
-                self.bank, self.filters)
+                self.bank, self._filter_words())
             self.batched = lanes > 1
             self.pad_lanes = lanes - len(self.filters)
         except Exception as e:
@@ -363,15 +479,19 @@ class _SweepGroup:
 
 class FusionCollector:
     """Per-batch registry of staged terminal evals, grouped by fusion
-    key, and of staged bank sweeps, grouped by bank. Installed
-    thread-locally by execute_batch (Executor._fusing); `flush()` runs
-    every open group — called before a write-containing request
-    dispatches (the fence) and once after the dispatch loop."""
+    key, of staged TopN filters, grouped by signature, and of staged
+    bank sweeps, grouped by bank. Installed thread-locally by
+    execute_batch (Executor._fusing); `flush()` runs every open group —
+    called before a write-containing request dispatches (the fence)
+    and once after the dispatch loop."""
 
     def __init__(self, executor: Any) -> None:
         self.executor = executor
         self.groups: Dict[tuple, _FuseGroup] = {}
+        self.filters: Dict[tuple, _FilterGroup] = {}
         self.sweeps: Dict[tuple, _SweepGroup] = {}
+        # Full sweep groups whose filters are not all in flight yet.
+        self.waiting: List[_SweepGroup] = []
 
     def add(self, staged: Any, prof: Any, plan_s: float) -> FusedEval:
         """Stage one eval; returns its FusedEval handle. Grouping is
@@ -385,13 +505,40 @@ class FusionCollector:
             group = self.groups[key] = _FuseGroup(self.executor)
         return group.add(staged, prof, plan_s)
 
+    def add_filter(self, staged: Any, prof: Any, plan_s: float,
+                   width: int) -> FusedEval:
+        """Stage one TopN's filter tree, whose words its sweep wants
+        `width` wide; returns its lane, which `add_sweep` takes.
+        Grouping is by (sig, width): the signature equates the tree
+        and every operand's shape, and each lane reads its own
+        member's bank arrays, so members over different arrays of one
+        shape share a launch. A full group launches here, and with it
+        the full sweep groups that waited for it."""
+        key = (staged.sig, width)
+        group = self.filters.get(key)
+        if group is None:
+            group = self.filters[key] = _FilterGroup(self.executor, width)
+        lane = group.add(staged, prof, plan_s)
+        if lane.b + 1 == FILTER_GROUP_MAX:
+            del self.filters[key]
+            group.run()
+            waiting, self.waiting = self.waiting, []
+            for sweep in waiting:
+                if sweep.ready():
+                    sweep.run()
+                else:
+                    self.waiting.append(sweep)
+        return lane
+
     def add_sweep(self, bank: Any, filt: Any) -> SweepLane:
-        """Stage one filtered sweep of `bank` ([R, S, W]) under `filt`
-        ([S, W], aligned to the bank's width); returns its lane.
-        Grouping is by the bank ARRAY's identity, as in `add`: what
-        decides a group's size is how many sweeps of this begin half
-        hold the same array, and nothing else. A full group launches
-        here."""
+        """Stage one filtered sweep of `bank` ([R, S, W]) under `filt`:
+        `[S, W]` words, or the lane `add_filter` gave (the group aligns
+        either to the bank's width when it launches). Returns the
+        sweep's lane. Grouping is by
+        the bank ARRAY's identity, as in `add`: what decides a group's
+        size is how many sweeps of this begin half hold the same array,
+        and nothing else. A full group launches here if its filters
+        are all in flight, else when the last of them is."""
         key = (id(bank), bank.shape)
         group = self.sweeps.get(key)
         if group is None:
@@ -399,14 +546,21 @@ class FusionCollector:
         lane = group.add(filt)
         if lane.b + 1 == SWEEP_GROUP_MAX:
             del self.sweeps[key]
-            group.run()
+            if group.ready():
+                group.run()
+            else:
+                self.waiting.append(group)
         return lane
 
     def flush(self) -> None:
-        # The sweeps first: their filters are already in flight, and
-        # they are the long device work of the flush.
+        # The filters first, then the sweeps that read them: the long
+        # device work of the flush.
+        filters, self.filters = self.filters, {}
+        for group in filters.values():
+            group.run()
+        waiting, self.waiting = self.waiting, []
         sweeps, self.sweeps = self.sweeps, {}
-        for sweep in sweeps.values():
+        for sweep in (*waiting, *sweeps.values()):
             sweep.run()
         groups, self.groups = self.groups, {}
         if not groups:

@@ -119,8 +119,10 @@ class API:
         for path in Executor.POPCOUNT_PATHS:
             self.stats.with_tags(f"path:{path}").count(
                 "executor.bank_popcounts", 0)
-        # ... and the bank-sweep launches, read per answer.
+        # ... and the bank-sweep launches and the filter programs
+        # launched for them inside a batch, read per answer.
         self.stats.count("executor.sweep_launches", 0)
+        self.stats.count("executor.filter_launches", 0)
         # ... and a GroupBy's: groups answered, level programs, and the
         # group-sum launches of `aggregate=Sum(field=f)` with the
         # (group, plane) rows they counted.

@@ -426,6 +426,15 @@ METRIC_HELP: Dict[str, str] = {
         "Constant 1 labeled with the server version and jax backend.",
     "pilosa_coalescer_batch_size":
         "Queries per coalesced executor batch.",
+    "pilosa_executor_filter_group_members_total":
+        "Staged TopN filters served by a filter program's launch, "
+        "labeled by its lane count k (1 = tree_row alone).",
+    "pilosa_executor_filter_launches_total":
+        "Filter programs (tree_row, tree_row_multi) launched for the "
+        "sweeps of a batch's staged TopN calls.",
+    "pilosa_executor_filter_pad_lanes_total":
+        "Lanes of filter group launches that repeat the last member "
+        "and are read by nobody.",
     "pilosa_executor_fusion_group_size":
         "Queries fused per executor dispatch group.",
     "pilosa_executor_jit_cache_size":

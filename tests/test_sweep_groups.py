@@ -144,8 +144,10 @@ def test_lone_sweep_in_a_batch_runs_the_one_filter_program(
 
 def test_every_lane_count_is_built_when_the_first_group_forms(
         ex, monkeypatch):
-    """The multi programs of a bank shape compile together, in warm-up:
-    a later group of another size compiles nothing."""
+    """The multi programs of a bank shape compile together, in warm-up
+    (those of the filters' signature did with the first direct answer:
+    tests/test_filter_groups.py): a later group of another size
+    compiles nothing."""
     for r in range(4):
         ex.execute("i", _topn("f", r))
     jc0 = ex.jit_compiles
