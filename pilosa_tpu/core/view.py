@@ -18,6 +18,7 @@ from pilosa_tpu.core.fragment import CONTAINER_BITS, Fragment
 from pilosa_tpu.core import cache as cache_mod
 from pilosa_tpu.utils.hotspots import WORKLOAD
 from pilosa_tpu.utils.memledger import LEDGER
+from pilosa_tpu.utils.profile import transfer
 from pilosa_tpu.utils.timeline import TIMELINE
 
 VIEW_STANDARD = "standard"
@@ -83,6 +84,71 @@ def _expand_sparse_chunk(pos16: np.ndarray, lens: np.ndarray,
     if n:
         row_of[:n] = np.repeat(rows_at.astype(np.uint32), lens)
     return _EXPAND_FN(jnp.asarray(pos), jnp.asarray(row_of), cap, width)
+
+
+# A bank patch writes whole (row slot, shard) cells into a cached bank:
+# one program per (bank shape, lane bucket). Lanes are a power of two up
+# to this; a longer patch is a chain of launches of the top bucket.
+PATCH_LANES_MAX = 64
+_PATCH_PROGRAMS: Dict[tuple, Any] = {}
+_PATCH_LOCK = make_lock("view._PATCH_LOCK")
+
+
+def _patch_programs(array) -> Dict[Tuple[int, bool], Any]:
+    """{(lanes, donating): compiled `bank_patch`} for banks of this
+    array's shape, dtype and placement. EVERY lane bucket is compiled
+    when the first patch of a shape runs — ahead of time, nothing is
+    executed — so a later patch of any cell count finds its program:
+    the cell count is what write traffic happens to leave between two
+    reads of a bank, and a count first met inside a serving window
+    must not compile there. The donating variant (top bucket only)
+    continues a chain over an array this module made itself; the
+    first launch of a patch never donates, because a staged filter or
+    a sweep group may still hold the cached bank's array."""
+    import jax
+
+    # The placement a mesh gave the bank, or none: an array the host
+    # uploaded plainly is uncommitted, and a program lowered FOR its
+    # device would hand back a committed one — another jit cache key
+    # for every program that takes the patched bank (the sweeps and
+    # filter programs compiled anew inside a window: 9 compiles, 11.7 s;
+    # PERF.md §6 PR 38).
+    sharding = array.sharding if array.committed else None
+    key = (array.shape, str(array.dtype), sharding)
+    with _PATCH_LOCK:
+        progs = _PATCH_PROGRAMS.get(key)
+        if progs is not None:
+            return progs
+
+        def bank_patch(bank, rows_idx, shard_idx, words):
+            return bank.at[rows_idx, shard_idx].set(words)
+
+        def compiled(lanes: int, donate: bool):
+            # graftlint: disable=GL006 — process-global build memoized
+            # in _PATCH_PROGRAMS, as _expand_sparse_chunk's: the view
+            # layer has no executor to ask; `xla.compiles` and
+            # /debug/queries' xla.byName count it (jit(bank_patch)).
+            fn = jax.jit(bank_patch,
+                         donate_argnums=(0,) if donate else ())
+            idx = jax.ShapeDtypeStruct((lanes,), np.int32)
+            return fn.lower(
+                jax.ShapeDtypeStruct(array.shape, array.dtype,
+                                     sharding=sharding),
+                idx, idx,
+                jax.ShapeDtypeStruct((lanes, array.shape[-1]),
+                                     array.dtype)).compile()
+
+        progs = {}
+        lanes = 1
+        while lanes <= PATCH_LANES_MAX:
+            progs[(lanes, False)] = compiled(lanes, False)
+            lanes *= 2
+        progs[(PATCH_LANES_MAX, True)] = compiled(PATCH_LANES_MAX, True)
+        # graftlint: disable=GL008 — one entry per distinct bank SHAPE
+        # (capacity is a power of two, shards and width the index's):
+        # grows with the schema, not with traffic.
+        _PATCH_PROGRAMS[key] = progs
+        return progs
 
 
 class BankBudget:
@@ -647,6 +713,11 @@ class View:
 
         shards = tuple(shards)
         mesh_key = mesh.cache_key() if mesh else None
+        # Why a bank that WAS cached is built anew, if it is: a full-view
+        # bank that could not be patched counts executor.bank_rebuilds
+        # and {cause:capacity|epoch|half|width}, a stale row-subset bank
+        # executor.bank_subset_rebuilds.
+        rebuild = None
         with self._lock:
             frags = {s: self.fragments.get(s) for s in shards}
             versions = {s: (f.version if f else -1) for s, f in frags.items()}
@@ -678,9 +749,11 @@ class View:
                         moved or list(shards))
                 row_set = sorted({r for f in frags.values() if f
                                   for r in f.row_ids()})
-                if cached is not None and cached.array.shape[-1] == width:
-                    patched = self._patch_bank(cached, frags, versions,
-                                               row_set, shards, width)
+                if cached is not None:
+                    patched, rebuild = None, "width"
+                    if cached.array.shape[-1] == width:
+                        patched, rebuild = self._patch_bank(
+                            cached, frags, versions, row_set, shards, width)
                     if patched is not None:
                         # Patch path: carry the PRIOR density estimate
                         # forward — a <=half-bank cell patch moves the
@@ -711,6 +784,15 @@ class View:
                         HOST_BLOCK_BUDGET.touch(
                             self, (shards, width, tuple(row_set)))
                         return cached
+                    if cached is not None:
+                        # A row-subset bank has no patch branch: any
+                        # write to a shard of its view builds it anew.
+                        rebuild = "subset"
+            if rebuild == "subset":
+                TIMELINE.count("executor.bank_subset_rebuilds")
+            elif rebuild is not None:
+                TIMELINE.count("executor.bank_rebuilds")
+                TIMELINE.count(f"executor.bank_rebuilds{{cause:{rebuild}}}")
             cap = bank_capacity(len(row_set))
             # Host blocks back ALL row-subset builds (cache_rows device
             # banks included): when HBM pressure evicts the device bank,
@@ -1202,16 +1284,21 @@ class View:
             return bank
 
     def _patch_bank(self, cached: "ViewBank", frags, versions, row_set,
-                    shards, width):
+                    shards, width) -> Tuple[Optional["ViewBank"], str]:
         """Incrementally refresh a cached bank: re-upload only (row, shard)
-        cells whose fragment reports a newer row version. Returns None when
-        a rebuild is required (new rows exceed capacity, or the patch would
-        touch most of the bank anyway)."""
-        import jax.numpy as jnp
-
+        cells whose fragment reports a newer row version, through the
+        `bank_patch` program of the bank's shape (`_patch_programs`).
+        Returns (bank, "") or (None, why a rebuild is required):
+        `capacity` (new rows exceed it), `epoch` (a fragment was
+        recreated), `half` (the patch would touch most of the bank
+        anyway). The patched bank is a new ViewBank over a new array —
+        the cached array is never donated, whoever staged it keeps
+        reading the version they took; it keeps the slot-ordered row
+        array when no row was added and drops the rows' popcounts,
+        which belong to one bank version."""
         new_rows = [r for r in row_set if r not in cached.slots]
         if len(cached.slots) + len(new_rows) + 1 > cached.array.shape[0]:
-            return None
+            return None, "capacity"
         for s, newv in versions.items():
             old = cached.versions.get(s, -1)
             if old != newv and (old < 0 or (old >> 48) != (newv >> 48)):
@@ -1220,7 +1307,7 @@ class View:
                 # _row_versions no longer attributes writes made in the
                 # old incarnation — rows_changed_since below would
                 # under-patch. Rebuild.
-                return None
+                return None, "epoch"
         patches = []  # (slot, shard_idx, words)
         for si, s in enumerate(shards):
             f = frags[s]
@@ -1241,15 +1328,52 @@ class View:
                                     f.row_dense(r, u32_words=width)))
         total_cells = cached.array.shape[0] * cached.array.shape[1]
         if len(patches) > max(16, total_cells // 2):
-            return None
+            return None, "half"
         array = cached.array
         if patches:
-            rows_idx = np.asarray([p[0] for p in patches], dtype=np.int32)
-            shard_idx = np.asarray([p[1] for p in patches], dtype=np.int32)
-            words = np.stack([p[2] for p in patches])
-            array = array.at[jnp.asarray(rows_idx),
-                             jnp.asarray(shard_idx)].set(jnp.asarray(words))
-        return ViewBank(array, slots, cached.zero_slot, versions)
+            array = self._launch_patches(array, patches)
+        bank = ViewBank(array, slots, cached.zero_slot, versions)
+        if not new_rows:
+            bank._slot_rows = cached._slot_rows
+        return bank, ""
+
+    def _launch_patches(self, array, patches):
+        """`array` with the cells of `patches` written: one `bank_patch`
+        launch for up to PATCH_LANES_MAX cells, lanes the next power of
+        two and the pad lanes repeating the last real cell (the same
+        words to the same place), then donating launches of the top
+        bucket over the array the launch before made. A
+        `plan.bank_patch` span of the request that met the stale bank."""
+        import jax.numpy as jnp
+
+        progs = _patch_programs(array)
+        n = len(patches)
+        first = n % PATCH_LANES_MAX or PATCH_LANES_MAX
+        chunks = [patches[:first]] + [
+            patches[i:i + PATCH_LANES_MAX]
+            for i in range(first, n, PATCH_LANES_MAX)]
+        lanes_of = [1 << (len(c) - 1).bit_length() for c in chunks]
+        pad = sum(lanes_of) - n
+        with TIMELINE.stage(
+                "plan.bank_patch", cells=n, lanes=sum(lanes_of),
+                bytes=n * array.shape[-1] * array.dtype.itemsize,
+                bank=f"{self.field}/{self.name}",
+                counts=(("executor.bank_patches", 1),
+                        ("executor.bank_patch_cells", n),
+                        ("executor.bank_patch_pad_lanes", pad))):
+            for k, (chunk, lanes) in enumerate(zip(chunks, lanes_of)):
+                chunk = chunk + [chunk[-1]] * (lanes - len(chunk))
+                rows_idx = np.asarray([p[0] for p in chunk], dtype=np.int32)
+                shard_idx = np.asarray([p[1] for p in chunk], dtype=np.int32)
+                words = np.stack([p[2] for p in chunk])
+                with transfer("h2d", rows_idx.nbytes + shard_idx.nbytes
+                              + words.nbytes, 3):
+                    operands = (jnp.asarray(rows_idx),
+                                jnp.asarray(shard_idx), jnp.asarray(words))
+                with TIMELINE.stage("dispatch", program="bank_patch",
+                                    lanes=lanes):
+                    array = progs[(lanes, k > 0)](array, *operands)
+        return array
 
     # Pass-throughs (reference view.go:294-421).
 

@@ -1746,19 +1746,32 @@ class Executor:
             return self._execute_group_by(idx, call, shards)
         if name in ("Sum", "Min", "Max"):
             return self._execute_val_count(idx, call, shards, name)
-        if name == "Set":
-            return self._execute_set(idx, call)
-        if name == "Clear":
-            return self._execute_clear(idx, call)
-        if name == "ClearRow":
-            return self._execute_clear_row(idx, call, shards)
-        if name == "Store":
-            return self._execute_store(idx, call, shards)
-        if name == "SetRowAttrs":
-            return self._execute_set_row_attrs(idx, call)
-        if name == "SetColumnAttrs":
-            return self._execute_set_column_attrs(idx, call)
+        if name in ALL_WRITE_CALLS:
+            return self._execute_write(idx, call, shards)
         raise ExecutionError(f"unknown call: {name}")
+
+    def _execute_write(self, idx: Index, call: Call, shards) -> Any:
+        """One write call applied where it stands in its query: the
+        fragment (or attribute store), its op log, and — through the
+        version it bumps — every cache and bank that held the old
+        state, which the NEXT read of each repairs (core/view.py:
+        `plan.bank_patch` or a rebuild). Span `write.apply`, counter
+        `executor.writes{call:...}`."""
+        name = call.name
+        with TIMELINE.stage(
+                "write.apply", call=name,
+                counts=((f"executor.writes{{call:{name}}}", 1),)):
+            if name == "Set":
+                return self._execute_set(idx, call)
+            if name == "Clear":
+                return self._execute_clear(idx, call)
+            if name == "ClearRow":
+                return self._execute_clear_row(idx, call, shards)
+            if name == "Store":
+                return self._execute_store(idx, call, shards)
+            if name == "SetRowAttrs":
+                return self._execute_set_row_attrs(idx, call)
+            return self._execute_set_column_attrs(idx, call)
 
     def _shards(self, idx: Index, shards, pad: bool = True) -> List[int]:
         available = idx.available_shards()

@@ -19,7 +19,8 @@ from pilosa_tpu.core.field import FieldOptions
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.core import timeq
 from pilosa_tpu.executor import Executor
-from pilosa_tpu.executor.executor import write_call_count
+from pilosa_tpu.executor.executor import (ALL_WRITE_CALLS,
+                                          write_call_count)
 from pilosa_tpu.executor.results import result_to_json
 from pilosa_tpu.ops.bitset import SHARD_WIDTH
 from pilosa_tpu.pql import Call, Query, parse_string_cached
@@ -129,6 +130,20 @@ class API:
         for name in ("groupby_groups", "groupby_levels",
                      "groupsum_launches", "groupsum_plane_rows"):
             self.stats.count(f"executor.{name}", 0)
+        # ... and the write path's: writes applied by call, what each
+        # stale bank cost the next read of it (a `bank_patch` of the
+        # cells that moved, or a rebuild and why), read per write and
+        # per window — 0 in a window without a write, never absent.
+        for call in sorted(ALL_WRITE_CALLS):
+            self.stats.with_tags(f"call:{call}").count(
+                "executor.writes", 0)
+        for name in ("bank_patches", "bank_patch_cells",
+                     "bank_patch_pad_lanes", "bank_rebuilds",
+                     "bank_subset_rebuilds"):
+            self.stats.count(f"executor.{name}", 0)
+        for cause in ("capacity", "epoch", "half", "width"):
+            self.stats.with_tags(f"cause:{cause}").count(
+                "executor.bank_rebuilds", 0)
         # The process-wide workload recorder (utils/hotspots.py)
         # increments its counters (pilosa_fragment_reads_total, ...)
         # straight into the stats client at record time so the

@@ -192,6 +192,26 @@ class QueryCoalescer:
         # is divided by. Written by the finalizer, read by the
         # dispatcher.
         self._member_s = 0.0
+        # Flushes by the path they took and by why the window closed,
+        # published from the start: a share of them is read over a
+        # window in which one path may never be taken.
+        for tag in [f"path:{p}" for p in self.FLUSH_PATHS] + \
+                [f"reason:{r}" for r in self.FLUSH_REASONS]:
+            self.stats.with_tags(tag).count("coalescer.flushes", 0)
+
+    # `pipelined`: read-only, two threads, overlapping the next flush;
+    # `batch`: barriered and run whole on the dispatcher (`thread.batch`)
+    # — every flush that holds a write; `direct`: a lone request.
+    FLUSH_PATHS = ("pipelined", "batch", "direct")
+    FLUSH_REASONS = ("window", "size", "write", "idle", "drain",
+                     "shutdown")
+
+    def _count_flush(self, path: str, reason: str, size: int) -> None:
+        self.stats.count(f"coalescer.flush.{reason}", 1)
+        self.stats.with_tags(f"path:{path}").count("coalescer.flushes", 1)
+        self.stats.with_tags(f"reason:{reason}").count(
+            "coalescer.flushes", 1)
+        self.stats.histogram("coalescer.batch_size", size)
 
     # ------------------------------------------------------------ lifecycle
 
@@ -462,8 +482,8 @@ class QueryCoalescer:
             self.stats.count("coalescer.window_repeat", repeats)
 
     def _execute(self, batch: List[_Item], reason: str) -> None:
-        self.stats.count(f"coalescer.flush.{reason}", 1)
-        self.stats.histogram("coalescer.batch_size", len(batch))
+        self._count_flush("direct" if len(batch) == 1 else "batch",
+                          reason, len(batch))
         self._note_workload(batch)
         try:
             if len(batch) == 1:
@@ -623,8 +643,7 @@ class QueryCoalescer:
         per-flush host time. Both halves are stages of ONE flush
         record; `coalescer.handoff` is the wait for the finalizer."""
         began = time.perf_counter()
-        self.stats.count(f"coalescer.flush.{reason}", 1)
-        self.stats.histogram("coalescer.batch_size", len(batch))
+        self._count_flush("pipelined", reason, len(batch))
         self._note_workload(batch)
         rec = None
         profiles: List[Any] = []

@@ -493,6 +493,13 @@ class TimelineRecorder:
         """span() on the record attached to this thread."""
         return self.span(getattr(self._tls, "rec", None), name, **kw)
 
+    def count(self, name: str, n: int = 1) -> None:
+        """Add to a counter of the record attached to this thread (as a
+        span's `counts` do), where no span of its own marks the event."""
+        rec = getattr(self._tls, "rec", None)
+        if rec is not None:
+            rec.counts[name] = rec.counts.get(name, 0) + n
+
     def add(self, rec: Optional[_TimelineRequest], name: str,
             pc_start: float, pc_end: float,
             link: Optional[Span] = None, own_lane: bool = False,

@@ -544,7 +544,9 @@ def test_the_benchmark_reads_filter_launches_per_answer():
     try:
         from harness.manifest import Manifest
         man = Manifest(repo)
-        entry = man.doc["per_layer"][-1]
+        # By name, not by position: later PRs append to the list.
+        entry = next(m for m in man.doc["per_layer"]
+                     if m["name"] == "filter_launches_per_op.sweep")
         assert entry == {
             "name": "filter_launches_per_op.sweep", "unit": "launches/op",
             "better": "lower", "source": "program_counter",

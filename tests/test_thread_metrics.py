@@ -15,7 +15,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH = os.path.join(REPO, "benchmark")
 
 SWEEP_CELLS = ["taxi-chip.topn-sweep", "taxi-host4.topn-sweep",
-               "chem-chip.tanimoto-sweep", "ssb-chip.flights"]
+               "chem-chip.tanimoto-sweep", "ssb-chip.flights",
+               "taxi-live-chip.report-ingest"]
 POINT = ["taxi-chip.point-serial"]
 
 
