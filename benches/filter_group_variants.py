@@ -137,7 +137,9 @@ def _bodies(ex, rep, lanes: int, width: int, tag: str) -> dict:
     # The shipped program under a name of its own in the trace.
     shipped = jax.jit(named(shipped.__wrapped__, f"fgv_{tag}_lanes{lanes}"))
     return {
-        "lanes": (shipped, (banks, ops), lambda out, b: out[b]),
+        "lanes": (shipped, (rep.shared_banks,
+                            (rep.owned_banks,) * lanes, ops),
+                  lambda out, b: out[b]),
         "lanes_gather": (
             jax.jit(named(lanes_gather, f"fgv_{tag}_lanesgather{lanes}")),
             (banks, ops), lambda out, b: out[b]),

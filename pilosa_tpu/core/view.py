@@ -289,11 +289,12 @@ class ViewBank:
     changes the compiled shape.
     """
 
-    def __init__(self, array, slots, zero_slot, versions):
+    def __init__(self, array, slots, zero_slot, versions, subset=False):
         self.array = array          # jnp [Rcap, S, W]
         self.slots = slots          # row id -> slot
         self.zero_slot = zero_slot
         self.versions = versions    # {shard: fragment.version} at build time
+        self.subset = subset        # built for a caller's `rows`, not all
         self._slot_rows = None
         # The rows' own popcounts [Rcap], computed FROM `array` by the
         # first tanimoto TopN that meets this bank (one unfiltered
@@ -874,7 +875,8 @@ class View:
                                                    slots, cap, row_set)
                         array = mesh.put_bank(host) if mesh \
                             else jnp.asarray(host)
-            bank = ViewBank(array, slots, cap - 1, versions)
+            bank = ViewBank(array, slots, cap - 1, versions,
+                            subset=rows is not None)
             if rows is None or cache_rows:
                 self._bank_cache[cache_key] = bank
                 BANK_BUDGET.admit(self, cache_key)

@@ -50,7 +50,7 @@ from pilosa_tpu.executor.results import (
     FieldRow, GroupCount, PairsResult, RowIdentifiers, RowResult, ValCount,
 )
 from pilosa_tpu.ops.bitset import SHARD_WIDTH, WORDS_PER_SHARD, \
-    transfer_nbytes
+    pick_rows, transfer_nbytes
 from pilosa_tpu.pql import Call, Condition, Query, parse_string_cached
 from pilosa_tpu.pql.ast import BETWEEN, EQ, GT, GTE, LT, LTE, NEQ
 from pilosa_tpu.utils.fingerprint import request_key
@@ -443,32 +443,6 @@ def _gb_shape(operand) -> str:
     return str(None if operand is None else operand.shape)
 
 
-def _gb_rows(w: int, *picks, fixed=()):
-    """Inside a GroupBy program: [n, S, w] whose row i is the AND of
-    arr[idx[i]][:, :w] over the `picks` (arr [N, S, W], idx int32 [n])
-    and of every [S, W] array of `fixed` — one row an iteration of a
-    loop, each operand row read in place (a dynamic slice) and the
-    result written once. Not a gather: for rows past 1 MiB XLA's TPU
-    gather first copies its WHOLE operand in two halves of the word
-    axis (2 GiB of temporaries and ~9 ms for the 2 GiB `p_brand1` bank,
-    what an eager `bank[slots]` paid; PERF.md §6 PR 33)."""
-    import jax.numpy as jnp
-    from jax import lax
-    n = picks[0][1].shape[0]
-    s = picks[0][0].shape[-2]
-
-    def body(i, out):
-        row = None
-        for arr, idx in picks:
-            r = lax.dynamic_slice(arr, (idx[i], 0, 0), (1, s, w))[0]
-            row = r if row is None else jnp.bitwise_and(row, r)
-        for f in fixed:
-            row = jnp.bitwise_and(row, f[..., :w])
-        return lax.dynamic_update_index_in_dim(out, row, i, 0)
-    return lax.fori_loop(0, n, body,
-                         jnp.zeros((n, s, w), picks[0][0].dtype))
-
-
 def _gb_prefixes(w: int, src, pi, prev, si):
     """Inside a GroupBy program: the prefixes [p, S, w] of one chunk of
     a `_Frontier`, gathered and ANDed from its operands —
@@ -484,13 +458,13 @@ def _gb_prefixes(w: int, src, pi, prev, si):
     picks = [] if prev is None else [(prev, si)]
     if not isinstance(src, tuple):      # the filter's words, or nothing
         fixed = () if src is None else (src,)
-        return _gb_rows(w, *picks, fixed=fixed) if picks \
+        return pick_rows(w, *picks, fixed=fixed) if picks \
             else src[None, :, :w]
     src = src[0] if len(src) == 1 else jnp.concatenate(src)
     if pi is not None:
-        return _gb_rows(w, (src, pi), *picks)
+        return pick_rows(w, (src, pi), *picks)
     src = src[..., :w]
-    return jnp.bitwise_and(src, _gb_rows(w, *picks)) if picks else src
+    return jnp.bitwise_and(src, pick_rows(w, *picks)) if picks else src
 
 
 @dataclass
@@ -608,6 +582,10 @@ class _Plan:
     # Time-range leaves staged by this plan, (path, view count) each:
     # counted once the staging settles (executor.range_leaves{path:}).
     range_leaves: List[Tuple[str, int]] = dc_field(default_factory=list)
+    # Operand positions of their day, month and year views: WHICH view
+    # sits there moves with the range, where every other position's
+    # bank is its view's, whatever the query (_StagedEval.own_banks).
+    ranged: set = dc_field(default_factory=set)
 
     def bank(self, key: Tuple[str, str], fresh: bool = False) -> int:
         """The operand position of `key`'s bank. `fresh` takes a new
@@ -671,6 +649,16 @@ class _StagedEval:
     # None when the tree is not lowerable — such evals keep the
     # per-signature-group vmap fusion path (executor/megakernel.py).
     ir: Any = None
+    # Positions of `bank_arrays` that two evals of one `sig` may fill
+    # with different arrays of one shape: a time range's views, a sparse
+    # bank's arrays and a row-subset bank (one a row set, of a view past
+    # BANK_MAX_BYTES). A lane of a filter group brings its own of these,
+    # `owned_banks`; every other position holds its view's bank, which
+    # the lanes of a group share as ONE operand each, `shared_banks`
+    # (the group's key says so). Both in position order.
+    own_banks: Tuple[int, ...] = ()
+    shared_banks: tuple = ()
+    owned_banks: tuple = ()
 
     def runner(self) -> Callable:
         """The traceable program body: expr + the mode's reduction."""
@@ -2063,13 +2051,22 @@ class Executor:
                 if prof is not None:
                     prof.touch_fragments(idx.name, key[0], key[1],
                                          shards)
+        own = tuple(pos for pos, b in enumerate(banks)
+                    if pos in plan.ranged or isinstance(b, SparseBank)
+                    or b.subset)
         return _StagedEval(mode=mode, sig=sig, expr=expr,
                            width=plan.width, n_shards=len(shards),
                            bank_arrays=bank_arrays,
                            idxs=list(plan.idxs), params=list(plan.params),
                            lits=lits, fp=fp, gen=gen,
                            cacheable=not plan.literals,
-                           ir=tuple(plan.ir) if plan.ir_ok else None)
+                           ir=tuple(plan.ir) if plan.ir_ok else None,
+                           own_banks=own,
+                           shared_banks=tuple(
+                               a for pos, a in enumerate(bank_arrays)
+                               if pos not in own),
+                           owned_banks=tuple(bank_arrays[pos]
+                                             for pos in own))
 
     def _capture_deps(self, idx: Index, plan: _Plan) -> None:
         """Request-tier dependency capture, STAMP-THEN-READ: the
@@ -2155,12 +2152,16 @@ class Executor:
 
     def _filter_group_fn(self, rep: "_StagedEval", lanes: int,
                          width: int) -> Tuple[Optional[Callable], bool]:
-        """jit: (a bank tuple a lane, operands [lanes, n]) -> `lanes`
-        filter rows [S, width], one array each: `rep`'s tree as ONE
-        lane body, called a lane — lane b over its own banks and row b
-        of the operands (its slots, then its u32 scalars), so a leaf's
-        read by a slot is a dynamic slice as in `tree_row` and never a
-        gather over a bank. Returns (fn, jit_hit); `lanes` = 1 asks
+        """jit: (the banks the lanes share, a tuple of its own banks a
+        lane, operands [lanes, n]) -> `lanes` filter rows [S, width],
+        one array each: `rep`'s tree as ONE lane body, called a lane —
+        lane b over the shared banks, its own (`_StagedEval.own_banks`)
+        and row b of the operands (its slots, then its u32 scalars), so
+        a leaf's read by a slot is a dynamic slice as in `tree_row` and
+        never a gather over a bank. A view's bank goes in ONCE: the
+        compiler adds up a program's operands as if no two were one
+        buffer, and eight lanes that each brought the 2 GiB grid bank
+        were 16 GiB to it. Returns (fn, jit_hit); `lanes` = 1 asks
         for nothing but the rule below (the caller runs `tree_row`).
 
         Every lane count of a signature — one, the solo program, among
@@ -2171,11 +2172,18 @@ class Executor:
         belongs to warm-up, where `retraces` says it happened."""
         import jax
         from pilosa_tpu.executor.fusion import FILTER_LANES
-        head = f"filters{{}}|W{width}|{rep.sig}"
+        # `sig` does not say which positions are a lane's own (a range
+        # fold of two days and a Union of two fields' rows may share
+        # one); the program does, so its key does.
+        head = f"filters{{}}|W{width}|O{rep.own_banks}|{rep.sig}"
         fn = self._jit_get(head.format(max(lanes, FILTER_LANES[0])))
         if fn is not None:
             return fn, True
         run, n_idx = rep.runner(), len(rep.idxs)
+        # Lengths and positions only: a program in the jit cache that
+        # closed over `rep` would keep the arrays `rep` was staged
+        # against alive for good, a 2 GiB bank version among them.
+        own, n_banks = rep.own_banks, len(rep.bank_arrays)
 
         def lane(lane_banks, row):
             # The slots go in as a list of scalars (u32: a dynamic
@@ -2195,9 +2203,13 @@ class Executor:
         # collector to walk.
         lane = jax.jit(named(lane, "tree_row_lane"))
 
-        def multi(banks, ops):
-            return tuple(lane(lane_banks, ops[b])
-                         for b, lane_banks in enumerate(banks))
+        def multi(shared, owned, ops):
+            def banks_of(mine):
+                rest, mine = iter(shared), iter(mine)
+                return [next(mine if pos in own else rest)
+                        for pos in range(n_banks)]
+            return tuple(lane(banks_of(mine), ops[b])
+                         for b, mine in enumerate(owned))
 
         if lanes > 1:
             solo, hit = self._tree_fn(rep)
@@ -2213,7 +2225,8 @@ class Executor:
             if k == lanes:
                 fn = built
             else:
-                built((rep.bank_arrays,) * k, upload(np.tile(row, (k, 1))))
+                built(rep.shared_banks, (rep.owned_banks,) * k,
+                      upload(np.tile(row, (k, 1))))
         # The caller's `dispatch` span pays for the one compile left:
         # that of the program it launches.
         if fn is None:
@@ -2507,9 +2520,13 @@ class Executor:
                 plan.range_leaves.append(("fold", n))
                 subs = [self._plan_slot_leaf(field, vn, row_id, shards, plan)
                         for vn in views]
+                plan.ranged.update(plan.bank_pos[(field.name, vn)]
+                                   for vn in views)
+                pads = len(plan.bank_keys)
                 subs += [self._plan_slot_leaf(field, views[-1], row_id,
                                               shards, plan, fresh=True)
                          for _ in range(_pow2(n) - n)]
+                plan.ranged.update(range(pads, len(plan.bank_keys)))
                 plan.sig_parts.append(f"U{len(subs)}")
                 plan.ir.append(("fold", "or", len(subs)))
                 return lambda b, i, p, l: functools.reduce(
@@ -2696,16 +2713,15 @@ class Executor:
         shards = tuple(shards)
         if rows_needed is not None:
             from pilosa_tpu.core.view import bank_capacity
-            width = view.trimmed_words()
-            # Upper bound on the full bank's row count (sum over shards,
-            # no union needed): if even the bound fits the budget, the
-            # exact full bank certainly does.
-            bound = sum(len(f.row_ids())
-                        for s in shards
-                        for f in [view.fragment(s)] if f is not None)
+            # The full bank holds the rows the view HAS: the union over
+            # the shards, which the view keeps per fragment versions (a
+            # TopN prices its bank by the same tuple). A sum over the
+            # shards would call a 77-row field on 16 shards 1,232 rows.
+            n_rows = len(view.merged_row_ids(shards))
             full_bytes = self._bank_device_bytes(
-                (bank_capacity(bound), len(shards), width))
-            if full_bytes > self.BANK_MAX_BYTES and len(rows_needed) < bound:
+                (bank_capacity(n_rows), len(shards), view.trimmed_words()))
+            if full_bytes > self.BANK_MAX_BYTES \
+                    and len(rows_needed) < n_rows:
                 return view.device_bank(shards, rows=sorted(rows_needed),
                                         mesh=self.mesh, trim=True,
                                         cache_rows=True)
@@ -3986,7 +4002,7 @@ class Executor:
             """`groupby_cnt0`: |row| of a child's rows, no prefix."""
             return _jit(
                 f"gb_cnt0:{bank.shape}:{slots.shape[0]}:{wmin}",
-                lambda b, sl: popcount(_gb_rows(wmin, (b, sl)),
+                lambda b, sl: popcount(pick_rows(wmin, (b, sl)),
                                        axis=(-2, -1))
             )(bank, slots)
 
@@ -4003,7 +4019,7 @@ class Executor:
                 pre = _gb_prefixes(wmin, src, pi, prev, si)
                 return pre, popcount(
                     jnp.bitwise_and(pre[:, None],
-                                    _gb_rows(wmin, (b, sl))[None]),
+                                    pick_rows(wmin, (b, sl))[None]),
                     axis=(-2, -1))
             return _jit(f"gb_{name}:{shapes}:{wmin}", run)(
                 *chunk, bank, slots)
@@ -4192,9 +4208,9 @@ class Executor:
                         // (n_shards * w * 4))
 
             def run(pre, pi, bank, si, plane_bank, sel):
-                planes = _gb_rows(w, (plane_bank, sel))  # [depth + 1, S, w]
+                planes = pick_rows(w, (plane_bank, sel))  # [depth + 1, S, w]
                 picks = [(bank, si)] + ([] if pre is None else [(pre, pi)])
-                mask = _gb_rows(w, *picks, fixed=(planes[-1],))
+                mask = pick_rows(w, *picks, fixed=(planes[-1],))
                 rows = [masked_row_counts_multi(
                     mask, *(planes[j] for j in range(i, min(i + k, depth))))
                     for i in range(0, depth, k)]

@@ -43,11 +43,12 @@ and the width their sweeps want form a ``_FilterGroup``, and the group
 launches ONE ``tree_row_multi`` program for up to ``FILTER_GROUP_MAX``
 of them, from ONE operand upload (the members' ``idxs`` and ``params``,
 a row a lane). The program is the representative's tree as one lane
-body, called a lane: lane ``b`` reads row ``b`` of the operands and its
-own member's banks, so a leaf's read by a slot is a dynamic slice (a
+body, called a lane: lane ``b`` reads row ``b`` of the operands, the
+view banks the group's members share (ONE operand each) and its own
+member's arrays where two members may differ (a time range's day views,
+a row-subset bank), so a leaf's read by a slot is a dynamic slice (a
 ``vmap`` would make it a gather over the bank) and members over
-different arrays of one shape, a time range's day views, still share a
-launch. Its lanes
+different days still share a launch. Its lanes
 come out as separate ``[S, W]`` arrays at the sweep's width. A group of
 one runs ``tree_row`` as a call outside a batch does. These groups
 never enter ``run_megakernel``: they are not in ``self.groups``.
@@ -330,7 +331,8 @@ class _FilterGroup(_FuseGroup):
     a tuple index on the host; alone, it is the solo program's array.
     Every member holds the bank arrays it was staged against: a later
     write in the batch, which builds new ones, changes nothing it
-    reads."""
+    reads (and members staged after it form another group: the key
+    holds the shared arrays' identity)."""
 
     __slots__ = ("width",)
 
@@ -367,7 +369,8 @@ class _FilterGroup(_FuseGroup):
             ds.set("filters", n)
             ds.set("lanes", lanes)
             self.out = ex._call_program(
-                fn, tuple(e.bank_arrays for e in rows), ops_dev)
+                fn, rep.shared_banks,
+                tuple(e.owned_banks for e in rows), ops_dev)
         self.batched = True
         ex._note_filter_launch(lanes, n)
         # The one upload (pad lanes included) spread over the members.
@@ -509,12 +512,17 @@ class FusionCollector:
                    width: int) -> FusedEval:
         """Stage one TopN's filter tree, whose words its sweep wants
         `width` wide; returns its lane, which `add_sweep` takes.
-        Grouping is by (sig, width): the signature equates the tree
-        and every operand's shape, and each lane reads its own
-        member's bank arrays, so members over different arrays of one
-        shape share a launch. A full group launches here, and with it
-        the full sweep groups that waited for it."""
-        key = (staged.sig, width)
+        Grouping is by (sig, width, which positions are a lane's own,
+        the shared banks' identity): the signature equates the tree and
+        every operand's shape, the members read the same array wherever
+        a position holds a view's bank, and each lane brings its own
+        where it may not (a time range's views, a sparse bank, a
+        row-subset bank: `_StagedEval.own_banks`), so members over
+        different days or row sets share a launch. A full group
+        launches here, and with it the full sweep groups that waited
+        for it."""
+        key = (staged.sig, width, staged.own_banks,
+               tuple(id(a) for a in staged.shared_banks))
         group = self.filters.get(key)
         if group is None:
             group = self.filters[key] = _FilterGroup(self.executor, width)

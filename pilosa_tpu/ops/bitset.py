@@ -100,6 +100,31 @@ def intersect_many(stack: jax.Array, axis: int = 0) -> jax.Array:
     )
 
 
+def pick_rows(w: int, *picks, fixed=()) -> jax.Array:
+    """Inside a program: [n, S, w] whose row i is the AND of
+    arr[idx[i]][:, :w] over the `picks` (arr [N, S, W], idx int32 [n])
+    and of every [S, W] array of `fixed` — one row an iteration of a
+    loop, each operand row read in place (a dynamic slice) and the
+    result written once. Not a gather: for rows past 1 MiB XLA's TPU
+    gather first copies its WHOLE operand in two halves of the word
+    axis (2 GiB of temporaries and ~9 ms for the 2 GiB `p_brand1` bank,
+    what an eager `bank[slots]` paid; PERF.md §6 PR 33), and a `vmap`
+    of a dynamic slice lowers to a `stablehlo.gather` too."""
+    n = picks[0][1].shape[0]
+    s = picks[0][0].shape[-2]
+
+    def body(i, out):
+        row = None
+        for arr, idx in picks:
+            r = jax.lax.dynamic_slice(arr, (idx[i], 0, 0), (1, s, w))[0]
+            row = r if row is None else jnp.bitwise_and(row, r)
+        for f in fixed:
+            row = jnp.bitwise_and(row, f[..., :w])
+        return jax.lax.dynamic_update_index_in_dim(out, row, i, 0)
+    return jax.lax.fori_loop(0, n, body,
+                             jnp.zeros((n, s, w), picks[0][0].dtype))
+
+
 # ---------------------------------------------------------------------------
 # Counting. popcount reduces the word axis; XLA fuses it into whatever
 # elementwise op produced the words.

@@ -1202,7 +1202,7 @@ def build_program(n_shards: int, w_mega: int, t_pad: int,
     import jax
     import jax.numpy as jnp
 
-    from pilosa_tpu.ops.bitset import popcount
+    from pilosa_tpu.ops.bitset import pick_rows, popcount
 
     def _fit(rows: Any) -> Any:
         """Slice or zero-pad the word axis to the launch width — the
@@ -1219,7 +1219,12 @@ def build_program(n_shards: int, w_mega: int, t_pad: int,
             instrs: Any, out_count: Any, out_row: Any,
             xbanks: Tuple[Any, ...] = (),
             xslots: Tuple[Any, ...] = ()) -> Tuple[Any, Any]:
-        parts = [_fit(bank[sl]) for bank, sl in zip(banks, slots)]
+        # A row a slot, each read in place (`pick_rows`): `bank[sl]` is
+        # a gather, which copies the WHOLE bank first once a row is
+        # past 1 MiB, and a leaf reads the view's full bank whenever it
+        # fits (2 GiB for a grid bank). verify_plan bounds every slot.
+        parts = [_fit(pick_rows(min(bank.shape[-1], w_mega), (bank, sl)))
+                 for bank, sl in zip(banks, slots)]
         # Expand registers: each sparse bank's referenced rows
         # scatter-expand to dense [S, w_mega] rows (one vmapped
         # expansion per bank), stacked into the slab right after the

@@ -140,7 +140,7 @@ def launches(monkeypatch, spoil_pads=False, fail=()):
     def stub(self, fn, *args):
         name = getattr(fn, "__name__", "")
         if name == "tree_row_multi":
-            calls.append((name, len(args[0])))
+            calls.append((name, len(args[1])))     # its own banks a lane
         elif name.startswith(("tree_", "topn_sweep", "range_fold",
                               "popcount_row", "groupby")):
             calls.append((name, max(1, len(args) - 1)
@@ -149,11 +149,11 @@ def launches(monkeypatch, spoil_pads=False, fail=()):
             raise RuntimeError(f"launch of {name} failed")
         out = orig(self, fn, *args)
         if spoil_pads and name == "tree_row_multi":
-            ops = np.asarray(args[1])
+            ops = np.asarray(args[2])
             out = tuple(
                 jnp.full_like(o, 0xFFFFFFFF)
                 if k and (ops[k] == ops[k - 1]).all()
-                and all(a is b for a, b in zip(args[0][k], args[0][k - 1]))
+                and all(a is b for a, b in zip(args[1][k], args[1][k - 1]))
                 else o for k, o in enumerate(out))
         return out
 
@@ -366,6 +366,88 @@ def test_a_write_between_two_topns_is_read_by_the_later_one(
                       f"Clear({col}, pickup_elapsed_time_of_day=8)")
 
 
+# (e2) a group's lanes share their views' banks as ONE operand each
+# (PR 39); where two members may hold other arrays of one shape — the
+# row-subset banks of a view past BANK_MAX_BYTES, one a row set — a lane
+# brings its own, as it brings a time range's views, and the members
+# still share a launch.
+def test_members_over_row_subset_banks_share_a_launch(
+        ex, direct, monkeypatch):
+    queries = [FAMILIES["topn_tod"](i) for i in range(3)]
+    queries.append(queries[0])          # the same row: the same bank
+    want = [direct(q) for q in queries]
+    view = ex.holder.index("taxi").field(
+        "pickup_elapsed_time_of_day").view()
+    view._bank_cache.clear()
+    monkeypatch.setattr(Executor, "BANK_MAX_BYTES", 4096)
+    calls = launches(monkeypatch)
+    try:
+        assert _answers(ex.execute_batch(_batch(queries))) == want
+        assert sorted(k[3] for k in view._bank_cache if len(k) == 4) \
+            == [(5,), (8,), (11,)]
+        assert _filters(calls) == [("tree_row_multi", 4)]
+    finally:
+        view._bank_cache.clear()
+
+
+# (e2') which positions are a lane's own is the program's, so its key's:
+# a range fold of two days and a Union of two fields' rows have ONE
+# signature where the banks' shapes agree, and the fold's lanes bring
+# both banks where the Union's bring none.
+_SAME_SIG = {
+    "fold": lambda i: (
+        f"TopN(pickup_grid_id, Row(pickup={i % 3}, from='{_iso(i)}', "
+        f"to='{_iso(i + 2)}'), n=10)"),
+    "union": lambda i: (
+        f"TopN(pickup_grid_id, Union(Row(cab_type={i % 3}), "
+        f"Row(pickup={(i + 1) % 3})), n=10)"),
+}
+
+
+@pytest.mark.parametrize("order", [("fold", "union"), ("union", "fold")])
+def test_one_signature_with_other_own_banks_is_another_program(
+        ex, direct, monkeypatch, order):
+    staged = []
+
+    class Keep(FusionCollector):
+        def add_filter(self, st, prof, plan_s, width):
+            staged.append(st)
+            return super().add_filter(st, prof, plan_s, width)
+
+    monkeypatch.setattr(ex_mod_fusion, "FusionCollector", Keep)
+    batches = [[_SAME_SIG[kind](i) for i in range(2)] for kind in order]
+    want = [[direct(q) for q in queries] for queries in batches]
+    calls = launches(monkeypatch)
+    for queries, answers in zip(batches, want):
+        assert _answers(ex.execute_batch(_batch(queries))) == answers
+    assert len({st.sig for st in staged}) == 1
+    assert sorted({st.own_banks for st in staged}) == [(), (0, 1)]
+    assert _filters(calls) == [("tree_row_multi", 2)] * 2
+
+
+# (e3) the compiled group programs outlive their first members; the
+# banks those were staged against must not (PR 39: a program that closed
+# over its representative kept a 2 GiB bank version alive for good).
+def test_a_group_program_keeps_no_bank_of_its_first_members(ex):
+    import gc
+    import weakref
+    queries = [FAMILIES["topn_tod"](i) for i in range(2)]
+    view = ex.holder.index("taxi").field(
+        "pickup_elapsed_time_of_day").view()
+    col = SHARD_WIDTH + 2999
+    try:
+        _answers(ex.execute_batch(_batch(queries)))
+        (bank,) = view._bank_cache.values()
+        old = weakref.ref(bank.array)
+        del bank
+        ex.execute("taxi", f"Set({col}, pickup_elapsed_time_of_day=5)")
+        _answers(ex.execute_batch(_batch(queries)))   # the patched bank
+        gc.collect()
+        assert old() is None
+    finally:
+        ex.execute("taxi", f"Clear({col}, pickup_elapsed_time_of_day=5)")
+
+
 # (f) a failed launch is its members' alone.
 def test_a_failed_filter_group_fails_its_members_only(ex, direct,
                                                       monkeypatch):
@@ -522,9 +604,18 @@ def test_the_group_program_reads_no_bank_through_a_gather(ex, monkeypatch,
         banks = (rep.bank_arrays,) * lanes
         ops = jnp.zeros((lanes, len(rep.idxs) + len(rep.params)),
                         jnp.uint32)
-        text = fn.lower(banks, ops).as_text()
+        lowered = fn.lower(rep.shared_banks,
+                           (rep.owned_banks,) * lanes, ops)
+        text = lowered.as_text()
         assert "stablehlo.dynamic_slice" in text
         assert _bank_gathers(text, banks) == []
+        # A view's bank is ONE operand whatever the lanes; a lane
+        # brings only its own range's views (PR 39: the compiler adds
+        # up operands as if no two were one buffer).
+        n_own = len(rep.own_banks)
+        assert (n_own > 0) == (family == "topn_pickup_range")
+        assert len(jax.tree_util.tree_leaves(lowered.in_avals)) == (
+            len(rep.bank_arrays) - n_own) + lanes * n_own + 1
     vmapped = jax.jit(jax.vmap(rep.runner(), in_axes=(None, 0, 0, None)))
     text = vmapped.lower(
         rep.bank_arrays, jnp.zeros((2, len(rep.idxs)), jnp.int32),

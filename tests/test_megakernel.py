@@ -267,6 +267,28 @@ def test_shared_operand_rows_share_one_slab_register(ex):
     assert sorted(plan.slots[0].tolist()) == [3, 5, 6, 7]
 
 
+@pytest.mark.parametrize("width", [64, 48, 96])
+def test_the_slab_reads_no_bank_through_a_gather(width):
+    """Lowered (StableHLO) text, platform-independent: the slab's rows
+    are read a slot a dynamic slice (`ops/bitset.pick_rows`), a bank
+    narrower or wider than the launch among them — `bank[slots]` is a
+    gather, which XLA's TPU backend serves from a copy of the WHOLE
+    bank once a row is past 1 MiB, and a leaf's bank may be 2 GiB."""
+    import jax
+    import jax.numpy as jnp
+    from pilosa_tpu.ops import megakernel as mk
+    bank = jnp.zeros((16, 2, width), jnp.uint32)   # the slab is [8, ...]
+    args = ((bank,), (jnp.zeros(4, jnp.int32),), jnp.zeros(8, jnp.int32),
+            jnp.zeros((4, 4), jnp.int32), jnp.zeros(2, jnp.int32),
+            jnp.zeros(2, jnp.int32))
+    text = jax.jit(mk.build_program(2, 64, 8)).lower(*args).as_text()
+    assert "stablehlo.dynamic_slice" in text
+    assert f"tensor<16x2x{width}xui32>" in text
+    for line in text.splitlines():
+        if "stablehlo.gather" in line:
+            assert f"(tensor<16x2x{width}xui32>" not in line, line[:200]
+
+
 def test_error_isolation_beside_megakernel(ex, monkeypatch):
     calls = count_dispatches(monkeypatch)
     out = ex.execute_batch([

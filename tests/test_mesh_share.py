@@ -186,26 +186,29 @@ def test_a_share_over_the_limit_still_streams_and_answers_right(
 def test_row_leaves_price_the_bank_by_the_share_too(holder, mesh4,
                                                     monkeypatch):
     """`_get_bank_for` builds a row subset only when a device's share of
-    the full bank is over BANK_MAX_BYTES. It prices the bank by a bound
-    on its rows (the sum over shards: 8 x 40 -> 512 slots, 8 x WHOLE)."""
+    the full bank is over BANK_MAX_BYTES. It prices the bank by the rows
+    the view has (the union over shards: 8 shards x the same 40 rows ->
+    64 slots, WHOLE; the sum over shards would say 512 slots)."""
     h, rows = holder
     want = np.intersect1d(rows["f"][1], rows["f"][2]).size
     pql = "Count(Intersect(Row(f=1), Row(f=2)))"
     view = h.index("i").field("f").view()
-    bound_share = 8 * WHOLE // 4
+    assert len(view.merged_row_ids(range(N_SHARDS))) == N_ROWS
+    assert sum(len(view.fragment(s).row_ids())
+               for s in range(N_SHARDS)) > 4 * N_ROWS
 
     def subset_keys():
         return [k for k in view._bank_cache if len(k) == 4]
 
     view._bank_cache.clear()
-    monkeypatch.setattr(Executor, "BANK_MAX_BYTES", bound_share)
+    monkeypatch.setattr(Executor, "BANK_MAX_BYTES", SHARE)
     assert Executor(h, mesh=mesh4).execute("i", pql) == [want]
     assert subset_keys() == [] and len(view._bank_cache) == 1
     # One device would have taken the row subset at that limit.
     assert Executor(h).execute("i", pql) == [want]
     assert [k[3] for k in subset_keys()] == [(1, 2)]
     view._bank_cache.clear()
-    monkeypatch.setattr(Executor, "BANK_MAX_BYTES", bound_share - 1)
+    monkeypatch.setattr(Executor, "BANK_MAX_BYTES", SHARE - 1)
     assert Executor(h, mesh=mesh4).execute("i", pql) == [want]
     assert [k[3] for k in subset_keys()] == [(1, 2)]
 

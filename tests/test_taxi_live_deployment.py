@@ -458,6 +458,65 @@ def test_the_write_paths_spans_and_counters_move(served):
                             for c in launches)
 
 
+@pytest.mark.parametrize("field, fresh", [("total_amount_dollars", 5),
+                                          ("drop_grid_id", 6)])
+def test_a_leaf_over_a_written_view_uploads_no_bank(served, monkeypatch,
+                                                    field, fresh):
+    """PR 39: a `report_miles_dollars` report after a `Set` to
+    `total_amount_dollars`, and a ride's read-back after the `Set` of
+    its `drop_grid_id`, at a limit the view's full bank just fits by
+    the rows it has (the sum over the shards is over it): the leaf
+    reads that bank, patched — no `plan.bank_upload` span, no row-subset
+    bank built anew — and the answer is the reference's."""
+    from pilosa_tpu.core.view import bank_capacity
+    from pilosa_tpu.executor import Executor
+    from pilosa_tpu.utils.timeline import TIMELINE
+    s, r = served, served.rides
+    col = _fresh_ride(s, fresh)
+    view = s.holder.index(INDEX).field(field).view()
+    shards = tuple(range(CONFIG["shards"]))
+    n_rows = len(view.merged_row_ids(shards))
+    by_sum = sum(len(view.fragment(sh).row_ids()) for sh in shards)
+    assert bank_capacity(by_sum) > bank_capacity(n_rows)
+    monkeypatch.setattr(
+        Executor, "BANK_MAX_BYTES",
+        bank_capacity(n_rows) * len(shards) * view.trimmed_words() * 4)
+    if field == "total_amount_dollars":
+        # Five draws: whichever rows they name, the one bank they all
+        # read is stale after the ride's Set and is patched once.
+        d = s.draws(39)
+        asked = [taxi_live.query(r, "report_miles_dollars", d, span=7)
+                 for _ in range(5)]
+        asked = [(pql, ref()) for pql, ref in asked]
+        others = []
+    else:
+        pql, want = taxi_live.readback(r, col, "topn")
+        asked = [(pql, want)]
+        others = [f for f in taxi_live.RIDE_FIELDS if f != field]
+    s.insert(col, others)
+    for pql, _ in asked:                        # every bank is built
+        s.srv.query(INDEX, pql)
+    time.sleep(0.3)                 # ... and those records have finished
+    before = s.counters()
+    seen = {rec.trace_id for rec in TIMELINE.requests(last=256)}
+    s.insert(col, [field])
+    for pql, want in asked:
+        got = s.srv.query(INDEX, pql)
+        assert taxi_live.equal(got, want), (pql, got, want)
+    after = s.counters(lambda c: c["executor.bank_patches"]
+                       > before["executor.bank_patches"])
+    moved = {k: after[k] - before[k] for k in (
+        "executor.bank_patches", "executor.bank_subset_rebuilds",
+        "executor.bank_upload_bytes")}
+    assert moved == {"executor.bank_patches": 1,
+                     "executor.bank_subset_rebuilds": 0,
+                     "executor.bank_upload_bytes": 0}
+    spans = [sp.name for rec in TIMELINE.requests(last=256)
+             if rec.trace_id not in seen for sp in rec.root.walk()]
+    assert "plan.bank_patch" in spans and "plan.bank_upload" not in spans
+    assert [k for k in view._bank_cache if len(k) == 4] == []
+
+
 def test_a_flush_that_holds_a_set_takes_the_batch_path(served):
     """Eight clients at once, one of them a Set: the flush that holds
     it barriers and runs whole on the dispatcher (`thread.batch`)."""
