@@ -17,7 +17,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 P = int(os.environ.get("PILOSA_PROBE_POSITIONS", 384 << 20))
 R = int(os.environ.get("PILOSA_PROBE_ROWS", 8 << 20))
-Q = 64  # padded sparse-filter slots
+# Padded sparse-filter slots: the compare's fan-out is this wide
+# whatever the query's own on-bits (the served kernel pads to
+# PILOSA_TPU_PBANK_SPARSE_BITS).
+Q = int(os.environ.get("PILOSA_PROBE_QSLOTS", 64))
+ONLY = [v for v in os.environ.get("PILOSA_PROBE_ONLY", "").split(",") if v]
 
 
 def main():
@@ -87,8 +91,11 @@ def main():
         ("compare_only", k_compare, (pos, qpad)),
         ("compare_rowsum_full", k_compare_rowsum, (pos, qpad, starts)),
     ]:
+        if ONLY and name not in ONLY:
+            continue
         t, out = timed(f, *args)
-        print(f"{name}: {t*1000:.1f} ms  ({P/t/1e9:.2f} Gpos/s) out={out}",
+        print(f"{name}: {t*1000:.1f} ms  ({P/t/1e9:.2f} Gpos/s) "
+              f"positions={P} qslots={Q} out={out}",
               flush=True)
 
 

@@ -427,12 +427,14 @@ class SparseBank:
 # i32-indexed (x64 stays off), so segment position counts must stay
 # well under 2^31; the build enforces the cap EXACTLY by splitting
 # gather chunks on row boundaries (a row contributes at most 2^16
-# positions, so no single row can break it). 2^27 keeps each segment
-# program's workspace a few hundred MB so several can queue beside a
-# ~10 GB resident bank without exhausting HBM (2^29 segments put
-# multi-GB transients next to the bank and OOMed the 100M run); the
-# extra dispatches are cheap — results fetch as one batched
-# device_get. The host gather chunk bounds the build's temporaries.
+# positions, so no single row can break it). At 2^27 a flat segment's
+# program compiles, for a v5e, to 2.15 GB of temporaries (PERF.md §6,
+# PR 40; "a few hundred MB" by this comment's first reckoning), so a
+# wave of four queues 8.6 GB beside the resident bank (2^29 segments
+# put multi-GB transients next to a ~10 GB bank and OOMed a 100M-row
+# run on an earlier machine); the extra dispatches are cheap — results
+# fetch as one batched device_get. The host gather chunk bounds the
+# build's temporaries.
 PBANK_SEGMENT_POSITIONS = int(os.environ.get(
     "PILOSA_TPU_PBANK_SEGMENT", 1 << 27))
 PBANK_GATHER_ROWS = 1 << 20
@@ -826,7 +828,16 @@ class View:
                       (np.empty(0, np.uint16), np.empty(0, np.int64),
                        np.empty(0, np.int64)))
                 if sp is not None:
-                    array = _expand_sparse_chunk(*sp, cap, width)
+                    # A bank built is a bank built, whichever way its
+                    # bits travel: the span and the counter of a dense
+                    # build (`bytes`: the array the device then holds).
+                    nbytes = cap * width * 4
+                    with TIMELINE.stage(
+                            "plan.bank_upload", bytes=nbytes, devices=1,
+                            blocks=1, form="positions",
+                            counts=(("executor.bank_upload_bytes",
+                                     nbytes),)):
+                        array = _expand_sparse_chunk(*sp, cap, width)
                     slots = {r: i for i, r in enumerate(row_set)}
             if array is None:
                 def gather(positions):
@@ -962,7 +973,8 @@ class View:
                 # build once (per version), so compile reuse matters
                 # little, and pow2 padding nearly doubled a ~10 GiB
                 # bank — pushing it over the HBM budget and into
-                # rebuild-per-query thrash (caught by the 100M run).
+                # rebuild-per-query thrash (caught by a 100M-row run on
+                # an earlier machine; on the v5e: PERF.md §4, chem-lib-chip).
                 padded = max(1 << 20, -(-p // (1 << 20)) * (1 << 20))
                 buf = np.full(padded, 0xFFFF, np.uint16)  # OOB pad
                 buf[:p] = pos16
@@ -1076,31 +1088,47 @@ class View:
                 return cached
             if frag is None:
                 return None
-        row_ids = frag.row_ids()  # sorted immutable tuple (contract)
-        built = None
-        # graftlint: disable=GL015 — deliberate lock-free rebuild: the
-        # bank is stamped with the versions read under the first
-        # acquisition, so a write landing during the build makes the
-        # stamp stale and the next probe rebuilds (write-back is
-        # last-writer-wins; a stale bank is never SERVED, only stored).
-        if isinstance(cached, PositionsBank) \
-                and cached.row_ids == row_ids:
-            # graftlint: disable=GL015 — same version-stamp argument.
-            built = self._patch_pbank(cached, frag, width)
-        if built is None:
-            # graftlint: disable=GL015 — same version-stamp argument.
-            built = self._build_pbank_segments(frag, row_ids, width, 0)
-        if built is None:
-            return None
-        segments, nbytes = built
+        # The build is a stage of the request that met the stale or
+        # absent bank (the first TopN after every start, and after a
+        # write): a host gather of every row, or of the written
+        # segments' rows, and their uploads.
+        with TIMELINE.stage("plan.pbank_build") as sp:
+            row_ids = frag.row_ids()  # sorted immutable tuple (contract)
+            built = None
+            kind = "patch"
+            # graftlint: disable=GL015 — deliberate lock-free rebuild: the
+            # bank is stamped with the versions read under the first
+            # acquisition, so a write landing during the build makes the
+            # stamp stale and the next probe rebuilds (write-back is
+            # last-writer-wins; a stale bank is never SERVED, only stored).
+            if isinstance(cached, PositionsBank) \
+                    and cached.row_ids == row_ids:
+                # graftlint: disable=GL015 — same version-stamp argument.
+                built = self._patch_pbank(cached, frag, width)
+            if built is None:
+                kind = "full"
+                # graftlint: disable=GL015 — same version-stamp argument.
+                built = self._build_pbank_segments(frag, row_ids, width, 0)
+            if built is None:
+                sp.set("kind", "none")  # too dense: the caller streams
+                return None
+            segments, nbytes = built
+            # Ideal (pad-free) footprint: 2 B per real position + one i32
+            # aux word per row (+1); the rest is pow2 / fixed-width / row
+            # padding — the number the padding gauge exists to surface.
+            ideal = sum(p * 2 + (n + 1) * 4 for _, n, _, _, p in segments)
+            for attr, value in (
+                    ("kind", kind), ("rows", len(row_ids)),
+                    ("positions", sum(s[4] for s in segments)),
+                    ("segments", len(segments)), ("bytes", nbytes),
+                    ("pad_bytes", max(0, nbytes - ideal))):
+                sp.set(attr, value)
+            TIMELINE.count("executor.pbank_builds")
+            TIMELINE.count(f"executor.pbank_builds{{kind:{kind}}}")
         bank = PositionsBank(segments, row_ids, versions, nbytes)
         with self._lock:
             self._bank_cache[key] = bank
         BANK_BUDGET.admit(self, key, nbytes=nbytes)
-        # Ideal (pad-free) footprint: 2 B per real position + one i32
-        # aux word per row (+1); the rest is pow2 / fixed-width / row
-        # padding — the number the padding gauge exists to surface.
-        ideal = sum(p * 2 + (n + 1) * 4 for _, n, _, _, p in segments)
         LEDGER.register(
             "pbank", key, nbytes,
             padded_bytes=max(0, nbytes - ideal), owner=self,

@@ -133,18 +133,27 @@ PBANK_ENABLED = os.environ.get("PILOSA_TPU_PBANK", "1") != "0"
 
 # Filters with at most this many set bits take the positions-bank
 # kernel's gather-free compare path (see _pbank_kernel.bits_compare);
-# denser filters use the table gather. 64 covers every fingerprint
-# query (48 draws) with headroom; raising it grows the [P, QCAP]
-# compare fan-out linearly.
+# denser filters use the table gather. 128 covers every fingerprint
+# query: a molecule has ~48 +- 12 on-bits and up to 128, and at the
+# 64 this was, one query molecule in twelve took the gather form —
+# on the v5e 9.85 ns a position against the compare's 0.09
+# (benches/pbank_kernel_probe.py, pbank_membership_probe.py): ~4 s an
+# answer among answers of ~0.45 s, and a window's count swung by a
+# third with how many it drew. At 128 every answer costs 0.72 s and
+# the count repeats (PERF.md section 6, PR 40). Raising it grows the
+# [P, QCAP] compare fan-out, and its cost faster than that: 316.6 ms
+# at 128 slots against 27.7 at 64 over 403 M positions
+# (PILOSA_PROBE_QSLOTS=128 benches/pbank_kernel_probe.py).
 PBANK_SPARSE_FILTER_BITS = int(os.environ.get(
-    "PILOSA_TPU_PBANK_SPARSE_BITS", 64))
+    "PILOSA_TPU_PBANK_SPARSE_BITS", 128))
 
 # Membership form for the sparse-filter pbank kernel: "compare" (the
 # [P] x [QCAP] equality fan-out), "search" (binary search in the
 # sorted filter positions, log2(QCAP) compare-select rounds), or
 # "auto" (default): search on the XLA CPU backend, where it compiles
-# and runs faster, compare on devices until
-# benches/pbank_membership_probe.py is run on the chip (not measured).
+# and runs faster, compare on devices: on the v5e the compare reads
+# 0.090 ns a position and the search 0.90 at 48 query bits
+# (benches/pbank_membership_probe.py; PERF.md §7 row 27).
 # Selection is a compile key, resolved per backend at kernel build.
 PBANK_MEMBERSHIP = os.environ.get("PILOSA_TPU_PBANK_MEMBERSHIP", "auto")
 if PBANK_MEMBERSHIP not in ("auto", "compare", "search"):
@@ -153,11 +162,12 @@ if PBANK_MEMBERSHIP not in ("auto", "compare", "search"):
         "must be 'auto', 'compare', or 'search'")
 
 # Max positions-bank segment programs enqueued before a sync (see
-# _topn_positions): bounds how many programs' workspaces (~2x segment
-# positions x 4 B at the 2^27 default segment size, i.e. ~1.1 GB each)
-# can coexist in HBM beside a resident bank that may itself be ~10 GB.
-# Each wave sync costs one blocking fetch, so the cap trades fetch
-# latency against OOM headroom; 4 keeps 100M-row queries ~4.4 GB of transients.
+# _topn_positions): bounds how many programs' workspaces can coexist in
+# HBM beside the resident bank. A flat segment of the 2^27 default
+# compiles, for a v5e, to 2.15 GB of temporaries (the [P] membership
+# words, the cumsum and its shifted copy), so 4 hold 8.6 GB. Each wave
+# sync is one blocking wait (span `pbank.wave_wait`), so the cap trades
+# that wait against OOM headroom.
 PBANK_INFLIGHT_SEGMENTS = int(os.environ.get(
     "PILOSA_TPU_PBANK_INFLIGHT", 4))
 
@@ -2922,6 +2932,13 @@ class Executor:
         with self._dispatch_span("popcount_row"):
             return self._call_program(fn, words)
 
+    def _tanimoto_source(self, filter_words):
+        """A tanimoto call's |filter row|, dispatched and left on the
+        device; counts the call (`executor.tanimoto_sweeps`)."""
+        if self.stats is not None:
+            self.stats.count("executor.tanimoto_sweeps", 1)
+        return self._popcount_row(filter_words)
+
     def _execute_topn(self, idx: Index, call: Call, shards) -> PairsResult:
         """Exact TopN (reference executeTopN 2-phase approximation,
         executor.go:694-733, fragment.top :1067). On TPU exact per-row
@@ -3127,33 +3144,21 @@ class Executor:
             if call.children and filter_words is None:
                 filter_words = self._run_staged(*staged_filter)
             if PBANK_ENABLED and self.mesh is None and len(shards) == 1 \
-                    and allowed_rows is None and not ids_arg and n \
-                    and selfcheck_pairs is None:
+                    and allowed_rows is None and not ids_arg \
+                    and (n or similar) and selfcheck_pairs is None:
                 # Positions-resident fast path: the whole view's sorted
                 # positions live on device; no streaming, no expansion.
+                # A tanimoto call without `n` (the source's own query:
+                # every molecule past the threshold) is answered here
+                # too — its survivors are counted on the device.
                 pb = view.positions_bank(shards[0], width)
                 if pb is not None:
-                    src_pb = None
-                    if similar:
-                        src_pb = self._popcount_row(filter_words)
-                    # Slice the filter row to the BANK's width: a plan
-                    # can be wider than the bank (Not() rides the
-                    # existence view, Shift(), a wider sibling field),
-                    # and a set bit at word 2047 would otherwise match
-                    # the fixed layout's 0xFFFF row pads — the gather's
-                    # OOB-fill and the compare's qtop extraction both
-                    # become pad-safe once fw stops at the bank width
-                    # (real positions are < width*32 <= 65503, so no
-                    # real count changes; the tanimoto denominator
-                    # src_pb deliberately keeps the FULL row's popcount,
-                    # matching the dense path's semantics).
-                    fw_b = None
-                    if filter_words is not None:
-                        fw_b = [filter_words[0][:width]]
+                    src_pb = self._tanimoto_source(filter_words) \
+                        if similar else None
                     self._note_topn("positions")
                     return self._topn_positions(
-                        pb, fw_b, n, tanimoto if similar else 0,
-                        min_threshold, src_pb)
+                        pb, filter_words, width, n,
+                        tanimoto if similar else 0, min_threshold, src_pb)
             # Huge row sets stream through transient chunk banks to bound
             # HBM (the 50k-row ranked-cache shape). Chunks are uploaded
             # lazily in finalize with one-chunk lookahead — dispatching
@@ -3162,11 +3167,7 @@ class Executor:
             self._note_topn("streamed")
             chunked = [all_rows[c0:c0 + TOPN_CHUNK_ROWS]
                        for c0 in range(0, len(all_rows), TOPN_CHUNK_ROWS)]
-        src_dev = None
-        if similar:
-            src_dev = self._popcount_row(filter_words)
-            if self.stats is not None:
-                self.stats.count("executor.tanimoto_sweeps", 1)
+        src_dev = self._tanimoto_source(filter_words) if similar else None
 
         # Chunk banks are admitted to the BANK_BUDGET HBM LRU only when
         # the WHOLE stream fits in half the budget: a repeat query over
@@ -3289,7 +3290,8 @@ class Executor:
 
     @classmethod
     def _pbank_kernel(cls, k: int, has_filter: bool,
-                      fixed: bool = False):
+                      fixed: bool = False, width: Optional[int] = None,
+                      survivors: bool = False):
         """Jitted per-segment TopN over a PositionsBank: |row ∧ filter|
         = Σ_{p ∈ row} filter_bit[p]. Two layouts (view.py flush):
 
@@ -3304,7 +3306,17 @@ class Executor:
         positions. Unfiltered TopN skips even that — counts are the
         start diffs / lens. Tanimoto/threshold ride as traced params;
         lax.top_k breaks ties by lower index, which IS the (-count,
-        row) order because rows are stored ascending."""
+        row) order because rows are stored ascending.
+
+        The program takes what the call already holds on the device —
+        `kernel(fw, pos, aux, params, src)`: the filter's words as the
+        tree program wrote them (`[1, W]`, cut to the bank's `width`
+        words inside), the segment's two arrays, the uploaded
+        `[threshold, tanimoto]` and, for a tanimoto call, the
+        filter row's own popcount as `_popcount_row` left it — so a
+        launch is this program and no eager helper beside it.
+        `survivors` adds a third output, the number of rows the rule
+        keeps: what a call without `n` sizes its top_k against."""
         import jax
         import jax.numpy as jnp
 
@@ -3313,7 +3325,7 @@ class Executor:
             membership = ("search"
                           if jax.devices()[0].platform == "cpu"
                           else "compare")
-        key = (k, has_filter, fixed, membership)
+        key = (k, has_filter, fixed, membership, width, survivors)
         fn = cls._PBANK_KERNELS.get(key)
         if fn is not None:
             return fn
@@ -3331,9 +3343,12 @@ class Executor:
             # and an element-wise [P] x [QCAP] compare-reduce against
             # its extracted set positions is VPU-shaped where the
             # P-sized dynamic gather is not (the two-stage top-k
-            # variant showed no gain, so top_k stays flat; neither is
-            # measured on this round's machine). Extraction: enumerate
-            # the filter's 32*W bit
+            # variant showed no gain on an earlier machine, so top_k
+            # stays flat; on the v5e the compare is 27 ms of a
+            # segment-sized pass's 388 at 384 M positions, the cumsum
+            # and the row-start gathers the rest, and the gather form
+            # 3.8 s: benches/pbank_kernel_probe.py, PERF.md §7 row
+            # 27). Extraction: enumerate the filter's 32*W bit
             # positions, keep set ones, take the QCAP smallest (pad
             # 2^30 sorts last; a real position is < 2^16).
             w = jnp.arange(fw.shape[0], dtype=jnp.int32)
@@ -3363,10 +3378,26 @@ class Executor:
             # trailing broadcast axis makes membership layout-agnostic.
             return (pos[..., None].astype(jnp.int32) == qtop).any(-1)
 
-        def kernel(fw, pos, aux, params):
+        def kernel(fw, pos, aux, params, src=None):
             # aux: starts [R+1] (flat) | lens [R] (fixed)
             raw = aux if fixed else aux[1:] - aux[:-1]
             if has_filter:
+                if fw.ndim == 2:
+                    fw = fw[0]      # the one shard's row of [1, W]
+                if width is not None:
+                    # Cut the filter row to the BANK's width: a plan
+                    # can be wider than the bank (Not() rides the
+                    # existence view, Shift(), a wider sibling field),
+                    # and a set bit at word 2047 would otherwise match
+                    # the fixed layout's 0xFFFF row pads — the gather's
+                    # OOB-fill and the compare's qtop extraction both
+                    # become pad-safe once fw stops at the bank width
+                    # (real positions are < width*32 <= 65503, so no
+                    # real count changes; the tanimoto denominator
+                    # `src` deliberately keeps the FULL row's popcount,
+                    # matching the dense path's semantics).
+                    fw = fw[:width]
+
                 def c_from(bits):
                     # Reduce to per-row counts INSIDE the cond branch:
                     # the branch output is then [R] i32 instead of a
@@ -3393,87 +3424,135 @@ class Executor:
                     lambda: c_from(bits_gather(fw, pos)))
             else:
                 c = raw
-            thresh, tani, src = (params[0].astype(jnp.int32),
-                                 params[1].astype(jnp.int32),
-                                 params[2].astype(jnp.int32))
+            thresh, tani = (params[0].astype(jnp.int32),
+                            params[1].astype(jnp.int32))
+            # Without a tanimoto rule there is no source count, and
+            # `tani` = 0 keeps `denom` out of the answer.
+            src = jnp.int32(0) if src is None else src.astype(jnp.int32)
             keep = c >= jnp.maximum(1, thresh)
             denom = raw + src - c
             keep &= jnp.where(tani > 0,
                               c * 100 > tani * denom,
                               True)
             score = jnp.where(keep, c, -1)
-            return jax.lax.top_k(score, k)
+            top = jax.lax.top_k(score, k)
+            if survivors:
+                return (*top, keep.sum(dtype=jnp.int32))
+            return top
 
         # graftlint: disable=GL006 — class-level kernel cache (benches
         # monkeypatch _pbank_kernel as a classmethod, so no instance is
         # available to note compiles on); keys are (k, filter, layout,
-        # membership) — a bounded, shape-stable set per deployment.
+        # membership, width, survivors) — a bounded, shape-stable set
+        # per deployment (a call without `n` takes k from powers of two).
         # The compile log (utils/jaxenv.py) counts it all the same.
         kernel = cls._PBANK_KERNELS[key] = jax.jit(
             named(kernel, "topn_positions"))
         return kernel
 
-    def _topn_positions(self, pb, filter_words, n: int, tanimoto: int,
-                        min_threshold: int, src_dev) -> "_Pending":
+    # A tanimoto call without `n` asks for every row past the
+    # threshold: each segment's survivors are counted on the device
+    # and its top_k starts at this bound; a segment with more runs
+    # again at the next power of two that holds them all (exact: never
+    # an approximate top-k, never a cut answer).
+    PBANK_EVERY_K = 256
+
+    def _topn_positions(self, pb, filter_words, width: int, n: int,
+                        tanimoto: int, min_threshold: int,
+                        src_dev) -> "_Pending":
         """TopN over a device-resident PositionsBank (see
         view.PositionsBank): per segment one kernel dispatch, host
-        merge of k-candidates across segments."""
-        import jax.numpy as jnp
-
+        merge of k-candidates across segments. `n` = 0 (a tanimoto
+        call only) is every row the rule keeps."""
         import jax
 
-        fw = None
-        if filter_words is not None:
-            fw = filter_words[0]  # [W] u32, single shard
+        fw = filter_words   # [1, W'] u32 (single shard), or None
+        every = n == 0
         # Params are identical for every segment — build/upload ONCE.
         # (Per-segment rebuilds were one host->device put per segment
-        # per query.)
-        params = upload(
-            np.asarray([min_threshold, tanimoto, 0], np.uint32))
-        if tanimoto and src_dev is not None:
-            params = params.at[2].set(
-                jnp.asarray(src_dev).astype(jnp.uint32))
-        fw_arg = fw if fw is not None else jnp.zeros((1,), jnp.uint32)
-        outs = []
-        wave = []
-        for row_lo, n_rows, pos, aux, _p in pb.segments:
-            k = min(n, n_rows)
-            if k == 0:
-                continue
-            kern = self._pbank_kernel(k, fw is not None,
-                                      fixed=pos.ndim == 2)
-            out = kern(fw_arg, pos, aux, params)
-            outs.append((row_lo, out))
+        # per query.) The filter row's own popcount stays where
+        # `_popcount_row` left it and rides as an operand of its own.
+        params = upload(np.asarray([min_threshold, tanimoto], np.uint32))
+        src = src_dev if tanimoto else None
+        wave = []   # launches since the last sync
+
+        def launch(si: int, k: int):
+            _lo, _n, pos, aux, p_real = pb.segments[si]
+            fixed = pos.ndim == 2
+            kern = self._pbank_kernel(
+                k, fw is not None, fixed=fixed,
+                width=width if fw is not None else None, survivors=every)
+            rows = int(aux.shape[0]) - (0 if fixed else 1)
+            with self._dispatch_span("topn_positions") as ds:
+                ds.set("segment", si)
+                ds.set("rows", rows)
+                ds.set("positions", p_real)
+                ds.set("layout", "fixed" if fixed else "flat")
+                ds.set("k", k)
+                out = self._call_program(kern, fw, pos, aux, params, src)
+            if self.stats is not None:
+                self.stats.count("executor.pbank_launches", 1)
+            self._note_topn_rows("swept", rows)
             # Bound enqueued-program concurrency: each segment program
-            # needs GBs of workspace next to the resident bank, and
-            # letting all segments queue at once OOMed the chip at 100M
-            # rows (9 x ~4 GB transients + the 9.6 GB bank). A wave
-            # sync caps coexisting workspaces; outputs are k-sized so
+            # needs GBs of workspace next to the resident bank (2.15 GB
+            # at 2^27 flat positions, compiled for a v5e), and letting
+            # all segments queue at once OOMed the chip at 100M rows
+            # (9 x ~4 GB transients + the 9.6 GB bank). A wave sync
+            # caps coexisting workspaces; outputs are k-sized so
             # keeping them all is free.
             wave.append(out)
             if len(wave) >= PBANK_INFLIGHT_SEGMENTS:
-                # graftlint: disable=GL003 — deliberate wave sync: caps
-                # coexisting segment workspaces in HBM (see comment
-                # above); removing it re-introduces the 100M-row OOM.
-                jax.block_until_ready(wave)
-                wave = []
+                # A blocking wait of the begin half, as `d2h` is of the
+                # finish: a stage of its own.
+                with TIMELINE.stage("pbank.wave_wait",
+                                    segments=len(wave)):
+                    # graftlint: disable=GL003 — deliberate wave sync:
+                    # caps coexisting segment workspaces in HBM (see
+                    # comment above); removing it re-introduces the
+                    # 100M-row OOM.
+                    jax.block_until_ready(wave)
+                del wave[:]
+            return out
+
+        outs = []   # (segment, k, the launch's outputs)
+        for si, (_lo, n_rows, _pos, _aux, _p) in enumerate(pb.segments):
+            k = min(self.PBANK_EVERY_K if every else n, n_rows)
+            if k:
+                outs.append((si, k, launch(si, k)))
 
         def finalize() -> PairsResult:
             # ONE batched transfer for all segments' k-candidates
             # (sequential per-segment np.asarray fetches each paid a
             # blocking RTT; the results are ~k ints per segment).
-            got = jax.device_get([(v, i) for _, (v, i) in outs])
+            got = jax.device_get([out for _, _, out in outs])
+            fetched = sum(a.size for out in got for a in out)
+            for j, (si, k, _) in enumerate(outs):
+                n_rows = pb.segments[si][1]
+                left = int(got[j][2]) if every else 0
+                if left > k:
+                    # More rows past the threshold than the bound: the
+                    # segment runs again, wide enough for all of them.
+                    k = min(n_rows, 1 << (left - 1).bit_length())
+                    out = launch(si, k)
+                    fetch_host(out)
+                    got[j] = jax.device_get(out)
+                    fetched += sum(a.size for a in got[j])
+                    if self.stats is not None:
+                        self.stats.count(
+                            "executor.pbank_overflow_reruns", 1)
+            self._note_topn_rows("fetched", fetched)
             pairs = []
-            for (row_lo, _), (v, ix) in zip(outs, got):
+            for (si, _, _), (v, ix, *_) in zip(outs, got):
+                row_lo = pb.segments[si][0]
                 for val, i in zip(v.tolist(), ix.tolist()):
                     if val > 0:
                         pairs.append((int(pb.row_ids[row_lo + i]),
                                       int(val)))
             pairs.sort(key=lambda rc: (-rc[1], rc[0]))
-            return PairsResult(pairs[:n])
+            return PairsResult(pairs if every else pairs[:n])
 
         return _Pending(finalize,
-                        arrays=tuple(x for _, vi in outs for x in vi))
+                        arrays=tuple(x for _, _, out in outs for x in out))
 
     # Row-churn bound for incremental rank-vector patches: more changed
     # rows than this and the full sweep rebuild is cheaper than the

@@ -782,6 +782,28 @@ class Bitmap:
                     a |= _as_dense(b)
                 self._invalidate(key)
 
+    def _absorb(self, other: "Bitmap") -> None:
+        """union_in_place for a bitmap nobody else holds (a replayed
+        record's payload): its containers move, counts and all, so a
+        file that ends in records opens into the views of a few load
+        blocks that a snapshot opens into — not into a small array a
+        container (0.7 M of them put chem-chip's `finish.select` into
+        its slow mode, 428 ms a flush for 66: PERF.md section 6, PR
+        40)."""
+        if not self.containers:
+            self.containers, self._counts = other.containers, other._counts
+            return
+        mine, counts = self.containers, other._counts
+        for key, b in other.containers.items():
+            if key not in mine:
+                mine[key] = b
+                if key in counts:
+                    self._counts[key] = counts[key]
+            else:
+                a = self._container(key)
+                a |= _as_dense(b)
+                self._invalidate(key)
+
     def copy(self) -> "Bitmap":
         out = Bitmap()
         out.containers = {k: v.copy() for k, v in self.containers.items()}
@@ -927,8 +949,16 @@ class Bitmap:
             # rows of one block — a sparse fingerprint-shaped fragment
             # loads its ~2 MB of real data instead of materializing
             # 8 KiB per tiny container and re-optimizing.
-            loaded = native.roaring_load_ex(bytes(data),
-                                            split_max_card=ARRAY_MAX_SIZE)
+            # That holds for a file WITHOUT an op tail only: with one
+            # the native parser replays into dense containers, 8 KiB
+            # each, every container of the snapshot included. So the
+            # snapshot section is loaded by itself (compactly) and the
+            # tail replayed here, record by record.
+            tail_at = _split_load_at(data)
+            loaded = native.roaring_load_ex(
+                bytes(data) if tail_at is None
+                else memoryview(data)[:tail_at],
+                split_max_card=ARRAY_MAX_SIZE)
             if loaded is not None:
                 if loaded["tail_dropped"] and not tolerate_torn_tail:
                     raise OpTruncatedError(
@@ -961,6 +991,9 @@ class Bitmap:
                 self.oplog_bytes = loaded["ops_bytes"]
                 self.snapshot_bytes = loaded["snapshot_bytes"]
                 self.tail_dropped = loaded["tail_dropped"]
+                if tail_at is not None:
+                    self._replay_ops(memoryview(data)[tail_at:],
+                                     tolerate_torn_tail, _depth)
                 return
         if len(data) < HEADER_BASE_SIZE:
             raise ValueError("data too small")
@@ -1023,16 +1056,21 @@ class Bitmap:
                 # present container has at least one bit).
                 del self.containers[key]
             ops_offset = max(ops_offset, end)
-        # Ops log replay. A record extending past EOF is a torn tail
-        # append (crash mid-write): tolerated, dropped, and reported via
-        # tail_dropped so the owner can truncate the file. Checksum
-        # mismatches on complete records still raise (data corruption;
-        # reference fails on both, op.UnmarshalBinary roaring.go:3659).
         self.op_n = 0
         self.op_n_small = 0
         self.oplog_bytes = 0
         self.snapshot_bytes = ops_offset
-        buf = memoryview(data)[ops_offset:]
+        self._replay_ops(memoryview(data)[ops_offset:],
+                         tolerate_torn_tail, _depth)
+
+    def _replay_ops(self, buf: memoryview, tolerate_torn_tail: bool,
+                    _depth: int) -> None:
+        """Ops log replay, onto the containers the snapshot section
+        left. A record extending past EOF is a torn tail append (crash
+        mid-write): tolerated, dropped, and reported via tail_dropped so
+        the owner can truncate the file. Checksum mismatches on complete
+        records still raise (data corruption; reference fails on both,
+        op.UnmarshalBinary roaring.go:3659)."""
         while len(buf):
             try:
                 op_typ, value, values, size = decode_op(buf)
@@ -1060,7 +1098,7 @@ class Bitmap:
                     raise ValueError("op nesting too deep")
                 batch = Bitmap.from_bytes(values, _depth=_depth + 1)
                 self.op_n += batch.count()
-                self.union_in_place(batch)
+                self._absorb(batch)
             self.oplog_bytes += size
             buf = buf[size:]
 
@@ -1095,6 +1133,53 @@ def _serialize_container_seq(items: Iterable[Tuple[int, np.ndarray, int]],
         header.write(struct.pack("<I", offset))
         offset += len(p)
     return header.getvalue() + b"".join(payloads)
+
+
+# The native parser loads a file that ends with its snapshot section
+# compactly (containers stay references into the input), and one with
+# an op tail DENSE: 8 KiB a container, the snapshot's included, before
+# the replay. A fragment of 16.7 M one-row containers whose tail held
+# a third of them asked for 137 GB that way (builder, PR 40). So a file
+# with a tail has its two sections loaded apart (Bitmap.read_bytes):
+# the snapshot section by the native parser, the tail by _replay_ops.
+_META_DTYPE = np.dtype([("key", "<u8"), ("typ", "<u2"), ("card", "<u2")])
+
+
+def _split_load_at(data) -> Optional[int]:
+    """Where the op tail of a roaring file starts, from its header
+    alone; None for a file without a tail (None too for a header this
+    cannot read: the parser that then loads the whole file says what is
+    wrong with it). The section's end is the parsers' own: the largest
+    container end."""
+    if len(data) < HEADER_BASE_SIZE:
+        return None
+    magic, version, n = struct.unpack_from("<HHI", data, 0)
+    offsets_at = HEADER_BASE_SIZE + 12 * n
+    end = offsets_at + 4 * n
+    if magic != MAGIC_NUMBER or version != STORAGE_VERSION \
+            or end > len(data):
+        return None
+    if n:
+        meta = np.frombuffer(data, dtype=_META_DTYPE, count=n,
+                             offset=HEADER_BASE_SIZE)
+        at = np.frombuffer(data, dtype="<u4", count=n,
+                           offset=offsets_at).astype(np.int64)
+        typ = meta["typ"]
+        ends = np.where(typ == CONTAINER_ARRAY,
+                        at + 2 * (meta["card"].astype(np.int64) + 1),
+                        at + 8 * CONTAINER_WORDS)
+        runs = np.flatnonzero(typ == CONTAINER_RUN)
+        if len(runs):
+            if int(at[runs].max()) + RUN_COUNT_HEADER_SIZE > len(data):
+                return None
+            raw = np.frombuffer(data, dtype=np.uint8)
+            run_n = raw[at[runs]].astype(np.int64) \
+                | (raw[at[runs] + 1].astype(np.int64) << 8)
+            ends[runs] = at[runs] + RUN_COUNT_HEADER_SIZE + 4 * run_n
+        if ((typ < CONTAINER_ARRAY) | (typ > CONTAINER_RUN)).any():
+            return None
+        end = max(end, int(ends.max()))
+    return end if end < len(data) else None
 
 
 def encode_op(typ: int, value: int = 0, values: Optional[np.ndarray] = None) -> bytes:

@@ -16,7 +16,8 @@ BENCH = os.path.join(REPO, "benchmark")
 
 SWEEP_CELLS = ["taxi-chip.topn-sweep", "taxi-host4.topn-sweep",
                "chem-chip.tanimoto-sweep", "ssb-chip.flights",
-               "taxi-live-chip.report-ingest"]
+               "taxi-live-chip.report-ingest",
+               "chem-lib-chip.tanimoto-library"]
 POINT = ["taxi-chip.point-serial"]
 
 
