@@ -365,21 +365,38 @@ class PositionsBank:
     - flat:  (row_lo, n_rows, pos u16 [Ppad], starts i32 [n_rows+1],
       p_real) — |row ∧ filter| = membership bits + cumsum differenced
       at starts; handles arbitrary per-row lengths.
-    - fixed: (row_lo, n_rows, pos u16 [n_rows, L], lens i32 [n_rows],
-      p_real) — rows padded to L slots with 0xFFFF; counts are one
-      axis-1 reduce, no cumsum. Chosen per segment when every row fits
-      PBANK_FIXED_ROW_SLOTS and density clears PBANK_FIXED_MIN_DENSITY.
+    - fixed: (row_lo, n_rows, pos u16 [L, n_rows], lens i32 [n_rows],
+      p_real) — rows padded to L slots with 0xFFFF and stored SLOT-MAJOR
+      (slot l of every row is one contiguous plane, rows on the device's
+      lanes); counts are an add over the L planes, no cumsum, no
+      gathers, no cross-lane reduce. Chosen per segment when every row
+      fits PBANK_FIXED_ROW_SLOTS, density clears
+      PBANK_FIXED_MIN_DENSITY and the bank so padded fits its share of
+      the HBM budget (pbank_fixed_fits).
 
     Segmented on row boundaries so every segment's position count fits
-    i32 offsets."""
+    i32 offsets. `row_widths` holds each segment's longest row: a Row of
+    this field can have no more on-bits than the largest of them, so
+    the segment program's membership compare is that wide (`qslots`)
+    and no wider."""
 
-    __slots__ = ("segments", "row_ids", "versions", "nbytes")
+    __slots__ = ("segments", "row_ids", "versions", "nbytes",
+                 "row_widths")
 
-    def __init__(self, segments, row_ids, versions, nbytes):
+    def __init__(self, segments, row_ids, versions, nbytes, row_widths):
         self.segments = segments
         self.row_ids = row_ids      # global sorted row ids
         self.versions = versions
         self.nbytes = nbytes
+        self.row_widths = row_widths    # per segment: its longest row
+
+    @property
+    def qslots(self) -> int:
+        """Query slots of the membership compare: what the bank's widest
+        row needs as the filter, rounded up to a multiple of 8 so that
+        a write which lengthens that row by a bit or two compiles
+        nothing (the width is a compile key)."""
+        return max(8, -(-max(self.row_widths, default=0) // 8) * 8)
 
 
 class SparseBank:
@@ -428,27 +445,64 @@ class SparseBank:
 # well under 2^31; the build enforces the cap EXACTLY by splitting
 # gather chunks on row boundaries (a row contributes at most 2^16
 # positions, so no single row can break it). At 2^27 a flat segment's
-# program compiles, for a v5e, to 2.15 GB of temporaries (PERF.md §6,
-# PR 40; "a few hundred MB" by this comment's first reckoning), so a
-# wave of four queues 8.6 GB beside the resident bank (2^29 segments
-# put multi-GB transients next to a ~10 GB bank and OOMed a 100M-row
-# run on an earlier machine); the extra dispatches are cheap — results
-# fetch as one batched device_get. The host gather chunk bounds the
-# build's temporaries.
+# program compiles, for a v5e, to 2.15 GB of temporaries, so a wave of
+# four queues 8.6 GB beside the resident bank (2^29 segments put
+# multi-GB transients next to a ~10 GB bank and OOMed a 100M-row run on
+# an earlier machine); a fixed segment's keeps 0.31 GB (PERF.md §7 row
+# 30). The extra dispatches are cheap — results fetch as one batched
+# device_get. The host gather chunk bounds the build's temporaries.
 PBANK_SEGMENT_POSITIONS = int(os.environ.get(
     "PILOSA_TPU_PBANK_SEGMENT", 1 << 27))
 PBANK_GATHER_ROWS = 1 << 20
 # Fixed-width segment eligibility: every row in the segment must fit
-# this many position slots, and real positions must fill at least this
-# fraction of the padded matrix (bounds the padding overhead to 2x the
-# flat bytes in the worst admitted case).
+# this many position slots, real positions must fill at least this
+# fraction of the padded matrix, and the whole bank so padded must fit
+# its share of the HBM budget (pbank_fixed_fits). Over 8.4 M rows of
+# 48 real positions in 104 slots (density 0.46) the padded compare +
+# row sum reads 91 ms, ~0.1 ns a SLOT, where the flat compare + cumsum
+# + one gather reads 265 ms, ~0.66 ns a POSITION (417 with the two
+# gathers it had; my chip run, PR 41, benches/pbank_kernel_probe.py).
+# By those two rates the padded form would stay the faster one down to
+# a density of ~0.16, but nothing was read below 0.46: the floor sits
+# just under the sparsest segment measured as served (a fingerprint
+# library's, 0.38-0.47). It was 0.5, which refused that library the
+# form that answers it 4.6 times as fast.
 PBANK_FIXED_ROW_SLOTS = int(os.environ.get(
     "PILOSA_TPU_PBANK_FIXED_SLOTS", 128))
-PBANK_FIXED_MIN_DENSITY = 0.5
+PBANK_FIXED_MIN_DENSITY = 0.35
+PBANK_FIXED_FILL_ROWS = 4096    # rows a block of the host fill
 # Segment row counts round up to this multiple so kernel shapes repeat
 # across segments (one compile per bank instead of one per segment).
 PBANK_FIXED_ROW_PAD = int(os.environ.get(
     "PILOSA_TPU_PBANK_ROW_PAD", 1 << 16))
+
+
+def pbank_fixed_bytes(slots: int, n_rows: int) -> int:
+    """HBM a fixed segment's `[slots, n_rows]` u16 matrix takes: the
+    device tiles it 16 slots by 128 rows."""
+    return (-(-slots // 16) * 16) * (-(-n_rows // 128) * 128) * 2
+
+
+def pbank_segment_bytes(pos, aux) -> int:
+    """HBM one segment holds: its positions (a fixed segment's as the
+    device pads them) and its i32 aux vector (lens or starts, possibly
+    row-padded: its own size is the truth)."""
+    pos_bytes = pbank_fixed_bytes(*pos.shape) if pos.ndim == 2 \
+        else int(pos.size) * 2
+    return pos_bytes + int(aux.size) * 4
+
+
+def pbank_fixed_fits(n_rows: int) -> bool:
+    """Whether a bank of `n_rows` rows may lay its segments out fixed:
+    padded to the layout's widest admitted row (PBANK_FIXED_ROW_SLOTS:
+    the build learns a segment's own L only as it gathers it) the bank
+    must stay inside half of one device's bank budget — the other half
+    is what the budget's other banks and the wave of segment programs
+    keep (the same half `_execute_topn` asks of a stream of chunk banks
+    before it caches them). A bank past it stays flat, 2 B a
+    position."""
+    padded = pbank_fixed_bytes(PBANK_FIXED_ROW_SLOTS, n_rows) + n_rows * 4
+    return padded <= BANK_BUDGET.budget // 2
 
 
 def view_bsi_name(field: str) -> str:
@@ -898,13 +952,17 @@ class View:
             return bank
 
     def _build_pbank_segments(self, frag, rows: list, width: int,
-                              row_lo0: int):
+                              row_lo0: int, fixed_fits: bool):
         """Gather `rows` (sorted) into device segments starting at
         global row index `row_lo0`: [(row_lo, n_rows, pos_dev,
-        starts_dev, p_real)], total nbytes — or None when too dense."""
+        starts_dev, p_real)], total nbytes and each segment's longest
+        row — or None when too dense. `fixed_fits`: whether the
+        WHOLE bank these rows belong to may be padded
+        (pbank_fixed_fits)."""
         import jax.numpy as jnp
 
         segments: list = []
+        row_widths: list = []
         nbytes = 0
         pos_parts: list = []
         lens_parts: list = []
@@ -921,14 +979,18 @@ class View:
             p = len(pos16)
             n = len(lens)
             # FIXED-WIDTH layout when the segment's rows are uniform
-            # enough: positions as [n_rows, L] (0xFFFF pad) + per-row
-            # real lengths. The TopN kernel then row-sums with one
-            # axis-1 reduce — no O(P) cumsum, no starts gathers.
-            # Fingerprint
-            # banks are ~99% dense at L=48; the density guard keeps
-            # padding ≤ 2x the flat bytes. Kind is carried by array
-            # rank (pos 2D = fixed), so every 5-tuple consumer —
-            # patcher, tests, benches — is untouched.
+            # enough: positions as [L, n_rows] (0xFFFF pad) + per-row
+            # real lengths. The TopN kernel then row-sums with an add
+            # over the L slot planes — no O(P) cumsum, no starts
+            # gathers. Slot-major, so that rows lie on the device's
+            # lanes whatever L is (left to itself the TPU compiler
+            # stores a [n_rows, L] array that way below 128 slots and
+            # row-major at 128: the layout, and the reduce with it,
+            # would turn on the longest row). L is that row rounded up
+            # to a multiple of 8, so that segments of one library share
+            # a shape. Kind is carried by array rank (pos 2D = fixed),
+            # so every 5-tuple consumer — patcher, tests, benches — is
+            # untouched.
             # Row-count pad (both layouts): kernels compile per array
             # SHAPE — a 36-segment bank with 36 distinct row counts
             # cost 36 cold compiles.
@@ -954,17 +1016,32 @@ class View:
                     row_pad = cand
                 cand *= 2
             n_pad = -n % row_pad
-            L = int(lens.max()) if n else 0
-            if 0 < L <= PBANK_FIXED_ROW_SLOTS \
+            longest = int(lens.max()) if n else 0
+            row_widths.append(longest)
+            L = -(-longest // 8) * 8
+            if fixed_fits and 0 < longest <= PBANK_FIXED_ROW_SLOTS \
                     and p >= PBANK_FIXED_MIN_DENSITY * n * L:
-                mat = np.full((n + n_pad, L), 0xFFFF, np.uint16)
-                mat[:n][np.arange(L)[None, :] < lens[:, None]] = pos16
+                # Filled a block of rows at a time: the block's padded
+                # rows and its transpose stay in the cache (the whole
+                # matrix masked and transposed at once took six times
+                # as long at 2.8 M rows).
+                mat = np.empty((L, n + n_pad), np.uint16)
+                mat[:, n:] = 0xFFFF
+                ends = np.cumsum(lens)
+                slot = np.arange(L)[None, :]
+                for b0 in range(0, n, PBANK_FIXED_FILL_ROWS):
+                    b1 = min(n, b0 + PBANK_FIXED_FILL_ROWS)
+                    blk = np.full((b1 - b0, L), 0xFFFF, np.uint16)
+                    blk[slot < lens[b0:b1, None]] = pos16[
+                        int(ends[b0 - 1]) if b0 else 0:int(ends[b1 - 1])]
+                    mat[:, b0:b1] = blk.T
                 lens32 = np.zeros(n + n_pad, np.int32)
                 lens32[:n] = lens
                 seg = (row_lo, n, jnp.asarray(mat),
                        jnp.asarray(lens32), p)
+                del mat
                 segments.append(seg)
-                nbytes += (n + n_pad) * L * 2 + (n + n_pad) * 4
+                nbytes += pbank_segment_bytes(seg[2], seg[3])
             else:
                 starts = np.zeros(n + n_pad + 1, np.int64)
                 np.cumsum(lens, out=starts[1:n + 1])
@@ -981,7 +1058,7 @@ class View:
                 seg = (row_lo, n, jnp.asarray(buf),
                        jnp.asarray(starts.astype(np.int32)), p)
                 segments.append(seg)
-                nbytes += padded * 2 + (n + n_pad + 1) * 4
+                nbytes += pbank_segment_bytes(seg[2], seg[3])
             pos_parts, lens_parts = [], []
             cur_p = 0
             row_lo += n
@@ -1024,7 +1101,7 @@ class View:
                 if cur_p >= PBANK_SEGMENT_POSITIONS:
                     flush()
         flush()
-        return segments, nbytes
+        return segments, nbytes, row_widths
 
     def merged_row_ids(self, shards) -> tuple:
         """Sorted union of row_ids() across `shards`, cached per shard
@@ -1108,11 +1185,12 @@ class View:
             if built is None:
                 kind = "full"
                 # graftlint: disable=GL015 — same version-stamp argument.
-                built = self._build_pbank_segments(frag, row_ids, width, 0)
+                built = self._build_pbank_segments(
+                    frag, row_ids, width, 0, pbank_fixed_fits(len(row_ids)))
             if built is None:
                 sp.set("kind", "none")  # too dense: the caller streams
                 return None
-            segments, nbytes = built
+            segments, nbytes, row_widths = built
             # Ideal (pad-free) footprint: 2 B per real position + one i32
             # aux word per row (+1); the rest is pow2 / fixed-width / row
             # padding — the number the padding gauge exists to surface.
@@ -1125,7 +1203,8 @@ class View:
                 sp.set(attr, value)
             TIMELINE.count("executor.pbank_builds")
             TIMELINE.count(f"executor.pbank_builds{{kind:{kind}}}")
-        bank = PositionsBank(segments, row_ids, versions, nbytes)
+        bank = PositionsBank(segments, row_ids, versions, nbytes,
+                             tuple(row_widths))
         with self._lock:
             self._bank_cache[key] = bank
         BANK_BUDGET.admit(self, key, nbytes=nbytes)
@@ -1142,16 +1221,19 @@ class View:
         their device arrays. Same-row-set only (the caller checked):
         global row indexes then stay aligned except where segment
         boundaries move, handled by rebuilding dirty ranges in place.
-        Returns (segments, nbytes) or None to force a full rebuild."""
+        Returns (segments, nbytes, row_widths) or None to force a full
+        rebuild."""
         changed = frag.rows_changed_since(
             next(iter(cached.versions.values())))
         if not changed or len(changed) > len(cached.row_ids) // 4:
             return None  # nothing known, or patch ~= rebuild
         dirty = set(changed)
         segments: list = []
+        row_widths: list = []
         nbytes = 0
         row_lo = 0
-        for seg in cached.segments:
+        fixed_fits = pbank_fixed_fits(len(cached.row_ids))
+        for seg, seg_widths in zip(cached.segments, cached.row_widths):
             s_lo, n_rows, pos_dev, starts_dev, p_real = seg
             seg_rows = cached.row_ids[s_lo:s_lo + n_rows]
             if dirty.isdisjoint(seg_rows):
@@ -1161,16 +1243,15 @@ class View:
                 # cannot — assert the invariant cheaply).
                 segments.append((row_lo, n_rows, pos_dev, starts_dev,
                                  p_real))
-                # aux is lens (fixed) or starts (flat), both i32 and
-                # possibly row-padded — its own size is the truth.
-                nbytes += int(pos_dev.size) * 2 + int(starts_dev.size) * 4
+                row_widths.append(seg_widths)
+                nbytes += pbank_segment_bytes(pos_dev, starts_dev)
                 row_lo += n_rows
                 continue
             rebuilt = self._build_pbank_segments(frag, seg_rows, width,
-                                                 row_lo)
+                                                 row_lo, fixed_fits)
             if rebuilt is None:
                 return None
-            new_segs, nb = rebuilt
+            new_segs, nb, new_widths = rebuilt
             # The clean-segment reuse above depends on every dirty
             # range rebuilding to the SAME real row count (row_lo
             # offsets of later clean segments assume it). A mismatch
@@ -1179,9 +1260,10 @@ class View:
             if sum(s[1] for s in new_segs) != n_rows:
                 return None
             segments.extend(new_segs)
+            row_widths.extend(new_widths)
             nbytes += nb
             row_lo += n_rows
-        return segments, nbytes
+        return segments, nbytes, row_widths
 
     # -- hybrid layout (driven by core/layout.py) ----------------------------
 

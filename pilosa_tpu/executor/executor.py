@@ -131,21 +131,29 @@ TOPN_CHUNK_ROWS = int(os.environ.get("PILOSA_TPU_TOPN_CHUNK_ROWS", 1024))
 # chunk banks (view.PositionsBank).
 PBANK_ENABLED = os.environ.get("PILOSA_TPU_PBANK", "1") != "0"
 
-# Filters with at most this many set bits take the positions-bank
-# kernel's gather-free compare path (see _pbank_kernel.bits_compare);
-# denser filters use the table gather. 128 covers every fingerprint
-# query: a molecule has ~48 +- 12 on-bits and up to 128, and at the
-# 64 this was, one query molecule in twelve took the gather form —
-# on the v5e 9.85 ns a position against the compare's 0.09
-# (benches/pbank_kernel_probe.py, pbank_membership_probe.py): ~4 s an
-# answer among answers of ~0.45 s, and a window's count swung by a
-# third with how many it drew. At 128 every answer costs 0.72 s and
-# the count repeats (PERF.md section 6, PR 40). Raising it grows the
-# [P, QCAP] compare fan-out, and its cost faster than that: 316.6 ms
-# at 128 slots against 27.7 at 64 over 403 M positions
-# (PILOSA_PROBE_QSLOTS=128 benches/pbank_kernel_probe.py).
+# Upper cap on the width of the positions-bank kernel's gather-free
+# membership compare (see _pbank_kernel.bits_compare). The width itself
+# is not a setting: it follows the bank (PositionsBank.qslots — its
+# widest row in steps of 8, 104 slots for a library whose widest
+# molecule has 103 on-bits), so a Row of the bank's own field always
+# takes the compare and pays for no slot the bank cannot need. A
+# filter with more on-bits than that takes the table gather: on the
+# v5e 9.85 ns a position against the compare's ~0.1
+# (benches/pbank_kernel_probe.py), ~4 s an answer among answers of
+# ~0.1 s. While this constant WAS the width (128, PR 40) every answer
+# of a library whose rows need 104 slots paid the 128-slot fan-out:
+# 0.72 s an answer, 0.30 s of it the compare (PERF.md section 6).
 PBANK_SPARSE_FILTER_BITS = int(os.environ.get(
     "PILOSA_TPU_PBANK_SPARSE_BITS", 128))
+
+# Query slots one compare fan-out may have. XLA's TPU compiler lays a
+# [P] x [Q] compare-reduce out differently at Q = 128 than below it:
+# over 402.7 M positions it reads 56.7 ms at 104 slots and 321.1 at
+# 128, where two fan-outs of 64 OR-ed together read 54.5 — they stay
+# two fusions (my chip run, PR 41; 27.7 / 28.4 / 35.4 / 40.4 ms at 72 /
+# 80 / 96 / 112 slots in u16, PR 40). So a width past 112 is compared
+# in equal chunks, and the cliff cannot come back with a wider library.
+PBANK_COMPARE_CHUNK = 112
 
 # Membership form for the sparse-filter pbank kernel: "compare" (the
 # [P] x [QCAP] equality fan-out), "search" (binary search in the
@@ -163,11 +171,13 @@ if PBANK_MEMBERSHIP not in ("auto", "compare", "search"):
 
 # Max positions-bank segment programs enqueued before a sync (see
 # _topn_positions): bounds how many programs' workspaces can coexist in
-# HBM beside the resident bank. A flat segment of the 2^27 default
-# compiles, for a v5e, to 2.15 GB of temporaries (the [P] membership
-# words, the cumsum and its shifted copy), so 4 hold 8.6 GB. Each wave
-# sync is one blocking wait (span `pbank.wave_wait`), so the cap trades
-# that wait against OOM headroom.
+# HBM beside the resident bank. Compiled for a v5e, a flat segment of
+# the 2^27 default keeps 2.15 GB of temporaries (the [P] membership
+# words, the cumsum and its shifted copy), so 4 hold 8.6 GB; a fixed
+# segment of the same positions ([104, 2.8 M] u16) keeps 0.31 GB (the
+# [L, R] membership bits), so 4 hold 1.2 GB. Each wave sync is one
+# blocking wait (span `pbank.wave_wait`), so the cap trades that wait
+# against OOM headroom.
 PBANK_INFLIGHT_SEGMENTS = int(os.environ.get(
     "PILOSA_TPU_PBANK_INFLIGHT", 4))
 
@@ -3291,16 +3301,18 @@ class Executor:
     @classmethod
     def _pbank_kernel(cls, k: int, has_filter: bool,
                       fixed: bool = False, width: Optional[int] = None,
-                      survivors: bool = False):
+                      survivors: bool = False,
+                      qslots: Optional[int] = None):
         """Jitted per-segment TopN over a PositionsBank: |row ∧ filter|
         = Σ_{p ∈ row} filter_bit[p]. Two layouts (view.py flush):
 
         - flat (pos [P], starts [R+1]): membership bits + a cumsum
           differenced at row starts (u32 wrap subtraction is exact —
           per-row counts fit u16);
-        - fixed (pos [R, L], lens [R]): membership summed with one
-          axis-1 reduce — no O(P) cumsum, no starts gathers. The
-          0xFFFF row pad matches nothing (compare) / gathers fill-0.
+        - fixed (pos [L, R] slot-major, lens [R]): membership summed
+          over the L slot planes, rows on the lanes — no O(P) cumsum,
+          no starts gathers, no cross-lane reduce. The 0xFFFF row pad
+          matches nothing (compare) / gathers fill-0.
 
         No dense expansion, no streaming: one pass over the resident
         positions. Unfiltered TopN skips even that — counts are the
@@ -3315,8 +3327,21 @@ class Executor:
         `[threshold, tanimoto]` and, for a tanimoto call, the
         filter row's own popcount as `_popcount_row` left it — so a
         launch is this program and no eager helper beside it.
-        `survivors` adds a third output, the number of rows the rule
-        keeps: what a call without `n` sizes its top_k against."""
+        `survivors` adds an output, the number of rows the rule
+        keeps: what a call without `n` sizes its top_k against. A
+        filtered program's LAST output is the form its gate took
+        (1 the sparse membership, 0 the table gather): `finalize`
+        counts it, `executor.pbank_form{form:compare|gather}`.
+
+        `qslots` is the width of the sparse membership: how many filter
+        positions it holds. The bank's widest row
+        (`PositionsBank.qslots`), capped by PBANK_SPARSE_FILTER_BITS. A
+        Row of the bank's own field can have no more on-bits, so it
+        always takes that form; a filter with more (a Union, another
+        field) takes the gather, exact. A filtered program needs the
+        bank's `width`: the filter is cut to it, and `positions_bank`
+        admits no width of 2048 words, so every real position is under
+        65504 — what the u16 compare's filter pad (0xFFFE) relies on."""
         import jax
         import jax.numpy as jnp
 
@@ -3325,7 +3350,11 @@ class Executor:
             membership = ("search"
                           if jax.devices()[0].platform == "cpu"
                           else "compare")
-        key = (k, has_filter, fixed, membership, width, survivors)
+        assert width is not None or not has_filter
+        qslots = min(qslots or PBANK_SPARSE_FILTER_BITS,
+                     PBANK_SPARSE_FILTER_BITS)
+        key = (k, has_filter, fixed, membership, width, survivors,
+               qslots if has_filter else None)
         fn = cls._PBANK_KERNELS.get(key)
         if fn is not None:
             return fn
@@ -3340,17 +3369,16 @@ class Executor:
         def bits_compare(fw, pos):
             # Sparse-filter membership WITHOUT the positions gather: a
             # tanimoto query's filter is one fingerprint (~48 set bits),
-            # and an element-wise [P] x [QCAP] compare-reduce against
-            # its extracted set positions is VPU-shaped where the
-            # P-sized dynamic gather is not (the two-stage top-k
-            # variant showed no gain on an earlier machine, so top_k
-            # stays flat; on the v5e the compare is 27 ms of a
-            # segment-sized pass's 388 at 384 M positions, the cumsum
-            # and the row-start gathers the rest, and the gather form
-            # 3.8 s: benches/pbank_kernel_probe.py, PERF.md §7 row
-            # 27). Extraction: enumerate the filter's 32*W bit
-            # positions, keep set ones, take the QCAP smallest (pad
-            # 2^30 sorts last; a real position is < 2^16).
+            # and an element-wise [P] x [Q] compare-reduce against its
+            # extracted set positions is VPU-shaped where the P-sized
+            # dynamic gather is not: on the v5e ~0.1 ns a position-slot
+            # at 104 query slots against the gather's 9.85
+            # (benches/pbank_kernel_probe.py, PERF.md §7 row 27; the
+            # two-stage top-k variant showed no gain on an earlier
+            # machine, so top_k stays flat). Extraction: enumerate the
+            # filter's 32*W bit positions, keep set ones, take the
+            # `qslots` smallest (pad 2^30 sorts last; a real position
+            # is < 2^16).
             w = jnp.arange(fw.shape[0], dtype=jnp.int32)
             allpos = w[:, None] * 32 + jnp.arange(32, dtype=jnp.int32)
             setmask = ((fw[:, None] >> jnp.arange(32, dtype=jnp.uint32))
@@ -3361,7 +3389,7 @@ class Executor:
             # filter row would crash every filtered query. The clamp is
             # exact: popcount(fw) <= 32*W == the clamped k, so the gate
             # below still guarantees every set position is captured.
-            qk = min(PBANK_SPARSE_FILTER_BITS, int(qpos.shape[0]))
+            qk = min(qslots, int(qpos.shape[0]))
             qtop = -jax.lax.top_k(-qpos, qk)[0]
             if membership == "search":
                 # qtop is sorted ascending: binary-search each position
@@ -3374,9 +3402,25 @@ class Executor:
                                                 pos.astype(jnp.int32)),
                                0, qk - 1)
                 return jnp.take(qtop, idx) == pos.astype(jnp.int32)
-            # pos is [P] (flat layout) or [R, L] (fixed layout); the
+            # pos is [P] (flat layout) or [L, R] (fixed layout); the
             # trailing broadcast axis makes membership layout-agnostic.
-            return (pos[..., None].astype(jnp.int32) == qtop).any(-1)
+            # The fan-out is as wide as `qslots` and no wider, in
+            # chunks that stay under the compiler's cliff
+            # (PBANK_COMPARE_CHUNK); its time is linear in the width.
+            # Compared as the u16 the bank stores (in i32 the program
+            # first wrote a 4-byte copy of every position: 1.17 GB of
+            # workspace a program at 2.8 M rows): a filter pad becomes
+            # 0xFFFE, which is neither a row pad (0xFFFF) nor a real
+            # position (< width * 32 <= 65504).
+            q16 = jnp.where(qtop < (1 << 16), qtop,
+                            0xFFFE).astype(jnp.uint16)
+            n_chunks = -(-qk // PBANK_COMPARE_CHUNK)
+            step = -(-qk // n_chunks)
+            member = None
+            for c0 in range(0, qk, step):
+                part = (pos[..., None] == q16[c0:c0 + step]).any(-1)
+                member = part if member is None else member | part
+            return member
 
         def kernel(fw, pos, aux, params, src=None):
             # aux: starts [R+1] (flat) | lens [R] (fixed)
@@ -3406,24 +3450,48 @@ class Executor:
                     # difference between fitting HBM and
                     # RESOURCE_EXHAUSTED.
                     if fixed:
-                        return bits.sum(axis=1, dtype=jnp.int32)
+                        return bits.sum(axis=0, dtype=jnp.int32)
                     s = jnp.concatenate(
                         [jnp.zeros(1, jnp.uint32),
                          jnp.cumsum(bits, dtype=jnp.uint32)])
-                    return (s[aux[1:]] - s[aux[:-1]]).astype(jnp.int32)
+                    # ONE gather of the R + 1 row starts, differenced:
+                    # two gathers of R (`s[aux[1:]] - s[aux[:-1]]`)
+                    # read 362.9 ms with their cumsum over 402.7 M
+                    # positions and 8.4 M rows, one reads 211.3 (the
+                    # cumsum alone 93.7; my chip run, PR 41).
+                    g = s[aux]
+                    return (g[1:] - g[:-1]).astype(jnp.int32)
+
+                def gather_fixed(fw, pos):
+                    # The gather form over a fixed segment, one slot
+                    # plane a step: its index and word temporaries are
+                    # [R] then, not [L, R] — three of those were the
+                    # whole program's workspace (3.36 GB at [104, 2.8 M],
+                    # compiled for a v5e; 0.31 GB so), and a branch's
+                    # workspace is reserved whichever branch runs.
+                    def plane(i, acc):
+                        return acc + bits_gather(fw, pos[i]).astype(
+                            jnp.int32)
+                    return jax.lax.fori_loop(
+                        0, pos.shape[0], plane,
+                        jnp.zeros(pos.shape[1], jnp.int32))
 
                 # Exactness gate ON DEVICE (no extra host round trip):
-                # the compare form only sees the QCAP smallest filter
+                # the compare form only sees the `qslots` smallest filter
                 # positions, so any denser filter falls back to the
                 # gather form inside the same compiled program.
                 fwpop = jnp.sum(
                     jax.lax.population_count(fw)).astype(jnp.int32)
+                sparse = fwpop <= qslots
                 c = jax.lax.cond(
-                    fwpop <= PBANK_SPARSE_FILTER_BITS,
+                    sparse,
                     lambda: c_from(bits_compare(fw, pos)),
-                    lambda: c_from(bits_gather(fw, pos)))
+                    lambda: (gather_fixed(fw, pos) if fixed
+                             else c_from(bits_gather(fw, pos))))
+                form = (sparse.astype(jnp.int32),)
             else:
                 c = raw
+                form = ()
             thresh, tani = (params[0].astype(jnp.int32),
                             params[1].astype(jnp.int32))
             # Without a tanimoto rule there is no source count, and
@@ -3437,14 +3505,15 @@ class Executor:
             score = jnp.where(keep, c, -1)
             top = jax.lax.top_k(score, k)
             if survivors:
-                return (*top, keep.sum(dtype=jnp.int32))
-            return top
+                return (*top, keep.sum(dtype=jnp.int32), *form)
+            return (*top, *form)
 
         # graftlint: disable=GL006 — class-level kernel cache (benches
         # monkeypatch _pbank_kernel as a classmethod, so no instance is
         # available to note compiles on); keys are (k, filter, layout,
-        # membership, width, survivors) — a bounded, shape-stable set
-        # per deployment (a call without `n` takes k from powers of two).
+        # membership, width, survivors, qslots) — a bounded, shape-stable
+        # set per deployment (a call without `n` takes k from powers of
+        # two; qslots moves in steps of 8 with the bank's widest row).
         # The compile log (utils/jaxenv.py) counts it all the same.
         kernel = cls._PBANK_KERNELS[key] = jax.jit(
             named(kernel, "topn_positions"))
@@ -3467,6 +3536,10 @@ class Executor:
         import jax
 
         fw = filter_words   # [1, W'] u32 (single shard), or None
+        filtered = fw is not None
+        # The membership compare is as wide as the bank's widest row (a
+        # compile key): a Row of this field always fits it.
+        qslots = min(pb.qslots, PBANK_SPARSE_FILTER_BITS)
         every = n == 0
         # Params are identical for every segment — build/upload ONCE.
         # (Per-segment rebuilds were one host->device put per segment
@@ -3480,8 +3553,9 @@ class Executor:
             _lo, _n, pos, aux, p_real = pb.segments[si]
             fixed = pos.ndim == 2
             kern = self._pbank_kernel(
-                k, fw is not None, fixed=fixed,
-                width=width if fw is not None else None, survivors=every)
+                k, filtered, fixed=fixed,
+                width=width if filtered else None, survivors=every,
+                qslots=qslots)
             rows = int(aux.shape[0]) - (0 if fixed else 1)
             with self._dispatch_span("topn_positions") as ds:
                 ds.set("segment", si)
@@ -3489,6 +3563,8 @@ class Executor:
                 ds.set("positions", p_real)
                 ds.set("layout", "fixed" if fixed else "flat")
                 ds.set("k", k)
+                if filtered:
+                    ds.set("qslots", qslots)
                 out = self._call_program(kern, fw, pos, aux, params, src)
             if self.stats is not None:
                 self.stats.count("executor.pbank_launches", 1)
@@ -3525,7 +3601,12 @@ class Executor:
             # (sequential per-segment np.asarray fetches each paid a
             # blocking RTT; the results are ~k ints per segment).
             got = jax.device_get([out for _, _, out in outs])
-            fetched = sum(a.size for out in got for a in out)
+            # values, indices[, survivors]; a filtered program's next
+            # output is the form its gate took, not a row: counted
+            # apart.
+            n_out = 3 if every else 2
+            forms = [int(out[n_out]) for out in got] if filtered else []
+            fetched = sum(a.size for out in got for a in out[:n_out])
             for j, (si, k, _) in enumerate(outs):
                 n_rows = pb.segments[si][1]
                 left = int(got[j][2]) if every else 0
@@ -3536,11 +3617,19 @@ class Executor:
                     out = launch(si, k)
                     fetch_host(out)
                     got[j] = jax.device_get(out)
-                    fetched += sum(a.size for a in got[j])
+                    fetched += sum(a.size for a in got[j][:n_out])
+                    if filtered:
+                        forms.append(int(got[j][n_out]))
                     if self.stats is not None:
                         self.stats.count(
                             "executor.pbank_overflow_reruns", 1)
             self._note_topn_rows("fetched", fetched)
+            if self.stats is not None:
+                for name, took in (("compare", sum(forms)),
+                                   ("gather", len(forms) - sum(forms))):
+                    if took:
+                        self.stats.with_tags(f"form:{name}").count(
+                            "executor.pbank_form", took)
             pairs = []
             for (si, _, _), (v, ix, *_) in zip(outs, got):
                 row_lo = pb.segments[si][0]

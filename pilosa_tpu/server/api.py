@@ -126,13 +126,17 @@ class API:
         self.stats.count("executor.filter_launches", 0)
         # ... and the positions bank's (a field past the resident
         # limit): its segment programs launched, a call without `n`
-        # whose segment ran again wider, and the bank's builds by kind.
+        # whose segment ran again wider, the bank's builds by kind, and
+        # which membership form a filtered launch's gate took.
         for name in ("pbank_launches", "pbank_overflow_reruns",
                      "pbank_builds"):
             self.stats.count(f"executor.{name}", 0)
         for kind in ("full", "patch"):
             self.stats.with_tags(f"kind:{kind}").count(
                 "executor.pbank_builds", 0)
+        for form in ("compare", "gather"):
+            self.stats.with_tags(f"form:{form}").count(
+                "executor.pbank_form", 0)
         # ... and a GroupBy's: groups answered, level programs, and the
         # group-sum launches of `aggregate=Sum(field=f)` with the
         # (group, plane) rows they counted.

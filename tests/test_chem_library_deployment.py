@@ -4,8 +4,8 @@ whose resident limit and segment size are patched small, so that the
 fingerprint field is served from the positions bank in five segments or
 more, with a wave sync a call. Every threshold of the traffic, with
 `n` = 50 and without `n` (the source's own query), ties at the n-th
-place, a ratio of exactly T, a query row wider than 64 on-bits (the
-gather form) and a segment that overflows its first bound — each equal
+place, a ratio of exactly T, a query row wider than the compare's cap
+(the gather form) and a segment that overflows its first bound — each equal
 to `benchmark/datasets/chem.py`'s plain reference; the path counter says
 `positions` and never `streamed`; the spans and counters the cell's
 metrics read are in the record. Then what an import-roaring body costs:
@@ -213,17 +213,29 @@ def test_a_ratio_of_exactly_t_and_a_tie_at_the_nth_place(served, threshold):
 
 def test_a_query_row_wider_than_the_compare_bound_takes_the_gather_form(
         served, monkeypatch):
-    """The bound (128: every fingerprint) set to 64 for the test, so
-    that the library's widest molecules pass it: the program's other
-    branch, the table gather, answers them."""
+    """The compare is as wide as the bank's widest row, so every
+    fingerprint takes it; its cap (128) set to 64 for the test, so that
+    the library's widest molecules pass it: the program's other branch,
+    the table gather, answers them, and is counted."""
     lib = served.lib
     monkeypatch.setattr(ex_mod, "PBANK_SPARSE_FILTER_BITS", 64)
     monkeypatch.setattr(ex_mod.Executor, "_PBANK_KERNELS", {})
     wide = np.flatnonzero(lib.popcount > 64)
     assert len(wide) >= 3
+    gather = "executor.pbank_form{form:gather}"
+    before = served.counters()
+    asked = 0
     for m in wide[:3].tolist():
         for n, t in ((50, 50), (0, 50), (50, 90), (0, 70)):
             assert served.ask(m, n, t) == chem.similar(lib, m, n, t), (m, t)
+            asked += 1
+    after = served.counters(
+        lambda c: _moved(before, c, gather)
+        >= _moved(before, c, "executor.pbank_launches") > 0)
+    assert _moved(before, after, gather) \
+        == _moved(before, after, "executor.pbank_launches") \
+        >= asked * len(served.view().positions_bank(
+            0, served.view().trimmed_words()).segments)
 
 
 def test_a_segment_past_its_first_bound_runs_again_wider(served,
@@ -265,6 +277,8 @@ def test_the_spans_and_counters_are_in_the_record(served):
         assert a["layout"] == ("fixed" if fixed else "flat")
         assert a["k"] == min(50, n_rows) and a["positions"] == p_real
         assert a["rows"] == aux.shape[0] - (0 if fixed else 1)
+        assert a["qslots"] == pb.qslots \
+            == -(-max(pb.row_widths) // 8) * 8
         assert a["jit"] in ("hit", "miss")
     waits = served.spans("pbank.wave_wait")
     assert waits and waits[0].attrs["segments"] \
@@ -275,10 +289,14 @@ def test_the_spans_and_counters_are_in_the_record(served):
                  "executor.pbank_builds{kind:full}",
                  "executor.pbank_builds{kind:patch}",
                  "executor.pbank_overflow_reruns",
+                 "executor.pbank_form{form:compare}",
+                 "executor.pbank_form{form:gather}",
                  "executor.topn_sweeps{path:positions}",
                  "executor.topn_sweeps{path:streamed}"):
         assert name in counters, name
     assert counters["executor.pbank_builds{kind:full}"] >= 1
+    # Every fingerprint fits the bank's own width: the compare, always.
+    assert counters["executor.pbank_form{form:compare}"] >= len(pb.segments)
 
 
 def test_the_query_rows_subset_bank_is_a_counted_upload(served):
