@@ -9,6 +9,7 @@ call this.
 
 from __future__ import annotations
 
+import threading
 import time as _time
 from datetime import datetime
 from typing import Any, Dict, List, Optional, Sequence
@@ -25,6 +26,7 @@ from pilosa_tpu.executor.results import result_to_json
 from pilosa_tpu.ops.bitset import SHARD_WIDTH
 from pilosa_tpu.pql import Call, Query, parse_string_cached
 from pilosa_tpu.utils.failpoints import FAILPOINTS
+from pilosa_tpu.utils.locks import make_lock
 from pilosa_tpu.utils.timeline import TIMELINE
 from pilosa_tpu import __version__
 
@@ -81,6 +83,43 @@ class ApiError(ValueError):
     def __init__(self, msg: str, status: int = 400):
         super().__init__(msg)
         self.status = status
+
+
+class HeldRequests:
+    """The query requests a server holds: one opens where its request
+    record opens (`API.begin_request` — the HTTP handler calls it before
+    it reads the body) and closes where that closes (`end_request`,
+    after the reply is on the socket). The coalescer asks it whether a
+    queued request is alone in the server: a window is a wait for
+    batch-mates, and a request nobody else is being read, parsed,
+    executed or written beside has none to wait for.
+
+    A thread serves one request at a time, so opens nest per thread and
+    count once: query() under a handler whose record the timeline does
+    not keep (switched off, or sampling) opens the request again."""
+
+    def __init__(self) -> None:
+        self._lock = make_lock("HeldRequests._lock")
+        self._tls = threading.local()
+        self._n = 0
+
+    def open(self) -> None:
+        depth = getattr(self._tls, "depth", 0)
+        self._tls.depth = depth + 1
+        if depth == 0:
+            with self._lock:
+                self._n += 1
+
+    def close(self) -> None:
+        self._tls.depth -= 1
+        if self._tls.depth == 0:
+            with self._lock:
+                self._n -= 1
+
+    def count(self) -> int:
+        """One attribute read, no lock: the coalescer's dispatcher asks
+        under its own condition."""
+        return self._n
 
 
 class API:
@@ -178,6 +217,9 @@ class API:
         # by the server wiring (cli/main.py) or a test harness; None
         # means every request takes the direct path.
         self.coalescer = None
+        # The query requests in the server, begin_request to
+        # end_request; every submit to the coalescer carries it.
+        self.held = HeldRequests()
         # Always-on memory watchdog (utils/memledger.MemoryWatchdog),
         # attached by cli/main.py; the health plane reports its state.
         self.watchdog = None
@@ -352,18 +394,25 @@ class API:
         if rec is not None and hasattr(self.tracer, "adopt"):
             # Outgoing node-to-node legs name the root as their parent.
             self.tracer.adopt(rec.trace_id, rec.root)
+        self.held.open()
         return rec
 
     def end_request(self, rec, err=None) -> None:
-        TIMELINE.finish(rec, error=err)
-        # The request is over: drop the thread-adopted trace id so an
-        # embedded (non-HTTP) caller's next query on this thread mints
-        # a fresh id instead of stitching every query into one trace.
-        # (The HTTP layer already resets per request via extract();
-        # library callers have no such reset.)
-        adopt = getattr(self.tracer, "adopt", None)
-        if adopt is not None:
-            adopt(None)
+        """Close what begin_request opened. `rec` is None for a request
+        the timeline keeps no record of; the server held it all the
+        same, so whoever called begin_request calls this."""
+        try:
+            TIMELINE.finish(rec, error=err)
+            # The request is over: drop the thread-adopted trace id so
+            # an embedded (non-HTTP) caller's next query on this thread
+            # mints a fresh id instead of stitching every query into
+            # one trace. (The HTTP layer already resets per request via
+            # extract(); library callers have no such reset.)
+            adopt = getattr(self.tracer, "adopt", None)
+            if adopt is not None:
+                adopt(None)
+        finally:
+            self.held.close()
 
     def _parse_stage(self, rec, query) -> Optional[bool]:
         """The `pql.parse` stage: parse the text once on the request's
@@ -451,7 +500,8 @@ class API:
             self.stats.count("query", 1)
             try:
                 resp = coal.submit(index, query, shards=shards,
-                                   profile=prof, is_write=is_write)
+                                   profile=prof, is_write=is_write,
+                                   held=self.held)
             except CoalescerStopped:
                 # Lost the race with coalescer.stop(): serve the
                 # request directly rather than failing it. (Only

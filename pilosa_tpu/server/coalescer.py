@@ -15,9 +15,14 @@ Mechanics:
 - A request thread enqueues its query and blocks on a per-item event.
 - A single dispatcher thread collects items arriving within a short
   batching window (default ~1.5 ms), flushing early when the batch hits
-  the size cap, a write-containing query arrives, or the device is idle
-  (nothing was in flight when the previous flush finished — waiting
-  would only add latency).
+  the size cap or a write-containing query arrives, and without a
+  window at all when the items queued behind a running flush (they have
+  waited already) or when the queued request is alone in the server:
+  the window is a wait for batch-mates, and the server's count of the
+  query requests it holds (`API.held`, begin_request to end_request)
+  says there are none — reason `alone`. A count of two or more waits
+  (somebody is reading a body or parsing and may submit in time), and
+  so does a caller that submits without a count: it may be one of many.
 - The batch runs through `Executor.execute_batch` (one pipelined
   dispatch-then-drain) with per-request error isolation: one bad query
   resolves to ITS exception without failing its batchmates, the same
@@ -60,7 +65,7 @@ from pilosa_tpu.utils.locks import make_condition
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-from pilosa_tpu.server.api import ApiError
+from pilosa_tpu.server.api import ApiError, HeldRequests
 from pilosa_tpu.utils.fingerprint import request_key
 from pilosa_tpu.utils.hotspots import WORKLOAD
 from pilosa_tpu.utils.timeline import TIMELINE
@@ -115,11 +120,13 @@ class DeadlineExceeded(ApiError):
 
 class _Item:
     __slots__ = ("index", "query", "shards", "is_write", "deadline",
-                 "state", "event", "result", "enqueued_at", "profile")
+                 "state", "event", "result", "enqueued_at", "profile",
+                 "held")
 
     def __init__(self, index: str, query: Any,
                  shards: Optional[Sequence[int]], is_write: bool,
-                 deadline: Optional[float], profile: Any = None):
+                 deadline: Optional[float], profile: Any = None,
+                 held: Optional[HeldRequests] = None):
         self.index = index
         self.query = query
         self.shards = shards
@@ -132,13 +139,19 @@ class _Item:
         # utils/profile QueryProfile the executor fills in while this
         # item's request executes (None on non-profiled paths).
         self.profile = profile
+        # The submitting server's count of the query requests it holds,
+        # this one among them; None from a caller that announces nothing.
+        self.held = held
 
 
 class QueryCoalescer:
     """Collects concurrent single-query requests into executor batches.
 
     `submit()` is the only entry point for request threads; `start()`/
-    `stop()` bracket the dispatcher thread's lifetime. `stop()` drains:
+    `stop()` bracket the dispatcher thread's lifetime. A request waits
+    at most `window_s` for batch-mates, and not at all when the server
+    that submitted it holds no other query request (`_collect_window`,
+    flush reason `alone`). `stop()` drains:
     everything already queued still executes before the thread exits, so
     a SIGTERM'd server answers its admitted requests (in-flight HTTP
     handlers block in submit until their batch completes)."""
@@ -204,7 +217,7 @@ class QueryCoalescer:
     # — every flush that holds a write; `direct`: a lone request.
     FLUSH_PATHS = ("pipelined", "batch", "direct")
     FLUSH_REASONS = ("window", "size", "write", "idle", "drain",
-                     "shutdown")
+                     "shutdown", "alone")
 
     def _count_flush(self, path: str, reason: str, size: int) -> None:
         self.stats.count(f"coalescer.flush.{reason}", 1)
@@ -286,7 +299,8 @@ class QueryCoalescer:
     def submit(self, index: str, query: Any,
                shards: Optional[Sequence[int]] = None,
                profile: Any = None,
-               is_write: Optional[bool] = None) -> Dict[str, Any]:
+               is_write: Optional[bool] = None,
+               held: Optional[HeldRequests] = None) -> Dict[str, Any]:
         """Queue one query and block until its batch resolves. Returns
         the shaped response dict; raises the per-request exception
         (executor errors, CoalescerOverload, DeadlineExceeded).
@@ -294,7 +308,10 @@ class QueryCoalescer:
         filled in by the executor when this item's request runs; forced
         profiles are excluded from read-dedup so their tree describes
         exactly this request's execution. `is_write`, when the caller
-        already parsed the query, saves parsing it again here.
+        already parsed the query, saves parsing it again here. `held`
+        is the server's count of the query requests it holds, this one
+        included (API.held): a request it shows to be alone does not
+        wait out the window (see _collect_window).
 
         The caller (API.query_coalesced) checks `running` first and
         falls back to the direct path, but the check races with stop():
@@ -306,7 +323,7 @@ class QueryCoalescer:
         if is_write is None:
             is_write = query_is_write(query)
         item = _Item(index, query, shards, is_write, deadline,
-                     profile=profile)
+                     profile=profile, held=held)
         with self._cond:
             if not self._running:
                 raise CoalescerStopped("coalescer stopped")
@@ -421,6 +438,16 @@ class QueryCoalescer:
             return "drain"
         if self.window_s <= 0:
             return "idle"
+        if self._flush_now is None and len(self._queue) == 1:
+            held = self._queue[0].held
+            if held is not None and held.count() == 1:
+                # The window is a wait for batch-mates, and the queued
+                # request is the only query request its server holds:
+                # there can be none. Two held means somebody is still
+                # reading a body or parsing and may submit in time, so
+                # that waits; so does a caller that announces nothing
+                # (held is None) — it may be one of many.
+                return "alone"
         deadline = time.monotonic() + self.window_s
         while (self._flush_now is None and not self._stop
                and len(self._queue) < self.max_batch):

@@ -108,8 +108,10 @@ class Handler(BaseHTTPRequestHandler):
     # response. (The client side sets TCP_NODELAY on its pooled sockets.)
     disable_nagle_algorithm = True
     # The open request record of the query being handled (None between
-    # requests and on every other route).
+    # requests and on every other route), and whether one was opened:
+    # the server holds a request the timeline keeps no record of too.
     _rec = None
+    _began = False
 
     # -- plumbing -----------------------------------------------------------
 
@@ -345,7 +347,8 @@ class Handler(BaseHTTPRequestHandler):
             # The query route's request record closes here, after the
             # reply (or the error reply) is on the socket.
             rec, self._rec = self._rec, None
-            if rec is not None:
+            began, self._began = self._began, False
+            if began:
                 self.api.end_request(rec, err)
             try:
                 self._observe_slo(method, path,
@@ -584,6 +587,7 @@ class Handler(BaseHTTPRequestHandler):
                 # the body is read and closes in _dispatch after the
                 # reply is written, so its stages tile the exchange.
                 rec = self._rec = api.begin_request(m.group(1))
+                self._began = True
                 # Reference-client protobuf surface
                 # (http/handler.go:916-995, internal/public.proto).
                 if self.headers.get("Content-Type", "").startswith(

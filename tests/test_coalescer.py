@@ -34,6 +34,12 @@ def post(base, path, body, timeout=30):
         return e.code, e.read(), dict(e.headers)
 
 
+def flushes_by_reason(stats):
+    counters = stats.snapshot()["counters"]
+    return {r: counters[f"coalescer.flushes{{reason:{r}}}"]
+            for r in QueryCoalescer.FLUSH_REASONS}
+
+
 def seed_data(holder):
     idx = holder.create_index("c")
     f = idx.create_field("f")
@@ -222,6 +228,47 @@ def test_dedup_identical_queries_one_execution(pair):
         api.coalescer.window_s = 0.002
 
 
+@pytest.mark.parametrize("recorded", [True, False])
+def test_sequential_client_does_not_wait_out_the_window(pair, recorded,
+                                                        monkeypatch):
+    """One client on one keep-alive connection: each of its requests is
+    the only one the server holds, so none waits for batch-mates — with
+    a 50 ms window every one of twenty answers in under 25 ms, and the
+    flush reason says why. The same with the timeline off: the handler
+    then holds no record, the query path opens the request a second
+    time, and the server still holds ONE (and lets it go)."""
+    import http.client
+    from pilosa_tpu.utils.timeline import TIMELINE
+    coal, _direct, api = pair
+    monkeypatch.setattr(TIMELINE, "enabled", recorded)
+    api.coalescer.window_s = 0.05
+    host, port = coal.removeprefix("http://").split(":")
+    conn = http.client.HTTPConnection(host, int(port), timeout=30)
+
+    def ask(q):
+        t0 = time.perf_counter()
+        conn.request("POST", "/index/c/query", body=q.encode())
+        resp = conn.getresponse()
+        body = resp.read()
+        assert resp.status == 200, body
+        return time.perf_counter() - t0
+
+    try:
+        queries = [f"Count(Row(f={r % 8}))" for r in range(20)]
+        for q in set(queries):      # compile outside the timed posts
+            ask(q)
+        before = flushes_by_reason(api.stats)
+        times = [ask(q) for q in queries]
+        after = flushes_by_reason(api.stats)
+    finally:
+        conn.close()
+        api.coalescer.window_s = 0.002
+    assert max(times) < 0.025, times
+    moved = {r: after[r] - before[r] for r in after if after[r] != before[r]}
+    assert moved == {"alone": 20}, moved
+    assert api.held.count() == 0
+
+
 class _GatedExecutor:
     """Delegating executor whose execute paths block on a release event
     — pins the dispatcher mid-batch so queue-capacity and deadline
@@ -270,8 +317,9 @@ def gated(tmp_path):
     h.close()
 
 
-def test_overload_429_and_deadline_ejection(gated):
-    base, gate, api = gated
+def _overload_and_eject(base, gate, api):
+    """Pin the dispatcher in the gate, fill the queue, and see one
+    request rejected (429) and the two queued ones ejected (408)."""
     results = {}
 
     def bg(name):
@@ -312,6 +360,45 @@ def test_overload_429_and_deadline_ejection(gated):
     gate.release.set()
     t1.join(timeout=10)
     assert results["inflight"][0] == 200, results["inflight"]
+
+
+def test_overload_429_and_deadline_ejection(gated):
+    _overload_and_eject(*gated)
+
+
+def test_held_count_returns_to_zero(gated):
+    """The server's count of the query requests it holds opens in
+    begin_request and closes in end_request whatever became of the
+    request: answered, unparseable, rejected at capacity (429) or
+    ejected by its deadline (408). A count that leaked would keep every
+    later lone request waiting out the window; one that closed early
+    would let a request with batch-mates flush without them."""
+    base, gate, api = gated
+    with urllib.request.urlopen(base + "/metrics") as resp:
+        text = resp.read().decode()
+    # Published from the start: a share of flushes is read over a
+    # window in which no request may be alone.
+    assert 'pilosa_coalescer_flushes_total{reason="alone"} 0' in text, \
+        [ln for ln in text.splitlines() if "coalescer_flushes" in ln]
+    assert api.held.count() == 0
+    _overload_and_eject(base, gate, api)
+    assert post(base, "/index/c/query", b"Count(Row(f=")[0] == 400
+    assert post(base, "/index/c/query", b"Count(Row(nope=1))")[0] == 400
+    for r in range(3):
+        st, body, _ = post(base, "/index/c/query",
+                           f"Count(Row(f={r}))".encode())
+        assert st == 200, body
+    # In-process callers hold a request too, and let it go on an error.
+    assert api.query_coalesced("c", "Count(Row(f=1))")["results"]
+    with pytest.raises(Exception):
+        api.query_coalesced("c", "Count(Row(nope=1))")
+    # A handler closes its request after the reply is on the socket.
+    deadline = time.monotonic() + 5
+    while api.held.count() and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert api.held.count() == 0
+    snap = api.stats.snapshot()["counters"]
+    assert snap["coalescer.flushes{reason:alone}"] >= 4, snap
 
 
 def test_stats_and_metrics_surface(pair):
@@ -628,3 +715,79 @@ def test_a_slow_flush_caps_the_next_ones_at_the_target(plex, monkeypatch):
         assert max(sizes) > 4
     finally:
         co.stop()
+
+
+# ------------------------------------------- a request that is alone
+#
+# The window is a wait for batch-mates. The server counts the query
+# requests it holds (API.held); a queued request that is the only one
+# does not wait, every other case does what it did before.
+
+
+@pytest.mark.parametrize("case", ["alone", "two_held", "bare_submit",
+                                  "write_alone"])
+def test_window_is_skipped_only_for_a_request_that_is_alone(plex, case):
+    from pilosa_tpu.server.api import HeldRequests
+
+    stats = MemStatsClient()
+    held = HeldRequests()
+    co = QueryCoalescer(plex, window_s=0.25, max_batch=8, stats=stats)
+    co.start()
+    both_held = threading.Barrier(2)
+    took, errors = {}, []
+
+    def client(name, query, announce, delay=0.0, together=False):
+        """What a handler does: hold the request, submit, let go."""
+        try:
+            if announce:
+                held.open()
+            if together:
+                both_held.wait(10)
+            time.sleep(delay)       # the second client is still "parsing"
+            t0 = time.perf_counter()
+            co.submit("c", query, held=held if announce else None)
+            took[name] = time.perf_counter() - t0
+        except Exception as e:  # noqa: BLE001
+            errors.append(e)
+        finally:
+            if announce:
+                held.close()
+
+    plans = {
+        "alone": [("a", "Count(Row(f=1))", True)],
+        "two_held": [("a", "Count(Row(f=1))", True, 0.0, True),
+                     ("b", "Count(Row(f=2))", True, 0.05, True)],
+        "bare_submit": [("a", "Count(Row(f=1))", False)],
+        "write_alone": [("a", f"Set({5 * 2**20}, f=30)", True)],
+    }
+    try:
+        plex.execute_full("c", "Count(Row(f=1))")    # compile first
+        threads = [threading.Thread(target=client, args=args)
+                   for args in plans[case]]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+    finally:
+        co.stop()
+    assert held.count() == 0
+    moved = {r: n for r, n in flushes_by_reason(stats).items() if n}
+    sizes = stats.snapshot()["histograms"]["coalescer.batch_size"]
+    if case == "alone":
+        assert moved == {"alone": 1}, moved
+        assert took["a"] < 0.1, took
+    elif case == "two_held":
+        # The first waited for the one still on its way, and both rode
+        # one flush.
+        assert moved == {"window": 1}, moved
+        assert sizes["count"] == 1 and sizes["sum"] == 2, sizes
+        assert took["a"] >= 0.2 and took["b"] >= 0.15, took
+    elif case == "bare_submit":
+        # A caller that announces nothing may be one of many: it waits.
+        assert moved == {"window": 1}, moved
+        assert took["a"] >= 0.2, took
+    else:
+        assert moved == {"write": 1}, moved
+        assert took["a"] < 0.1, took

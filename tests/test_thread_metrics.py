@@ -151,6 +151,9 @@ def test_the_server_publishes_what_the_metric_files_name(tmp_holder):
     api.coalescer = QueryCoalescer(api.executor, window_s=0.25,
                                    max_batch=2, stats=stats)
     api.coalescer.start()
+    # The test holds a request of its own: the first of the two to
+    # arrive is then not alone in the server, and waits for the other.
+    api.held.open()
     try:
         out = [None, None]
         ts = [threading.Thread(target=lambda i=i: out.__setitem__(
@@ -164,6 +167,7 @@ def test_the_server_publishes_what_the_metric_files_name(tmp_holder):
         gc.collect()
         snap = stats.snapshot()
     finally:
+        api.held.close()
         api.coalescer.stop()
         mon.stop()
         TIMELINE.reset()

@@ -898,6 +898,9 @@ def test_http_coalesced_request_tiles(tmp_holder):
     srv = serve(api, "localhost", 0, background=True)
     base = f"http://localhost:{srv.server_address[1]}"
     flush_shares = []
+    # The test holds a request of its own: the first of four to arrive
+    # is then not alone in the server, and waits for its batch-mates.
+    api.held.open()
     try:
         for warm in range(4):
             TIMELINE.reset()
@@ -916,6 +919,7 @@ def test_http_coalesced_request_tiles(tmp_holder):
                              for r in TIMELINE.requests()
                              if r.kind == "flush"]
     finally:
+        api.held.close()
         srv.shutdown()
         srv.server_close()
         api.coalescer.stop()
