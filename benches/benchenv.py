@@ -3,11 +3,27 @@ salted chain-slope method and its physical-validity guard."""
 
 import time
 
-from pilosa_tpu.utils.roofline import resolve_roofline
+from benchmark.harness.peaks import PEAK_HBM_GBPS
 
 # Tolerance above the roofline before a slope measurement is rejected:
 # covers catalog rounding, not measurement error.
 ROOFLINE_SLACK = 1.05
+
+
+class UnknownDeviceKind(LookupError):
+    """The device's kind has no row in the benchmark's peak table."""
+
+
+def resolve_roofline(device):
+    """(peak HBM GB/s, device kind) of a jax device, from the one peak
+    table the repo keeps (benchmark/harness/peaks.py). A kind that is
+    not there is an error, never a default."""
+    kind = getattr(device, "device_kind", "") or ""
+    try:
+        return PEAK_HBM_GBPS[kind][0], kind
+    except KeyError:
+        raise UnknownDeviceKind(
+            f"no HBM peak on record for device kind {kind!r}") from None
 
 
 def chain_slope_gbps(timed, bytes_per_iter, ks=(8, 32, 72, 128), reps=3,

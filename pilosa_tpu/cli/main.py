@@ -48,18 +48,6 @@ def drain_telemetry(api, watchdog=None, logger=None) -> None:
     from pilosa_tpu.utils.timeline import TIMELINE
     if TIMELINE.enabled:
         TIMELINE.dump(logger)
-    # Roofline plane: achieved-bandwidth EWMAs and predicted-vs-
-    # measured residuals (utils/roofline.py) — the calibration state a
-    # post-mortem needs to judge the optimizer's cost model.
-    from pilosa_tpu.utils.roofline import ROOFLINE
-    if ROOFLINE.enabled:
-        ROOFLINE.dump(logger)
-    # SLO sentinel (utils/sentinel.py): the budget verdict per
-    # objective + the last alert fire/clear events — whether the
-    # process died inside or outside its objectives.
-    from pilosa_tpu.utils.sentinel import SENTINEL
-    if SENTINEL.enabled:
-        SENTINEL.dump(logger)
     tracer = getattr(api, "tracer", None)
     if tracer is not None:
         # The timeline dump above left the last records in the log;
@@ -223,11 +211,8 @@ def cmd_server(args) -> int:
         from pilosa_tpu.utils.failpoints import FAILPOINTS
         FAILPOINTS.http_enabled = True
         logger.printf("failpoints surface enabled (nothing armed)")
-    # Query profiler policy: device-fence 1-in-N unforced queries and
-    # bound the /debug/queries slow-query ring (utils/profile.py;
-    # ?profile=true always fences regardless of sample_every).
-    api.profiler.configure(sample_every=cfg.profile_sample_every,
-                           ring_size=cfg.profile_slow_ring)
+    # Bound the /debug/queries slow-query ring (utils/profile.py).
+    api.profiler.configure(ring_size=cfg.profile_slow_ring)
     # Workload analytics plane (utils/hotspots.py): the process-wide
     # recorder picks up the [workload] config — decay half-life,
     # rolling repeat window, top-K, LRU bounds, kill switch.
@@ -256,33 +241,6 @@ def cmd_server(args) -> int:
     # Every XLA compile from here on is counted, with its cause
     # (xla.* counters, the table in GET /debug/queries).
     COMPILES.install(stats)
-    # Roofline attribution plane ([roofline] section, utils/roofline):
-    # per-launch bytes joined with the profiler's sampled fences into
-    # achieved GB/s at GET /debug/roofline. gbps = 0 auto-resolves
-    # from the device kind at first launch.
-    from pilosa_tpu.utils.roofline import ROOFLINE
-    ROOFLINE.configure(enabled=cfg.roofline_enabled,
-                       gbps=cfg.roofline_gbps,
-                       ewma_alpha=cfg.roofline_ewma_alpha,
-                       max_cohorts=cfg.roofline_max_cohorts)
-    # SLO & regression sentinel ([sentinel]/[slo] sections,
-    # utils/sentinel.py): bounded metrics history + burn-rate alerts,
-    # sampled from the watchdog's extra-gauges hook below. The HBM
-    # pressure condition shares the watchdog's watermark.
-    from pilosa_tpu.core.view import BANK_BUDGET as _SENT_BUDGET
-    from pilosa_tpu.utils.sentinel import SENTINEL
-    SENTINEL.configure(enabled=cfg.sentinel_enabled,
-                       ring=cfg.sentinel_ring,
-                       decimate=cfg.sentinel_decimate,
-                       alert_ring=cfg.sentinel_alert_ring,
-                       objectives=cfg.slo,
-                       watermark_bytes=int(
-                           _SENT_BUDGET.budget
-                           * cfg.telemetry_hbm_watermark))
-    if cfg.slo:
-        logger.printf("slo objectives: %s",
-                      ", ".join(f"{k}: {v}"
-                                for k, v in sorted(cfg.slo.items())))
     # Cross-request cache tier ([cache] section): the generation-keyed
     # result cache lives on the executor, the device rank-cache store
     # is process-wide. The PILOSA_TPU_RESULT_CACHE=0 /
@@ -333,14 +291,6 @@ def cmd_server(args) -> int:
         from pilosa_tpu.utils.memledger import LEDGER, MemoryWatchdog
 
         def _telemetry_gauges():
-            # The sentinel samples its history rings at the watchdog
-            # cadence (gauges must never kill the watchdog — the
-            # sample_once wrapper already swallows, but the queue
-            # gauges below must survive a sentinel bug too).
-            try:
-                api.sample_sentinel()
-            except Exception:
-                pass
             coal = api.coalescer
             return {
                 "queueDepth": (coal.queue_depth()

@@ -375,11 +375,13 @@ class _CacheFillEval:
             else host
 
 
-# graftlint: materialize — sampled device-time fence: reached ONLY when
-# the active QueryProfile requests device sampling (?profile=true or the
-# configured 1-in-N sample). The unprofiled hot path never calls it, so
-# the dispatch queue stays async (tests/test_profile.py asserts zero
-# calls without a sampling profile).
+# graftlint: materialize — the program's one host-clock fence: reached
+# ONLY under a QueryProfile an operator asked for with ?profile=true. The
+# unprofiled hot path never calls it, so the dispatch queue stays async
+# (tests/test_profile.py asserts zero calls without such a profile).
+# Returns the host's wait in block_until_ready after the enqueue: an
+# upper bound on that program's device time only when the queue ahead
+# of it was empty. Device speed comes from a profiler trace.
 def _fence_device(out) -> float:
     import jax
     t0 = time.perf_counter()
@@ -773,9 +775,9 @@ class Executor:
         # {launches,collective_bytes}_total.
         self.mesh_launches = 0
         self.mesh_collective_bytes = 0
-        # Launch cost attribution (ops/megakernel.plan_cost, the
-        # roofline plane): HBM bytes each launch moved split by kind,
-        # plus per-opcode instruction totals. /metrics exports
+        # Launch cost attribution (ops/megakernel.plan_cost): HBM
+        # bytes each launch moved split by kind, plus per-opcode
+        # instruction totals. /metrics exports
         # pilosa_executor_launch_bytes_total{kind=gather|compute|
         # expand|pad} and pilosa_executor_opcode_total{op=...}.
         self.launch_bytes_gather = 0
@@ -1079,9 +1081,8 @@ class Executor:
 
     def _note_launch_cost(self, cost: Dict[str, Any]) -> None:
         """Account one launch's HBM traffic attribution (ops/
-        megakernel.plan_cost — the roofline plane's byte splits and
-        per-opcode histogram). '+=' is not atomic and batches can run
-        from several threads."""
+        megakernel.plan_cost's byte splits and per-opcode histogram).
+        '+=' is not atomic and batches can run from several threads."""
         with self._jit_stats_lock:
             self.launch_bytes_gather += cost["gatherBytes"]
             self.launch_bytes_compute += cost["computeBytes"]
@@ -2311,9 +2312,9 @@ class Executor:
         idxs, params, uploaded = self._staged_args(staged)
         # planS is the tree staging; dispatchS is the fn() call itself
         # (async enqueue on a cache hit, trace+compile on a miss);
-        # deviceS is the fenced XLA execution time — sampled queries
-        # only, so the unprofiled path keeps its fully-async dispatch
-        # queue.
+        # deviceS is the fenced wait (_fence_device) — ?profile=true
+        # queries only, so the unprofiled path keeps its fully-async
+        # dispatch queue.
         with self._dispatch_span(staged.program) as ds:
             out = self._call_program(fn, staged.bank_arrays, idxs,
                                      params, staged.lits)
@@ -2327,16 +2328,16 @@ class Executor:
         device_s = 0.0
         if prof.sample_device:
             # A `device` span exists ONLY when the profiler already
-            # fenced this query (?profile=true / sampled 1-in-N) — the
-            # record adds zero fences of its own.
+            # fenced this query (?profile=true) — the record adds zero
+            # fences of its own.
             with TIMELINE.stage("device"):
                 device_s = _fence_device(out)
             prof.tree_device(node, device_s)
         if staged.fp is not None:
             # Feed the cache-opportunity estimator: what one eval of
-            # this signature actually cost (dispatch enqueue + fenced
-            # device time when sampled) — the seconds a result-cache
-            # hit would have saved.
+            # this signature actually cost (dispatch enqueue + the
+            # fenced wait under ?profile=true) — the seconds a
+            # result-cache hit would have saved.
             WORKLOAD.note_eval_seconds(staged.fp, dispatch_s + device_s)
         return out
 

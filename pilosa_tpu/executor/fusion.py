@@ -72,7 +72,6 @@ import numpy as np
 
 from pilosa_tpu.utils.hotspots import WORKLOAD
 from pilosa_tpu.utils.memledger import LEDGER
-from pilosa_tpu.utils.roofline import ROOFLINE
 from pilosa_tpu.utils.timeline import TIMELINE
 
 
@@ -303,11 +302,7 @@ class _FuseGroup:
                 device_s = _fence_device(self.out)
             for prof, node in fence_profs:
                 prof.tree_device(node, device_s)
-            # No plan IR on this path, so no byte attribution: count
-            # the fenced time as unattributed so /debug/roofline
-            # states how much sampled device time its bytes explain.
-            ROOFLINE.note_unattributed_fence(device_s)
-        # Cache-opportunity attribution AFTER the (sampled) fence so
+        # Cache-opportunity attribution AFTER the (?profile=true) fence so
         # fused evals report the same dispatch + device cost basis as
         # the unfused path (_run_staged) — one fused dispatch covered
         # B queries, so each member's eval cost its share.

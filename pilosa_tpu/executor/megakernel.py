@@ -44,7 +44,6 @@ from pilosa_tpu.ops import megakernel as mk
 from pilosa_tpu.utils.hotspots import WORKLOAD
 from pilosa_tpu.utils.memledger import LEDGER
 from pilosa_tpu.utils.profile import transfer
-from pilosa_tpu.utils.roofline import ROOFLINE
 from pilosa_tpu.utils.timeline import TIMELINE
 
 def _default_enabled() -> bool:
@@ -374,37 +373,14 @@ def _launch(executor: Any, cohort: List[Any], plan: mk.Plan,
             g.entries, g.profs, g.nodes = [], [], []
         return
     launch = _MegaLaunch(out)
-    # Launch cost attribution (the roofline plane): price the verified
-    # IR's HBM traffic in host numpy — microseconds, no fences, and
-    # best-effort by contract: a surprised cost model must never fail
-    # a request that already has its results in flight.
+    # Launch cost: price the verified IR's HBM traffic in host numpy —
+    # microseconds, no fences, and best-effort by contract: a surprised
+    # cost model must never fail a request that already has its results
+    # in flight.
     try:
         cost = mk.plan_cost(plan, n_shards, w_mega, mesh=spec)
     except Exception:
         cost = None
-    # Cohort signature for the per-cohort bandwidth EWMAs: the capacity
-    # buckets (not bank identity), so steady-state traffic of one shape
-    # aggregates instead of fragmenting.
-    ckey = (f"S{n_shards}|W{w_mega}|T{plan.n_regs}"
-            f"|P{plan.instrs.shape[0]}")
-    if cost is not None and ROOFLINE.enabled:
-        if ROOFLINE.needs_resolve():
-            from pilosa_tpu.utils.roofline import (
-                UnknownDeviceKind, resolve_roofline,
-            )
-            dev = jax.devices()[0]
-            try:
-                gbps, kind = resolve_roofline(dev)
-            except UnknownDeviceKind:
-                # No peak on record for this kind (the CPU backend
-                # included): byte counters and achieved GB/s still
-                # accumulate, no fraction is published.
-                gbps, kind = 0.0, dev.device_kind
-            ROOFLINE.set_resolved(gbps, kind, gbps <= 0)
-        opt = plan.opt_stats
-        ROOFLINE.note_launch(
-            ckey, cost,
-            opt.predicted_bytes if opt is not None else None)
     try:
         for g, g_lanes in zip(cohort, lanes):
             rep = g.entries[0]
@@ -439,10 +415,10 @@ def _launch(executor: Any, cohort: List[Any], plan: mk.Plan,
         if plan.opt_stats is not None:
             ex._note_opt(plan.opt_stats)
         _attribute(ex, cohort, launch, jit_hit, dispatch_s, plan,
-                   plan_bytes, n_entries, cost, ckey)
+                   plan_bytes, n_entries, cost)
     except Exception as e:
         # Per-member error isolation, the _FuseGroup.run contract: an
-        # async device failure surfacing here (e.g. the sampled
+        # async device failure surfacing here (e.g. the ?profile=true
         # _fence_device inside _attribute) lands on THIS cohort's
         # groups — FusedEval._out checks `error` before `out`, so the
         # already-assigned views never serve — and batchmates in other
@@ -457,15 +433,11 @@ def _launch(executor: Any, cohort: List[Any], plan: mk.Plan,
 def _attribute(ex: Any, cohort: List[Any], launch: _MegaLaunch,
                jit_hit: bool, dispatch_s: float, plan: mk.Plan,
                plan_bytes: int, n_entries: int,
-               cost: Optional[Dict[str, Any]] = None,
-               ckey: str = "") -> None:
+               cost: Optional[Dict[str, Any]] = None) -> None:
     """Profile attribution, the _FuseGroup._attribute
     convention: the program ran once for the whole launch, so every
-    member sees the shared dispatch (and sampled device) time labeled
-    with its launch coordinates. When a sampled fence fires, the cost
-    vector joins the measured device time into the roofline plane —
-    achieved GB/s rides EXISTING fences only; the unsampled path adds
-    none (pinned by tests/test_roofline.py)."""
+    member sees the shared dispatch (and, under ?profile=true, the
+    fenced wait) labeled with its launch coordinates."""
     fence_profs: List[Tuple[Any, Any]] = []
     opt = plan.opt_stats
     mega_index = 0
@@ -510,11 +482,7 @@ def _attribute(ex: Any, cohort: List[Any], launch: _MegaLaunch,
             device_s = _fence_device(launch.out)
         for prof, node in fence_profs:
             prof.tree_device(node, device_s)
-        if cost is not None:
-            # Bytes ÷ the fence we already paid = achieved bandwidth:
-            # per-cohort EWMA + drift detection in the recorder.
-            ROOFLINE.note_device(ckey, cost["totalBytes"], device_s)
-    # Cache-opportunity attribution AFTER the (sampled) fence — the
+    # Cache-opportunity attribution AFTER the (?profile=true) fence — the
     # per-entry share of one launch, same cost basis as the fused and
     # unfused paths.
     per_eval = (dispatch_s + device_s) / max(1, n_entries)

@@ -106,8 +106,7 @@ class OptStats:
                  "folds_reordered", "regs_before", "regs_after",
                  "slab_bytes_before", "slab_bytes_after",
                  "plan_bytes_before", "plan_bytes_after",
-                 "narrowed_lanes", "predicted_bytes",
-                 "fold_order_densities")
+                 "narrowed_lanes")
 
     def __init__(self) -> None:
         self.entries_before = 0
@@ -121,15 +120,6 @@ class OptStats:
         self.plan_bytes_before = 0
         self.plan_bytes_after = 0
         self.narrowed_lanes = 0
-        # Calibration feed (utils/roofline.py): the density-weighted
-        # traffic this model PREDICTS for the plan it emitted, recorded
-        # beside the measured per-launch cost so the drift detector can
-        # flag cohorts where the heuristic mis-ranks work.
-        self.predicted_bytes = 0
-        # The density-ordered operand weights of the first reordered
-        # fold chains (bounded): the concrete "predicted fold order"
-        # a /debug/roofline reader compares against measured drift.
-        self.fold_order_densities: List[Tuple[float, ...]] = []
 
     @property
     def entries_eliminated(self) -> int:
@@ -155,7 +145,6 @@ class OptStats:
             "slabBytesAfter": self.slab_bytes_after,
             "bytesSaved": self.bytes_saved,
             "narrowedLanes": self.narrowed_lanes,
-            "predictedBytes": self.predicted_bytes,
         }
 
 
@@ -227,11 +216,6 @@ def _reorder_folds(rows: List[List[int]], dens: Dict[int, float],
             ordered = [head] + [x for _, x in sorted(
                 enumerate(tail),
                 key=lambda t: (-weight(t[1]), t[0]))]
-        if len(stats.fold_order_densities) < 8:
-            # The predicted order itself, as sort weights — what the
-            # roofline plane's drift detector calibrates against.
-            stats.fold_order_densities.append(
-                tuple(round(weight(x), 4) for x in ordered))
         if ordered != operands:
             stats.folds_reordered += 1
             rows[i] = [op, r, ordered[0], ordered[1]]
@@ -239,34 +223,6 @@ def _reorder_folds(rows: List[List[int]], dens: Dict[int, float],
                 rows[i + 1 + m] = [op, r, r, x]
         i = j
 
-
-def predict_cost_bytes(rows: List[List[int]], dens: Dict[int, float],
-                       n_shards: int, w_mega: int) -> int:
-    """The optimizer's own density-weighted traffic prediction for a
-    plan body, in bytes: each instruction reads its operands at their
-    sampled live density and writes one dense row — the same weights
-    _reorder_folds sorts by, priced in the megakernel's row unit so
-    the roofline plane can compare it against plan_cost()'s measured
-    model (and the fenced device time) per cohort."""
-    row = int(n_shards) * int(w_mega) * 4
-
-    def weight(r: int) -> float:
-        return dens.get(r, SCRATCH_DENSITY)
-
-    total = 0.0
-    for op, dst, a, b in rows:
-        reads = 0.0
-        if op == mk.OP_EXPAND:
-            reads = SPARSE_DENSITY
-        else:
-            if op in mk._READS_A:
-                reads += weight(a)
-            if op in mk._READS_B:
-                reads += weight(b)
-            if op in mk._READS_DST:
-                reads += weight(dst)
-        total += (reads + 1.0) * row
-    return int(total)
 
 # ------------------------------------------------- value numbering / CSE
 #
@@ -541,11 +497,6 @@ def optimize_plan(plan: mk.Plan, n_shards: int,
     try:
         dens = _register_densities(plan, rows)
         _reorder_folds(rows, dens, stats)
-        # Predicted cost of the (reordered) plan body — recorded even
-        # when a later pass bails, so the calibration loop always has
-        # the heuristic's number beside the measured one.
-        stats.predicted_bytes = predict_cost_bytes(
-            rows, dens, n_shards, w_mega)
         nodes, spans, reg_vn = _value_number(
             plan, rows, n_slots, n_gathered, widths, stats)
 
@@ -625,12 +576,6 @@ def optimize_plan(plan: mk.Plan, n_shards: int,
     stats.regs_after = t_pad
     stats.slab_bytes_after = mk.slab_nbytes(t_pad, n_shards, w_mega)
     stats.plan_bytes_after = new_plan.plan_nbytes
-    # The plan that will actually launch is the rewritten one — its
-    # predicted cost is what the measured per-launch bytes/time must
-    # be compared against (slot registers keep their numbering, so the
-    # density map still applies; rebuilt scratch carries the default).
-    stats.predicted_bytes = predict_cost_bytes(
-        new_rows, dens, n_shards, w_mega)
     new_plan.opt_stats = stats
     return new_plan, stats
 

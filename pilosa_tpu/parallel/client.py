@@ -371,14 +371,6 @@ class InternalClient:
         return self._req("GET", f"{uri}/debug/hotspots{q}",
                          timeout=timeout or self.health_timeout)
 
-    def node_slo(self, uri: str,
-                 timeout: Optional[float] = None) -> dict:
-        """One node's SLO snapshot (GET /debug/slo) for the
-        /cluster/slo merge — same short-timeout rule as node_health:
-        a wedged node is reported, not waited on."""
-        return self._req("GET", f"{uri}/debug/slo",
-                         timeout=timeout or self.health_timeout)
-
     def node_timeline(self, uri: str, trace_id: str,
                       timeout: Optional[float] = None) -> dict:
         """One node's timeline slices for a trace id (GET

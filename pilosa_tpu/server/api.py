@@ -223,10 +223,6 @@ class API:
         # Always-on memory watchdog (utils/memledger.MemoryWatchdog),
         # attached by cli/main.py; the health plane reports its state.
         self.watchdog = None
-        # Sentinel node-down edge tracking (sample_sentinel): which
-        # members were down at the previous sample, so the alert ring
-        # sees fire/clear transitions instead of steady-state spam.
-        self._sentinel_down_prev: set = set()
         # Cached backend label for pilosa_build_info: resolved from an
         # already-imported jax only (never forces backend init from a
         # metrics scrape).
@@ -505,7 +501,7 @@ class API:
             except CoalescerStopped:
                 # Lost the race with coalescer.stop(): serve the
                 # request directly rather than failing it. (Only
-                # this sentinel retries — a genuine executor
+                # this exception retries — a genuine executor
                 # RuntimeError must surface, not re-run.) Inline
                 # direct path, not self._query: "query" was already
                 # counted above and must not double-count.
@@ -971,20 +967,13 @@ class API:
         import sys as _sys
         from pilosa_tpu.utils.hotspots import WORKLOAD
         from pilosa_tpu.utils.memledger import LEDGER
-        from pilosa_tpu.utils.roofline import ROOFLINE
-        from pilosa_tpu.utils.sentinel import SENTINEL
         from pilosa_tpu.utils.timeline import TIMELINE
         # Telemetry rings register their own bytes (category
         # "telemetry") before the ledger publishes, so /debug/memory
         # totals cover the observability plane itself.
         TIMELINE.register_memory(LEDGER)
-        ROOFLINE.register_memory(LEDGER)
-        SENTINEL.register_memory(LEDGER)
         LEDGER.publish(self.stats)
         WORKLOAD.publish(self.stats)
-        # Roofline gauges (pilosa_roofline_*): resolved/achieved GB/s,
-        # the fraction EWMA, cohort count, and the drift counter.
-        ROOFLINE.publish(self.stats)
         # Result-cache live gauges (hit/miss/eviction counters
         # increment at event time); the rank-cache store publishes its
         # entry/byte gauges the same way.
@@ -998,9 +987,6 @@ class API:
         self.layout.publish(self.stats)
         self.stats.gauge("executor.jit_cache_size",
                          self.executor.jit_cache_size())
-        # Sentinel burn/budget/alert gauges (pilosa_slo_*,
-        # pilosa_sentinel_*) ride the same scrape-time refresh.
-        SENTINEL.publish(self.stats)
         # Process identity on /metrics: uptime (previously only in the
         # node_health JSON) and the build-info constant gauge every
         # Prometheus setup joins version rollouts against.
@@ -1086,122 +1072,6 @@ class API:
         self.refresh_memory_gauges()
         return TIMELINE.snapshot(last=last, trace_id=trace,
                                  node_id=node_id, slowest=slowest)
-
-    def debug_roofline(self) -> Dict[str, Any]:
-        """The GET /debug/roofline document (utils/roofline.py): the
-        per-opcode instruction table and per-kind byte splits priced
-        by ops/megakernel.plan_cost, per-cohort achieved bandwidth
-        EWMAs from the profiler's sampled fences, and the
-        predicted-vs-measured cost-model residuals ranked by drift."""
-        from pilosa_tpu.utils.roofline import ROOFLINE
-        node_id, _ = self._node_ident()
-        self.refresh_memory_gauges()
-        doc = ROOFLINE.snapshot()
-        doc["node"] = node_id
-        # The executor's cumulative splits beside the recorder's: the
-        # two count the same launches (the recorder LRU-bounds only
-        # its per-cohort state, never the totals), so a reader can
-        # cross-check the plane against /debug/queries.
-        ex = self.executor
-        doc["executor"] = {
-            "launchBytesGather": ex.launch_bytes_gather,
-            "launchBytesCompute": ex.launch_bytes_compute,
-            "launchBytesExpand": ex.launch_bytes_expand,
-            "launchBytesPad": ex.launch_bytes_pad,
-            "opcodeTotals": dict(ex.opcode_counts),
-            "megaLaunches": ex.mega_launches,
-            "meshLaunches": ex.mesh_launches,
-            "meshCollectiveBytes": ex.mesh_collective_bytes,
-        }
-        return doc
-
-    def sample_sentinel(self) -> None:
-        """One sentinel history tick: gather the key gauges from every
-        plane (host-side dict reads only — no device touch), hand them
-        plus the cumulative RED histograms to the sentinel, and report
-        the edge-triggered alert conditions (roofline drift, HBM
-        watermark pressure, cluster node-down). Called from the memory
-        watchdog's extra-gauges hook at its cadence, and by tests
-        directly with an injected clock."""
-        from pilosa_tpu.utils.memledger import HOST_CATEGORIES, LEDGER
-        from pilosa_tpu.utils.roofline import ROOFLINE
-        from pilosa_tpu.utils.sentinel import SENTINEL
-        if not SENTINEL.enabled:
-            return
-        rsnap = ROOFLINE.snapshot()
-        rc = self.executor.result_cache.snapshot()
-        live = padded = 0
-        for cat, t in LEDGER.totals().items():
-            if cat not in HOST_CATEGORIES:
-                live += t["bytes"]
-                padded += t["paddedBytes"]
-        hits = self.executor.rank_cache_hits
-        rebuilds = self.executor.rank_cache_rebuilds
-        coal = self.coalescer
-        gauges = {
-            "roofline_achieved_gbps": rsnap["achievedGbps"],
-            "roofline_fraction": rsnap["rooflineFraction"],
-            "result_cache_hit_ratio": rc["hitRatio"],
-            "rank_cache_hit_ratio": (hits / (hits + rebuilds)
-                                     if hits + rebuilds else 0.0),
-            "hbm_live_bytes": live,
-            "hbm_padded_bytes": padded,
-            "mesh_collective_bytes":
-                self.executor.mesh_collective_bytes,
-            "coalescer_queue_depth": (coal.queue_depth()
-                                      if coal is not None else 0),
-        }
-        snap_fn = getattr(self.stats, "snapshot", None)
-        histos = (snap_fn() or {}).get("histograms") \
-            if snap_fn is not None else None
-        SENTINEL.sample(gauges=gauges, histograms=histos)
-        flagged = sum(1 for c in rsnap["cohorts"] if c["drift"])
-        SENTINEL.note_condition(
-            "roofline.drift", flagged > 0,
-            f"{flagged} cohort(s) invert the optimizer's predicted "
-            f"cost ordering (see /debug/roofline)", kind="roofline")
-        if SENTINEL.watermark_bytes > 0:
-            SENTINEL.note_condition(
-                "hbm.pressure", live >= SENTINEL.watermark_bytes,
-                f"{live} device bytes ledgered (watermark "
-                f"{SENTINEL.watermark_bytes})", kind="memory")
-        if self.cluster is not None:
-            down = set(getattr(self.cluster, "down_ids", set()))
-            for nid in down:
-                SENTINEL.note_condition(
-                    f"cluster.node_down:{nid}", True,
-                    f"node {nid} marked down by the failure detector",
-                    kind="cluster")
-            for nid in self._sentinel_down_prev - down:
-                SENTINEL.note_condition(
-                    f"cluster.node_down:{nid}", False,
-                    f"node {nid} recovered", kind="cluster")
-            self._sentinel_down_prev = down
-
-    def debug_history(self, series: Optional[List[str]] = None,
-                      last: Optional[int] = None) -> Dict[str, Any]:
-        """The GET /debug/history document (utils/sentinel.py): the
-        bounded per-series history rings (raw + decimated tiers) plus
-        a Perfetto counter-track export (`ph:"C"`) that loads beside
-        the /debug/timeline slices."""
-        from pilosa_tpu.utils.sentinel import SENTINEL
-        node_id, _ = self._node_ident()
-        self.refresh_memory_gauges()
-        doc = SENTINEL.history(series=series, last=last)
-        doc["node"] = node_id
-        return doc
-
-    def debug_slo(self) -> Dict[str, Any]:
-        """The GET /debug/slo document (utils/sentinel.py): declared
-        objectives, per-endpoint error budgets + multi-window burn
-        rates, derived q/s + windowed latency quantiles, and the
-        bounded alert ring."""
-        from pilosa_tpu.utils.sentinel import SENTINEL
-        node_id, _ = self._node_ident()
-        self.refresh_memory_gauges()
-        doc = SENTINEL.slo_snapshot()
-        doc["node"] = node_id
-        return doc
 
     @staticmethod
     def _merge_timeline_events(pid: int, node_id: str,
@@ -1302,7 +1172,6 @@ class API:
         from pilosa_tpu.utils.hotspots import WORKLOAD
         from pilosa_tpu.utils.jaxenv import COMPILES as _COMPILES
         from pilosa_tpu.utils.memledger import LEDGER
-        from pilosa_tpu.utils.sentinel import SENTINEL as _SENTINEL
         from pilosa_tpu.utils.timeline import TIMELINE as _TIMELINE
         now = _time.time()
         if self.cluster is not None:
@@ -1375,13 +1244,13 @@ class API:
                     "foldsReordered": self.executor.opt_folds_reordered,
                     "bytesSaved": self.executor.opt_bytes_saved,
                 },
-                # Roofline attribution plane (utils/roofline.py): what
-                # the launched plans moved, and how fast. launchBytes
-                # are cumulative plan_cost splits; achievedGbps /
-                # fraction are fence-sampled EWMAs; driftFlags > 0
-                # means the optimizer's cost model currently mis-ranks
-                # cohorts on this node (see GET /debug/roofline).
-                "roofline": self._roofline_health(),
+                # What the launched megakernel plans moved: the sum
+                # of plan_cost's cumulative byte splits (a count from
+                # shapes; the splits are in GET /debug/queries).
+                "launchBytes": (self.executor.launch_bytes_gather
+                                + self.executor.launch_bytes_compute
+                                + self.executor.launch_bytes_expand
+                                + self.executor.launch_bytes_pad),
             },
             # Cross-request cache tier (executor/result_cache.py +
             # core/cache.RANK_CACHE): hit ratios and live bytes in the
@@ -1411,10 +1280,6 @@ class API:
                 "lastSampleAt": (wd.last_sample_at if wd is not None
                                  else None),
             },
-            # SLO sentinel (utils/sentinel.py): objective count, active
-            # burn-rate/condition alerts, worst current burn — the
-            # paging-relevant subset of GET /debug/slo.
-            "slo": _SENTINEL.health_stanza(),
             # Adaptive hybrid layout (core/layout.py): how many views
             # serve sparse, what re-layout reclaimed, when it last ran
             # — the capacity axis in the same health document.
@@ -1434,35 +1299,13 @@ class API:
                              if self.cluster is not None else 0),
         }
 
-    def _roofline_health(self) -> Dict[str, Any]:
-        """The compact roofline stanza embedded in node_health() — the
-        paging-relevant subset of GET /debug/roofline."""
-        from pilosa_tpu.utils.roofline import ROOFLINE
-        snap = ROOFLINE.snapshot()
-        ex = self.executor
-        return {
-            "enabled": snap["enabled"],
-            "launches": snap["launches"],
-            "fencedLaunches": snap["fencedLaunches"],
-            "launchBytes": (ex.launch_bytes_gather
-                            + ex.launch_bytes_compute
-                            + ex.launch_bytes_expand
-                            + ex.launch_bytes_pad),
-            "rooflineGbps": snap["rooflineGbps"],
-            "achievedGbps": snap["achievedGbps"],
-            "fraction": snap["rooflineFraction"],
-            "estimateOnly": snap["estimateOnly"],
-            "driftFlags": snap["driftFlags"],
-        }
-
     @staticmethod
     def _merge_health_totals(nodes: List[Dict[str, Any]]
                              ) -> Dict[str, Any]:
         tot = {"memoryBytes": 0, "paddingBytes": 0, "queueDepth": 0,
                "jitCacheSize": 0, "retraces": 0, "slowQueries": 0,
                "fragmentReads": 0, "fragmentWrites": 0,
-               "launchBytes": 0, "rooflineDriftFlags": 0,
-               "sloAlertsActive": 0, "sloAlertsFired": 0}
+               "launchBytes": 0}
         for d in nodes:
             mem = d.get("memory") or {}
             tot["memoryBytes"] += int(mem.get("totalBytes", 0))
@@ -1476,17 +1319,7 @@ class API:
             wl = d.get("workload") or {}
             tot["fragmentReads"] += int(wl.get("fragmentReads", 0))
             tot["fragmentWrites"] += int(wl.get("fragmentWrites", 0))
-            # Fleet-wide roofline rollup: total bytes attributed to
-            # megakernel launches and how many nodes currently flag
-            # cost-model drift (any nonzero is worth a look).
-            rf = ex.get("roofline") or {}
-            tot["launchBytes"] += int(rf.get("launchBytes", 0))
-            tot["rooflineDriftFlags"] += int(rf.get("driftFlags", 0))
-            # Fleet-wide alert pressure: any nonzero active count is
-            # the first number an operator reads off /cluster/health.
-            slo = d.get("slo") or {}
-            tot["sloAlertsActive"] += int(slo.get("alertsActive", 0))
-            tot["sloAlertsFired"] += int(slo.get("alertsFired", 0))
+            tot["launchBytes"] += int(ex.get("launchBytes", 0))
         return tot
 
     def cluster_health(self) -> Dict[str, Any]:
@@ -1715,94 +1548,6 @@ class API:
         tot["queryRepeatRatio"] = (
             tot["windowRepeats"] / tot["windowSeen"]
             if tot["windowSeen"] else 0.0)
-        return tot
-
-    def cluster_slo(self) -> Dict[str, Any]:
-        """The GET /cluster/slo document: one debug_slo() snapshot per
-        member — local inline, remote fanned out in parallel over the
-        internal client (the cluster_hotspots pattern) — with a fleet
-        error-budget roll-up per objective. An unreachable node is
-        REPORTED with its error, never dropped: a node whose SLO
-        surface cannot be read is itself an availability fact."""
-        import threading as _threading
-        local = self.debug_slo()
-        if self.cluster is None:
-            nodes = [{"id": self.holder.node_id, "uri": "",
-                      "healthy": True, "slo": local}]
-            return {"totalNodes": 1, "respondedNodes": 1,
-                    "nodes": nodes,
-                    "totals": self._merge_slo_totals(nodes)}
-        docs: Dict[str, Dict[str, Any]] = {}
-        down = set(getattr(self.cluster, "down_ids", set()))
-
-        def fetch(node):
-            if node.id == self.cluster.local.id:
-                docs[node.id] = {"id": node.id, "uri": node.uri,
-                                 "healthy": True, "slo": local}
-                return
-            try:
-                doc = self._client.node_slo(node.uri)
-                if not isinstance(doc, dict):
-                    raise ValueError(f"bad slo body: {doc!r}")
-                docs[node.id] = {"id": node.id, "uri": node.uri,
-                                 "healthy": True, "slo": doc}
-            except Exception as e:
-                docs[node.id] = {"id": node.id, "uri": node.uri,
-                                 "healthy": False,
-                                 "error": f"{type(e).__name__}: {e}"}
-
-        members = list(self.cluster.nodes())
-        threads = [_threading.Thread(target=fetch, args=(n,))
-                   for n in members]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        nodes = []
-        for node in members:
-            doc = docs.get(node.id,
-                           {"id": node.id, "uri": node.uri,
-                            "healthy": False, "error": "no response"})
-            doc["down"] = node.id in down
-            if doc["down"]:
-                doc["healthy"] = False
-            nodes.append(doc)
-        return {
-            "totalNodes": len(nodes),
-            "respondedNodes": sum(1 for d in nodes if "slo" in d),
-            "nodes": nodes,
-            "totals": self._merge_slo_totals(nodes),
-        }
-
-    @staticmethod
-    def _merge_slo_totals(nodes: List[Dict[str, Any]]
-                          ) -> Dict[str, Any]:
-        """Fleet error-budget roll-up over every node that RESPONDED:
-        per-objective bad/total sums re-derive one fleet-wide budget —
-        a node burning alone can hide inside a per-node average, never
-        inside a summed ratio."""
-        tot: Dict[str, Any] = {"alertsActive": 0, "alertsFired": 0,
-                               "endpoints": {}}
-        for d in nodes:
-            doc = d.get("slo") or {}
-            alerts = doc.get("alerts") or {}
-            tot["alertsActive"] += len(alerts.get("active") or [])
-            tot["alertsFired"] += int(alerts.get("fired", 0))
-            for ep in doc.get("endpoints") or []:
-                if "target" not in ep:
-                    continue
-                label = ep.get("alias") or ep["endpoint"]
-                agg = tot["endpoints"].setdefault(
-                    label, {"target": ep["target"], "total": 0,
-                            "bad": 0})
-                agg["total"] += int(ep.get("total", 0))
-                agg["bad"] += int(ep.get("bad", 0))
-        for agg in tot["endpoints"].values():
-            budget = 1.0 - agg["target"]
-            consumed = ((agg["bad"] / agg["total"]) / budget
-                        if agg["total"] and budget > 0 else 0.0)
-            agg["budgetConsumed"] = consumed
-            agg["budgetRemaining"] = max(0.0, 1.0 - consumed)
         return tot
 
     # ---------------------------------------------------------------- status

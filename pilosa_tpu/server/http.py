@@ -44,10 +44,10 @@ from pilosa_tpu.server import proto_compat, wire
 from pilosa_tpu.server.api import API, ApiError
 from pilosa_tpu.utils.timeline import TIMELINE
 
-# Per-endpoint RED/SLO latency buckets (seconds): powers of two from
+# Per-endpoint RED latency buckets (seconds): powers of two from
 # ~61 µs to 8 s — wide enough that a cold compile, a full-bank sweep
 # and a sub-ms cache hit land in different buckets.
-SLO_BUCKETS = tuple(2.0 ** e for e in range(-14, 4))
+REQUEST_BUCKETS = tuple(2.0 ** e for e in range(-14, 4))
 
 # Endpoint label normalization: path parameters collapse to
 # placeholders so `pilosa_http_request_seconds{endpoint=...}` stays a
@@ -69,9 +69,7 @@ _EP_STATIC = frozenset({
     "/", "/schema", "/status", "/info", "/version", "/index",
     "/metrics", "/batch/query", "/export", "/recalculate-caches",
     "/debug/vars", "/debug/queries", "/debug/memory", "/debug/hotspots",
-    "/debug/timeline", "/debug/roofline", "/debug/history",
-    "/debug/slo", "/cluster/health", "/cluster/hotspots",
-    "/cluster/slo",
+    "/debug/timeline", "/cluster/health", "/cluster/hotspots",
     # Internal/cluster routes are fixed strings: an explicit whitelist,
     # NOT a prefix match — unknown paths under these prefixes must fold
     # into "other" like everything else or a scanner mints series.
@@ -90,7 +88,7 @@ _EP_STATIC = frozenset({
 
 
 def endpoint_label(path: str) -> str:
-    """Bounded endpoint label for the SLO series. Unknown paths fold
+    """Bounded endpoint label for the RED series. Unknown paths fold
     into "other" — a scanner walking random URLs must not mint series."""
     if path in _EP_STATIC:
         return path
@@ -293,14 +291,15 @@ class Handler(BaseHTTPRequestHandler):
         self._dispatch("DELETE")
 
     def send_response(self, code, message=None):
-        # Remember the response status for the per-endpoint SLO
-        # histogram (each request sets it anew before _observe_slo
+        # Remember the response status for the per-endpoint RED
+        # histogram (each request sets it anew before _observe_request
         # reads it, so connection reuse cannot leak a stale code).
-        self._slo_status = code
+        self._status = code
         super().send_response(code, message)
 
-    def _observe_slo(self, method: str, path: str, dur: float) -> None:
-        """One RED/SLO observation per request:
+    def _observe_request(self, method: str, path: str, dur: float
+                         ) -> None:
+        """One RED observation per request:
         pilosa_http_request_seconds{endpoint,status} with pow2 buckets.
         Slow non-query endpoints cross-link their trace id into the
         slow-query ring (the query routes already record there with a
@@ -311,9 +310,9 @@ class Handler(BaseHTTPRequestHandler):
         if stats is None:
             return
         ep = endpoint_label(path)
-        status = getattr(self, "_slo_status", 200)
+        status = getattr(self, "_status", 200)
         stats.with_tags(f"endpoint:{ep}", f"status:{status}").histogram(
-            "http_request_seconds", dur, buckets=SLO_BUCKETS)
+            "http_request_seconds", dur, buckets=REQUEST_BUCKETS)
         lqt = getattr(api, "long_query_time", 0.0)
         if lqt > 0 and dur > lqt and ep not in (
                 "/index/{index}/query", "/batch/query"):
@@ -351,8 +350,8 @@ class Handler(BaseHTTPRequestHandler):
             if began:
                 self.api.end_request(rec, err)
             try:
-                self._observe_slo(method, path,
-                                  time.perf_counter() - t0)
+                self._observe_request(method, path,
+                                      time.perf_counter() - t0)
             except Exception:
                 pass  # metrics must never fail a served response
 
@@ -419,10 +418,9 @@ class Handler(BaseHTTPRequestHandler):
                                 api.executor.opt_folds_reordered,
                             "optBytesSaved":
                                 api.executor.opt_bytes_saved,
-                            # Roofline plane rollup (plan_cost splits
-                            # + per-opcode instruction totals over
-                            # every megakernel launch) — the full
-                            # bandwidth view lives at /debug/roofline.
+                            # plan_cost's byte splits + per-opcode
+                            # instruction totals over every
+                            # megakernel launch: counts from shapes.
                             "launchBytesGather":
                                 api.executor.launch_bytes_gather,
                             "launchBytesCompute":
@@ -463,33 +461,6 @@ class Handler(BaseHTTPRequestHandler):
                     last=int(q["last"]) if q.get("last") else None,
                     trace=q.get("trace"),
                     slowest=q.get("slowest") == "1"))
-            elif path == "/debug/roofline":
-                # Kernel cost & roofline attribution plane
-                # (utils/roofline.py): per-opcode byte/instruction
-                # totals, per-cohort achieved bandwidth vs the device
-                # roofline, and predicted-vs-measured cost-model
-                # residuals ranked by drift.
-                self._json(api.debug_roofline())
-            elif path == "/debug/history":
-                # Metrics history plane (utils/sentinel.py): bounded
-                # per-series rings (raw + decimated) with a Perfetto
-                # counter-track export. ?series=a,b filters, ?last=N
-                # bounds the raw points per series.
-                self._check_args(q, "series", "last")
-                series = [s for s in
-                          (q.get("series") or "").split(",") if s]
-                self._json(api.debug_history(
-                    series=series or None,
-                    last=int(q["last"]) if q.get("last") else None))
-            elif path == "/debug/slo":
-                # SLO engine surface (utils/sentinel.py): objectives,
-                # error budgets, multi-window burn rates, alert ring.
-                self._json(api.debug_slo())
-            elif path == "/cluster/slo":
-                # Coordinator-merged fleet SLO view: one slo snapshot
-                # per node + the fleet error-budget roll-up,
-                # unreachable nodes reported not dropped.
-                self._json(api.cluster_slo())
             elif path == "/cluster/timeline":
                 # Cluster lifecycle timeline (no trace id): merged
                 # membership/failure/resize events from every member —

@@ -31,14 +31,11 @@ class Config:
     # disables both.
     long_query_time: float = 0.0
     # Per-query execution profiler (utils/profile.py). ?profile=true on
-    # POST /index/{i}/query always profiles with device-time fencing;
-    # sample_every additionally fences 1 in N unforced queries so
-    # /metrics carries real device timings under production traffic
-    # (0 = no sampling: the hot path pays zero block_until_ready
-    # fences). slow_ring bounds the /debug/queries ring. TOML accepts a
-    # [profile] table (sample_every / slow_ring) or the flat profile_*
-    # spelling; env uses PILOSA_TPU_PROFILE_SAMPLE_EVERY etc.
-    profile_sample_every: int = 0
+    # POST /index/{i}/query profiles with a block_until_ready fence
+    # after each program; no other query is fenced and no key here can
+    # turn a fence on. slow_ring bounds the /debug/queries ring. TOML
+    # accepts a [profile] table (slow_ring) or the flat profile_*
+    # spelling.
     profile_slow_ring: int = 128
     # Serving-path query coalescer (server/coalescer.py): concurrent
     # single-query POSTs arriving within the batching window share one
@@ -191,22 +188,6 @@ class Config:
     timeline_enabled: bool = True
     timeline_ring: int = 256        # request records kept
     timeline_sample_every: int = 1  # record 1 in N requests (1 = all)
-    # Roofline attribution plane (utils/roofline.py): per-launch HBM
-    # bytes from ops/megakernel.plan_cost joined with the profiler's
-    # SAMPLED device fences into achieved-GB/s / roofline-fraction
-    # estimators (served at GET /debug/roofline, gauges on /metrics).
-    # `gbps = 0` auto-resolves the roofline from the attached device
-    # kind (utils/roofline.PEAK_HBM_GBPS); a kind that is not in the
-    # table has no roofline and publishes no fraction. No fences of
-    # its own: with profile_sample_every =
-    # 0 and no ?profile=true traffic the plane only accumulates byte
-    # counters. TOML accepts a [roofline] table (enabled / gbps /
-    # ewma_alpha / max_cohorts) or the flat roofline_* spelling; env
-    # uses PILOSA_TPU_ROOFLINE_*.
-    roofline_enabled: bool = True
-    roofline_gbps: float = 0.0       # 0 = auto-resolve by device kind
-    roofline_ewma_alpha: float = 0.25  # per-cohort bandwidth EWMA
-    roofline_max_cohorts: int = 256  # LRU bound on per-cohort state
     # Metrics (reference server/config.go Metric.Service/Host: expvar |
     # statsd | none — "mem" is the expvar equivalent)
     metric_service: str = "mem"   # mem | statsd | none
@@ -269,25 +250,6 @@ class Config:
     # PILOSA_TPU_FAILPOINTS="site=spec;site=spec". Any entry enables
     # the test-only POST /internal/failpoints surface.
     failpoints: dict = field(default_factory=dict)
-    # SLO objectives (utils/sentinel.py): endpoint -> objective spec,
-    # e.g. [slo] query = "99.9% < 25ms". Keys are endpoint labels
-    # ("/index/{index}/query", quoted in TOML) or their last path
-    # segment as a short alias ("query"). Also settable via
-    # PILOSA_TPU_SLO="query=99.9% < 25ms;metrics=99% < 100ms".
-    # Declaring any objective makes the sentinel judge that endpoint's
-    # RED histogram with multi-window burn-rate alerts.
-    slo: dict = field(default_factory=dict)
-    # SLO & regression sentinel (utils/sentinel.py): bounded metrics
-    # history rings sampled at the watchdog cadence + the burn-rate
-    # alert engine. Host-side dict arithmetic only — never fences the
-    # device. `enabled = false` is the kill switch (no sampling, no
-    # alerts; the surfaces serve empty documents). TOML accepts a
-    # [sentinel] table (enabled / ring / decimate / alert_ring) or the
-    # flat sentinel_* spelling; env uses PILOSA_TPU_SENTINEL_*.
-    sentinel_enabled: bool = True
-    sentinel_ring: int = 720       # raw points kept per series
-    sentinel_decimate: int = 10    # raw:decimated tier ratio
-    sentinel_alert_ring: int = 256  # fire/clear events kept
     advertise: str = ""  # URI peers reach us at; default <scheme>://<bind>
     # TLS (reference server/config.go:120-166: TLS.CertificatePath,
     # TLS.CertificateKeyPath, TLS.SkipCertificateVerification; listener
@@ -329,8 +291,6 @@ class Config:
             raise ValueError("coalescer window/deadline must be >= 0")
         if self.coalescer_max_batch < 1 or self.coalescer_max_queue < 1:
             raise ValueError("coalescer max_batch/max_queue must be >= 1")
-        if self.profile_sample_every < 0:
-            raise ValueError("profile sample_every must be >= 0")
         if self.profile_slow_ring < 1:
             raise ValueError("profile slow_ring must be >= 1")
         if self.telemetry_sample_every_s < 0:
@@ -361,12 +321,6 @@ class Config:
         if self.timeline_ring < 1 or self.timeline_sample_every < 1:
             raise ValueError(
                 "timeline ring/sample_every must be >= 1")
-        if self.roofline_gbps < 0:
-            raise ValueError("roofline gbps must be >= 0 (0 = auto)")
-        if not 0 < self.roofline_ewma_alpha <= 1:
-            raise ValueError("roofline ewma_alpha must be in (0, 1]")
-        if self.roofline_max_cohorts < 1:
-            raise ValueError("roofline max_cohorts must be >= 1")
         if not 0 <= self.telemetry_hbm_watermark <= 1:
             raise ValueError(
                 "telemetry hbm_watermark must be in [0, 1]")
@@ -391,19 +345,6 @@ class Config:
                     raise ValueError(
                         f"failpoint site names must be strings: "
                         f"{site!r}")
-        if self.slo:
-            from pilosa_tpu.utils.sentinel import parse_objective
-            for ep, spec in self.slo.items():
-                if not isinstance(ep, str) or not ep:
-                    raise ValueError(
-                        f"slo endpoint keys must be strings: {ep!r}")
-                parse_objective(str(spec))  # ValueError on bad spec
-        if self.sentinel_ring < 2:
-            raise ValueError("sentinel ring must be >= 2")
-        if self.sentinel_decimate < 1:
-            raise ValueError("sentinel decimate must be >= 1")
-        if self.sentinel_alert_ring < 8:
-            raise ValueError("sentinel alert_ring must be >= 8")
 
     def server_ssl_context(self):
         """ssl.SSLContext for the listener, or None when TLS is off
@@ -473,10 +414,9 @@ def load_config(path: Optional[str] = None,
         settable = {f.name for f in fields(cfg)}
         for k, v in data.items():
             k = k.replace("-", "_")
-            if k in ("failpoints", "slo"):
-                # Keys carry dots/slashes ("client.connect",
-                # "/index/{index}/query") — these tables stay dicts
-                # instead of flattening to field names.
+            if k == "failpoints":
+                # Keys carry dots ("client.connect") — this table
+                # stays a dict instead of flattening to field names.
                 if not isinstance(v, dict):
                     raise ValueError(
                         f"[{k}] must be a table of "

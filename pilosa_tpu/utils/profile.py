@@ -10,15 +10,15 @@ the attribution layer:
 - ``QueryProfile``: a per-query tree of ``ProfileNode``s the executor
   fills in as it runs — one op node per PQL call, with ``eval`` children
   per compiled tree program recording planning time, jit cache hit/miss,
-  dispatch time, H2D upload bytes and (when device sampling is on) a
-  fenced device-execution time. Materialization time and D2H bytes land
-  on the op node during finalize.
-- ``Profiler``: process-wide policy + sinks. Decides which queries get
-  the ``block_until_ready`` device fence (``?profile=true`` always; a
-  configurable 1-in-N sample otherwise — unsampled queries pay ZERO
-  fences, the hot path stays fully async), feeds every finished profile
-  into the stats client (``executor.*`` timings/counters -> the
-  ``pilosa_executor_*`` Prometheus series) and keeps the bounded
+  dispatch time, H2D upload bytes and (under ``?profile=true``) the
+  host's wait in a ``block_until_ready`` fence. Materialization time and
+  D2H bytes land on the op node during finalize.
+- ``Profiler``: process-wide policy + sinks. Gives the
+  ``block_until_ready`` device fence to ``?profile=true`` queries and to
+  no other (every other query pays ZERO fences, the hot path stays
+  fully async), feeds every finished profile into the stats client
+  (``executor.*`` timings/counters -> the ``pilosa_executor_*``
+  Prometheus series) and keeps the bounded
   slow-query ring served at ``GET /debug/queries`` (the structured
   replacement for the printf-only slow-query log; reference
   ``LongQueryTime``, api.go:1048).
@@ -92,7 +92,7 @@ class ProfileNode:
         node = ProfileNode(name, **attrs)
         # graftlint: disable=GL008 — not long-lived state: the tree
         # lives for ONE query (bounded by its plan size) and only
-        # sampled trees outlive the request, inside the slow-query
+        # slow queries' trees outlive the request, inside the slow-query
         # ring, which is itself the bound.
         self.children.append(node)
         return node
@@ -118,9 +118,11 @@ class QueryProfile:
         self.index = index
         self.pql = pql_text(query)
         self.shards = list(shards) if shards is not None else None
-        # Device fencing on: every compiled tree program is followed by
-        # a block_until_ready fence so deviceS is the real XLA execution
-        # time, not the enqueue time. Off: zero fences (hot path).
+        # Device fencing on (?profile=true only): every compiled tree
+        # program is followed by a block_until_ready fence, and deviceS
+        # is the host's wait in it — an upper bound on the program's
+        # device time only when the queue ahead of it was empty. Off:
+        # zero fences (hot path).
         self.sample_device = bool(sample_device)
         # forced = explicit ?profile=true: the profile embeds in the
         # response, propagates to remote nodes, and is never deduped by
@@ -276,7 +278,7 @@ class QueryProfile:
         with self._frag_lock:
             # graftlint: disable=GL008 — one entry per cluster node,
             # on an object that lives for ONE query (see ProfileNode:
-            # only sampled profiles outlive the request, inside the
+            # only slow queries' profiles outlive the request, inside the
             # bounded slow-query ring).
             self.node_fragments[node_id] = fragment
 
@@ -340,9 +342,10 @@ class Profiler:
     """Process-wide profiling policy + sinks (one per API instance).
 
     ``begin`` is on the path of EVERY query: it builds a passive
-    QueryProfile (a few host-side objects; no device interaction) and
-    decides device sampling. ``observe`` is the single funnel every
-    query path reports through — it feeds the stats client, maintains
+    QueryProfile (a few host-side objects; no device interaction);
+    only ``force`` (?profile=true) makes it fence. ``observe`` is the
+    single funnel every query path reports through — it feeds the
+    stats client, maintains
     the process-wide retrace counter, and keeps the slow-query ring
     (replacing the previously copy-pasted SLOW QUERY printf blocks in
     server/api.py)."""
@@ -352,43 +355,25 @@ class Profiler:
         from pilosa_tpu.utils.tracing import NopTracer
         self.stats = stats or NopStatsClient()
         self.tracer = tracer or NopTracer()
-        self.sample_every = 0   # fence 1-in-N unforced queries; 0 = none
         self._lock = make_lock("Profiler._lock")
-        self._seq = 0
         self._ring: deque = deque(maxlen=128)
         # Cumulative slow-query count: the ring is bounded (its length
         # saturates at capacity), so rate consumers — /internal/health,
         # the fleet totals — need the running total.
         self.slow_total = 0
 
-    def configure(self, sample_every: Optional[int] = None,
-                  ring_size: Optional[int] = None) -> None:
-        if sample_every is not None:
-            # Under the lock: begin() divides by it inside the same
-            # critical section that bumps _seq.
-            with self._lock:
-                self.sample_every = max(0, int(sample_every))
-            # The roofline plane scales its total-device-time estimate
-            # by the fence rate (the sampled="true" bias warning made
-            # quantitative).
-            from pilosa_tpu.utils.roofline import ROOFLINE
-            ROOFLINE.note_sample_every(self.sample_every)
-        if ring_size is not None:
-            with self._lock:
-                self._ring = deque(self._ring, maxlen=max(1, int(ring_size)))
+    def configure(self, ring_size: int) -> None:
+        with self._lock:
+            self._ring = deque(self._ring, maxlen=max(1, int(ring_size)))
 
     # ----------------------------------------------------------- lifecycle
 
     def begin(self, index: str, query: Any,
               shards: Optional[Sequence[int]] = None,
               force: bool = False) -> QueryProfile:
-        sample = bool(force)
-        if not sample and self.sample_every > 0:
-            with self._lock:
-                self._seq += 1
-                sample = self._seq % self.sample_every == 0
         tid = getattr(self.tracer, "current_trace_id", lambda: None)()
-        return QueryProfile(index, query, shards, sample_device=sample,
+        return QueryProfile(index, query, shards,
+                            sample_device=bool(force),
                             forced=bool(force), trace_id=tid)
 
     def observe(self, index: str, query: Any, duration: float,
@@ -410,15 +395,10 @@ class Profiler:
             st.timing("executor.dispatch", p.totals["dispatch"])
             st.timing("executor.materialize", p.totals["materialize"])
             if p.sample_device:
-                # Fed ONLY by sampled fences (1-in-N + forced), never
-                # total device time: the label says so, and the gauge
-                # beside it carries the rate a reader must scale by
-                # (0 = only ?profile=true fences; see the roofline
-                # plane's deviceSecondsEstimate for the scaled view).
+                # Fed ONLY by ?profile=true fences, never total
+                # device time: the label says so.
                 st.with_tags("sampled:true").timing(
                     "executor.device", p.totals["device"])
-                st.gauge("executor.device_sample_every",
-                         self.sample_every)
             if p.jit_hits:
                 st.count("executor.jit_hit", p.jit_hits)
             if p.jit_misses:
@@ -444,7 +424,7 @@ class Profiler:
                     error: Optional[BaseException] = None,
                     kind: str = "query",
                     trace_id: Optional[str] = None) -> None:
-        """`trace_id` cross-links profile-less records (the HTTP SLO
+        """`trace_id` cross-links profile-less records (the HTTP
         layer's slow non-query endpoints) into the timeline plane: the
         ring record's traceId opens the request in
         /debug/timeline?trace=... and /cluster/timeline/{trace}."""

@@ -65,7 +65,7 @@ class NopStatsClient(StatsClient):
 # Default bucket upper bounds for MemStatsClient histograms (+Inf
 # implied). Powers of two because the original histogrammed quantities
 # are batch / fusion group sizes, which pad to powers of two by
-# construction. Callers with a different distribution (the HTTP SLO
+# construction. Callers with a different distribution (the HTTP endpoint
 # latency histograms) pass their own `buckets=`; the bucket set is
 # fixed per metric family at first observation.
 HISTOGRAM_BUCKETS = (1, 2, 4, 8, 16, 32, 64)
@@ -492,32 +492,6 @@ METRIC_HELP: Dict[str, str] = {
         "Seconds spent in garbage collections, every generation.",
     "pilosa_runtime_uptime_seconds_total":
         "Monotonic seconds since the runtime monitor started.",
-    "pilosa_roofline_achieved_gbps":
-        "Fence-sampled achieved HBM bandwidth, GB/s.",
-    "pilosa_roofline_cohorts":
-        "Cohort-signature entries tracked by the roofline recorder.",
-    "pilosa_roofline_drift_flagged":
-        "Cohorts currently inverting the optimizer's predicted cost "
-        "ordering.",
-    "pilosa_roofline_drift_total":
-        "Cumulative cost-model drift flags raised.",
-    "pilosa_roofline_fraction":
-        "EWMA of achieved bandwidth over the device roofline.",
-    "pilosa_roofline_gbps":
-        "Configured or auto-resolved device roofline, GB/s.",
-    "pilosa_sentinel_alerts_active":
-        "Alerts currently active in the sentinel (burn-rate + "
-        "conditions).",
-    "pilosa_sentinel_alerts_fired":
-        "Cumulative alerts fired since process start.",
-    "pilosa_sentinel_series":
-        "History series tracked by the sentinel ring store.",
-    "pilosa_slo_burn_rate":
-        "Error-budget burn rate over the trailing window (1.0 = "
-        "burning exactly at budget), labeled by endpoint and window.",
-    "pilosa_slo_error_budget_remaining":
-        "Fraction of the error budget left over the retained history "
-        "span, per endpoint objective.",
     "pilosa_xla_cache_hits_total":
         "XLA compiles answered from the persistent compilation cache.",
     "pilosa_xla_compile_seconds_total":

@@ -1,12 +1,13 @@
 """The device-time measurement helpers (benches/benchenv.py) and the HBM
-peak table they judge against (utils/roofline.resolve_roofline)."""
+peak table they judge against (benchmark/harness/peaks.py, the only one
+the repo keeps)."""
 
 from types import SimpleNamespace
 
 import pytest
 
 from benches import benchenv
-from pilosa_tpu.utils.roofline import UnknownDeviceKind, resolve_roofline
+from benches.benchenv import UnknownDeviceKind, resolve_roofline
 
 
 def _dev(kind):
@@ -15,7 +16,7 @@ def _dev(kind):
 
 @pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v5e"])
 def test_resolve_roofline_knows_the_v5e(kind):
-    assert resolve_roofline(_dev(kind)) == (819.0, kind.lower())
+    assert resolve_roofline(_dev(kind)) == (819.0, kind)
 
 
 @pytest.mark.parametrize("kind", ["cpu", "TPU v4", "TPU v6 lite", ""])

@@ -33,6 +33,25 @@ def test_config_precedence(tmp_path, monkeypatch):
         load_config(str(bad))
 
 
+@pytest.mark.parametrize("toml_text, key", [
+    ("[roofline]\ngbps = 819.0\n", "roofline.'gbps'"),
+    ("[sentinel]\nring = 360\n", "sentinel.'ring'"),
+    ('[slo]\nquery = "99.9% < 25ms"\n', "slo.'query'"),
+    ("[profile]\nsample_every = 100\n", "profile.'sample_every'"),
+    ("profile_sample_every = 100\n", "'profile_sample_every'"),
+])
+def test_a_config_for_a_deleted_plane_is_refused_by_name(
+        tmp_path, toml_text, key):
+    """No compatibility shim: a file that still configures the roofline
+    recorder, the sentinel, an objective or the sampled fence meets the
+    loader's rule for any unknown key, and the error names it."""
+    p = tmp_path / "old.toml"
+    p.write_text(toml_text)
+    with pytest.raises(ValueError, match="unknown config key") as e:
+        load_config(str(p))
+    assert key in str(e.value)
+
+
 def test_import_export_check_inspect(tmp_path, capsys):
     csv_file = tmp_path / "data.csv"
     csv_file.write_text("1,10\n1,20\n2,10\n")

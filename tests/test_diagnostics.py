@@ -250,6 +250,52 @@ def test_slow_query_logged(tmp_path):
     holder.close()
 
 
+def test_drain_telemetry_order_once_and_reentrant(tmp_holder):
+    """One drain dumps every ring exactly once, in plane order
+    (watchdog -> profiler -> workload -> timeline -> tracer); a second
+    call is a no-op."""
+    from pilosa_tpu.cli.main import drain_telemetry
+    from pilosa_tpu.server.api import API
+    from tests.test_memledger import _LogStub
+
+    api = API(tmp_holder, stats=MemStatsClient())
+    api.profiler.record_slow("i", "Count(Row(f=1))", 2.5)
+
+    class _Tracer:
+        stops = 0
+
+        def stop(self):
+            self.stops += 1
+
+    api.tracer = _Tracer()
+    log = _LogStub()
+    drain_telemetry(api, watchdog=None, logger=log)
+    # Ordering: the profiler's slow-query line precedes the workload
+    # summary.
+    slow = next(i for i, l in enumerate(log.lines)
+                if "Count(Row(f=1))" in l)
+    first_workload = next(i for i, l in enumerate(log.lines)
+                          if l.startswith("workload:"))
+    assert slow < first_workload
+    assert api.tracer.stops == 1
+    # Re-entrant second drain: nothing dumps twice, tracer not
+    # re-stopped.
+    n = len(log.lines)
+    drain_telemetry(api, watchdog=None, logger=log)
+    assert len(log.lines) == n
+    assert api.tracer.stops == 1
+
+
+def test_metrics_carry_uptime_and_build_info(live_server):
+    import urllib.request
+    base, _api, _h = live_server
+    with urllib.request.urlopen(base + "/metrics", timeout=30) as r:
+        met = r.read().decode()
+    assert "pilosa_process_uptime_seconds" in met
+    assert 'pilosa_build_info{' in met and 'version="' in met \
+        and 'backend="' in met
+
+
 def test_statsd_client_wire_format():
     """DataDog-flavored statsd datagrams over UDP (reference
     statsd/statsd.go:41: prefix 'pilosa.', |c/|g/|ms types, #tags)."""

@@ -98,6 +98,20 @@ def test_end_to_end_http(server):
     assert schema["indexes"] == []
 
 
+@pytest.mark.parametrize("path", ["/debug/roofline", "/debug/history",
+                                  "/debug/slo", "/cluster/slo"])
+def test_no_route_serves_a_host_clock_under_a_device_name(server, path):
+    """The recorder and the sentinel went with their routes: a path
+    that served one is a 404 like any unknown path, and folds into the
+    `other` endpoint label."""
+    from pilosa_tpu.server.http import endpoint_label
+    base, _ = server
+    with pytest.raises(urllib.error.HTTPError) as e:
+        req(base, "GET", path)
+    assert e.value.code == 404
+    assert endpoint_label(path) == "other"
+
+
 def test_http_errors(server):
     base, _ = server
     with pytest.raises(urllib.error.HTTPError) as e:

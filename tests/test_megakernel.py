@@ -17,6 +17,7 @@ from pilosa_tpu.core.field import FieldOptions
 from pilosa_tpu.core.holder import Holder
 from pilosa_tpu.executor import Executor
 from pilosa_tpu.executor import megakernel as megamod
+from pilosa_tpu.ops import megakernel as mk
 from pilosa_tpu.ops.bitset import SHARD_WIDTH
 
 N_ROWS = 16
@@ -232,7 +233,7 @@ def test_profile_attribution_mega_fields(ex):
 
 def test_post_dispatch_failure_isolates_per_member(ex, monkeypatch):
     """An async device failure surfacing AFTER the launch (at the
-    sampled _fence_device inside attribution) must land on the
+    ?profile=true _fence_device inside attribution) must land on the
     cohort's members as per-request errors — the _FuseGroup.run
     isolation contract — and leave the executor serving."""
     from pilosa_tpu.executor import executor as exmod
@@ -276,7 +277,6 @@ def test_the_slab_reads_no_bank_through_a_gather(width):
     bank once a row is past 1 MiB, and a leaf's bank may be 2 GiB."""
     import jax
     import jax.numpy as jnp
-    from pilosa_tpu.ops import megakernel as mk
     bank = jnp.zeros((16, 2, width), jnp.uint32)   # the slab is [8, ...]
     args = ((bank,), (jnp.zeros(4, jnp.int32),), jnp.zeros(8, jnp.int32),
             jnp.zeros((4, 4), jnp.int32), jnp.zeros(2, jnp.int32),
@@ -597,3 +597,180 @@ def test_a_device_that_cannot_initialise_is_an_error(monkeypatch):
     # An explicit setting never asks the backend.
     monkeypatch.setenv("PILOSA_TPU_MEGAKERNEL", "0")
     assert megamod._default_enabled() is False
+
+
+# ---------------------------------------------------------------------------
+# ops/megakernel.plan_cost: a launch's HBM bytes priced from the verified
+# IR's shapes (counts, no clock), and the executor counters it feeds.
+
+
+def _plan(*, n_slots, widths, instrs, n_instrs, n_regs, out_count,
+          out_row, lane_count_widths=(), lane_row_widths=(),
+          slots=None, xbanks=(), xslots=(), n_xslots=0):
+    """Hand-built Plan: plan_cost reads only host-side fields, so dense
+    banks can be empty stand-ins."""
+    if slots is None:
+        slots = tuple(np.array([i], np.int32) for i in range(n_slots))
+    w = np.zeros(n_regs, np.int32)
+    w[:len(widths)] = widths
+    return mk.Plan(
+        banks=tuple(None for _ in range(n_slots)), slots=slots,
+        widths=w, instrs=np.asarray(instrs, np.int32),
+        out_count=np.asarray(out_count, np.int32),
+        out_row=np.asarray(out_row, np.int32),
+        n_slots=n_slots, n_regs=n_regs, n_instrs=n_instrs,
+        lane_count_widths=lane_count_widths,
+        lane_row_widths=lane_row_widths,
+        xbanks=xbanks, xslots=xslots, n_xslots=n_xslots)
+
+
+def test_plan_cost_full_opcode_table_exact():
+    """Every opcode priced by its verifier read set: ZERO writes only
+    (1 row), COPY reads one (2), AND/OR/XOR/ANDNOT read two (3),
+    THRESH is the accumulate opcode — dst is a READ operand too (4)."""
+    S, W = 2, 8
+    row = S * W * 4                                   # 64
+    instrs = [
+        (mk.OP_AND, 2, 0, 1), (mk.OP_OR, 3, 0, 1),
+        (mk.OP_XOR, 4, 0, 1), (mk.OP_ANDNOT, 5, 2, 3),
+        (mk.OP_ZERO, 6, 0, 0), (mk.OP_COPY, 2, 4, 0),
+        (mk.OP_THRESH, 6, 2, 3),
+        (mk.OP_ZERO, 7, 7, 7),                        # pad tail
+    ]
+    plan = _plan(n_slots=2, widths=[3, 8], instrs=instrs, n_instrs=7,
+                 n_regs=8, out_count=[6, 7], out_row=[4],
+                 lane_count_widths=(5,), lane_row_widths=(8,))
+    cost = mk.plan_cost(plan, S, W)
+    # Gather: per dense slot, live masked words read + one row written.
+    assert cost["gatherBytes"] == (S * 3 * 4 + row) + (S * 8 * 4 + row)
+    # Compute: 4 three-operand ops + ZERO(1) + COPY(2) + THRESH(4),
+    # plus 1 real count lane (popcount row + S*4 out) and 1 real row
+    # lane (2 rows).
+    assert cost["computeBytes"] == (4 * 3 * row + 1 * row + 2 * row
+                                    + 4 * row
+                                    + (row + S * 4) + 2 * row)
+    assert cost["expandBytes"] == 0
+    # Pad: 1 slab register above the high-water mark (the spare), 1 pad
+    # instruction, 1 pad count lane; row lanes have no padding.
+    assert cost["padBytes"] == row + row + (row + S * 4)
+    assert cost["totalBytes"] == (cost["gatherBytes"]
+                                  + cost["computeBytes"]
+                                  + cost["expandBytes"]
+                                  + cost["padBytes"])
+    assert cost["opcodeHist"] == {"and": 1, "or": 1, "xor": 1,
+                                  "andnot": 1, "zero": 1, "copy": 1,
+                                  "thresh": 1}   # REAL instrs only
+    assert cost["nInstrs"] == 7
+    # Ledger restatement: slab/live-slab/plan bytes as registered.
+    assert cost["slabBytes"] == mk.slab_nbytes(8, S, W)
+    assert cost["liveSlabBytes"] == mk.slab_nbytes(2, S, W)
+    assert cost["planBytes"] == plan.plan_nbytes
+
+
+def test_plan_cost_expand_scatter_exact():
+    """OP_EXPAND traffic: per expand register the sparse bank's full
+    (pos, starts) buffers + one scatter-written row; per instruction
+    one row read + one written."""
+    S, W = 2, 8
+    row = S * W * 4
+    pos = np.zeros(10, np.int32)                      # 40 bytes
+    starts = np.zeros(5, np.int32)                    # 20 bytes
+    instrs = [
+        (mk.OP_EXPAND, 4, 1, 0), (mk.OP_EXPAND, 5, 2, 0),
+        (mk.OP_AND, 6, 4, 5),
+        (mk.OP_ZERO, 7, 7, 7),                        # pad tail
+    ]
+    plan = _plan(n_slots=1, widths=[4], instrs=instrs, n_instrs=3,
+                 n_regs=8, out_count=[], out_row=[6],
+                 lane_row_widths=(4,),
+                 xbanks=((pos, starts),),
+                 xslots=(np.array([0, 1], np.int32),), n_xslots=2)
+    cost = mk.plan_cost(plan, S, W)
+    assert cost["gatherBytes"] == S * 4 * 4 + row
+    # 2 expand instrs * 2 rows + 2 expand regs * (pos + starts + row).
+    assert cost["expandBytes"] == 2 * 2 * row \
+        + 2 * (pos.nbytes + starts.nbytes + row)
+    assert cost["computeBytes"] == 3 * row + 2 * row  # AND + row lane
+    assert cost["padBytes"] == row + row              # spare + pad instr
+    assert cost["liveSlabBytes"] == mk.slab_nbytes(3, S, W)  # slot+2x
+
+
+def test_plan_cost_zero_reads_opaque_xbank_buffers():
+    """Device-opaque (pos, starts) stubs without .nbytes price as 0
+    instead of raising — attribution never kills a launch."""
+    S, W = 1, 4
+
+    class _Opaque:  # no nbytes, no shape
+        pass
+
+    plan = _plan(n_slots=0, widths=[], slots=(),
+                 instrs=[(mk.OP_EXPAND, 1, 0, 0)], n_instrs=1,
+                 n_regs=4, out_count=[], out_row=[1],
+                 lane_row_widths=(4,),
+                 xbanks=((_Opaque(), _Opaque()),),
+                 xslots=(np.array([0], np.int32),), n_xslots=1)
+    cost = mk.plan_cost(plan, S, W)
+    row = S * W * 4
+    assert cost["expandBytes"] == 2 * row + 1 * row   # buffers priced 0
+    assert cost["totalBytes"] > 0
+
+
+def test_launch_cost_metrics_families(ex, monkeypatch):
+    """/metrics invariants: the byte splits export as one counter
+    family split by kind=, opcodes as one family split by op= — never
+    a family per kind/op (bounded label sets, test_stats.py rules).
+    The executor's counters are the launch's plan_cost, and what
+    plan_cost calls pad waste is exactly what the launch registers
+    with the memory ledger as fusion_pad padding."""
+    from pilosa_tpu.utils.memledger import LEDGER
+    from pilosa_tpu.utils.stats import MemStatsClient, prometheus_text
+
+    costs, tracked = [], []
+    orig_cost, orig_track = mk.plan_cost, LEDGER.track
+
+    def cost_spy(plan, n_shards, w_mega, mesh=None):
+        costs.append(orig_cost(plan, n_shards, w_mega, mesh=mesh))
+        return costs[-1]
+
+    def track_spy(obj, category, nbytes, padded_bytes=0, **meta):
+        if category == "fusion_pad":
+            tracked.append(int(padded_bytes))
+        return orig_track(obj, category, nbytes, padded_bytes, **meta)
+
+    monkeypatch.setattr(mk, "plan_cost", cost_spy)
+    monkeypatch.setattr(LEDGER, "track", track_spy)
+    ex.stats = MemStatsClient()
+    ex.execute_batch_shaped(MIXED)
+    assert ex.mega_launches == 1 and len(costs) == 1
+    cost = costs[0]
+    assert cost["gatherBytes"] > 0 and cost["computeBytes"] > 0
+    assert ex.launch_bytes_gather == cost["gatherBytes"]
+    assert ex.launch_bytes_compute == cost["computeBytes"]
+    assert ex.opcode_counts == dict(cost["opcodeHist"])
+    assert tracked == [cost["slabBytes"] - cost["liveSlabBytes"]
+                       + cost["planBytes"]]
+    prom = prometheus_text(ex.stats)
+    for kind in ("gather", "compute", "pad"):
+        assert f'pilosa_executor_launch_bytes_total{{kind="{kind}"}}' \
+            in prom, prom
+    assert 'pilosa_executor_opcode_total{op="' in prom
+    assert prom.count("# TYPE pilosa_executor_launch_bytes_total") == 1
+    assert prom.count("# TYPE pilosa_executor_opcode_total") == 1
+
+
+def test_cost_rides_profile_tree_and_slow_ring(ex):
+    """Eval nodes of a megakernel launch carry launchBytes +
+    opcodeHist, so the slow-query ring shows what a launch MOVED."""
+    from pilosa_tpu.utils.profile import QueryProfile
+
+    profs = [QueryProfile(i, q) for i, q, _s in MIXED]
+    ex.execute_batch(MIXED, profiles=profs)
+    assert ex.mega_launches == 1
+    for p in profs:
+        evals = [n for op in p.ops for n in op.children
+                 if n.name.startswith("eval:")]
+        assert evals, p.ops
+        node = evals[0]
+        assert node.attrs["launchBytes"] > 0
+        assert isinstance(node.attrs["opcodeHist"], dict)
+        assert sum(node.attrs["opcodeHist"].values()) > 0

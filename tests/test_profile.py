@@ -1,6 +1,6 @@
 """Per-query execution profiler tests (utils/profile.py + executor/
-server wiring): profile tree shape, device-fence sampling policy, the
-slow-query ring, /debug/queries + ?profile=true HTTP surfaces, and the
+server wiring): profile tree shape, the ?profile=true-only device
+fence, the slow-query ring, /debug/queries + ?profile=true HTTP surfaces, and the
 pilosa_executor_* metrics feed."""
 
 import json
@@ -66,60 +66,58 @@ def test_profile_tree_count_intersect_two_shards(tmp_holder):
 
 
 def test_no_fence_without_sampling_profile(tmp_holder, monkeypatch):
-    """Acceptance: profiling disabled adds no block_until_ready fences
-    on the hot path."""
+    """Acceptance: only ?profile=true fences. No other query adds a
+    block_until_ready on the hot path, and no config key can turn a
+    fence on: the config has no field that names a sampling rate and
+    the profiler takes none."""
+    import dataclasses
+    import inspect
+
     import pilosa_tpu.executor.executor as ex
+    from pilosa_tpu.utils.config import Config
 
     _seed_two_shards(tmp_holder)
     api = API(tmp_holder, stats=MemStatsClient())
+    # Repeats must DISPATCH; the result cache would serve them
+    # without any device work.
+    api.executor.result_cache.enabled = False
     fences = []
     real = ex._fence_device
     monkeypatch.setattr(ex, "_fence_device",
                         lambda out: fences.append(1) or real(out))
-    api.query("p", "Count(Row(f=1))")
+    for _ in range(6):
+        api.query("p", "Count(Row(f=1))")
     assert fences == []  # passive profile: zero fences
     api.query("p", "Count(Row(f=1))", profile=True)
     assert fences  # forced profile fences
-
-
-def test_sample_every_fences_one_in_n(tmp_holder, monkeypatch):
-    import pilosa_tpu.executor.executor as ex
-
-    _seed_two_shards(tmp_holder)
-    api = API(tmp_holder, stats=MemStatsClient())
-    # Sampled fences require the repeats to DISPATCH; the result
-    # cache would serve queries 2-6 without any device work.
-    api.executor.result_cache.enabled = False
-    api.profiler.configure(sample_every=3)
-    fences = []
-    monkeypatch.setattr(ex, "_fence_device",
-                        lambda out: fences.append(1) or 0.0)
-    for _ in range(6):
-        api.query("p", "Count(Row(f=1))")
-    assert len(fences) == 2  # queries 3 and 6
+    profile_keys = [f.name for f in dataclasses.fields(Config)
+                    if f.name.startswith("profile_")]
+    assert profile_keys == ["profile_slow_ring"]
+    assert list(inspect.signature(Profiler.configure).parameters) \
+        == ["self", "ring_size"]
+    assert not api.profiler.begin("p", "Count(Row(f=1))").sample_device
+    assert api.profiler.begin("p", "Count(Row(f=1))",
+                              force=True).sample_device
 
 
 def test_device_seconds_carries_sampled_label(tmp_holder):
-    """Satellite (ISSUE 18): pilosa_executor_device_seconds is fed
-    ONLY by 1-in-N sampled fences, so the series carries an explicit
-    sampled="true" label and the live fence rate exports beside it —
-    a dashboard scaling device time must multiply by the rate."""
+    """pilosa_executor_device_seconds is fed ONLY by ?profile=true
+    fences, so the series carries an explicit sampled="true" label: it
+    is never total device time."""
     _seed_two_shards(tmp_holder)
     api = API(tmp_holder, stats=MemStatsClient())
     api.executor.result_cache.enabled = False
-    api.profiler.configure(sample_every=2)
-    for _ in range(4):
+    for _ in range(3):
         api.query("p", "Count(Row(f=1))")
+    assert "pilosa_executor_device_seconds" not in \
+        prometheus_text(api.stats)
+    api.query("p", "Count(Row(f=1))", profile=True)
     prom = prometheus_text(api.stats)
     line = next(l for l in prom.splitlines()
                 if l.startswith("pilosa_executor_device_seconds{"))
     assert 'sampled="true"' in line, line
     # No unlabeled twin series: one family, one label shape.
     assert "pilosa_executor_device_seconds{quantile" not in prom
-    assert "pilosa_executor_device_sample_every 2" in prom
-    # The recorder learned the rate through Profiler.configure.
-    from pilosa_tpu.utils.roofline import ROOFLINE
-    assert ROOFLINE.sample_every == 2
 
 
 def test_retrace_counter_and_metrics(tmp_holder):

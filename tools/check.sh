@@ -429,96 +429,6 @@ with tempfile.TemporaryDirectory() as d:
 print("plan-optimizer smoke OK")
 EOF
 
-step "roofline smoke (mixed burst -> /debug/roofline populated, ledger-consistent bytes, counter tracks)"
-# The ISSUE 18 cost & roofline attribution plane: a 32-query mixed
-# burst with sampled device fences must populate /debug/roofline
-# (per-opcode totals, per-cohort bandwidth), the plan_cost pad split
-# must agree EXACTLY with the ledger's fusion_pad registration
-# (slabBytes - liveSlabBytes + planBytes == padded_bytes), and the
-# timeline export must carry the ph:"C" bandwidth counter tracks.
-PILOSA_TPU_RESULT_CACHE=0 PILOSA_TPU_MEGAKERNEL=1 \
-    PILOSA_TPU_PLAN_VERIFY=on JAX_PLATFORMS=cpu \
-    python - <<'EOF' || fail=1
-import tempfile
-import numpy as np
-from pilosa_tpu.core.holder import Holder
-from pilosa_tpu.executor import Executor
-from pilosa_tpu.ops import megakernel as mk
-from pilosa_tpu.utils.memledger import LEDGER
-from pilosa_tpu.utils.profile import QueryProfile
-from pilosa_tpu.utils.roofline import ROOFLINE
-from pilosa_tpu.ops.bitset import SHARD_WIDTH
-
-ROOFLINE.reset(); ROOFLINE.configure(enabled=True)
-costs = []
-orig_cost = mk.plan_cost
-def spy(plan, n_shards, w_mega, mesh=None):
-    c = orig_cost(plan, n_shards, w_mega, mesh=mesh)
-    costs.append(c)
-    return c
-mk.plan_cost = spy
-# The fusion_pad entry dies with the launch object (ledger tracks by
-# liveness), so capture what _launch REGISTERS rather than racing the
-# finalizer.
-tracked = []
-orig_track = LEDGER.track
-def track_spy(obj, category, nbytes, padded_bytes=0, **meta):
-    if category == "fusion_pad":
-        tracked.append((int(nbytes), int(padded_bytes)))
-    return orig_track(obj, category, nbytes, padded_bytes, **meta)
-LEDGER.track = track_spy
-with tempfile.TemporaryDirectory() as d:
-    h = Holder(d); h.open()
-    idx = h.create_index("roof")
-    f = idx.create_field("f"); g = idx.create_field("g")
-    rng = np.random.default_rng(11)
-    rows = rng.integers(0, 8, 4000).astype(np.uint64)
-    cols = rng.integers(0, 2 * SHARD_WIDTH, 4000).astype(np.uint64)
-    f.import_bits(rows, cols); g.import_bits(rows[::2], cols[::2])
-    idx.add_existence(cols)
-    ex = Executor(h)
-    reqs = []
-    for k in range(32):
-        r = k % 8
-        reqs.append(("roof", [f"Count(Row(f={r}))", f"Row(g={r})",
-                              f"Count(Intersect(Row(f={r}), Row(g={r})))",
-                              f"Count(Union(Row(f={r}), Row(g={r})))"
-                              ][(k // 8) % 4], None))
-    profs = [QueryProfile(i, q, sample_device=True) for i, q, s in reqs]
-    out = ex.execute_batch_shaped(reqs, profiles=profs)
-    assert ex.mega_launches == 1 and len(costs) == 1, \
-        (ex.mega_launches, len(costs))
-    cost = costs[0]
-    # Byte split sanity: every split priced, totals add up.
-    assert cost["totalBytes"] == (cost["gatherBytes"] + cost["computeBytes"]
-                                  + cost["expandBytes"] + cost["padBytes"])
-    assert cost["gatherBytes"] > 0 and cost["computeBytes"] > 0
-    # Ledger consistency: what plan_cost calls pad waste is EXACTLY
-    # what _launch registered as fusion_pad padding.
-    assert len(tracked) == 1 and tracked[0][1] == \
-        (cost["slabBytes"] - cost["liveSlabBytes"] + cost["planBytes"]), \
-        (tracked, cost["slabBytes"], cost["liveSlabBytes"],
-         cost["planBytes"])
-    # /debug/roofline document: per-opcode + per-cohort populated,
-    # fenced bandwidth measured.
-    snap = ROOFLINE.snapshot()
-    assert snap["launches"] == 1 and snap["fencedLaunches"] == 1, snap
-    assert snap["opcodeTotals"] and snap["cohorts"], snap
-    assert snap["bytesByKind"]["gather"] == cost["gatherBytes"]
-    assert snap["achievedGbps"] > 0, snap["achievedGbps"]
-    # The CPU backend has no HBM peak on record: no roofline, and no
-    # fraction of a guessed one.
-    assert snap["estimateOnly"] and snap["rooflineGbps"] == 0, snap
-    # Executor counters mirror the same split.
-    assert ex.launch_bytes_gather == cost["gatherBytes"]
-    assert ex.opcode_counts == dict(cost["opcodeHist"])
-    del out
-    h.close()
-mk.plan_cost = orig_cost
-LEDGER.track = orig_track
-print("roofline smoke OK")
-EOF
-
 step "plan-fuzz gate (corpus replay + deterministic sweep + digest stability)"
 # The plan-space differential oracle (tools/plan_fuzz): committed
 # corpus replays clean, then a seeded sweep — every batch bit-exact
@@ -642,9 +552,10 @@ with tempfile.TemporaryDirectory() as d:
 print("result-cache smoke OK")
 EOF
 
-step "telemetry smoke (live /debug/memory + /cluster/health)"
+step "telemetry smoke (live /debug/memory + /cluster/health + doctor self-diff)"
 JAX_PLATFORMS=cpu python - <<'EOF' || fail=1
 import json
+import pathlib
 import tempfile
 import urllib.request
 import numpy as np
@@ -653,6 +564,7 @@ from pilosa_tpu.ops.bitset import SHARD_WIDTH
 from pilosa_tpu.server import API, serve
 from pilosa_tpu.utils.memledger import LEDGER, MemoryWatchdog
 from pilosa_tpu.utils.stats import MemStatsClient
+from tools.doctor import main as doctor_main, snapshot_bundle
 
 with tempfile.TemporaryDirectory() as d:
     h = Holder(d); h.open()
@@ -683,6 +595,13 @@ with tempfile.TemporaryDirectory() as d:
     met = urllib.request.urlopen(base + "/metrics").read().decode()
     assert 'pilosa_memory_bytes{category="bank"}' in met
     assert "pilosa_memory_padding_bytes" in met
+    # Doctor bundle: all surfaces captured, self-diff empty.
+    bundle = snapshot_bundle(base)
+    errs = [k for k, s in bundle["surfaces"].items() if "error" in s]
+    assert not errs, errs
+    p = pathlib.Path(d) / "bundle.json"
+    p.write_text(json.dumps(bundle, default=str))
+    assert doctor_main(["diff", str(p), str(p)]) == 0
     srv.shutdown(); srv.server_close(); h.close()
 print("telemetry smoke OK")
 EOF
@@ -797,7 +716,7 @@ with tempfile.TemporaryDirectory() as d:
     assert s["requests"] == 16, s
     assert s["stageMedianS"]["dispatch"] > 0, s
     assert s["byCall"]["Count"]["requests"] == 16, s
-    # The cumulative stage histograms and the per-endpoint SLO
+    # The cumulative stage histograms and the per-endpoint RED
     # histograms export; the host-clock idle gauge is gone.
     met = urllib.request.urlopen(base + "/metrics").read().decode()
     assert "# TYPE pilosa_request_stage_seconds histogram" in met
@@ -808,126 +727,6 @@ with tempfile.TemporaryDirectory() as d:
     assert 'endpoint="/index/{index}/query"' in met
     srv.shutdown(); srv.server_close(); api.coalescer.stop(); h.close()
 print("timeline smoke OK")
-EOF
-
-step "sentinel smoke (burn-rate fire/clear on client.5xx + /debug/history + doctor self-diff)"
-# The SLO plane end to end on a 2-node in-process cluster with an
-# injected sentinel clock (no wall-clock sleeps): history ring fills
-# monotonically, a client.5xx failpoint burst fires the burn-rate
-# alert pair and recovery past the slow window clears it, and a
-# doctor bundle diffed against itself is empty (volatile keys
-# normalized).
-JAX_PLATFORMS=cpu python - <<'EOF' || fail=1
-import json
-import pathlib
-import tempfile
-import time
-import urllib.error
-
-from pilosa_tpu.utils.failpoints import FAILPOINTS
-from pilosa_tpu.utils.sentinel import SENTINEL
-from tests.test_cluster import _seed_bits, req, run_cluster
-from tools.doctor import main as doctor_main, snapshot_bundle
-
-clock = [1000.0]
-SENTINEL.reset()
-# 100 s threshold sits past every finite pow-2 latency bucket, so the
-# objective degrades to availability-only: CI latency noise cannot
-# burn budget here — only the injected 5xx burst can.
-SENTINEL.configure(enabled=True, objectives={"query": "99.9% < 100s"},
-                   clock=lambda: clock[0])
-with tempfile.TemporaryDirectory() as d:
-    nodes = run_cluster(pathlib.Path(d), 2, replica_n=1)
-    try:
-        base = nodes[0].uri
-        _seed_bits(base)
-        api = nodes[0].api
-        sent = [0]
-
-        def settle():
-            # The SLO observation lands AFTER the response bytes hit
-            # the socket; wait for every sent query to be recorded so
-            # a straggler 5xx cannot leak past a sample into the
-            # recovery window.
-            def landed():
-                return sum(
-                    h["count"] for k, h in
-                    api.stats.snapshot()["histograms"].items()
-                    if k.startswith("http_request_seconds")
-                    and "/index/{index}/query" in k)
-            deadline = time.time() + 10.0
-            while landed() < sent[0] and time.time() < deadline:
-                time.sleep(0.005)
-            assert landed() >= sent[0], (landed(), sent[0])
-
-        for _ in range(8):      # warm jit/caches before the baseline
-            sent[0] += 1
-            req(base, "POST", "/index/ci/query", b"Count(Row(f=1))")
-
-        def burst(n=32, expect_5xx=False):
-            bad = 0
-            for _ in range(n):
-                sent[0] += 1
-                try:
-                    req(base, "POST", "/index/ci/query",
-                        b"Count(Row(f=1))")
-                except urllib.error.HTTPError as e:
-                    assert e.code >= 500, e.code
-                    bad += 1
-            assert (bad > 0) == expect_5xx, bad
-            settle()
-            clock[0] += 30.0
-            api.sample_sentinel()
-
-        settle()
-        api.sample_sentinel()   # baseline sample
-        clock[0] += 30.0
-        burst(); burst()        # healthy traffic, >=3 samples total
-        hist = req(base, "GET", "/debug/history")
-        assert hist["samples"] >= 3, hist["samples"]
-        assert len(hist["series"]) >= 3, sorted(hist["series"])
-        for s in hist["series"].values():
-            ts = [p[0] for p in s["points"]]
-            assert ts == sorted(ts), "non-monotone history timestamps"
-        doc = req(base, "GET", "/debug/slo")
-        assert doc["alerts"]["active"] == []
-
-        # Fail the partner's client leg: fan-out queries now 500.
-        port1 = nodes[1].uri.rsplit(":", 1)[1]
-        FAILPOINTS.arm("client.5xx", f"partition(:{port1})")
-        burst(expect_5xx=True)
-        FAILPOINTS.disarm_all()
-        doc = req(base, "GET", "/debug/slo")
-        active = {a["key"] for a in doc["alerts"]["active"]}
-        assert active == {"slo-burn:query:300s",
-                          "slo-burn:query:1800s"}, active
-        met = req(base, "GET", "/metrics", raw=True).decode()
-        assert "pilosa_sentinel_alerts_active 2" in met
-
-        # Recovery: jump past the 6 h slow window; hysteresis clears.
-        clock[0] += 22000.0
-        burst()
-        doc = req(base, "GET", "/debug/slo")
-        assert doc["alerts"]["active"] == [], doc["alerts"]
-        assert doc["alerts"]["cleared"] == 2, doc["alerts"]
-        # The burst stays visible in the consumed budget after clear.
-        ep = next(e for e in doc["endpoints"] if "target" in e)
-        assert ep["budgetConsumed"] > 0, ep
-
-        # Doctor bundle: all surfaces captured, self-diff empty.
-        bundle = snapshot_bundle(base)
-        errs = [k for k, s in bundle["surfaces"].items()
-                if "error" in s]
-        assert not errs, errs
-        p = pathlib.Path(d) / "bundle.json"
-        p.write_text(json.dumps(bundle, default=str))
-        assert doctor_main(["diff", str(p), str(p)]) == 0
-    finally:
-        FAILPOINTS.disarm_all()
-        SENTINEL.reset()
-        for nd in nodes:
-            nd.stop()
-print("sentinel smoke OK")
 EOF
 
 step "hybrid-layout smoke (skewed corpus -> re-layout -> ledger delta + kill-switch identity)"

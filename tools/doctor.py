@@ -1,11 +1,9 @@
 #!/usr/bin/env python3
-"""One-command diagnostic bundles + regression verdicts.
+"""One-command diagnostic bundles.
 
-The regression-sentinel leg of the SLO plane (utils/sentinel.py):
-where the in-process sentinel watches trends *inside* one process
-lifetime, this tool makes the whole observability surface portable —
-one timestamped JSON bundle per incident, diffable against another
-capture, judgeable against BASELINE.json.
+Makes a live server's observability surface portable: one timestamped
+JSON bundle per incident, diffable against another capture, checked
+for internal consistency.
 
     # Snapshot every debug surface of a live server into one bundle
     python tools/doctor.py snapshot --base http://localhost:10101 \
@@ -15,8 +13,7 @@ capture, judgeable against BASELINE.json.
     # exit 0 iff no differences remain
     python tools/doctor.py diff before.json after.json
 
-    # Judge a bundle: internal-consistency checks + comparison against
-    # BASELINE.json's published numbers; exit 1 on any REGRESSED/FAIL
+    # Judge a bundle: internal-consistency checks; exit 1 on any FAIL
     python tools/doctor.py baseline bundle.json
 
 Stdlib only (urllib) — the tool must run on a box that has nothing
@@ -33,15 +30,12 @@ from typing import Any, Dict, List, Optional, Tuple
 
 # Every surface a bundle captures: bundle key -> path. A surface that
 # errors is RECORDED with its error, never dropped — a 500 on
-# /debug/slo is itself a diagnostic fact.
+# /debug/memory is itself a diagnostic fact.
 SURFACES = [
     ("memory", "/debug/memory"),
     ("queries", "/debug/queries"),
     ("hotspots", "/debug/hotspots"),
     ("timeline", "/debug/timeline"),
-    ("roofline", "/debug/roofline"),
-    ("history", "/debug/history"),
-    ("slo", "/debug/slo"),
     ("health", "/internal/health"),
     ("cluster_health", "/cluster/health"),
     # Identity/config group: schema + versions + cluster topology.
@@ -56,8 +50,8 @@ SURFACES = [
 # server diff down to the differences that matter.
 VOLATILE_KEYS = frozenset({
     "t", "ts", "time", "now", "uptimeS", "ageS", "lastSampleAt",
-    "lastRunAt", "firedAt", "capturedAt", "samples", "samplesTaken",
-    "traceEvents", "points", "decimated", "_received",
+    "lastRunAt", "capturedAt", "samples", "samplesTaken",
+    "traceEvents", "_received",
 })
 
 
@@ -133,12 +127,9 @@ def _get(doc: Any, *keys: str, default: Any = None) -> Any:
     return doc
 
 
-def judge_bundle(bundle: Dict[str, Any],
-                 baseline: Optional[Dict[str, Any]] = None,
-                 tolerance: float = 0.25) -> List[Tuple[str, str, str]]:
-    """Internal-consistency + baseline verdicts:
-    (check, PASS|FAIL|REGRESSED|SKIP, detail) triples. Any FAIL or
-    REGRESSED makes the CLI exit nonzero."""
+def judge_bundle(bundle: Dict[str, Any]) -> List[Tuple[str, str, str]]:
+    """Internal-consistency verdicts: (check, PASS|FAIL|SKIP, detail)
+    triples. Any FAIL makes the CLI exit nonzero."""
     verdicts: List[Tuple[str, str, str]] = []
     surfaces = bundle.get("surfaces", {})
 
@@ -161,52 +152,15 @@ def judge_bundle(bundle: Dict[str, Any],
             total == int(mem.get("totalBytes", -1)),
             f"sum(categories)={total} totalBytes="
             f"{mem.get('totalBytes')}")
-        add("memory.sentinel-ledgered", "telemetry" in cats,
-            f"telemetry category bytes="
-            f"{_get(cats, 'telemetry', 'bytes', default=0)}")
     else:
         add("memory.totals-consistent", None, "no memory surface",
             skip=True)
-
-    slo = _get(surfaces, "slo", "doc")
-    if isinstance(slo, dict):
-        active = _get(slo, "alerts", "active", default=[]) or []
-        add("slo.no-active-alerts", not active,
-            f"{len(active)} active: "
-            f"{[a.get('key') for a in active]}" if active
-            else "0 active alerts")
-    else:
-        add("slo.no-active-alerts", None, "no slo surface", skip=True)
 
     health = _get(surfaces, "health", "doc")
     if isinstance(health, dict):
         add("health.healthy", bool(health.get("healthy")),
             f"state={health.get('state')}")
 
-    published = (baseline or {}).get("published") or {}
-    if not published:
-        add("baseline.published", None,
-            "BASELINE.json has no published numbers yet", skip=True)
-    else:
-        # Published numbers compare against the bundle's own metrics
-        # namespace (bundle["metrics"], written by bench/doctor
-        # integrations) with a relative tolerance; a metric the bundle
-        # does not carry is reported, not silently passed.
-        ours = bundle.get("metrics") or {}
-        for name, ref in published.items():
-            if not isinstance(ref, (int, float)):
-                continue
-            got = ours.get(name)
-            if not isinstance(got, (int, float)):
-                add(f"baseline.{name}", None,
-                    f"bundle carries no metric {name!r}", skip=True)
-                continue
-            ok = got >= ref * (1.0 - tolerance)
-            verdicts.append((
-                f"baseline.{name}",
-                "PASS" if ok else "REGRESSED",
-                f"got {got:g} vs published {ref:g} "
-                f"(tolerance {tolerance:.0%})"))
     return verdicts
 
 
@@ -243,16 +197,11 @@ def cmd_diff(args) -> int:
 def cmd_baseline(args) -> int:
     with open(args.bundle) as f:
         bundle = json.load(f)
-    baseline = None
-    if args.baseline:
-        with open(args.baseline) as f:
-            baseline = json.load(f)
-    verdicts = judge_bundle(bundle, baseline=baseline,
-                            tolerance=args.tolerance)
+    verdicts = judge_bundle(bundle)
     width = max(len(c) for c, _s, _d in verdicts)
     bad = 0
     for check, status, detail in verdicts:
-        if status in ("FAIL", "REGRESSED"):
+        if status == "FAIL":
             bad += 1
         print(f"{check:<{width}}  {status:<9} {detail}")
     print(f"doctor: {len(verdicts)} checks, {bad} failing")
@@ -281,14 +230,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     d.set_defaults(fn=cmd_diff)
 
     b = sub.add_parser("baseline",
-                       help="judge a bundle: consistency checks + "
-                            "BASELINE.json comparison")
+                       help="judge a bundle: consistency checks")
     b.add_argument("bundle")
-    b.add_argument("--baseline", default="BASELINE.json",
-                   help="published-numbers file (default "
-                        "BASELINE.json; '' skips)")
-    b.add_argument("--tolerance", type=float, default=0.25,
-                   help="relative regression tolerance")
     b.set_defaults(fn=cmd_baseline)
 
     args = p.parse_args(argv)
