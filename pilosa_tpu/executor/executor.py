@@ -502,13 +502,14 @@ class _Frontier:
     si: Optional[np.ndarray]
     rows: List[tuple]
 
-    def chunk(self, c0: int, c1: int) -> tuple:
+    def chunk(self, c0: int, c1: int, put) -> tuple:
         """(src, pi, prev, si) of prefixes c0:c1, index vectors
-        uploaded."""
+        uploaded; a spilled chunk's prefixes [p, S, w] go up through
+        `put` (`Executor._put_prefixes`)."""
         src = self.src
         pi = None if self.pi is None else self.pi[c0:c1]
         if isinstance(src, np.ndarray):
-            src, pi = (upload(src[pi]),), None
+            src, pi = (put(src[pi]),), None
         return (src, None if pi is None else upload(pi), self.prev,
                 None if self.si is None else upload(self.si[c0:c1]))
 
@@ -830,9 +831,6 @@ class Executor:
         # counts; the caches involved are repaired from storage).
         self.topn_selfchecks = 0
         self.topn_selfcheck_mismatches = 0
-        # Times a GroupBy frontier outgrew GROUPBY_CHUNK_BYTES and was
-        # spilled to host memory (re-uploaded per expansion chunk).
-        self.groupby_spill_events = 0
         # Cluster mode installs a resolver that allocates keys on the
         # translation primary (reference: primary-owned TranslateFile with
         # chained replication, translate.go:56,400). None = local stores.
@@ -923,18 +921,19 @@ class Executor:
         COMPILES.note_key(program, key)
         self._tls.jit_miss = str(key)[:200]
 
-    def _dispatch_span(self, program: str):
+    def _dispatch_span(self, program: str, **attrs):
         """The `dispatch` stage around one enqueue of `program`:
         `jit=miss` plus the readable key when this thread just missed
         the jit cache (the call then traces and compiles), else
         `jit=hit`; `mesh_devices` is how many devices the one enqueue
-        drives."""
+        drives; `attrs` are the launch's own (how a GroupBy level was
+        cut: `prefixes`, `rows` or `lanes`, `chunk_of`)."""
         key = self._tls.__dict__.pop("jit_miss", None)
-        if key is None:
-            return TIMELINE.stage("dispatch", program=program, jit="hit",
-                                  mesh_devices=self.mesh_devices)
-        return TIMELINE.stage("dispatch", program=program, jit="miss",
-                              key=key, mesh_devices=self.mesh_devices)
+        if key is not None:
+            attrs["key"] = key
+        return TIMELINE.stage("dispatch", program=program,
+                              jit="hit" if key is None else "miss",
+                              mesh_devices=self.mesh_devices, **attrs)
 
     def _profile(self):
         """The QueryProfile attached to the current thread's in-flight
@@ -4114,7 +4113,7 @@ class Executor:
 
         levels = [0]    # level programs launched (executor.groupby_levels)
 
-        def _jit(key, builder, span="groupby"):
+        def _jit(key, builder, span="groupby", **cut):
             fn = self._jit_get(key)
             if fn is None:
                 # "gb_cnt0:(3, 16, 48)" -> program "groupby_cnt0".
@@ -4125,7 +4124,7 @@ class Executor:
 
             def call(*args):
                 levels[0] += span == "groupby"
-                with self._dispatch_span(span):
+                with self._dispatch_span(span, **cut):
                     return fn(*args)
             return call
 
@@ -4139,7 +4138,12 @@ class Executor:
                 return np.asarray(dev)
 
         n_shards, depth_n = len(shards), len(child_rows)
-        per_prefix = max(1, n_shards * wmin * 4)    # bytes of [S, wmin]
+        # Bytes ONE device holds of a prefix [S, wmin]: the prefix
+        # arrays [p, S, W] and the group masks [g, S, W] are split along
+        # S like the banks they are cut from, so every chunk below is
+        # priced as a bank is (`_bank_device_bytes`) and a chip of a
+        # host cuts a level as a lone chip with its shards does.
+        per_prefix = max(1, self._bank_device_bytes((1, n_shards, wmin)))
         # child_slots[d]: the bank slots of child d's rows, beside
         # child_rows[d]'s ids. A child pruned by the filter keeps the
         # rows the filter meets, padded to a multiple of eight with the
@@ -4154,7 +4158,8 @@ class Executor:
                 sweep = _jit(
                     f"gb_prune:{bank.array.shape}:{wmin}",
                     lambda b, f: masked_row_counts(b[..., :wmin],
-                                                   f[..., :wmin]))
+                                                   f[..., :wmin]),
+                    level="prune", rows=len(ids))
                 met = _host(sweep(bank.array, filter_words))[
                     child_slots[d]] > 0
                 kept = [r for r, m in zip(ids, met) if m]
@@ -4172,16 +4177,21 @@ class Executor:
             return _jit(
                 f"gb_cnt0:{bank.shape}:{slots.shape[0]}:{wmin}",
                 lambda b, sl: popcount(pick_rows(wmin, (b, sl)),
-                                       axis=(-2, -1))
+                                       axis=(-2, -1)),
+                level="cnt0", rows=int(slots.shape[0])
             )(bank, slots)
 
-        def count_level(name, chunk, bank, slots):
-            """One level program over one chunk of the frontier:
-            (prefixes [p, S, wmin], counts [p, R]) — the chunk's
-            prefixes gathered and ANDed (`_gb_prefixes`) from `chunk`'s
-            resident operands, and |prefix ∧ row| for the R rows at
-            `slots` of the level's resident `bank`. Keyed by every
-            operand's shape and every index vector's length."""
+        def count_level(name, frontier, c0, chunk_p, bank, slots):
+            """One level program over the chunk of `chunk_p` prefixes
+            of the frontier from c0 on: (prefixes [p, S, wmin], counts
+            [p, R]) — the chunk's prefixes gathered and ANDed
+            (`_gb_prefixes`) from its resident operands, and
+            |prefix ∧ row| for the R rows at `slots` of the level's
+            resident `bank`. Keyed by every operand's shape and every
+            index vector's length; its `dispatch` span says how the
+            level was cut."""
+            n = len(frontier.rows)
+            chunk = frontier.chunk(c0, c0 + chunk_p, self._put_prefixes)
             shapes = ":".join(_gb_shape(a) for a in (*chunk, bank, slots))
 
             def run(src, pi, prev, si, b, sl):
@@ -4190,7 +4200,10 @@ class Executor:
                     jnp.bitwise_and(pre[:, None],
                                     pick_rows(wmin, (b, sl))[None]),
                     axis=(-2, -1))
-            return _jit(f"gb_{name}:{shapes}:{wmin}", run)(
+            return _jit(
+                f"gb_{name}:{shapes}:{wmin}", run, level=name,
+                prefixes=min(chunk_p, n - c0), rows=int(slots.shape[0]),
+                chunk_of=f"{c0 // chunk_p + 1}/{-(-n // chunk_p)}")(
                 *chunk, bank, slots)
 
         # The frontier: the prefixes that survived the levels so far, as
@@ -4226,8 +4239,8 @@ class Executor:
             n_out = kept_bytes = 0
             spilled = False
             for c0 in range(0, len(frontier.rows), chunk_p):
-                pre, counts = count_level(
-                    "exp", frontier.chunk(c0, c0 + chunk_p), bank, slots)
+                pre, counts = count_level("exp", frontier, c0, chunk_p,
+                                          bank, slots)
                 nz = _host(counts).ravel() > 0
                 keep_idx = np.flatnonzero(nz)
                 if len(keep_idx) == 0:
@@ -4249,10 +4262,10 @@ class Executor:
                     for k in keep_idx)
                 kept_rows.extend([(-1,) * (depth + 1)] * pad)
                 n_out += pre.shape[0]
-                kept_bytes += pre.nbytes
+                kept_bytes += self._bank_device_bytes(pre.shape)
                 if not spilled and kept_bytes > self.GROUPBY_CHUNK_BYTES:
                     spilled = True
-                    self.groupby_spill_events += 1
+                    self._count("executor.groupby_spills", 1)
                     outs = [_host(o) for o in outs]
                 outs.append(_host(pre) if spilled else pre)
             if not outs:
@@ -4286,8 +4299,8 @@ class Executor:
                 break
             pre = None
             if counts is None:
-                pre, dev = count_level(
-                    "cntN", frontier.chunk(c0, c0 + chunk_p), bank, slots)
+                pre, dev = count_level("cntN", frontier, c0, chunk_p,
+                                       bank, slots)
                 chunk_counts = _host(dev)  # [p, R]
             else:
                 chunk_counts = counts[c0:c0 + chunk_p]
@@ -4324,6 +4337,16 @@ class Executor:
             return results
 
         return _Pending(finalize, arrays=sums.arrays())
+
+    def _put_prefixes(self, host: np.ndarray):
+        """A spilled chunk of GroupBy prefixes [p, S, w] back on the
+        device: under a mesh split along S as the level programs' other
+        operands are, never whole onto device 0 for the jit to
+        re-shard."""
+        if self.mesh is None:
+            return upload(host)
+        with transfer("h2d", int(host.nbytes)):
+            return self.mesh.put_row(host)
 
     def _count(self, name: str, n: int) -> None:
         if self.stats is not None:
@@ -4372,9 +4395,10 @@ class Executor:
             from pilosa_tpu.ops.bitset import (masked_row_counts_multi,
                                                popcount)
             depth, w, k = self.depth, self.width, Executor.GROUPSUM_PLANES
-            n_shards = self.bank.shape[-2]
+            # A launch's masks [g, S, w], by what ONE device holds.
             g_max = max(1, Executor.GROUPSUM_CHUNK_BYTES
-                        // (n_shards * w * 4))
+                        // self.ex._bank_device_bytes(
+                            (1, self.bank.shape[-2], w)))
 
             def run(pre, pi, bank, si, plane_bank, sel):
                 planes = pick_rows(w, (plane_bank, sel))  # [depth + 1, S, w]
@@ -4406,7 +4430,10 @@ class Executor:
                         f"gb_sum:{lanes}:{_gb_shape(pre)}:"
                         f"{self.bank.shape}:{self.planes.shape}:"
                         f"d{depth}:{w}",
-                        run, span="groupby_sum")
+                        run, span="groupby_sum", lanes=lanes,
+                        prefixes=0 if pre is None else int(pre.shape[0]),
+                        chunk_of=f"{g0 // g_max + 1}/"
+                                 f"{-(-len(picked) // g_max)}")
                     out = fn(pre, None if pre is None else upload(idx[0]),
                              self.bank, upload(self.slots[idx[1]]),
                              self.planes, self.sel)

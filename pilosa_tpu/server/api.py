@@ -176,10 +176,11 @@ class API:
         for form in ("compare", "gather"):
             self.stats.with_tags(f"form:{form}").count(
                 "executor.pbank_form", 0)
-        # ... and a GroupBy's: groups answered, level programs, and the
-        # group-sum launches of `aggregate=Sum(field=f)` with the
-        # (group, plane) rows they counted.
-        for name in ("groupby_groups", "groupby_levels",
+        # ... and a GroupBy's: groups answered, level programs, levels
+        # whose prefixes moved to host memory, and the group-sum
+        # launches of `aggregate=Sum(field=f)` with the (group, plane)
+        # rows they counted.
+        for name in ("groupby_groups", "groupby_levels", "groupby_spills",
                      "groupsum_launches", "groupsum_plane_rows"):
             self.stats.count(f"executor.{name}", 0)
         # ... and the write path's: writes applied by call, what each

@@ -283,13 +283,19 @@ def test_group_by_frontier_spills_to_host(ex, monkeypatch):
         f.import_bits(np.array(rows_l, np.uint64),
                       np.array(cols_l, np.uint64))
     q = "GroupBy(Rows(a), Rows(b), Rows(c))"
+    from pilosa_tpu.utils.stats import MemStatsClient
+    e.stats = MemStatsClient()
+
+    def spills():
+        return e.stats.snapshot()["counters"].get(
+            "executor.groupby_spills", 0)
     (want,) = e.execute("gs", q)
-    assert e.groupby_spill_events == 0
+    assert spills() == 0
     monkeypatch.setattr(type(e), "GROUPBY_CHUNK_BYTES", 1 << 14)
     e._jit_cache = {k: v for k, v in e._jit_cache.items()
                     if not k.startswith("gb_")}
     (got,) = e.execute("gs", q)
-    assert e.groupby_spill_events > 0  # frontier really left the device
+    assert spills() > 0  # frontier really left the device
     # ... and came back chunk by chunk: a spilled chunk's prefixes are
     # gathered on the host, so its program takes no index vector for
     # them (None where the key holds that vector's length).

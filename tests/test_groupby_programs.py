@@ -261,7 +261,7 @@ def test_level_programs_and_nothing_else(served, monkeypatch, case):
     counters = e.stats.snapshot()["counters"]
     assert counters["executor.groupby_levels"] >= 2 * len(first["names"])
     if case == "frontier_spilled":
-        assert e.groupby_spill_events >= 2
+        assert counters["executor.groupby_spills"] >= 2
     if case == "child_pruned_by_the_filter":
         assert any(k.startswith("gb_prune:") for k in e._jit_cache)
         # Several prefix arrays of the level before, read end to end.

@@ -17,7 +17,7 @@ BENCH = os.path.join(REPO, "benchmark")
 SWEEP_CELLS = ["taxi-chip.topn-sweep", "taxi-host4.topn-sweep",
                "chem-chip.tanimoto-sweep", "ssb-chip.flights",
                "taxi-live-chip.report-ingest",
-               "chem-lib-chip.tanimoto-library"]
+               "chem-lib-chip.tanimoto-library", "ssb-host4.flights"]
 POINT = ["taxi-chip.point-serial"]
 
 
