@@ -29,6 +29,8 @@ import logging
 import os
 import threading
 import time
+import types
+from collections import deque
 from pilosa_tpu.utils.locks import make_lock
 from dataclasses import dataclass, field as dc_field
 from datetime import datetime
@@ -289,6 +291,18 @@ def prefetch_pendings(staged) -> None:
                         fn()
                     except Exception:
                         pass  # transfer still happens in finalize
+
+
+def _drive(steps):
+    """Take a resumable dispatch (`Executor._dispatch_query`) to its
+    end on the spot: a query that is alone blocks on each count fetch
+    of its GroupBy as the level loop comes to it, with nothing queued
+    behind. Returns what the generator returns."""
+    try:
+        while True:
+            next(steps)
+    except StopIteration as end:
+        return end.value
 
 
 class _BatchInFlight:
@@ -1261,17 +1275,28 @@ class Executor:
         # the TPU analog of the reference streaming per-shard results
         # into reduceFn as they arrive (executor.go:2277).
         with self._profiled(profile):
-            idx, staged, opts = self._dispatch_query(index_name, query,
-                                                     shards)
+            idx, staged, opts = _drive(self._dispatch_query(
+                index_name, query, shards))
             prefetch_pendings(staged)
             return self._finalize_staged(idx, staged), opts
 
     def _dispatch_query(self, index_name: str, query, shards,
                         batch_tail_writes: bool = False):
         """Parse/validate/translate and dispatch every call's device
-        program; returns (idx, staged, opts) with results still pending.
-        `batch_tail_writes`: a later query in the same batch writes, so
-        deferred reads must snapshot (see _tls.later_writes)."""
+        program: a generator that returns (idx, staged, opts) with
+        results still pending. `batch_tail_writes`: a later query in
+        the same batch writes, so deferred reads must snapshot (see
+        _tls.later_writes).
+
+        It stops where a GroupBy's level loop hands up a count fetch
+        it is about to block on (`_group_by_levels`) — only inside a
+        flush whose dispatcher gives its members turns
+        (`_batch_begin`), which puts the member's profile, dependency
+        capture and collector on the thread around every step;
+        `later_writes` is put back here, and the op's dispatchS leaves
+        out the time the call stood aside for other members. Anywhere
+        else a GroupBy blocks where it stands and the first step is the
+        last (`_drive`)."""
         with TIMELINE.phase("plan"):
             if isinstance(query, str):
                 query = parse_string_cached(query)
@@ -1299,20 +1324,36 @@ class Executor:
                 # it. The op node's dispatchS is the span's reading:
                 # its own segments plus what interrupted them.
                 sp = TIMELINE.phase("plan", op=call.name)
+                aside = 0.0     # seconds other members had the thread
                 try:
                     with sp:
                         self._translate_call(idx, call)
                         # Deferred reads (TopN chunking) consult this to
                         # know whether lazily re-reading fragment state
                         # in finalize is still safe.
-                        self._tls.later_writes = batch_tail_writes or any(
-                            _peel_options(c).name in _WRITE_CALLS
-                            for c in calls[i + 1:])
-                        staged.append((call, self._execute_call(
-                            idx, call, shards, opts)))
+                        later = self._tls.later_writes = \
+                            batch_tail_writes or any(
+                                _peel_options(c).name in _WRITE_CALLS
+                                for c in calls[i + 1:])
+                        res = self._execute_call(idx, call, shards, opts)
+                        if isinstance(res, types.GeneratorType):
+                            # A GroupBy's level loop: each fetch it is
+                            # about to block on goes up to the driver.
+                            covered = None
+                            while True:
+                                try:
+                                    dev = res.send(covered)
+                                except StopIteration as end:
+                                    res = end.value
+                                    break
+                                t0 = time.perf_counter()
+                                covered = yield dev
+                                aside += time.perf_counter() - t0
+                                self._tls.later_writes = later
+                        staged.append((call, res))
                 finally:
                     if op is not None:
-                        prof.end_op(op, sp.elapsed())
+                        prof.end_op(op, sp.elapsed() - aside)
         finally:
             self._tls.later_writes = False
         return idx, staged, opts
@@ -1392,7 +1433,6 @@ class Executor:
             else [None] * len(requests)
         deps_l = list(deps) if deps is not None \
             else [None] * len(requests)
-        staged_q: List[Any] = []
         out: List[Any] = [None] * len(requests)
         # Parse ONCE per request (the parsed tree is handed straight to
         # _dispatch_query — no second parse/clone) and pre-scan for
@@ -1423,30 +1463,68 @@ class Executor:
         # uncollected — so every read observes exactly the fragment
         # state sequential execution would have shown it.
         fuser = FusionCollector(self)
+        # GroupBy members take turns at their count fetches. A member
+        # runs until its level loop is about to block (its program is
+        # launched, its counts' copy started), waits in `turns` — the
+        # earliest launched first, which is the order the device runs
+        # them in — and the next member starts, up to
+        # GROUPBY_INFLIGHT_MEMBERS; then the dispatcher blocks with the
+        # member at the head, whose fetch completes first, while the
+        # others' programs run behind it. All on this thread.
+        done: List[Any] = [None] * len(requests)
+        turns: deque = deque()
+        self._tls.turns = True      # read by `_execute_group_by`
+
+        def step(j, steps, covered=None):
+            """Member j to its next count fetch or to its end, with
+            what one member has of the thread put there around it."""
+            fusing = contextlib.nullcontext() if has_writes[j] \
+                else self._fusing(fuser)
+            try:
+                with self._profiled(profs[j]), \
+                        self._dep_capture(deps_l[j]), fusing:
+                    steps.send(covered)
+            except StopIteration as end:
+                done[j] = end.value
+            except Exception as e:
+                out[j] = e
+            else:
+                turns.append((j, steps))
+
+        def drain(keep=0):
+            while len(turns) > keep:
+                j, steps = turns.popleft()
+                step(j, steps, covered=bool(turns))
+
         try:
             for j, (index_name, _, shards) in enumerate(requests):
                 if parsed[j] is None:
                     continue
+                # A write is a fence for the members in flight as it is
+                # for the collector's groups: all of them run to their
+                # ends before it dispatches, and it to its own before
+                # the next member starts.
+                drain(0 if has_writes[j]
+                      else self.GROUPBY_INFLIGHT_MEMBERS - 1)
                 try:
                     if has_writes[j]:
                         fuser.flush()
-                    with self._profiled(profs[j]), \
-                            self._dep_capture(deps_l[j]):
-                        if has_writes[j]:
-                            ctx = contextlib.nullcontext()
-                        else:
-                            ctx = self._fusing(fuser)
-                        with ctx:
-                            staged_q.append(
-                                (j, self._dispatch_query(
-                                    index_name, parsed[j], shards,
-                                    batch_tail_writes=writes_after[j])))
                 except Exception as e:
                     out[j] = e
+                    continue
+                step(j, self._dispatch_query(
+                    index_name, parsed[j], shards,
+                    batch_tail_writes=writes_after[j]))
+                if has_writes[j]:
+                    drain()
+            drain()
         finally:
+            self._tls.turns = False
             # Groups must resolve before any result is consumed —
             # prefetch/finalize below read through FusedEval handles.
             fuser.flush()
+        # In the order of the requests, whatever order they ended in.
+        staged_q = [(j, d) for j, d in enumerate(done) if d is not None]
         for _, (_, staged, _) in staged_q:
             prefetch_pendings(staged)
         return _BatchInFlight(staged_q, out, profs, deps_l)
@@ -1731,6 +1809,10 @@ class Executor:
     def _execute_call(self, idx: Index, call: Call,
                       shards: Optional[Sequence[int]],
                       opts: Optional["ExecOptions"] = None) -> Any:
+        """The call's result, a `_Pending` over it, or — a GroupBy,
+        bare or under Options, inside a flush that gives its members
+        turns — its level loop, a generator that `_dispatch_query`
+        runs."""
         name = call.name
         cap = getattr(self._tls, "deps", None)
         if cap is not None and name != "Count" \
@@ -3964,6 +4046,14 @@ class Executor:
     GROUPBY_CHUNK_BYTES = int(os.environ.get("PILOSA_TPU_GROUPBY_CHUNK_BYTES",
                                              256 << 20))
 
+    # GroupBy members of one flush that may stand at a count fetch at
+    # once (`_batch_begin`): each holds its level's prefixes (up to
+    # GROUPBY_CHUNK_BYTES) and a queued `groupby_sum` its temporaries
+    # (1.1 GB a device at 512 lanes). Read on `ssb-chip.flights`
+    # (PERF.md §6, PR 45): 2 → 40.0, 4 → 42.6, 8 → 44.5 answers a
+    # second (the parent 35.0), `hbm_peak_gb` 4.49 → 5.1 of 16 at 8.
+    GROUPBY_INFLIGHT_MEMBERS = 8
+
     # Bytes of group masks one `groupby_sum` launch gathers ([g, S, W]
     # u32): 512 groups of 16 shards. A launch's masks are read
     # ceil(planes / GROUPSUM_PLANES) times; four filters a pass is what
@@ -3994,13 +4084,26 @@ class Executor:
             raise ExecutionError(f"field {fname} is not an int field")
         return field, bsig
 
+    def _execute_group_by(self, idx: Index, call: Call, shards):
+        """GroupBy(...): its level loop (`_group_by_levels`) taken to
+        its end here and now, every count fetch blocking as the loop
+        comes to it — unless a flush's dispatcher is giving its members
+        turns on this thread (`_batch_begin`): then the loop itself,
+        for `_dispatch_query` to hand its fetches up."""
+        levels = self._group_by_levels(idx, call, shards)
+        if getattr(self._tls, "turns", False):
+            return levels
+        return _drive(levels)
+
     # graftlint: materialize — GroupBy is level-synchronous by design:
     # the host reads each depth's [P, R] count matrix to prune empty
     # prefixes, page (`previous`), and decide HBM spills before
     # expanding the next level. Those per-level fetches ARE the
-    # algorithm's materialization boundary (see docstring below).
-    def _execute_group_by(self, idx: Index, call: Call, shards
-                          ) -> List[GroupCount]:
+    # algorithm's materialization boundary (see docstring below): each
+    # blocks in `_host`, after the loop has handed the array to its
+    # driver and been resumed — `_drive` for a lone GroupBy, the turns
+    # of `_batch_begin` inside a flush.
+    def _group_by_levels(self, idx: Index, call: Call, shards):
         """Cross-product of Rows() children with intersection counts
         (reference executeGroupByShard, executor.go:1062 + groupByIterator
         :2820). TPU shape: level-synchronous — ALL prefixes at a depth
@@ -4047,7 +4150,25 @@ class Executor:
         them and into the level's bank (`_Frontier`), and the [p*R, S, W]
         cross product is never written. No `jnp` call and no indexing
         of a device array happens outside a jit here
-        (tests/test_groupby_programs.py holds it to that)."""
+        (tests/test_groupby_programs.py holds it to that).
+
+        A generator: the ONE level loop, resumable at its blocking
+        fetches. Before each — the pruning sweep's counts,
+        `groupby_cnt0`'s, every chunk's of `groupby_exp` and
+        `groupby_cntN`, a spilling level's prefixes — the program is
+        launched and the copy of its output started, and the loop
+        yields the device array; it blocks on it (`d2h`) when it is
+        resumed, and is sent whether another member's level program was
+        queued behind it then (`executor.groupby_fetches{covered:…}`).
+        A GroupBy that is alone is resumed at once (`_drive`, from
+        `_execute_group_by`: execute(), the coalescer's direct path, a
+        cluster node's per-shard call): the same launches and fetches
+        in the same order as a loop that never stopped. In a flush
+        `_batch_begin` resumes the member whose fetch was launched
+        earliest, so the device runs the other members' programs
+        through this one's round trip. Returns, as the generator's
+        value, the groups, or a `_Pending` over the sums of
+        `aggregate=Sum`."""
         import jax
         import jax.numpy as jnp
         from pilosa_tpu.ops.bitset import masked_row_counts, popcount
@@ -4128,9 +4249,19 @@ class Executor:
                     return fn(*args)
             return call
 
+        fetches = [0, 0]    # blocking fetches: [uncovered, covered]
+
         def _host(dev):
             # GroupBy iterates on the host: each depth's counts are
-            # fetched before the next is planned.
+            # fetched before the next is planned. The program is
+            # launched and its output's copy started; the loop stops
+            # here until its driver says this fetch is the one to block
+            # on (at once when the GroupBy is alone; in a flush, when
+            # it is the earliest launched of the members' fetches), and
+            # is told whether another member's program is queued behind.
+            dev.copy_to_host_async()
+            covered = yield dev
+            fetches[bool(covered)] += 1
             with transfer("d2h", int(dev.nbytes)):
                 # graftlint: disable=GL003 — GroupBy frontier pruning
                 # is a host decision by design: one count vector per
@@ -4160,11 +4291,11 @@ class Executor:
                     lambda b, f: masked_row_counts(b[..., :wmin],
                                                    f[..., :wmin]),
                     level="prune", rows=len(ids))
-                met = _host(sweep(bank.array, filter_words))[
+                met = (yield from _host(sweep(bank.array, filter_words)))[
                     child_slots[d]] > 0
                 kept = [r for r, m in zip(ids, met) if m]
                 if not kept:
-                    self._note_group_by(0, levels[0])
+                    self._note_group_by(0, levels[0], fetches)
                     return []
                 pad = -len(kept) % 8
                 child_rows[d] = (fname, kept + [-1] * pad)
@@ -4218,7 +4349,8 @@ class Executor:
             R = len(ids)
             slots = upload(child_slots[depth])
             if frontier is None:
-                keep_idx = np.flatnonzero(_host(count_rows(bank, slots)))
+                keep_idx = np.flatnonzero(
+                    (yield from _host(count_rows(bank, slots))))
                 frontier = _Frontier(
                     None, None, bank, child_slots[depth][keep_idx],
                     [(int(ids[i]),) for i in keep_idx])
@@ -4241,7 +4373,7 @@ class Executor:
             for c0 in range(0, len(frontier.rows), chunk_p):
                 pre, counts = count_level("exp", frontier, c0, chunk_p,
                                           bank, slots)
-                nz = _host(counts).ravel() > 0
+                nz = (yield from _host(counts)).ravel() > 0
                 keep_idx = np.flatnonzero(nz)
                 if len(keep_idx) == 0:
                     continue
@@ -4266,10 +4398,11 @@ class Executor:
                 if not spilled and kept_bytes > self.GROUPBY_CHUNK_BYTES:
                     spilled = True
                     self._count("executor.groupby_spills", 1)
-                    outs = [_host(o) for o in outs]
-                outs.append(_host(pre) if spilled else pre)
+                    for k, o in enumerate(outs):
+                        outs[k] = yield from _host(o)
+                outs.append((yield from _host(pre)) if spilled else pre)
             if not outs:
-                self._note_group_by(0, levels[0])
+                self._note_group_by(0, levels[0], fetches)
                 return []
             frontier = _Frontier(
                 np.concatenate(outs) if spilled else tuple(outs),
@@ -4284,7 +4417,8 @@ class Executor:
         sums = None if aggregate is None else self._GroupSums(
             self, aggregate, shards, bank, child_slots[-1], wmin, _jit)
         if frontier is None:
-            counts = _host(count_rows(bank, slots))[None, :]  # [1, R]
+            counts = (yield from _host(
+                count_rows(bank, slots)))[None, :]      # [1, R]
             prefix_rows = [()]
         else:
             counts = None
@@ -4301,7 +4435,7 @@ class Executor:
             if counts is None:
                 pre, dev = count_level("cntN", frontier, c0, chunk_p,
                                        bank, slots)
-                chunk_counts = _host(dev)  # [p, R]
+                chunk_counts = yield from _host(dev)    # [p, R]
             else:
                 chunk_counts = counts[c0:c0 + chunk_p]
             first = len(results)
@@ -4328,7 +4462,7 @@ class Executor:
                     picked.append((pi, int(ri)))
             if sums is not None and picked:
                 sums.launch(pre, picked, results[first:])
-        self._note_group_by(len(results), levels[0])
+        self._note_group_by(len(results), levels[0], fetches)
         if sums is None or not results:
             return results
 
@@ -4352,11 +4486,19 @@ class Executor:
         if self.stats is not None:
             self.stats.count(name, n)
 
-    def _note_group_by(self, groups: int, levels: int) -> None:
+    def _note_group_by(self, groups: int, levels: int, fetches) -> None:
         """A GroupBy's counters: groups answered, level programs
-        launched (a pruning sweep, an expansion, a count)."""
+        launched (a pruning sweep, an expansion, a count), and the
+        blocking fetches of its level loop as `executor.groupby_fetches
+        {covered:no|yes}` — `yes` where, as the loop blocked, another
+        member of its flush had a level program queued on the device."""
         self._count("executor.groupby_groups", groups)
         self._count("executor.groupby_levels", levels)
+        if self.stats is not None:
+            for covered, n in zip(("no", "yes"), fetches):
+                if n:
+                    self.stats.with_tags(f"covered:{covered}").count(
+                        "executor.groupby_fetches", n)
 
     class _GroupSums:
         """The `sum` of a GroupBy's groups (`aggregate=Sum(field=f)`):

@@ -177,12 +177,16 @@ class API:
             self.stats.with_tags(f"form:{form}").count(
                 "executor.pbank_form", 0)
         # ... and a GroupBy's: groups answered, level programs, levels
-        # whose prefixes moved to host memory, and the group-sum
-        # launches of `aggregate=Sum(field=f)` with the (group, plane)
-        # rows they counted.
+        # whose prefixes moved to host memory, the group-sum launches
+        # of `aggregate=Sum(field=f)` with the (group, plane) rows they
+        # counted, and its level loop's blocking fetches by whether
+        # another member of the flush had a program queued meanwhile.
         for name in ("groupby_groups", "groupby_levels", "groupby_spills",
                      "groupsum_launches", "groupsum_plane_rows"):
             self.stats.count(f"executor.{name}", 0)
+        for covered in ("yes", "no"):
+            self.stats.with_tags(f"covered:{covered}").count(
+                "executor.groupby_fetches", 0)
         # ... and the write path's: writes applied by call, what each
         # stale bank cost the next read of it (a `bank_patch` of the
         # cells that moved, or a rebuild and why), read per write and

@@ -336,3 +336,51 @@ def test_the_dispatch_spans_say_how_a_level_was_cut(host, monkeypatch):
     assert [a["chunk_of"] for a in last] \
         == [f"{i + 1}/{len(last)}" for i in range(len(last))]
     assert all(a["lanes"] >= 8 and a["prefixes"] >= 1 for a in sums)
+
+
+# ------------- (f) a flush's GroupBy members take turns under the mesh
+
+
+# case -> (members, GROUPBY_CHUNK_BYTES or None)
+TURNS = {"four": (4, None), "nine": (9, None), "nine_in_chunks": (9, SMALL),
+         "six_spilled": (6, 1 << 15)}
+
+
+@pytest.mark.parametrize("case", list(TURNS))
+def test_a_batch_of_groupbys_on_the_host_answers_as_each_alone(
+        host, monkeypatch, case):
+    """PR 45: inside `execute_batch` the members' level loops are
+    resumed in turn (tests/test_groupby_overlap.py); under the mesh
+    every level program and every count fetch is a four-device one. The
+    answers are the reference's, and the launches add up to what the
+    members make one by one."""
+    _, lo, parts, api = host
+    n, chunk_bytes = TURNS[case]
+    if chunk_bytes:
+        monkeypatch.setattr(Executor, "GROUPBY_CHUNK_BYTES", chunk_bytes)
+        monkeypatch.setattr(Executor, "GROUPSUM_CHUNK_BYTES", SMALL)
+    families = [list(PARENT_LONE)[(3 * i) % len(PARENT_LONE)]
+                for i in range(n)]
+    queries = [ssb.FAMILIES[f].pql(ssb.FAMILIES[f].fixed) for f in families]
+    ex = api.executor
+    alone = [_launches(ex, q) for q in queries]
+    from pilosa_tpu.utils.stats import MemStatsClient
+    keep, ex.stats = ex.stats, MemStatsClient()
+    try:
+        out = ex.execute_batch([(ssb.INDEX, q, None) for q in queries])
+        c = ex.stats.snapshot()["counters"]
+    finally:
+        ex.stats = keep
+    whole = _rows(lo, parts)
+    for family, r, (table, *_) in zip(families, out, alone):
+        assert not isinstance(r, Exception), (family, r)
+        assert _table(r[0][0]) == table == _table(ssb.answer(
+            whole, family, ssb.FAMILIES[family].fixed)), family
+    assert c["executor.groupby_levels"] == sum(a[1] for a in alone)
+    assert c["executor.groupsum_launches"] == sum(a[2] for a in alone)
+    assert c.get("executor.groupby_spills", 0) == sum(a[3] for a in alone)
+    assert (c.get("executor.groupby_spills", 0) > 0) \
+        == (chunk_bytes == 1 << 15)
+    yes = c.get("executor.groupby_fetches{covered:yes}", 0)
+    no = c.get("executor.groupby_fetches{covered:no}", 0)
+    assert yes > no > 0 and yes + no >= c["executor.groupby_levels"]
