@@ -4048,18 +4048,21 @@ class Executor:
 
     # GroupBy members of one flush that may stand at a count fetch at
     # once (`_batch_begin`): each holds its level's prefixes (up to
-    # GROUPBY_CHUNK_BYTES) and a queued `groupby_sum` its temporaries
-    # (1.1 GB a device at 512 lanes). Read on `ssb-chip.flights`
+    # GROUPBY_CHUNK_BYTES) and a queued `groupby_sum` its count vectors
+    # (50 MB at 512 lanes of 25 planes; where a device's shards are no
+    # multiple of eight also the launch's distinct rows, GROUPSUM_CHUNK_BYTES
+    # at most). Read on `ssb-chip.flights` with PR 45's sum program
     # (PERF.md §6, PR 45): 2 → 40.0, 4 → 42.6, 8 → 44.5 answers a
     # second (the parent 35.0), `hbm_peak_gb` 4.49 → 5.1 of 16 at 8.
     GROUPBY_INFLIGHT_MEMBERS = 8
 
-    # Bytes of group masks one `groupby_sum` launch gathers ([g, S, W]
-    # u32): 512 groups of 16 shards. A launch's masks are read
-    # ceil(planes / GROUPSUM_PLANES) times; four filters a pass is what
-    # one bank-reading fusion takes before it splits (PERF.md §6, PR 29).
+    # The groups of one `groupby_sum` launch, as bytes of operand rows:
+    # a device's share of one row [S, W] u32 a group — 512 groups of 16
+    # shards. It bounds how long one launch holds the device while a
+    # flush's other members queue behind it, and what a launch may copy: where XLA keeps a bank eight rows to a
+    # tile (a device's shards no multiple of eight) the launch's distinct
+    # rows are cut out first, a row a group at most (`ops/groupsum.py`).
     GROUPSUM_CHUNK_BYTES = 1 << 30
-    GROUPSUM_PLANES = 4
 
     def _group_by_aggregate(self, idx: Index, call: Call):
         """(field, bsiGroup) of a GroupBy's `aggregate=Sum(field=f)`,
@@ -4124,12 +4127,13 @@ class Executor:
         ascending child by child, `limit` and `previous` act on groups
         as ever. Anything but `Sum(field=<int field>)` is an error.
         The sums are computed on the device after the last level, for
-        the groups the answer holds and no others: their masks
-        (prefix ∧ row ∧ not-null) are gathered once, GROUPSUM_CHUNK_BYTES
-        of them a launch, and every pass over them counts
-        GROUPSUM_PLANES bit planes of `f` at once (`groupby_sum`:
-        ceil(planes / GROUPSUM_PLANES) passes over a launch's masks,
-        never a launch a group); the host weighs the plane counts.
+        the groups the answer holds and no others (`groupby_sum`, up to
+        GROUPSUM_CHUNK_BYTES of groups a launch, never a launch a
+        group): a group's mask (prefix ∧ row ∧ not-null) is formed on
+        chip a word tile at a time from rows read where they lie, and
+        counted against every bit plane of `f` before the tile is
+        dropped — no mask is written to HBM (`ops/groupsum.py`); the
+        host weighs the plane counts.
 
         With a filter, a child whose row stack is larger than a chunk
         (GROUPBY_CHUNK_BYTES) is first counted against the filter in ONE
@@ -4506,18 +4510,25 @@ class Executor:
         picked, fetched together when the answer is finalized.
 
         One `groupby_sum` launch takes up to GROUPSUM_CHUNK_BYTES of
-        groups: it gathers their masks — prefix ∧ row ∧ f's not-null
-        plane, [g, S, W] — from the last level's prefixes, the last
-        child's resident bank and f's plane bank by three index
-        vectors, and counts |mask ∧ plane| for every bit plane
-        of f, GROUPSUM_PLANES planes a pass over the masks
-        (`masked_row_counts_multi`, the sweep group's kernel with the
-        masks as its bank and the planes as its filters). g is padded to
-        a few sizes, so a family of queries meets a few shapes. The
-        counts come back as u32 [planes + 1, g] (the last row |mask|,
-        the columns with a value); the host weighs them: a plane's
-        count << its bit, plus the field's offset (bsiGroup.min, which
-        a signed field's is negative) times the columns with a value."""
+        groups. Its program (`ops/groupsum.group_plane_counts`, a
+        Pallas kernel; interpreted off a TPU) reads the last level's
+        prefixes, the last child's resident bank and f's plane bank by
+        three index vectors, a word tile at a time: a tile of f's
+        planes is held on chip, each group's mask — prefix ∧ row ∧ f's
+        not-null plane — is formed there from its two operand tiles,
+        and |mask ∧ plane| of every bit plane and |mask| are added into
+        per-(group, plane) count vectors before the tile is dropped;
+        lanes are reduced once a launch. No [g, S, W] mask array is
+        written; f's planes are fetched once a block of groups, a
+        group's row and prefix once a run of groups that name the same
+        one (`executor.groupsum_operand_rows` counts those fetches).
+        Under a mesh each device runs it over its own shards and one
+        `psum` adds the counts. g is padded to a few sizes, so a family
+        of queries meets a few shapes. The counts come back as u32
+        [planes + 1, g] (the last row |mask|, the columns with a
+        value); the host weighs them: a plane's count << its bit, plus
+        the field's offset (bsiGroup.min, which a signed field's is
+        negative) times the columns with a value."""
 
         def __init__(self, ex, aggregate, shards, bank, slots, wmin, jit):
             field, self.bsig = aggregate
@@ -4533,28 +4544,36 @@ class Executor:
             self.pending = []   # (device counts [planes + 1, g], groups)
 
         def launch(self, pre, picked, groups) -> None:
-            import jax.numpy as jnp
-            from pilosa_tpu.ops.bitset import (masked_row_counts_multi,
-                                               popcount)
-            depth, w, k = self.depth, self.width, Executor.GROUPSUM_PLANES
-            # A launch's masks [g, S, w], by what ONE device holds.
-            g_max = max(1, Executor.GROUPSUM_CHUNK_BYTES
-                        // self.ex._bank_device_bytes(
-                            (1, self.bank.shape[-2], w)))
+            import jax
+            from pilosa_tpu.ops.groupsum import (MAX_LANES,
+                                                 group_plane_counts,
+                                                 rows_fetched, tile_words)
+            depth, w = self.depth, self.width
+            # A launch's groups, by a device's share of one row [S, w]
+            # a group — and, where rows are narrow, by the kernel's own
+            # limit.
+            row_bytes = self.ex._bank_device_bytes(
+                (1, self.bank.shape[-2], w))
+            g_max = min(MAX_LANES, max(
+                1, Executor.GROUPSUM_CHUNK_BYTES // row_bytes))
+            # Word tiles the kernel walks on a device.
+            tiles = w // tile_words(w, row_bytes // (4 * w),
+                                    depth + 2 + (pre is not None))
+            mesh = self.ex.mesh
+            over = {} if mesh is None else {"mesh": mesh.mesh,
+                                            "axis": mesh.SHARD_AXIS}
+            # Off a TPU the same kernel runs interpreted: results, no
+            # speed.
+            interpret = jax.devices()[0].platform != "tpu"
 
             def run(pre, pi, bank, si, plane_bank, sel):
-                planes = pick_rows(w, (plane_bank, sel))  # [depth + 1, S, w]
-                picks = [(bank, si)] + ([] if pre is None else [(pre, pi)])
-                mask = pick_rows(w, *picks, fixed=(planes[-1],))
-                rows = [masked_row_counts_multi(
-                    mask, *(planes[j] for j in range(i, min(i + k, depth))))
-                    for i in range(0, depth, k)]
-                rows.append(popcount(mask, axis=(-2, -1))[None])
-                return jnp.concatenate(rows)     # [depth + 1, g]
+                return group_plane_counts(
+                    pre, pi, bank, si, plane_bank, sel, w,
+                    interpret=interpret, **over)    # [depth + 1, g]
 
             with TIMELINE.stage("groupby.aggregate", groups=len(picked),
                                 planes=depth + 1) as span:
-                launches = 0
+                launches = operand_rows = 0
                 for g0 in range(0, len(picked), g_max):
                     part = picked[g0:g0 + g_max]
                     g = len(part)
@@ -4581,10 +4600,15 @@ class Executor:
                              self.planes, self.sel)
                     self.pending.append((out, groups[g0:g0 + g]))
                     launches += 1
+                    # The rows the launch's kernel fetches.
+                    operand_rows += rows_fetched(
+                        None if pre is None else idx[0], idx[1],
+                        depth + 1, tiles)
                 span.set("launches", launches)
             self.ex._count("executor.groupsum_launches", launches)
             self.ex._count("executor.groupsum_plane_rows",
                            len(picked) * (depth + 1))
+            self.ex._count("executor.groupsum_operand_rows", operand_rows)
 
         def arrays(self) -> tuple:
             return tuple(out for out, _ in self.pending)

@@ -179,10 +179,12 @@ class API:
         # ... and a GroupBy's: groups answered, level programs, levels
         # whose prefixes moved to host memory, the group-sum launches
         # of `aggregate=Sum(field=f)` with the (group, plane) rows they
-        # counted, and its level loop's blocking fetches by whether
+        # counted and the operand rows their kernel fetched for them, and
+        # its level loop's blocking fetches by whether
         # another member of the flush had a program queued meanwhile.
         for name in ("groupby_groups", "groupby_levels", "groupby_spills",
-                     "groupsum_launches", "groupsum_plane_rows"):
+                     "groupsum_launches", "groupsum_plane_rows",
+                     "groupsum_operand_rows"):
             self.stats.count(f"executor.{name}", 0)
         for covered in ("yes", "no"):
             self.stats.with_tags(f"covered:{covered}").count(
